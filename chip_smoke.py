@@ -1,0 +1,858 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the tree still starts on the chip.
+
+Drives the Program -> Executor training path once on the TPU this
+process finds, through the entry points a user calls
+(``fluid.Program``/``program_guard``, ``paddle_tpu.models``,
+``contrib.mixed_precision.decorate``, ``fluid.Executor(fluid.TPUPlace(0))``,
+``fluid.CompiledProgram(...).with_data_parallel``), at the full width
+of BERT-base with random weights made from a seed, and checks what
+comes out against the repo's own references. ONE process, no children:
+a chip belongs to one process at a time.
+
+    python3 chip_smoke.py                 # on a TPU host; exits non-zero
+                                          # before any phase without one
+    JAX_PLATFORMS=cpu python3 chip_smoke.py --rehearse-cpu
+                                          # tiny sizes, interpret-mode
+                                          # kernels; prints platform cpu
+
+Phases (each prints one JSON line; every failed assertion or exception
+is fatal — nothing is caught and turned into a warning):
+
+  device   the first device is a TPU; kind and count reported
+  train    BERT-base pretraining, bf16 AMP, b32 x s128: startup + steps
+           on one fixed batch fed from host numpy; then the same
+           program with bench.py's three fast-path knobs
+  kernels  every kernel under ops/pallas/ compiled (Mosaic, not
+           interpret) at the shapes its callers use, against its own
+           reference under ``jax.default_matmul_precision("highest")``
+  dp4      (>= 4 devices) the BERT-base program data-parallel over four
+           chips, global batch 128, against the one-chip loss
+
+The set-up seconds and step milliseconds on the phase lines are SMOKE
+figures (a handful of steps, one window): evidence that the step runs
+and how long a cold or warm start takes, not benchmark measurements.
+
+The last stdout line is the result the driver reads:
+``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}``.
+"""
+from __future__ import annotations
+
+import argparse
+import gc
+import json
+import os
+import sys
+import time
+
+import numpy as np
+
+SEED = 2024
+FAST_PATH_KNOBS = ("PADDLE_TPU_FUSED_OPTIMIZER", "PADDLE_TPU_FUSED_EPILOGUE",
+                   "PADDLE_TPU_ASYNC_FEED")
+
+# one model, two sizes: the repo's own BERT-base config on the chip, a
+# toy of the same shape for the CPU rehearsal
+FULL = {
+    "bert": dict(layers=12, d_model=768, heads=12, d_ff=3072, vocab=30522,
+                 batch=32, seq=128, masked=20, steps=6),
+    "gpt": dict(layers=2, d_model=1024, heads=16, vocab=8192, batch=2,
+                seq=4096, steps=3),
+    "flash": dict(b=1, h=4, s=4096, d=64),
+    "masked": dict(b=64, h=8, s=256, d=64),
+    "opt_elems": 2 * 1024 * 1024,
+    "conv": dict(batch=8, hw=28, cin=128, cout_1x1=512, cout_3x3=128),
+    "decode": dict(streams=5, max_tokens=12),
+    "dp_steps": 4,
+}
+REHEARSAL = {
+    "bert": dict(layers=2, d_model=64, heads=4, d_ff=128, vocab=512,
+                 batch=4, seq=16, masked=4, steps=6),
+    "gpt": dict(layers=1, d_model=64, heads=4, vocab=256, batch=1,
+                seq=256, steps=2),
+    "flash": dict(b=1, h=2, s=256, d=16),
+    "masked": dict(b=2, h=2, s=128, d=16),
+    "opt_elems": 4096,
+    "conv": dict(batch=1, hw=8, cin=128, cout_1x1=128, cout_3x3=128),
+    "decode": dict(streams=3, max_tokens=6),
+    "dp_steps": 2,
+}
+
+
+class _XlaCompiles:
+    """Every executable JAX builds in this process, from jax.monitoring:
+    ``builds`` counts backend compiles (served from the persistent
+    cache or not), ``cache_hits`` those the cache served, ``seconds``
+    the time they took. The executor's own counters see fresh jit
+    closures and retraces; an XLA recompile for a changed input
+    sharding shows only here."""
+
+    def __init__(self):
+        import jax
+
+        self.builds = self.cache_hits = 0
+        self.seconds = 0.0
+        jax.monitoring.register_event_duration_secs_listener(self._timed)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _timed(self, event, seconds, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.builds += 1
+            self.seconds += seconds
+
+    def _event(self, event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.cache_hits += 1
+
+    def snapshot(self):
+        return {"xla_builds": self.builds, "cache_hits": self.cache_hits,
+                "xla_build_s": round(self.seconds, 2)}
+
+
+def _since(now, then):
+    return {k: round(now[k] - then[k], 2) for k in now}
+
+
+def _emit(phase, device, **fields):
+    rec = {"phase": phase, "smoke": True}
+    rec.update(device)
+    rec.update(fields)
+    print(json.dumps(rec), flush=True)
+
+
+def _mosaic_calls(lowered) -> int:
+    return lowered.as_text().count("tpu_custom_call")
+
+
+def _rel_err(got, ref) -> float:
+    """max |got - ref| over max |ref| — one number per tensor, robust
+    to the near-zero entries a plain rtol chokes on."""
+    got = np.asarray(got, np.float32)
+    ref = np.asarray(ref, np.float32)
+    assert got.shape == ref.shape, (got.shape, ref.shape)
+    assert np.all(np.isfinite(got)), "non-finite kernel output"
+    return float(np.max(np.abs(got - ref)) / max(np.max(np.abs(ref)), 1e-30))
+
+
+# ---------------------------------------------------------------------------
+# programs
+# ---------------------------------------------------------------------------
+
+
+def build_bert(cfg, batch):
+    """BERT-base masked-LM pretraining step: models.bert_base_pretrain
+    + softmax-CE over the masked positions, Adam 1e-4, bf16 AMP."""
+    import paddle_tpu as fluid
+    from paddle_tpu import models
+    from paddle_tpu.contrib import mixed_precision as mp
+
+    T, M, V = cfg["seq"], cfg["masked"], cfg["vocab"]
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = SEED
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        src = fluid.data(name="src", shape=[batch, T], dtype="int64")
+        pos = fluid.data(name="pos", shape=[batch, T], dtype="int64")
+        mpos = fluid.data(name="mpos", shape=[batch, M], dtype="int64")
+        labels = fluid.data(name="labels", shape=[batch, M, 1],
+                            dtype="int64")
+        logits = models.bert_base_pretrain(
+            src, pos, mpos, vocab_size=V, max_len=T,
+            num_layers=cfg["layers"], num_heads=cfg["heads"],
+            d_model=cfg["d_model"], d_ff=cfg["d_ff"])
+        loss = fluid.layers.mean(fluid.layers.softmax_with_cross_entropy(
+            fluid.layers.reshape(logits, [batch * M, V]),
+            fluid.layers.reshape(labels, [batch * M, 1])))
+        mp.decorate(fluid.optimizer.AdamOptimizer(1e-4)).minimize(loss)
+    return main, startup, loss
+
+
+def bert_feed(cfg, batch):
+    rng = np.random.RandomState(0)
+    T, M, V = cfg["seq"], cfg["masked"], cfg["vocab"]
+    return {
+        "src": rng.randint(0, V, (batch, T)).astype("int64"),
+        "pos": np.tile(np.arange(T), (batch, 1)).astype("int64"),
+        "mpos": rng.randint(0, T, (batch, M)).astype("int64"),
+        "labels": rng.randint(0, V, (batch, M, 1)).astype("int64"),
+    }
+
+
+def build_gpt_long(cfg):
+    """The gpt_long cell: a small causal LM at long sequence whose
+    attention is the flash_attention op (fwd + dQ + dK/dV kernels)."""
+    import paddle_tpu as fluid
+    from paddle_tpu import layers
+    from paddle_tpu.contrib import mixed_precision as mp
+
+    B, S, D, H, V = (cfg["batch"], cfg["seq"], cfg["d_model"],
+                     cfg["heads"], cfg["vocab"])
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = SEED
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        ids = fluid.data(name="ids", shape=[B, S], dtype="int64")
+        lbl = fluid.data(name="lbl", shape=[B * S, 1], dtype="int64")
+        x = layers.embedding(ids, size=(V, D))
+
+        def heads(t):
+            t = layers.reshape(t, [B, S, H, D // H])
+            return layers.transpose(t, [0, 2, 1, 3])
+
+        for _ in range(cfg["layers"]):
+            h = layers.layer_norm(x)
+            q, k, v = (layers.fc(h, D, num_flatten_dims=2)
+                       for _ in range(3))
+            ctx = layers.flash_attention(heads(q), heads(k), heads(v),
+                                         causal=True)
+            ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
+                                 [B, S, D])
+            x = x + layers.fc(ctx, D, num_flatten_dims=2)
+            m = layers.fc(layers.layer_norm(x), D * 4, num_flatten_dims=2,
+                          act="gelu")
+            x = x + layers.fc(m, D, num_flatten_dims=2)
+        logits = layers.fc(layers.layer_norm(x), V, num_flatten_dims=2)
+        loss = layers.mean(layers.softmax_with_cross_entropy(
+            layers.reshape(logits, [B * S, V]), lbl))
+        mp.decorate(fluid.optimizer.AdamOptimizer(1e-4)).minimize(loss)
+    rng = np.random.RandomState(1)
+    feed = {"ids": rng.randint(0, V, (B, S)).astype("int64"),
+            "lbl": rng.randint(0, V, (B * S, 1)).astype("int64")}
+    return main, startup, loss, feed
+
+
+# ---------------------------------------------------------------------------
+# one-device training driver
+# ---------------------------------------------------------------------------
+
+
+def _counters(obs):
+    return {
+        "compiled": obs.counter_value("executor.steps", path="compiled") or 0,
+        "interpreter": obs.counter_value("executor.steps",
+                                         path="interpreter") or 0,
+        "fallbacks": obs.counter_value("executor.compile_fallbacks") or 0,
+        "compiles": obs.counter_value("executor.compiles") or 0,
+        "traces": obs.counter_value("executor.jit_traces") or 0,
+    }
+
+
+def train_one_device(main, startup, loss, feed, steps, platform, xla,
+                     param_probe=None):
+    """startup + ``steps`` steps under Executor(TPUPlace(0)), feeding
+    host numpy every step (through the double-buffered feeder when
+    PADDLE_TPU_ASYNC_FEED is on, as bench.py does). Returns the loss
+    trajectory, set-up seconds (startup + compile + first step), the
+    steady step ms, what XLA built, the executor and the scope (kept
+    alive so the caller can lower the step), and asserts the path the
+    steps took."""
+    import jax
+
+    import paddle_tpu as fluid
+    from paddle_tpu import observability as obs
+    from paddle_tpu.core.native_feed import (AsyncDeviceFeeder,
+                                             async_feed_enabled)
+
+    scope = fluid.Scope()
+    exe = fluid.Executor(fluid.TPUPlace(0))
+    device = fluid.TPUPlace(0).jax_device()
+    losses, step_s = [], []
+    x0 = xla.snapshot()
+    with fluid.scope_guard(scope):
+        t0 = time.perf_counter()
+        exe.run(startup)
+        c0 = _counters(obs)
+        if async_feed_enabled():
+            batches = AsyncDeviceFeeder(feed for _ in range(steps))
+        else:
+            batches = (feed for _ in range(steps))
+        c1 = None
+        for batch in batches:
+            t = time.perf_counter()
+            (out,) = exe.run(main, feed=batch, fetch_list=[loss],
+                             return_numpy=False)
+            jax.block_until_ready(out.array)
+            step_s.append(time.perf_counter() - t)
+            losses.append(float(np.asarray(out.array).ravel()[0]))
+            if c1 is None:
+                setup_s = time.perf_counter() - t0
+                c1, x1 = _counters(obs), xla.snapshot()
+        c_end = _counters(obs)
+
+        # the path every step took
+        assert len(losses) == steps
+        assert all(np.isfinite(losses)), losses
+        assert losses[-1] < losses[0], "loss did not fall: %r" % (losses,)
+        assert c_end["compiled"] - c0["compiled"] == steps, (c0, c_end)
+        assert c_end["interpreter"] == c0["interpreter"], (c0, c_end)
+        assert c_end["fallbacks"] == 0, c_end
+        # one compile (and one trace) for the program, none after step 1
+        assert c1["compiles"] - c0["compiles"] == 1, (c0, c1)
+        assert c1["traces"] - c0["traces"] == 1, (c0, c1)
+        assert (c_end["compiles"], c_end["traces"]) == \
+            (c1["compiles"], c1["traces"]), (c1, c_end)
+        # ... and XLA built no executable after it either
+        assert xla.builds == x1["xla_builds"], (x1, xla.snapshot())
+        # where the results live
+        assert {d.platform for d in out.array.devices()} == {platform}, \
+            out.array.devices()
+        if param_probe is not None:
+            p = scope.find_var(param_probe).raw().array
+            assert p.devices() == {device}, (param_probe, p.devices())
+    return {"losses": losses, "setup_s": setup_s,
+            "step_ms": 1e3 * float(np.mean(step_s[1:])),
+            "xla": _since(x1, x0), "exe": exe, "scope": scope}
+
+
+def _first_param(main):
+    return next(n for n, v in main.global_block().vars.items()
+                if getattr(v, "persistable", False)
+                and type(v).__name__ == "Parameter")
+
+
+def _peak_bytes(device, platform):
+    stats = device.memory_stats() or {}
+    peak = int(stats.get("peak_bytes_in_use", 0))
+    if platform == "tpu":
+        assert peak > 0, "device reports no peak_bytes_in_use: %r" % stats
+    return peak
+
+
+# ---------------------------------------------------------------------------
+# phases
+# ---------------------------------------------------------------------------
+
+
+def phase_train(sizes, dev_rec, platform, xla):
+    import jax
+
+    import paddle_tpu as fluid
+    from paddle_tpu.core.enforce import PreconditionNotMetError
+
+    cfg = sizes["bert"]
+    feed = bert_feed(cfg, cfg["batch"])
+    for k in FAST_PATH_KNOBS:
+        os.environ.pop(k, None)
+
+    main, startup, loss = build_bert(cfg, cfg["batch"])
+    probe = _first_param(main)
+    base = train_one_device(main, startup, loss, feed, cfg["steps"],
+                            platform, xla, param_probe=probe)
+    peak = _peak_bytes(jax.devices()[0], platform)
+    _emit("train", dev_rec, program="bert_base", path="default",
+          setup_s=round(base["setup_s"], 2),
+          step_ms=round(base["step_ms"], 2),
+          losses=[round(x, 5) for x in base["losses"]],
+          peak_bytes_in_use=peak,
+          ops=len(main.global_block().ops), **base["xla"])
+    del base["exe"], base["scope"]
+    gc.collect()
+
+    # the same program as bench.py runs it: fused optimizer (auto ->
+    # flat layout -> Pallas on TPU), fused epilogues, async host feed
+    for k in FAST_PATH_KNOBS:
+        os.environ[k] = "1"
+    try:
+        fmain, fstartup, floss = build_bert(cfg, cfg["batch"])
+        fast = train_one_device(fmain, fstartup, floss, feed,
+                                cfg["steps"], platform, xla)
+        ops = [op.type for op in fmain.global_block().ops]
+        assert "fused_optimizer" in ops, "fused optimizer pass did not run"
+        layout = ("flat" if getattr(fmain, "_sharded_flat_layout", None)
+                  else "chain")
+        with fluid.scope_guard(fast["scope"]):
+            n_mosaic = _mosaic_calls(
+                fast["exe"].lower(fmain, feed=feed, fetch_list=[floss]))
+    finally:
+        for k in FAST_PATH_KNOBS:
+            os.environ.pop(k, None)
+    if platform == "tpu":
+        # the compiled step holds the fused-optimizer kernel as a Mosaic
+        # call over the flat buffer — not its XLA twin
+        assert layout == "flat", layout
+        assert n_mosaic >= 1, "no Mosaic call in the fused BERT step"
+    # same bound tools/sc_smoke.py holds the fused path to on CPU: the
+    # fused ops evaluate the same expressions, so six steps of an
+    # iterated system may drift by rounding only
+    drift = max(abs(a - b) / abs(b)
+                for a, b in zip(fast["losses"], base["losses"]))
+    assert drift < 1e-3, (drift, fast["losses"], base["losses"])
+    _emit("train", dev_rec, program="bert_base", path="fast",
+          setup_s=round(fast["setup_s"], 2),
+          step_ms=round(fast["step_ms"], 2),
+          losses=[round(x, 5) for x in fast["losses"]],
+          loss_drift_vs_default=drift, optimizer_layout=layout,
+          mosaic_calls=n_mosaic, ops=len(ops), **fast["xla"])
+    del fast
+    gc.collect()
+
+    # Executor(CPUPlace()) on the chip host: the kernels follow where
+    # the computation runs, so a flash_attention + fused-optimizer
+    # program must run as plain XLA on the host — or the place raises
+    # its typed error where this process has no CPU backend
+    try:
+        fluid.CPUPlace().jax_device()
+    except PreconditionNotMetError as e:
+        cpu_place = "unavailable: %s" % e
+    else:
+        cpu_place = _cpu_place_program()
+    _emit("train", dev_rec, program="cpu_place_probe", cpu_place=cpu_place)
+    return base["losses"]
+
+
+def _cpu_place_program():
+    """A tiny flash_attention + Adam program through
+    Executor(CPUPlace()) with the fused-optimizer knob on."""
+    import paddle_tpu as fluid
+    from paddle_tpu import layers
+
+    os.environ["PADDLE_TPU_FUSED_OPTIMIZER"] = "1"
+    try:
+        main, startup = fluid.Program(), fluid.Program()
+        startup.random_seed = SEED
+        with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+            x = fluid.data(name="x", shape=[2, 2, 128, 16],
+                           dtype="float32")
+            q = layers.fc(x, 16, num_flatten_dims=3)
+            k = layers.fc(x, 16, num_flatten_dims=3)
+            o = layers.flash_attention(q, k, x, causal=True)
+            loss = layers.mean(layers.square(o))
+            fluid.optimizer.AdamOptimizer(1e-2).minimize(loss)
+        feed = {"x": np.random.RandomState(3).randn(
+            2, 2, 128, 16).astype("float32")}
+        scope = fluid.Scope()
+        with fluid.scope_guard(scope):
+            exe = fluid.Executor(fluid.CPUPlace())
+            exe.run(startup)
+            vals = []
+            for _ in range(2):
+                (out,) = exe.run(main, feed=feed, fetch_list=[loss],
+                                 return_numpy=False)
+                vals.append(float(np.asarray(out.array)))
+            assert all(np.isfinite(vals)), vals
+            assert {d.platform for d in out.array.devices()} == {"cpu"}
+            n = _mosaic_calls(exe.lower(main, feed=feed,
+                                        fetch_list=[loss]))
+            assert n == 0, "Mosaic call in a CPU-place computation"
+    finally:
+        os.environ.pop("PADDLE_TPU_FUSED_OPTIMIZER", None)
+    return "ran on cpu, xla path, loss %.5f -> %.5f" % tuple(vals)
+
+
+def phase_kernels(sizes, dev_rec, platform, xla):
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    fo = importlib.import_module("paddle_tpu.ops.pallas.fused_optimizer")
+    pa = importlib.import_module("paddle_tpu.ops.pallas.paged_attention")
+    cv = importlib.import_module("paddle_tpu.ops.pallas.conv")
+    on_tpu = platform == "tpu"
+    t_phase, x_phase = time.perf_counter(), xla.snapshot()
+    report = {}
+
+    def check_mosaic(name, fn, args, want):
+        """On the chip the jitted comparison must hold the kernel as a
+        Mosaic custom call; interpret mode cannot pass for it."""
+        n = _mosaic_calls(jax.jit(fn).lower(*args))
+        if on_tpu:
+            assert n >= want, "%s: %d Mosaic calls, want >= %d" % (
+                name, n, want)
+        return n
+
+    # -- flash fwd + dQ + dK/dV through a gpt_long step ---------------------
+    cfg = sizes["gpt"]
+    main, startup, loss, feed = build_gpt_long(cfg)
+    run = train_one_device(main, startup, loss, feed, cfg["steps"],
+                           platform, xla)
+    with fluid.scope_guard(run["scope"]):
+        n_step = _mosaic_calls(run["exe"].lower(main, feed=feed,
+                                                fetch_list=[loss]))
+    if on_tpu:
+        # fwd, dQ, dK/dV per layer: a dense fallback has none
+        assert n_step >= 3 * cfg["layers"], n_step
+    report["gpt_long_step"] = {"mosaic_calls": n_step,
+                               "setup_s": round(run["setup_s"], 2),
+                               "step_ms": round(run["step_ms"], 2),
+                               "losses": [round(x, 5)
+                                          for x in run["losses"]]}
+    gpt_step_ms, gpt_setup_s = run["step_ms"], run["setup_s"]
+    del run
+    gc.collect()
+
+    rng = np.random.RandomState(7)
+
+    def flash_case(name, c, causal, lengths, tol):
+        shape = (c["b"], c["h"], c["s"], c["d"])
+        q, k, v, w = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+                      for _ in range(4))
+        scale = float(c["d"]) ** -0.5
+        lens = None if lengths is None else jnp.asarray(lengths, jnp.int32)
+
+        def kernel(q, k, v):
+            def f(q, k, v):
+                o = fa.flash_attention(q, k, v, causal=causal,
+                                       force_pallas=True, lengths=lens)
+                return jnp.sum(o.astype(jnp.float32)
+                               * w.astype(jnp.float32)), o
+            (_, o), g = jax.value_and_grad(f, argnums=(0, 1, 2),
+                                           has_aux=True)(q, k, v)
+            return (o,) + g
+
+        def reference(q, k, v):
+            def f(q, k, v):
+                o = fa._dense_attention(q, k, v, causal, scale, lens)
+                return jnp.sum(o.astype(jnp.float32)
+                               * w.astype(jnp.float32)), o
+            with jax.default_matmul_precision("highest"):
+                (_, o), g = jax.value_and_grad(f, argnums=(0, 1, 2),
+                                               has_aux=True)(q, k, v)
+            return (o,) + g
+
+        n = check_mosaic(name, kernel, (q, k, v), 3)
+        got = jax.jit(kernel)(q, k, v)
+        ref = jax.jit(reference)(q, k, v)
+        errs = {t: _rel_err(g, r)
+                for t, g, r in zip(("out", "dq", "dk", "dv"), got, ref)}
+        assert max(errs.values()) < tol, (name, errs)
+        report[name] = {"mosaic_calls": n, "rel_err": errs, "tol": tol}
+
+    # bf16 in and out: the output and the three gradients are each
+    # rounded to bf16 (2^-9 of their magnitude) and P / dS pass through
+    # one bf16 MXU pass inside the kernel where the reference keeps
+    # f32 — 2e-2 of the tensor's max leaves ~4x over what that predicts
+    flash_case("flash_causal", sizes["flash"], True, None, 2e-2)
+    m = sizes["masked"]
+    lengths = rng.randint(m["s"] // 2, m["s"] + 1, (m["b"],))
+    flash_case("flash_masked", m, False, lengths, 2e-2)
+
+    # -- fused optimizer over a flat buffer vs _update_math ----------------
+    n_el = sizes["opt_elems"]
+    p, g, sa = (jnp.asarray(rng.randn(n_el), jnp.float32) for _ in range(3))
+    sb = jnp.abs(jnp.asarray(rng.randn(n_el), jnp.float32))
+    lr, b1p, b2p = (jnp.float32(x) for x in (1e-3, 0.81, 0.98))
+    for op_type, attrs in (("adam", {"beta1": 0.9, "beta2": 0.999,
+                                     "epsilon": 1e-8}),
+                           ("momentum", {"mu": 0.9})):
+        adam = op_type == "adam"
+        args = (p, g, lr, sa, sb if adam else None,
+                b1p if adam else None, b2p if adam else None)
+
+        def kernel(*a, op_type=op_type, attrs=attrs):
+            return fo.fused_optimizer_update(op_type, attrs, *a,
+                                             force_pallas=True)
+
+        def reference(*a, op_type=op_type, attrs=attrs):
+            return fo._update_math(op_type, attrs, *a)
+
+        n = check_mosaic("fused_" + op_type, kernel, args, 1)
+        got = [x for x in jax.jit(kernel)(*args) if x is not None]
+        ref = [x for x in jax.jit(reference)(*args) if x is not None]
+        # the kernel and the XLA lowering evaluate the SAME f32
+        # expression sequence; they may differ in how sqrt and divide
+        # round on the VPU, i.e. by a few ULP of an O(1) value
+        tol = 1e-5
+        errs = [_rel_err(a, b) for a, b in zip(got, ref)]
+        assert max(errs) < tol, (op_type, errs)
+        report["fused_" + op_type] = {"mosaic_calls": n, "elems": n_el,
+                                      "rel_err": errs, "tol": tol}
+
+    # -- paged attention at the decode engine's shapes ----------------------
+    from paddle_tpu.serving.decode import DecodeConfig, DecodeEngine
+
+    dc = DecodeConfig()
+    cc = dc.cache
+    B, nb, bt = dc.max_batch_size, cc.num_blocks, cc.block_tokens
+    H, D = cc.num_heads, cc.head_dim
+    q = rng.randn(B, H, D).astype(np.float32)
+    k_ar = rng.randn(nb, bt, H, D).astype(np.float32)
+    v_ar = rng.randn(nb, bt, H, D).astype(np.float32)
+    lens = rng.randint(0, 5 * bt, (B,)).astype(np.int32)
+    lens[0], lens[1] = 0, 5 * bt - 1          # an empty row, a long one
+    width = -(-int(lens.max()) // bt)
+    table = np.full((B, width), -1, np.int32)
+    perm = rng.permutation(nb)
+    at = 0
+    for b in range(B):
+        n_blk = -(-int(lens[b]) // bt)
+        table[b, :n_blk] = perm[at:at + n_blk]
+        at += n_blk
+    backend = "pallas" if on_tpu else "pallas_interpret"
+    got = pa.paged_decode_attention(q, k_ar, v_ar, table, lens,
+                                    block_tokens=bt, backend=backend)
+    ref = pa.paged_attention_reference(q, k_ar, v_ar, table, lens,
+                                       block_tokens=bt)
+    n = _mosaic_calls(pa._paged_pallas.lower(
+        q, k_ar, v_ar, np.maximum(table, 0), lens, block_tokens=bt,
+        scale=float(D) ** -0.5, interpret=not on_tpu))
+    if on_tpu:
+        assert n >= 1, "paged kernel lowered without a Mosaic call"
+    # f32 throughout on the VPU (no MXU pass); the running softmax
+    # re-associates the sums and exp is the hardware's — 1e-4 of max
+    err, tol = _rel_err(got, ref), 1e-4
+    assert err < tol, ("paged", err)
+    report["paged_attention"] = {"mosaic_calls": n, "rel_err": err,
+                                 "tol": tol, "shape": [B, H, D, bt, nb]}
+
+    # -- the decode engine, default backend, token-exact vs dense ----------
+    d = sizes["decode"]
+    prompts = [[int(t) for t in rng.randint(1, dc.vocab_size,
+                                            rng.randint(3, 40))]
+               for _ in range(d["streams"])]
+
+    def serve(attn_backend):
+        eng = DecodeEngine(DecodeConfig(attn_backend=attn_backend,
+                                        eos_token=None)).start()
+        try:
+            streams = [eng.submit(pr, max_tokens=d["max_tokens"])
+                       for pr in prompts]
+            return [s.result(timeout_s=600)[0] for s in streams]
+        finally:
+            eng.stop(drain=False)
+
+    compiled_before = pa._paged_pallas._cache_size()
+    t0 = time.perf_counter()
+    toks = serve(None if on_tpu else "pallas_interpret")
+    serve_s = time.perf_counter() - t0
+    kernel_shapes = pa._paged_pallas._cache_size() - compiled_before
+    assert kernel_shapes > 0, "the engine never reached the paged kernel"
+    want = serve("dense")
+    assert all(len(t) == d["max_tokens"] for t in toks), toks
+    assert toks == want, "decode streams diverge from the dense backend"
+    report["decode_engine"] = {"streams": len(prompts),
+                               "tokens": sum(map(len, toks)),
+                               "kernel_shapes_compiled": kernel_shapes,
+                               "serve_s": round(serve_s, 2),
+                               "token_exact_vs_dense": True}
+
+    # -- conv kernel (default-off, C4 deletion candidate — but while it
+    # is in the tree it compiles) at one 1x1 and one 3x3 ResNet shape ------
+    c = sizes["conv"]
+    for name, ksz, cout, pad in (("conv_1x1", 1, c["cout_1x1"], 0),
+                                 ("conv_3x3", 3, c["cout_3x3"], 1)):
+        x = jnp.asarray(rng.randn(c["batch"], c["hw"], c["hw"], c["cin"]),
+                        jnp.bfloat16)
+        w = jnp.asarray(rng.randn(ksz, ksz, c["cin"], cout)
+                        / np.sqrt(ksz * ksz * c["cin"]), jnp.bfloat16)
+        scale = jnp.asarray(1.0 + 0.1 * rng.randn(cout), jnp.float32)
+        shift = jnp.asarray(0.1 * rng.randn(cout), jnp.float32)
+
+        def kernel(x, w, scale, shift, pad=pad):
+            return cv.conv2d_bn_act(x, w, scale, shift, stride=1,
+                                    padding=pad, relu=True)
+
+        def reference(x, w, scale, shift, pad=pad):
+            with jax.default_matmul_precision("highest"):
+                y = cv._xla_conv_nhwc(x.astype(jnp.float32),
+                                      w.astype(jnp.float32), 1, pad)
+            return jnp.maximum(y * scale + shift, 0.0)
+
+        n = check_mosaic(name, kernel, (x, w, scale, shift), 1)
+        got = jax.jit(kernel)(x, w, scale, shift)
+        ref = jax.jit(reference)(x, w, scale, shift)
+        # bf16 operands into an f32 accumulator (exact products), bf16
+        # output: one rounding of 2^-9 — 1e-2 of max is ~2.5x over it
+        err, tol = _rel_err(got, ref), 1e-2
+        assert err < tol, (name, err)
+        report[name] = {"mosaic_calls": n, "rel_err": err, "tol": tol,
+                        "x": list(x.shape), "w": list(w.shape)}
+
+    _emit("kernels", dev_rec,
+          setup_s=round(gpt_setup_s, 2), step_ms=round(gpt_step_ms, 2),
+          phase_s=round(time.perf_counter() - t_phase, 2),
+          interpret=not on_tpu, kernels=report,
+          **_since(xla.snapshot(), x_phase))
+
+
+def phase_dp4(sizes, dev_rec, platform, xla):
+    """BERT-base data-parallel over four chips in this one process."""
+    import jax
+
+    import paddle_tpu as fluid
+    from paddle_tpu import observability as obs
+    from paddle_tpu.core.enforce import OutOfRangeError
+    from paddle_tpu.parallel.mesh_utils import make_mesh
+
+    cfg = sizes["bert"]
+    n = 4
+    devices = jax.devices()[:n]
+    gbatch = n * cfg["batch"]
+    feed = bert_feed(cfg, gbatch)
+    for k in FAST_PATH_KNOBS:
+        os.environ.pop(k, None)
+    x_phase = xla.snapshot()
+
+    # the one-chip loss on the SAME global batch, from the same seed
+    main1, startup1, loss1 = build_bert(cfg, gbatch)
+    probe = _first_param(main1)
+    scope1 = fluid.Scope()
+    with fluid.scope_guard(scope1):
+        exe1 = fluid.Executor(fluid.TPUPlace(0))
+        exe1.run(startup1)
+        init_probe = np.asarray(scope1.find_var(probe).raw().array)
+        (l1,) = exe1.run(main1, feed=feed, fetch_list=[loss1])
+    loss_one_chip = float(np.asarray(l1).ravel()[0])
+    del scope1, exe1
+    gc.collect()
+
+    # dp4: the program is built at the per-replica batch and fed the
+    # global one (shard_map slices the feed over the mesh)
+    main, startup, loss = build_bert(cfg, cfg["batch"])
+    mesh = make_mesh([n], ["dp"], devices)
+    scope = fluid.Scope()
+    losses, step_s = [], []
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.TPUPlace(0))
+        t0 = time.perf_counter()
+        exe.run(startup)
+        # same seed -> same weights as the one-chip run
+        np.testing.assert_array_equal(
+            np.asarray(scope.find_var(probe).raw().array), init_probe)
+        cp = fluid.CompiledProgram(main).with_data_parallel(
+            loss_name=loss.name, places=mesh)
+        coll0 = obs.counter_value("parallel.collective_ops") or 0
+        for i in range(sizes["dp_steps"]):
+            t = time.perf_counter()
+            (out,) = exe.run(cp, feed=feed, fetch_list=[loss],
+                             return_numpy=False)
+            jax.block_until_ready(out)
+            step_s.append(time.perf_counter() - t)
+            # the fetch is all-gathered: one per-replica mean each
+            losses.append(float(np.mean(np.asarray(out))))
+            if i == 0:
+                setup_s = time.perf_counter() - t0
+                x1 = xla.snapshot()
+        assert xla.builds == x1["xla_builds"], (x1, xla.snapshot())
+        coll = (obs.counter_value("parallel.collective_ops") or 0) - coll0
+        p = scope.find_var(probe).raw().array
+        shard_devices = {s.device for s in p.addressable_shards}
+
+    assert all(np.isfinite(losses)), losses
+    assert losses[-1] < losses[0], losses
+    # mean of four per-replica means of 32 == mean over 128; bf16
+    # matmuls tile a b32 and a b128 problem differently, so the sums
+    # re-associate: 2e-3 relative on a loss of ~ln(vocab)
+    tol = 2e-3
+    gap = abs(losses[0] - loss_one_chip) / abs(loss_one_chip)
+    assert gap < tol, (losses[0], loss_one_chip)
+    assert coll > 0, "no collective ops counted over %d steps" % len(losses)
+    assert shard_devices == set(devices), (shard_devices, devices)
+    assert {d.platform for d in shard_devices} == {platform}
+    peaks = [_peak_bytes(d, platform) for d in devices]
+
+    # TPUPlace(i) is chip i, and past the last chip it raises
+    place_probe = _place_probe(devices[1])
+    try:
+        fluid.TPUPlace(len(jax.devices())).jax_device()
+    except OutOfRangeError:
+        pass
+    else:
+        raise AssertionError("TPUPlace past the device count resolved")
+
+    _emit("dp4", dev_rec, program="bert_base", mesh={"dp": n},
+          global_batch=gbatch, setup_s=round(setup_s, 2),
+          step_ms=round(1e3 * float(np.mean(step_s[1:])), 2),
+          losses=[round(x, 5) for x in losses],
+          loss_one_chip=round(loss_one_chip, 5),
+          first_step_rel_gap=gap, tol=tol, collective_ops=coll,
+          param_shard_devices=sorted(str(d) for d in shard_devices),
+          peak_bytes_in_use=peaks, tpuplace_1=place_probe,
+          **_since(xla.snapshot(), x_phase))
+
+
+def _place_probe(device):
+    """An MLP step under Executor(TPUPlace(1)): feeds, parameters and
+    the loss must all live on chip 1."""
+    import paddle_tpu as fluid
+
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = SEED
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.data(name="x", shape=[16, 32], dtype="float32")
+        y = fluid.data(name="y", shape=[16, 1], dtype="float32")
+        pred = fluid.layers.fc(fluid.layers.fc(x, 64, act="relu"), 1)
+        loss = fluid.layers.mean(fluid.layers.square_error_cost(pred, y))
+        fluid.optimizer.SGD(0.05).minimize(loss)
+    rng = np.random.RandomState(5)
+    feed = {"x": rng.randn(16, 32).astype("float32"),
+            "y": rng.randn(16, 1).astype("float32")}
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.TPUPlace(1))
+        exe.run(startup)
+        for _ in range(2):
+            (out,) = exe.run(main, feed=feed, fetch_list=[loss],
+                             return_numpy=False)
+        assert out.array.devices() == {device}, out.array.devices()
+        p = scope.find_var(_first_param(main)).raw().array
+        assert p.devices() == {device}, p.devices()
+        assert np.isfinite(float(np.asarray(out.array)))
+    return "loss and parameters on %s" % device
+
+
+# ---------------------------------------------------------------------------
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rehearse-cpu", action="store_true",
+                    help="tiny sizes on the CPU platform with the Pallas "
+                         "kernels in interpret mode; prints platform cpu "
+                         "and is not a pass on the chip")
+    args = ap.parse_args(argv)
+
+    import jax
+
+    devs = jax.devices()
+    platform = devs[0].platform
+    dev_rec = {"platform": platform, "device_kind": devs[0].device_kind,
+               "device_count": len(devs)}
+    if args.rehearse_cpu:
+        if platform != "cpu":
+            print("chip_smoke: --rehearse-cpu is for JAX_PLATFORMS=cpu; "
+                  "this process found %r" % platform, file=sys.stderr)
+            return 2
+        sizes = REHEARSAL
+    elif platform != "tpu":
+        print("chip_smoke: JAX found no TPU (first device: %r). Run it on "
+              "the chip; --rehearse-cpu is the explicit CPU rehearsal."
+              % (devs[0],), file=sys.stderr)
+        return 2
+    else:
+        sizes = FULL
+
+    from paddle_tpu import observability as obs
+    from paddle_tpu.core.compile_cache import enable_compile_cache
+
+    # the rehearsal's toy programs gain nothing from a persistent cache
+    cache_dir = None if args.rehearse_cpu else enable_compile_cache()
+    obs.enable()
+    xla = _XlaCompiles()
+    t_all = time.perf_counter()
+    _emit("device", dev_rec, devices=[str(d) for d in devs],
+          compile_cache=cache_dir,
+          compile_cache_entries=(len(os.listdir(cache_dir))
+                                 if cache_dir and os.path.isdir(cache_dir)
+                                 else 0),
+          rehearsal=args.rehearse_cpu)
+
+    phase_train(sizes, dev_rec, platform, xla)
+    phase_kernels(sizes, dev_rec, platform, xla)
+    if len(devs) >= 4:
+        phase_dp4(sizes, dev_rec, platform, xla)
+
+    result = {"ok": True,
+              "device": {"platform": platform,
+                         "kind": devs[0].device_kind, "count": len(devs)},
+              "wall_s": round(time.perf_counter() - t_all, 1)}
+    result.update(xla.snapshot())
+    if args.rehearse_cpu:
+        result["rehearsal"] = True
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -27,9 +27,22 @@ from typing import Dict, List, Sequence, Set, Tuple
 
 import numpy as np
 
+from .enforce import UnimplementedError
 from .registry import BOUND_OUTPUTS_ATTR, RNG_SEED_ATTR, OpInfoMap
 from .scope import Scope
 from .tensor import LoDTensor
+
+
+class UntraceableProgramError(UnimplementedError):
+    """The program is valid but cannot be traced into ONE XLA function:
+    a while carry whose shape/dtype varies across trips, a host op
+    that is not const-foldable here, a LoD feed. These are the cases
+    the op-by-op interpreter exists for, and the only ones the executor
+    answers by falling back to it. Anything raised AFTER the trace — a
+    Pallas kernel Mosaic refuses, an XLA compile error, running out of
+    device memory — is a failure of a traceable program and
+    propagates."""
+
 
 # compiled step functions (XLA executables — the heaviest objects in
 # the process): LRU-bounded so program-churning workloads (e.g. a
@@ -461,16 +474,12 @@ def _fold_block_values(block) -> Dict[str, np.ndarray]:
         # under ensure_compile_time_eval: _trace_block is usually already
         # inside an outer jit trace, where any jnp bind would otherwise
         # produce tracers — np.asarray on those raises.
-        import contextlib
-
         import jax
 
         scratch_exe = CoreExecutor(CPUPlace())
         scratch = Scope()
         infos = OpInfoMap.instance()
-        ctx = getattr(jax, "ensure_compile_time_eval",
-                      contextlib.nullcontext)
-        with ctx():
+        with jax.ensure_compile_time_eval():
             for i in sorted(needed | fold_idxs):
                 op = block.ops[i]
                 info = infos.get(op.type)
@@ -542,7 +551,15 @@ def compile_program(program, feed_names: Tuple[str, ...],
         _obs.inc("executor.jit_traces")
         env = dict(state)
         env.update(feeds)
-        _trace_block(block, env, step_seed)
+        try:
+            _trace_block(block, env, step_seed)
+        except (NotImplementedError, TypeError) as e:
+            # raised while TRACING the block (lax.while_loop rejecting
+            # a varying carry raises TypeError); lowering and compiling
+            # happen after this function returns
+            raise UntraceableProgramError(
+                "program %s cannot be traced whole: %r"
+                % (program._uid, e)) from e
         new_state = {n: env[n] for n in out_state_names if n in env}
         fetches = [env[n] for n in fetch_names]
         return fetches, new_state
@@ -552,8 +569,13 @@ def compile_program(program, feed_names: Tuple[str, ...],
     return fn
 
 
-def run_compiled_program(core, program, scope: Scope, feed: Dict,
-                         fetch_list: Sequence, return_numpy: bool = True):
+def _stage_compiled_call(core, device, program, scope: Scope, feed: Dict,
+                         fetch_list: Sequence):
+    """The jitted step for this (program, feed, fetch) signature and
+    the arguments to call it with: ``(fn, (state, feed_vals, seed),
+    fetch_names)``. Shared by ``run_compiled_program`` (which calls
+    it) and ``lower_compiled_program`` (which only lowers it).
+    ``device`` is the executor's place, resolved once by the caller."""
     import jax
     import jax.numpy as jnp
 
@@ -569,18 +591,22 @@ def run_compiled_program(core, program, scope: Scope, feed: Dict,
     # its H2D work never lands on this step's critical path; the old
     # np.asarray round-trip would have pulled a staged array back to
     # host). Host numpy feeds pay their H2D here, measured as
-    # executor.feed_ms so the profiler can attribute it.
+    # executor.feed_ms so the profiler can attribute it. They are
+    # staged on the executor's OWN device: under TPUPlace(1) a feed put
+    # on the default device (chip 0) would cross chips every step.
     t_feed = _time.perf_counter() if _obs.enabled() else None
     feed_vals = {}
-    for name, value in feed.items():
-        if isinstance(value, LoDTensor):
-            if value.lod():
-                raise NotImplementedError("LoD feeds use the interpreter")
-            feed_vals[name] = value.array
-        elif isinstance(value, jax.Array):
-            feed_vals[name] = value
-        else:
-            feed_vals[name] = jnp.asarray(np.asarray(value))
+    with jax.default_device(device):
+        for name, value in feed.items():
+            if isinstance(value, LoDTensor):
+                if value.lod():
+                    raise UntraceableProgramError(
+                        "LoD feeds use the interpreter")
+                feed_vals[name] = value.array
+            elif isinstance(value, jax.Array):
+                feed_vals[name] = value
+            else:
+                feed_vals[name] = jnp.asarray(np.asarray(value))
     if t_feed is not None:
         _obs.observe("executor.feed_ms",
                      (_time.perf_counter() - t_feed) * 1e3)
@@ -596,7 +622,7 @@ def run_compiled_program(core, program, scope: Scope, feed: Dict,
                 "variable %r must be fed or initialized in scope" % n)
         h = var.raw()
         if not isinstance(h, LoDTensor):
-            raise NotImplementedError("non-dense state %r" % n)
+            raise UntraceableProgramError("non-dense state %r" % n)
         state[n] = h.array
         state_names.append(n)
     state_names = tuple(state_names)
@@ -606,18 +632,46 @@ def run_compiled_program(core, program, scope: Scope, feed: Dict,
 
     fn = compile_program(program, feed_names, fetch_names, state_names,
                          out_state_names)
+    seed = jnp.uint32(core.rng.next_seed(0)
+                      ^ (core.rng.step * 2654435761 & 0xFFFFFFFF))
+    return fn, (state, feed_vals, seed), fetch_names
+
+
+def lower_compiled_program(core, program, scope: Scope, feed: Dict,
+                           fetch_list: Sequence):
+    """The ``jax.stages.Lowered`` of the step ``run_compiled_program``
+    would run for these inputs — for inspecting what the step contains
+    (e.g. that a Pallas kernel went in as a Mosaic custom call and not
+    as its XLA reference). Executes nothing and leaves the scope and
+    the RNG stream untouched."""
+    import jax
+
+    device = core.place.jax_device()
+    fn, args, _ = _stage_compiled_call(core, device, program, scope, feed,
+                                       fetch_list)
+    with jax.default_device(device):
+        return fn.lower(*args)
+
+
+def run_compiled_program(core, program, scope: Scope, feed: Dict,
+                         fetch_list: Sequence, return_numpy: bool = True):
+    import jax
+
     import time
 
+    from .. import observability as _obs
+
+    device = core.place.jax_device()
+    fn, args, fetch_names = _stage_compiled_call(core, device, program,
+                                                 scope, feed, fetch_list)
     # compiled path = ONE fused dispatch: a single step-level host span
     # (per-op detail lives in the XPlane device trace; the op-by-op
     # interpreter records per-op spans)
     t_step = time.perf_counter() if _obs.enabled() else None
-    with jax.default_device(core.place.jax_device()), \
+    with jax.default_device(device), \
             _obs.tracing.span("compiled_step", cat="step",
                               path="compiled"):
-        fetches, new_state = fn(state, feed_vals, jnp.uint32(
-            core.rng.next_seed(0)
-            ^ (core.rng.step * 2654435761 & 0xFFFFFFFF)))
+        fetches, new_state = fn(*args)
     core.rng.advance()
     if t_step is not None:
         _obs.inc("executor.steps", path="compiled")

@@ -95,13 +95,16 @@ def fused_epilogue_enabled() -> bool:
     return _env_on(os.environ.get("PADDLE_TPU_FUSED_EPILOGUE"))
 
 
-def maybe_rewrite_single_chip(program, scope) -> None:
+def maybe_rewrite_single_chip(program, scope, place=None) -> None:
     """Executor entry point, called on every run. The knobs are read
     at a program's FIRST run and baked in (the same contract the
     collective-path knobs keep), so the steady-state cost is ONE
     attribute read + a branch — the gate-4 per-run budget. Applies
     the epilogue pass, then the optimizer pass; a program the
-    parallel transpiler already rewrote keeps its collective path."""
+    parallel transpiler already rewrote keeps its collective path.
+    ``place`` is the executor's: the optimizer pass runs under its
+    device, so the ``auto`` layout follows where the program will run
+    (``compute_platform()``), not the default backend."""
     state = getattr(program, "_sc_fusion", None)
     if state is not None:
         if state and scope is not None:
@@ -118,7 +121,13 @@ def maybe_rewrite_single_chip(program, scope) -> None:
     if fuse_epi:
         apply_fused_epilogues(program)
     if mode is not None:
-        n_opt = apply_fused_optimizer(program, scope, layout=mode)
+        import contextlib
+
+        import jax
+
+        with (jax.default_device(place.jax_device()) if place is not None
+              else contextlib.nullcontext()):
+            n_opt = apply_fused_optimizer(program, scope, layout=mode)
     try:
         # flat layout re-laid state into flat vars -> later runs must
         # resync them after a startup re-run; chain layout kept the
@@ -154,7 +163,7 @@ def apply_fused_optimizer(program, scope, use_pallas: bool = True,
     flat zero-padded vars (padding to the pallas lane tile) so ONE
     pallas streaming kernel updates the whole buffer — the TPU
     layout. ``"auto"`` picks flat exactly when the pallas kernel
-    would actually run (TPU backend).
+    would actually run (``compute_platform()`` is a TPU).
 
     Grouping key: (op type, hyperparam attrs, LearningRate var, param
     dtype) — one group per optimizer instance per dtype, mirroring the
@@ -173,9 +182,9 @@ def apply_fused_optimizer(program, scope, use_pallas: bool = True,
             getattr(program, "_sharded_update_n", None) is not None:
         return 0  # dp-transpiled: the collective path owns the update
     if layout == "auto":
-        import jax
+        from .place import compute_platform
 
-        layout = "flat" if jax.default_backend() == "tpu" else "chain"
+        layout = "flat" if compute_platform() == "tpu" else "chain"
     if layout not in ("flat", "chain"):
         raise ValueError("fused optimizer layout %r" % (layout,))
     from .. import framework

@@ -18,8 +18,8 @@ reimplementation:
   (``memory::Alloc(place, size)`` parity: a raw byte buffer);
 - ``memory_stats`` / ``memory_usage`` expose the live allocator
   counters (the stats surface the reference keeps in
-  memory/stats.h), with graceful zeros where a backend (the CPU one)
-  publishes none.
+  memory/stats.h), with zeros where a backend (the CPU one) publishes
+  none; a backend that fails the query raises.
 """
 from __future__ import annotations
 
@@ -85,12 +85,10 @@ def _device(place=None):
 
 def memory_stats(place=None) -> Dict:
     """Raw allocator counters from the backend (empty dict when the
-    platform publishes none — e.g. the CPU backend)."""
-    d = _device(place)
-    try:
-        return dict(d.memory_stats() or {})
-    except Exception:
-        return {}
+    platform publishes none — the CPU backend returns None). A failing
+    query propagates: on a TPU an empty answer would read as "nothing
+    allocated"."""
+    return dict(_device(place).memory_stats() or {})
 
 
 def memory_usage(place=None) -> Dict[str, int]:

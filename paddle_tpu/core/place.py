@@ -10,6 +10,8 @@ from __future__ import annotations
 
 import functools
 
+from .enforce import OutOfRangeError, PreconditionNotMetError
+
 
 class Place:
     """Base place. Equality is (kind, device_id)."""
@@ -33,11 +35,17 @@ class Place:
 
         devs = _devices_for_platform(self.jax_platform)
         if not devs:
-            raise RuntimeError(
+            raise PreconditionNotMetError(
                 "No %s device available (jax backends: %s)"
                 % (self.jax_platform, [d.platform for d in jax.devices()])
             )
-        return devs[self._device_id % len(devs)]
+        if self._device_id >= len(devs):
+            # no modulo wrap: Place(5) on a four-chip host silently
+            # piling onto chip 1 hides a mis-sized job
+            raise OutOfRangeError(
+                "%r: this process has %d %s device(s)"
+                % (self, len(devs), devs[0].platform))
+        return devs[self._device_id]
 
     def __eq__(self, other):
         return (
@@ -84,10 +92,13 @@ class CPUPlace(Place):
 
 
 class TPUPlace(Place):
-    """The accelerator place. On hosts without a real TPU (unit tests on a
+    """The accelerator place: device ``i`` of the default JAX backend.
 
-    virtual CPU mesh) it resolves to the default JAX backend, so programs
-    written against TPUPlace run everywhere.
+    It resolves to a CPU device only in a process explicitly started on
+    the CPU platform (``JAX_PLATFORMS=cpu`` — the test suite's virtual
+    host mesh). Where JAX merely FOUND no accelerator and fell back to
+    the CPU, it raises: a training job must not run on the host while
+    its logs say TPUPlace.
     """
 
     kind = "tpu"
@@ -95,6 +106,18 @@ class TPUPlace(Place):
     @property
     def jax_platform(self):
         return "any_accelerator"
+
+    def jax_device(self):
+        import jax
+
+        dev = super().jax_device()
+        if dev.platform == "cpu" and not str(
+                jax.config.jax_platforms or "").startswith("cpu"):
+            raise PreconditionNotMetError(
+                "%r: JAX found no accelerator (default backend is cpu). "
+                "Set JAX_PLATFORMS=cpu to run on the host on purpose, "
+                "or use CPUPlace()." % (self,))
+        return dev
 
 
 # The reference exposes CUDAPlace; scripts being migrated may still name it.
@@ -119,3 +142,18 @@ def _current_expected_place_default():
 
     dev = jax.devices()[0]
     return CPUPlace() if dev.platform == "cpu" else TPUPlace(0)
+
+
+def compute_platform() -> str:
+    """Platform of the device the computation being traced or run will
+    execute on: the ``jax.default_device`` an executor entered for its
+    Place, else the default backend. The Pallas kernels choose between
+    Mosaic, interpret mode and the XLA/dense math by THIS, not by
+    ``jax.default_backend()`` — an ``Executor(CPUPlace())`` on a TPU
+    host must not emit a Mosaic call into a CPU computation."""
+    import jax
+
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.default_backend()
+    return dev if isinstance(dev, str) else dev.platform

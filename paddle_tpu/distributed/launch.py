@@ -204,6 +204,44 @@ def _log(msg: str) -> None:
     print("[launch] %s" % msg, file=sys.stderr, flush=True)
 
 
+def _refuse_shared_tpu(nproc_per_node: int) -> None:
+    """A TPU host's chips belong to ONE process at a time: every
+    ``--nproc_per_node`` child would open all of them (nothing here
+    partitions chips per process), so the second child hangs or dies
+    inside the runtime. Refuse up front with the reason. The multi-chip
+    path on one host is the in-process mesh
+    (``CompiledProgram.with_data_parallel`` over ``jax.devices()``).
+
+    The launcher itself must never touch the chip, so what the children
+    would get is read from ``JAX_PLATFORMS`` or, when that is unset,
+    asked of a short-lived probe process that exits before any child
+    starts."""
+    if nproc_per_node <= 1:
+        return
+    platforms = [p for p in os.environ.get("JAX_PLATFORMS", "").split(",")
+                 if p]
+    if platforms:
+        on_tpu = platforms[0] == "tpu"
+    else:
+        probe = subprocess.run(
+            [sys.executable, "-c",
+             "import jax; print(jax.default_backend())"],
+            capture_output=True, text=True, timeout=300)
+        if probe.returncode != 0:
+            raise SystemExit(
+                "launch: could not determine the children's JAX "
+                "backend: %s" % probe.stderr[-1000:])
+        on_tpu = probe.stdout.strip().splitlines()[-1] == "tpu"
+    if on_tpu:
+        raise SystemExit(
+            "launch: --nproc_per_node=%d on a TPU host: a host's chips "
+            "belong to one process at a time and every child would "
+            "open all of them. Run ONE process per host and drive its "
+            "chips through the in-process mesh "
+            "(CompiledProgram.with_data_parallel), or pin the children "
+            "off the chip with JAX_PLATFORMS=cpu." % nproc_per_node)
+
+
 class _Worker:
     """One supervised rank: its env, restart budget, and log sink."""
 
@@ -427,6 +465,7 @@ def launch(args=None):
         except ValueError as e:
             raise SystemExit(str(e))
     nranks = len(node_ips) * args.nproc_per_node
+    _refuse_shared_tpu(args.nproc_per_node)
 
     workers = []
     for local_rank in range(args.nproc_per_node):

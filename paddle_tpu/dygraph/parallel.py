@@ -163,8 +163,6 @@ def _allreduce_across_processes(flat, nranks):
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from ..parallel.mesh_utils import shard_map_compat
-
     try:
         devs = np.array(jax.devices()[:nranks])
         mesh = Mesh(devs, ("dp",))
@@ -173,9 +171,9 @@ def _allreduce_across_processes(flat, nranks):
         garr = jax.make_array_from_single_device_arrays(
             (nranks,) + flat.shape, dist,
             [jax.device_put(local, jax.local_devices()[0])])
-        psummed = shard_map_compat(
-            lambda x: jax.lax.psum(x, "dp"), mesh,
-            in_specs=P("dp"), out_specs=P("dp"))
+        psummed = jax.shard_map(
+            lambda x: jax.lax.psum(x, "dp"), mesh=mesh,
+            in_specs=P("dp"), out_specs=P("dp"), check_vma=False)
         out = jax.jit(psummed)(garr)
         [shard] = [s.data for s in out.addressable_shards]
         return shard[0]

@@ -78,7 +78,8 @@ class Executor:
         # FLAGS_check_nan_inf needs the per-op interpreter (the check
         # runs after every op, reference operator.cc:1032)
         if not _flag("check_nan_inf"):
-            from .core.compiler_engine import (_program_version,
+            from .core.compiler_engine import (UntraceableProgramError,
+                                               _program_version,
                                                run_compiled_program)
 
             # single-chip fusion rewrites (fused optimizer update /
@@ -87,7 +88,7 @@ class Executor:
             # idempotent per program
             from .core.fusion import maybe_rewrite_single_chip
 
-            maybe_rewrite_single_chip(program, scope)
+            maybe_rewrite_single_chip(program, scope, self.place)
             ver = _program_version(program)
             if ver not in self._compile_fallbacks:
                 run_args = None
@@ -115,13 +116,16 @@ class Executor:
                         _capture.maybe_sample_step(
                             "executor", run_args[0], scope, run_args[1])
                         return out
-                    except (NotImplementedError, TypeError) as e:
+                    except UntraceableProgramError as e:
                         # e.g. a while carry whose shape/dtype varies
                         # across trips — valid for the host interpreter,
                         # untraceable for lax.while_loop. Remember so
                         # later steps skip the doomed trace attempt —
                         # and SAY so: this is a large perf cliff that
-                        # must not be silent.
+                        # must not be silent. A failure AFTER the trace
+                        # (kernel lowering, XLA compile) is not caught:
+                        # a traceable program that does not compile is
+                        # an error, not an interpreter run.
                         import warnings
 
                         warnings.warn(
@@ -134,6 +138,22 @@ class Executor:
                         _obs.inc("executor.compile_fallbacks")
         return self._core.run_program(program, scope, feed, fetch_list,
                                       return_numpy)
+
+    def lower(self, program=None, feed=None, fetch_list=None,
+              scope=None):
+        """The ``jax.stages.Lowered`` of the whole-program step
+        ``run`` would execute for this (program, feed, fetch_list) —
+        ``.as_text()`` shows what the step contains, e.g. whether a
+        Pallas kernel is in it as a ``tpu_custom_call``. Call it after
+        a ``run`` of the same program (the single-chip rewrites happen
+        there); it executes nothing."""
+        from .core.compiler_engine import lower_compiled_program
+
+        scope = scope if scope is not None else global_scope()
+        if program is None:
+            program = framework.default_main_program()
+        return lower_compiled_program(self._core, program, scope,
+                                      feed or {}, list(fetch_list or []))
 
     def _lod_lowered(self, program, feed, fetch_list):
         """(lowered_program, padded_feed) when every ragged feed pads

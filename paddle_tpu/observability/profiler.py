@@ -60,8 +60,10 @@ Analytic per-op FLOPs from static block shapes (matmul/conv/attention
 formulas; ``*_grad`` ops cost 2x their forward op — the standard
 "training step = 3x forward" accounting), so ``bench.py`` computes
 ``mfu_est`` from the op registry for every workload instead of a
-hardcoded per-model estimate. ``peak_flops`` carries the TPU v5e MXU
-peaks the estimates are normalized against.
+hardcoded per-model estimate. ``DEVICE_PEAKS`` is the one table of
+published chip peaks the estimates are normalized against, keyed by
+``device_kind``; a device that is not in it (the CPU included) gets no
+``mfu_est`` rather than a number against some other chip's peak.
 """
 from __future__ import annotations
 
@@ -80,7 +82,7 @@ __all__ = [
     "build_phase_plan", "profile_step", "analyze_timeline",
     # FLOP accounting
     "program_flops", "flops_mlp", "flops_transformer_lm",
-    "peak_flops", "mfu_est",
+    "DEVICE_PEAKS", "peak_flops", "mfu_est",
     # legacy fluid.profiler session API (absorbed shim)
     "RecordEvent", "record_event", "is_profiler_enabled",
     "get_trace_events", "reset_profiler", "start_profiler",
@@ -526,8 +528,6 @@ def _mesh_runner_factory(block, mesh, data_axes, shard_specs, feed_specs,
 
     from ..core.compiler_engine import _trace_ops
     from ..ops.collective_ops import mesh_axes_guard, ring_axis_guard
-    from ..parallel.mesh_utils import shard_map_compat
-
     mesh_axes = set(mesh.axis_names) if mesh is not None else set()
     ring_val = (tuple(data_axes) if len(data_axes) > 1
                 else (data_axes[0] if data_axes else None))
@@ -555,13 +555,13 @@ def _mesh_runner_factory(block, mesh, data_axes, shard_specs, feed_specs,
 
         if mesh is None:
             return jax.jit(step)
-        mapped = shard_map_compat(
-            step, mesh,
+        mapped = jax.shard_map(
+            step, mesh=mesh,
             in_specs=({n: P(*shard_specs.get(n, ()))
                        for n in state_names},
                       {n: P(*feed_specs.get(n, default_feed_spec))
                        for n in feed_names}, P()),
-            out_specs=P())
+            out_specs=P(), check_vma=False)
         return jax.jit(mapped)
 
     return make_fn
@@ -584,8 +584,6 @@ def _bench_collective(mesh, data_axes, numel: int, dtype: str,
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-
-    from ..parallel.mesh_utils import shard_map_compat
 
     if mesh is None or not data_axes or numel <= 0:
         return 0.0
@@ -618,8 +616,9 @@ def _bench_collective(mesh, data_axes, numel: int, dtype: str,
 
     # shard dim 0 over EVERY data axis: per-shard payload must equal
     # the op's numel even on a multi-data-axis (dp x sp) mesh
-    mapped = jax.jit(shard_map_compat(
-        body, mesh, in_specs=P(tuple(data_axes)), out_specs=P()))
+    mapped = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=P(tuple(data_axes)), out_specs=P(),
+        check_vma=False))
     # per-shard payload = the op's numel (replicas each hold the full
     # flat grad); a global array sharded over the axis keeps shard
     # values distinct so the psum cannot be folded away
@@ -864,23 +863,34 @@ def _emit_profile(prof: Dict) -> None:
 
 # -- analytic FLOP accounting ----------------------------------------------
 
-# TPU v5e (lite) MXU peak — the anchor bench.py normalized its
-# hardcoded resnet estimate against; kept here as THE one place the
-# assumption lives
-PEAK_FLOPS_BF16 = 197e12
-PEAK_FLOPS_F32 = 98.5e12
+# Published per-chip peaks, keyed by the ``device_kind`` JAX reports —
+# THE one place a peak lives. MFU is model FLOPs over the chip's bf16
+# MXU peak whatever dtype the program computes in.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,
+        "hbm_bytes_per_s": 819e9,
+        "source": "Google Cloud documentation, 'TPU v5e': 197 TFLOP/s "
+                  "bf16, 16 GB HBM at 819 GB/s per chip",
+    },
+}
 
 
-def peak_flops(bf16: bool = False, n_devices: int = 1) -> float:
-    return (PEAK_FLOPS_BF16 if bf16 else PEAK_FLOPS_F32) * max(
-        1, int(n_devices))
-
-
-def mfu_est(flops_per_step: float, step_s: float, bf16: bool = False,
-            n_devices: int = 1) -> Optional[float]:
-    if not step_s or not flops_per_step:
+def peak_flops(device_kind: str, n_devices: int = 1) -> Optional[float]:
+    """bf16 peak FLOP/s of ``n_devices`` chips of this kind, or None
+    for a kind the table does not list."""
+    peaks = DEVICE_PEAKS.get(device_kind)
+    if peaks is None:
         return None
-    return flops_per_step / step_s / peak_flops(bf16, n_devices)
+    return peaks["bf16_flops"] * max(1, int(n_devices))
+
+
+def mfu_est(flops_per_step: float, step_s: float, device_kind: str,
+            n_devices: int = 1) -> Optional[float]:
+    peak = peak_flops(device_kind, n_devices)
+    if not step_s or not flops_per_step or peak is None:
+        return None
+    return flops_per_step / step_s / peak
 
 
 def _shape_of(block, state, name) -> Optional[Tuple[int, ...]]:

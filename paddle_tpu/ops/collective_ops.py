@@ -80,17 +80,6 @@ def mesh_axis_active(name: Optional[str]) -> bool:
     return bool(name) and name in _ACTIVE_MESH_AXES
 
 
-def static_axis_size(axis_name) -> int:
-    """Size of a live named mesh axis as a python int.
-    ``lax.axis_size`` only exists on newer jax; ``psum(1, axis)`` of a
-    literal is the portable spelling — constant-folded to the axis size
-    at trace time on every jax this repo supports."""
-    try:
-        return int(jax.lax.axis_size(axis_name))
-    except AttributeError:
-        return int(jax.lax.psum(1, axis_name))
-
-
 def _allreduce(name, reducer):
     @register_op(
         name,
@@ -190,7 +179,7 @@ def _alltoall(ins, attrs):
     x = ins["X"]
     if axis is None:
         return {"Out": x}
-    n = static_axis_size(axis)
+    n = jax.lax.axis_size(axis)
     xs = x.reshape((n, x.shape[0] // n) + x.shape[1:])
     out = jax.lax.all_to_all(xs, axis, split_axis=0, concat_axis=0, tiled=False)
     return {"Out": out.reshape(x.shape)}
@@ -323,7 +312,7 @@ def strategy_psum(x, axis, strategy="ring"):
         return out
     if strategy == "tree":
         a0 = axes[0]
-        n = static_axis_size(a0)
+        n = jax.lax.axis_size(a0)
         flat = x.reshape(-1)
         pad = (-flat.size) % n
         if pad:

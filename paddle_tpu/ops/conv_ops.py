@@ -64,6 +64,7 @@ def _maybe_pallas_conv(ins, attrs, data_format):
     stride-1/2 square 1x1/3x3, groups=1, symmetric padding qualify; in
     'auto' mode only the measured-win class routes (BASELINE.md r5)."""
     from ..core.flags import flag
+    from ..core.place import compute_platform
     from .pallas.conv import pallas_conv, route_pallas
 
     mode = flag("use_pallas_conv")
@@ -73,7 +74,7 @@ def _maybe_pallas_conv(ins, attrs, data_format):
         warnings.warn("FLAGS_use_pallas_conv=%r is not one of "
                       "off/auto/all; treating as 'off'" % (mode,))
         return None
-    if mode == "off" or jax.default_backend() not in ("tpu",):
+    if mode == "off" or compute_platform() != "tpu":
         return None
     x, w = ins["Input"], ins["Filter"]
     strides = attrs.get("strides", [1, 1])

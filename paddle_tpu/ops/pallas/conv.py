@@ -29,6 +29,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ...core.place import compute_platform
+
 
 def _kernel(x_ref, w_ref, scale_ref, shift_ref, *rest,
             block_h, w_out, kh, kw, stride, relu, has_residual):
@@ -113,7 +115,7 @@ def conv2d_bn_act(x, w, scale=None, shift=None, *, stride=1, padding=0,
         raise ValueError("conv2d_bn_act supports stride 1 or 2, got %r"
                          % (stride,))
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = compute_platform() != "tpu"
     B, H, W, Cin = x.shape
     KH, KW, _, Cout = w.shape
     if padding:

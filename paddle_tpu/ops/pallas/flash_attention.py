@@ -19,10 +19,11 @@ score matrix never materializes in HBM:
   blocks per K block; both consume the dense precomputed
   delta = rowsum(dO ∘ O) (an elementwise pass XLA fuses).
 
-Off-TPU the public entry falls back to the identical dense math, so
-programs are portable and CI (CPU) still exercises the call sites;
-tests run the kernels in interpret mode on CPU where the math is
-exact.
+Where the computation runs on a TPU (``core.place.compute_platform``)
+the public entry builds the kernels, and a kernel Mosaic refuses
+raises. Elsewhere it computes the identical dense math, so programs
+are portable and CI (CPU) still exercises the call sites; tests run the
+kernels in interpret mode on CPU where the math is exact.
 
 Numerics, measured on v5e: with float32 inputs both this kernel and
 XLA's dense attention run the MXU's default (bfloat16-pass) precision;
@@ -42,7 +43,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .support import compiler_params as _compiler_params
+from jax.experimental.pallas import tpu as pltpu
+
+from ...core.place import compute_platform
 
 NEG_INF = -1e30
 
@@ -185,7 +188,6 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
                    lengths=None):
     """Returns (out [B,H,S,D], lse [B*H, S] float32)."""
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     B, H, S, D = q.shape
     S_kv = k.shape[2]
@@ -239,7 +241,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)
@@ -367,7 +369,6 @@ def _flash_bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, nq,
 def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
                     block_k, interpret, lengths=None):
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     B, H, S, D = q.shape
     bq = min(block_q, S)
@@ -408,7 +409,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q3, k3, v3, do3, lse, delta, *extra_args)
@@ -439,7 +440,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, D), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q3, k3, v3, do3, lse, delta, *extra_args)
@@ -553,8 +554,9 @@ def flash_attention(q, k, v, causal: bool = False,
     the additive-mask formulation; mask the loss, as seq2seq training
     already does.
 
-    Uses the pallas kernels on TPU backends (or when ``force_pallas`` —
-    interpret mode — is requested, e.g. in tests); dense math elsewhere.
+    Uses the pallas kernels where the computation runs on a TPU (or
+    when ``force_pallas`` — interpret mode off-TPU — is requested, e.g.
+    in tests); dense math elsewhere.
 
     Block defaults are tuned on v5e (b4 h16 d64, causal, fwd+bwd):
     512x1024 blocks turn the 128x128 default's 0.6-0.9x vs XLA dense
@@ -576,9 +578,9 @@ def flash_attention(q, k, v, causal: bool = False,
             warnings.warn(
                 "flash_attention: seq_len %d has no 128-aligned block "
                 "divisor; using dense O(S^2) attention" % S)
-    backend = jax.default_backend()
-    interpret = backend != "tpu"
-    if backend == "tpu" or force_pallas:
+    on_tpu = compute_platform() == "tpu"
+    interpret = not on_tpu
+    if on_tpu or force_pallas:
         if lengths is not None:
             return _flash_masked(q, k, v, lengths, causal, scale,
                                  block_q, block_k, interpret)

@@ -17,11 +17,11 @@ jnp expression sequence as ops/optimizer_ops.py — sqrt/mul/add/div
 only, each correctly rounded, so the pallas kernel, the XLA fallback
 (``use_pallas=False``), and the per-param op chain are bit-identical.
 
-The XLA fallback path is chosen automatically off-TPU (same rule as
-flash_attention): XLA fuses the flat elementwise chain into one fused
-loop there, which is already the fused-launch win on hosts without
-pallas; tests run the kernels in interpret mode via
-``force_pallas=True`` where the math is numpy-exact.
+Off-TPU (same rule as flash_attention: ``compute_platform()``) the
+update is the XLA lowering of the same expressions: XLA fuses the flat
+elementwise chain into one loop there; tests run the kernels in
+interpret mode via ``force_pallas=True`` where the math is numpy-exact.
+On a TPU the kernel is the path, and a build failure raises.
 """
 from __future__ import annotations
 
@@ -31,8 +31,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from .support import compiler_params as _compiler_params
-from .support import pallas_supported
+from ...core.place import compute_platform
 
 # flat buffers are padded to a multiple of this so [rows, 128] tiling
 # always satisfies the TPU (8, 128) tile rule
@@ -138,10 +137,10 @@ def fused_optimizer_update(op_type: str, attrs: Dict, param, grad, lr,
     ``param``/``grad`` (and the state buffers) are flat, zero-padded to
     a multiple of ``LANE_PAD``; scalars are 0-d/1-element arrays.
     Returns ``(param_out, state_a_out, state_b_out)`` (None where the
-    optimizer carries no such state). Routes to the pallas kernel on
-    TPU backends (or under ``force_pallas`` — interpret mode — in
-    tests); the XLA fallback is the same math on the same flat buffer,
-    which XLA fuses into one loop — still a single fused launch.
+    optimizer carries no such state). Routes to the pallas kernel
+    where the computation runs on a TPU (or under ``force_pallas`` —
+    interpret mode — in tests); the XLA path is the same math on the
+    same flat buffer, which XLA fuses into one loop.
     """
     n_state = _n_states(op_type)
     has_pows = op_type in ("adam", "adamw")
@@ -153,15 +152,13 @@ def fused_optimizer_update(op_type: str, attrs: Dict, param, grad, lr,
         scalars += [jnp.asarray(beta1_pow).reshape(1).astype(param.dtype),
                     jnp.asarray(beta2_pow).reshape(1).astype(param.dtype)]
 
-    backend = jax.default_backend()
-    use_pallas = (backend == "tpu") if force_pallas is None \
-        else bool(force_pallas)
-    if use_pallas and param.size % LANE_PAD == 0 and param.size > 0 \
-            and pallas_supported(interpret=backend != "tpu"):
+    on_tpu = compute_platform() == "tpu"
+    use_pallas = on_tpu if force_pallas is None else bool(force_pallas)
+    if use_pallas and param.size % LANE_PAD == 0 and param.size > 0:
         return _pallas_update(op_type, attrs, param, grad, scalars,
                               state_a, state_b, n_state, has_pows,
-                              interpret=backend != "tpu")
-    # XLA fallback: identical expressions over the same flat buffers
+                              interpret=not on_tpu)
+    # XLA path: identical expressions over the same flat buffers
     b1pow = scalars[1][0] if has_pows else None
     b2pow = scalars[2][0] if has_pows else None
     return _update_math(op_type, attrs, param,
@@ -204,7 +201,7 @@ def _pallas_update(op_type, attrs, param, grad, scalars, state_a,
         out_specs=[tile] * n_out,
         out_shape=[jax.ShapeDtypeStruct((rows, 128), param.dtype)
                    for _ in range(n_out)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*args)

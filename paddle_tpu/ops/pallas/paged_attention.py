@@ -19,10 +19,11 @@ so the kernel reads the arena THROUGH the block table:
   the sequentially-iterated block axis. Padded table entries re-fetch
   block 0 and are masked by the per-sequence length, so the ragged
   batch pads to a rectangle without touching ragged memory.
-- **dense fallback** (CPU/CI and any host without pallas): identical
-  math in numpy over the same arena + block table. The serving smoke
-  runs on CPU hosts, so this path IS the production path there; the
-  pallas path takes over on TPU where the arena actually lives in HBM.
+- **dense reference** (CPU hosts, quantized arenas): identical math in
+  numpy over the same arena + block table. The serving smoke runs on
+  CPU hosts, so this path IS the production path there; on a TPU
+  backend the pallas path is the default for f32 arenas and a kernel
+  that fails to build raises — nothing routes back here silently.
 
 Quantized arenas (the EQuARX-shaped KV trick: shared-scale int8 codes,
 ``serving/decode/kvcache.py``) pass their per-(block, head) scales;
@@ -36,7 +37,11 @@ from __future__ import annotations
 import functools
 from typing import Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+
+from ...core.place import compute_platform
 
 __all__ = ["paged_decode_attention", "paged_attention_reference"]
 
@@ -111,8 +116,6 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
     """One (sequence, cache-block) grid step: flash accumulation over
     this block's keys/values. The index maps already routed the RIGHT
     arena block into ``k_ref``/``v_ref`` via the prefetched table."""
-    import jax
-    import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     j = pl.program_id(1)
@@ -129,19 +132,24 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(valid)
     def _accumulate():
+        # one query row per sequence: the step is bandwidth-bound, so
+        # the scores are a broadcast-multiply-reduce on the VPU rather
+        # than an MXU contraction (Mosaic refuses the 3-D "hd,thd->ht"
+        # dot). Every intermediate keeps K/V's own [T, H, .] layout:
+        # T is the leading (untiled) dim, H sublanes, D lanes — only
+        # lane reductions with keepdims and leading-dim reductions.
         q = q_ref[0].astype(jnp.float32)                 # [H, D]
         k = k_ref[0].astype(jnp.float32)                 # [T, H, D]
         v = v_ref[0].astype(jnp.float32)
-        s = jnp.einsum("hd,thd->ht", q, k) * scale       # [H, T]
-        pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < seq_len, s, NEG_INF)
+        s = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        s = jnp.where(pos < seq_len, s, NEG_INF)         # [T, H, 1]
         m_prev = m_ref[...]                              # [H, 1]
-        m_cur = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        m_cur = jnp.maximum(m_prev, s.max(axis=0))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur)                           # [H, T]
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + \
-            jnp.einsum("ht,thd->hd", p, v)
+        p = jnp.exp(s - m_cur[None])                     # [T, H, 1]
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=0)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.sum(p * v, axis=0)
         m_ref[...] = m_cur
 
     @pl.when(j == n_blocks - 1)
@@ -150,10 +158,12 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("block_tokens", "scale",
+                                             "interpret"))
 def _paged_pallas(q, k_arena, v_arena, block_tables, seq_lens, *,
                   block_tokens, scale, interpret):
-    import jax
-    import jax.numpy as jnp
+    """Jitted so the decode loop compiles once per (batch bucket, table
+    width), not once per call."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -183,14 +193,12 @@ def _paged_pallas(q, k_arena, v_arena, block_tables, seq_lens, *,
     )
     kernel = functools.partial(_paged_kernel, block_tokens=block_tokens,
                                scale=scale, n_blocks=max_blocks)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), jnp.float32),
         interpret=interpret,
-    )(tables, lens, jnp.asarray(q, jnp.float32),
-      jnp.asarray(k_arena), jnp.asarray(v_arena))
-    return np.asarray(out)
+    )(tables, lens, q, k_arena, v_arena)
 
 
 def paged_decode_attention(q, k_arena, v_arena, block_tables, seq_lens,
@@ -211,24 +219,20 @@ def paged_decode_attention(q, k_arena, v_arena, block_tables, seq_lens,
         scale = 1.0 / float(np.sqrt(np.asarray(q).shape[-1]))
     quantized = k_scales is not None or v_scales is not None
     if backend is None:
-        use_pallas = False
-        if not quantized:
-            try:
-                import jax
-                use_pallas = jax.default_backend() == "tpu"
-            except Exception:  # noqa: BLE001 — no jax, dense it is
-                use_pallas = False
-        backend = "pallas" if use_pallas else "dense"
+        backend = ("pallas" if not quantized
+                   and compute_platform() == "tpu" else "dense")
     if backend == "dense":
         return paged_attention_reference(
             q, k_arena, v_arena, block_tables, seq_lens,
             block_tokens=block_tokens, scale=scale,
             k_scales=k_scales, v_scales=v_scales)
+    if backend not in ("pallas", "pallas_interpret"):
+        raise ValueError("paged attention backend %r" % (backend,))
     if quantized:
         raise ValueError("pallas paged attention path takes f32 arenas; "
                          "dequantize via backend='dense' off-TPU")
-    return _paged_pallas(
+    return np.asarray(_paged_pallas(
         np.asarray(q, np.float32), np.asarray(k_arena, np.float32),
-        np.asarray(v_arena, np.float32), block_tables, seq_lens,
-        block_tokens=block_tokens, scale=scale,
-        interpret=(backend == "pallas_interpret"))
+        np.asarray(v_arena, np.float32), np.asarray(block_tables),
+        np.asarray(seq_lens), block_tokens=int(block_tokens),
+        scale=float(scale), interpret=(backend == "pallas_interpret")))

@@ -29,8 +29,7 @@ from ..core.registry import BOUND_OUTPUTS_ATTR
 from ..core.scope import Scope
 from ..core.tensor import LoDTensor
 from ..ops.collective_ops import mesh_axes_guard, ring_axis_guard
-from .mesh_utils import (default_mesh, mesh_key as _mesh_key,
-                         shard_map_compat as _shard_map)
+from .mesh_utils import default_mesh, mesh_key as _mesh_key
 from .transpiler import insert_allreduce_ops
 
 _dp_cache: Dict = {}
@@ -318,6 +317,15 @@ def run_data_parallel(core, program, scope: Scope, feed: Dict,
                              scope=scope)
         _obs.inc("parallel.compiles")
         coll_est = _estimate_collective_bytes(program, state)
+        if not multiproc:
+            # lay the state out over the mesh NOW, as the step's own
+            # outputs will be from step 2 on: fed as the startup run
+            # left it (one device, uncommitted), step 1 compiles for
+            # that layout and step 2 compiles the whole program again
+            # for the sharded one
+            state = {n: jax.device_put(
+                a, NamedSharding(mesh, P(*shard_specs.get(n, ()))))
+                for n, a in state.items()}
         def shard_step(state_d, feeds_d, seed):
             with ring_axis_guard({0: ring_val, -1: ring_val}), \
                     mesh_axes_guard(mesh_axes):
@@ -332,8 +340,8 @@ def run_data_parallel(core, program, scope: Scope, feed: Dict,
                 new_state = {n: env[n] for n in out_state_names if n in env}
                 return fetches, new_state
 
-        mapped = _shard_map(
-            shard_step, mesh,
+        mapped = jax.shard_map(
+            shard_step, mesh=mesh,
             in_specs=({n: P(*shard_specs.get(n, ()))
                        for n in state_names},
                       {n: P(*feed_specs.get(n, default_feed_spec))
@@ -341,7 +349,7 @@ def run_data_parallel(core, program, scope: Scope, feed: Dict,
             out_specs=([P() for _ in fetch_names],
                        {n: P(*shard_specs.get(n, ()))
                         for n in out_state_names}),
-        )
+            check_vma=False)
         fn = jax.jit(mapped, donate_argnums=(0,))
         hit = (fn, coll_est)
         _dp_cache[key] = hit

@@ -32,18 +32,3 @@ def mesh_key(mesh) -> Tuple:
     for different devices."""
     return (tuple(d.id for d in mesh.devices.flat),
             tuple(mesh.axis_names))
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """shard_map across JAX versions: new jax.shard_map(check_vma=...)
-    with fallback to jax.experimental.shard_map(check_rep=...)."""
-    import jax
-
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)

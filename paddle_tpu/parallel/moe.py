@@ -66,9 +66,7 @@ def expert_parallel_moe(x, gate_w, w_in, w_out, axis_name: str,
     if axis_size:
         n = int(axis_size)
     else:
-        from ..ops.collective_ops import static_axis_size
-
-        n = static_axis_size(axis_name)
+        n = jax.lax.axis_size(axis_name)
     T, D = x.shape
     e_local = w_in.shape[0]
     e_total = e_local * n

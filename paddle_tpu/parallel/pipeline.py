@@ -41,7 +41,7 @@ import numpy as np
 from ..core.compiler_engine import _program_version, _trace_ops
 from ..core.scope import Scope
 from ..core.tensor import LoDTensor
-from .mesh_utils import make_mesh, shard_map_compat
+from .mesh_utils import make_mesh
 
 _pp_cache: Dict = {}
 
@@ -513,13 +513,13 @@ def _build_pipeline_fn(block, stages, live, meta, mesh, axis_name,
 
     feed_spec = P(None, dp_axis) if dp_axis else P()
     param_specs = {n: P(*shard_specs.get(n, ())) for n in param_names}
-    smap = shard_map_compat(
-        shard_step, mesh,
+    smap = jax.shard_map(
+        shard_step, mesh=mesh,
         in_specs=(param_specs,
                   {n: P(*shard_specs.get(n, ())) for n in other_names},
                   {n: feed_spec for n in feed_names},
                   P()),
-        out_specs=(P(), param_specs))
+        out_specs=(P(), param_specs), check_vma=False)
 
     # -- optimizer update: trace the program's own update block ----------
     update_ops = meta["update_ops"]

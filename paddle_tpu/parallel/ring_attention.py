@@ -29,15 +29,15 @@ from __future__ import annotations
 import functools
 from typing import Optional
 
+import jax
+
 NEG_INF = -1e30
 
 
 def _axis_size(axis_name: str, axis_size: Optional[int]):
     if axis_size is not None:
         return int(axis_size)
-    from ..ops.collective_ops import static_axis_size
-
-    return static_axis_size(axis_name)
+    return jax.lax.axis_size(axis_name)
 
 
 def ring_attention(q, k, v, axis_name: str, causal: bool = False,
@@ -204,8 +204,6 @@ def sequence_parallel_attention(q, k, v, mesh, sp_axis: str = "sp",
     """
     from jax.sharding import PartitionSpec as P
 
-    from .mesh_utils import shard_map_compat
-
     n = int(mesh.shape[sp_axis])
     fn = {"ring": ring_attention, "ulysses": ulysses_attention}[mode]
     local = functools.partial(fn, axis_name=sp_axis, causal=causal,
@@ -213,13 +211,14 @@ def sequence_parallel_attention(q, k, v, mesh, sp_axis: str = "sp",
 
     spec = P(None, None, sp_axis, None)
     if lengths is None:
-        smap = shard_map_compat(local, mesh,
-                                in_specs=(spec, spec, spec),
-                                out_specs=spec)
+        smap = jax.shard_map(local, mesh=mesh,
+                             in_specs=(spec, spec, spec),
+                             out_specs=spec, check_vma=False)
         return smap(q, k, v)
-    smap = shard_map_compat(
-        lambda q, k, v, ln: local(q, k, v, lengths=ln), mesh,
-        in_specs=(spec, spec, spec, P()), out_specs=spec)
+    smap = jax.shard_map(
+        lambda q, k, v, ln: local(q, k, v, lengths=ln), mesh=mesh,
+        in_specs=(spec, spec, spec, P()), out_specs=spec,
+        check_vma=False)
     return smap(q, k, v, lengths)
 
 

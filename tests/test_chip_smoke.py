@@ -1,0 +1,147 @@
+"""What can be known about the chip path without a chip (ISSUE 21):
+chip_smoke.py's CPU rehearsal and its refusal to pass without a TPU,
+the compile-cache helper, TPUPlace strictness, and that the TPU
+compiler still accepts every Pallas kernel."""
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+import jax
+
+import paddle_tpu as fluid
+from paddle_tpu.core import compile_cache
+from paddle_tpu.core.enforce import OutOfRangeError, PreconditionNotMetError
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import tpu_kernel_cases  # noqa: E402
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SMOKE = os.path.join(ROOT, "chip_smoke.py")
+
+
+def _run(args, cwd=ROOT, devices=4, pythonpath=None):
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("PYTHONPATH", "JAX_COMPILATION_CACHE_DIR")}
+    if pythonpath:
+        env["PYTHONPATH"] = pythonpath
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = \
+        "--xla_force_host_platform_device_count=%d" % devices
+    return subprocess.run([sys.executable] + args, cwd=cwd, env=env,
+                          capture_output=True, text=True, timeout=600)
+
+
+def test_rehearsal_runs_every_phase_on_cpu():
+    proc = _run([SMOKE, "--rehearse-cpu"])
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    lines = [json.loads(x) for x in proc.stdout.splitlines()
+             if x.startswith("{")]
+    result = lines[-1]
+    assert result["ok"] is True and result["rehearsal"] is True
+    # never mistakable for a pass on the chip
+    assert result["device"]["platform"] == "cpu"
+    phases = [rec["phase"] for rec in lines[:-1]]
+    assert phases == ["device", "train", "train", "train", "kernels",
+                      "dp4"], phases
+    assert all(rec["platform"] == "cpu" and rec["smoke"]
+               for rec in lines[:-1])
+
+
+def test_without_the_rehearsal_argument_a_cpu_host_fails():
+    proc = _run([SMOKE])
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout and '"phase"' not in proc.stdout
+    assert "no TPU" in proc.stderr
+
+
+def test_alone_in_a_directory_it_fails_and_prints_no_result(tmp_path):
+    shutil.copy(SMOKE, tmp_path / "chip_smoke.py")
+    proc = _run(["chip_smoke.py", "--rehearse-cpu"], cwd=str(tmp_path))
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout
+    assert "paddle_tpu" in proc.stderr
+
+
+# -- compile cache placed from outside --------------------------------------
+
+
+def test_cache_dir_is_the_env_var_or_the_fixed_checkout_path(monkeypatch):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    assert compile_cache.compile_cache_dir() == "/some/dir"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    # fixed, in the checkout, the same on every call — never a temp,
+    # pid or time-stamped name (the path is part of the cache key)
+    want = os.path.join(ROOT, ".jax_compile_cache")
+    assert compile_cache.compile_cache_dir() == want
+    assert compile_cache.compile_cache_dir() == want
+
+
+def test_enable_sets_no_other_dir_when_the_env_var_is_set(monkeypatch):
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: calls.append((k, v)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    assert compile_cache.enable_compile_cache() == "/some/dir"
+    assert calls == []          # JAX reads the variable itself
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = compile_cache.enable_compile_cache()
+    assert calls == [("jax_compilation_cache_dir", path)]
+    assert path == os.path.join(ROOT, ".jax_compile_cache")
+
+
+# -- places ------------------------------------------------------------------
+
+
+def test_tpuplace_past_the_device_count_raises():
+    n = len(jax.devices())
+    assert fluid.TPUPlace(n - 1).jax_device() == jax.devices()[n - 1]
+    with pytest.raises(OutOfRangeError):
+        fluid.TPUPlace(n).jax_device()      # no modulo wrap
+
+
+def test_tpuplace_refuses_a_cpu_jax_merely_fell_back_to():
+    """The suite's explicit CPU pin lets TPUPlace resolve to a CPU
+    device; a process in which JAX just found no accelerator must not
+    train on the host under the name TPUPlace."""
+    pinned = jax.config.jax_platforms
+    assert fluid.TPUPlace(0).jax_device().platform == "cpu"
+    try:
+        jax.config.update("jax_platforms", None)
+        with pytest.raises(PreconditionNotMetError):
+            fluid.TPUPlace(0).jax_device()
+        assert fluid.CPUPlace().jax_device().platform == "cpu"
+    finally:
+        jax.config.update("jax_platforms", pinned)
+
+
+# -- the TPU compiler still accepts the kernels -------------------------------
+
+
+@pytest.mark.parametrize("case", list(tpu_kernel_cases.cases()),
+                         ids=lambda c: c[0])
+def test_kernel_cross_lowers_for_tpu(case):
+    """Mosaic lowering on the CPU host: the break that kept the paged
+    kernel from ever compiling (a 3-D einsum) raises here."""
+    name, fn, specs = case
+    args = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in specs]
+    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    assert lowered.as_text().count("tpu_custom_call") >= 1, name
+
+
+def test_kernels_compile_for_v5e():
+    """The real thing minus the chip: libtpu's Mosaic and XLA:TPU back
+    ends compile every kernel for a compile-only v5e topology — VMEM
+    and layout refusals included. In a process of its own (it loads
+    libtpu); skipped where no TPU compiler can be set up."""
+    proc = _run([os.path.join(ROOT, "tests", "tpu_kernel_cases.py")],
+                devices=1, pythonpath=ROOT)
+    if proc.returncode == 3:
+        pytest.skip("no compile-only TPU topology here: %s"
+                    % proc.stdout[-300:])
+    ok = [x for x in proc.stdout.splitlines() if x.startswith("OK ")]
+    assert proc.returncode == 0, proc.stdout[-3000:]
+    assert len(ok) == len(list(tpu_kernel_cases.cases())), proc.stdout

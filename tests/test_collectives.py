@@ -437,7 +437,7 @@ def test_quantized_psum_error_bounds():
     from jax.sharding import PartitionSpec as P
 
     from paddle_tpu.ops.collective_ops import quantized_psum
-    from paddle_tpu.parallel.mesh_utils import make_mesh, shard_map_compat
+    from paddle_tpu.parallel.mesh_utils import make_mesh
 
     n = 8
     mesh = make_mesh([n], ["dp"])
@@ -448,8 +448,8 @@ def test_quantized_psum_error_bounds():
     def body(mode):
         def f(xs):
             return quantized_psum(xs.reshape(-1), "dp", mode)[None, :]
-        return shard_map_compat(f, mesh, in_specs=P("dp"),
-                                out_specs=P("dp"))
+        return jax.shard_map(f, mesh=mesh, in_specs=P("dp"),
+                             out_specs=P("dp"), check_vma=False)
 
     exact = np.asarray(jax.jit(body("none"))(jnp.asarray(x)))[0]
     assert np.array_equal(exact, x.sum(0).astype("float32")) or \

@@ -10,17 +10,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu.ops.pallas.support import pallas_supported
-
-if not pallas_supported(interpret=True):
-    # backend-capability probe (ops/pallas/support.py — shared with the
-    # fused-optimizer fallback): a host whose jax cannot execute pallas
-    # interpret mode at all SKIPS the kernel suite instead of failing
-    # it; the op-level flash_attention falls back to dense math there.
-    pytest.skip("pallas interpret mode unavailable on this backend",
-                allow_module_level=True)
-
-from paddle_tpu.ops.pallas.flash_attention import (  # noqa: E402
+from paddle_tpu.ops.pallas.flash_attention import (
     _dense_attention, flash_attention)
 
 B, H, S, D = 2, 3, 32, 16

@@ -162,15 +162,9 @@ class TestShardedEmbedding:
         def f(local_block, ids):
             return sharded_embedding_lookup(local_block[0], ids, "mp")
 
-        try:
-            smap = jax.shard_map(f, mesh=mesh,
-                                 in_specs=(P("mp"), P()), out_specs=P(),
-                                 check_vma=False)
-        except (AttributeError, TypeError):
-            from jax.experimental.shard_map import shard_map
-
-            smap = shard_map(f, mesh=mesh, in_specs=(P("mp"), P()),
-                             out_specs=P(), check_rep=False)
+        smap = jax.shard_map(f, mesh=mesh,
+                             in_specs=(P("mp"), P()), out_specs=P(),
+                             check_vma=False)
         out = jax.jit(smap)(jnp.asarray(blocks), jnp.asarray(ids))
         np.testing.assert_allclose(np.asarray(out), table[ids], rtol=1e-6)
 
@@ -197,15 +191,9 @@ class TestShardedEmbedding:
                 e = sharded_embedding_lookup(local_block[0], ids, "mp")
                 return jax.lax.psum(jnp.zeros(()), "mp") + (e ** 2).sum()
 
-            try:
-                smap = jax.shard_map(f, mesh=mesh,
-                                     in_specs=(P("mp"), P()),
-                                     out_specs=P(), check_vma=False)
-            except (AttributeError, TypeError):
-                from jax.experimental.shard_map import shard_map
-
-                smap = shard_map(f, mesh=mesh, in_specs=(P("mp"), P()),
-                                 out_specs=P(), check_rep=False)
+            smap = jax.shard_map(f, mesh=mesh,
+                                 in_specs=(P("mp"), P()),
+                                 out_specs=P(), check_vma=False)
             return smap(blocks3, ids)
 
         g = jax.jit(jax.grad(loss_fn))(jnp.asarray(blocks),

@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from paddle_tpu.parallel.mesh_utils import make_mesh, shard_map_compat
+from paddle_tpu.parallel.mesh_utils import make_mesh
 from paddle_tpu.parallel.moe import expert_parallel_moe, moe_reference
 
 N = 4
@@ -27,9 +27,9 @@ def _sharded(cf=2.0):
     def local(x, gate_w, w_in, w_out):
         return expert_parallel_moe(x, gate_w, w_in, w_out, "ep", cf, N)
 
-    return shard_map_compat(local, mesh,
-                            in_specs=(P("ep"), P(), P("ep"), P("ep")),
-                            out_specs=P("ep"))
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P("ep"), P(), P("ep"), P("ep")),
+                         out_specs=P("ep"), check_vma=False)
 
 
 def test_matches_oracle():

@@ -11,15 +11,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from paddle_tpu.ops.pallas.support import pallas_supported
-
-if not pallas_supported(interpret=True):
-    # backend-capability probe (ops/pallas/support.py): skip, don't
-    # fail, where jax cannot run pallas interpret mode at all
-    pytest.skip("pallas interpret mode unavailable on this backend",
-                allow_module_level=True)
-
-from paddle_tpu.ops.pallas.conv import (  # noqa: E402
+from paddle_tpu.ops.pallas.conv import (
     conv2d_bn_act, pallas_conv, pallas_conv_viable, route_pallas)
 
 

@@ -446,8 +446,6 @@ def test_strategy_psum_spellings_two_stage():
     from jax.sharding import PartitionSpec as P
 
     from paddle_tpu.ops.collective_ops import strategy_psum
-    from paddle_tpu.parallel.mesh_utils import shard_map_compat
-
     mesh = make_mesh([4, 2], ["dp", "sp"])
     x = jnp.arange(8 * 6, dtype=jnp.float32).reshape(8, 6)
 
@@ -455,8 +453,9 @@ def test_strategy_psum_spellings_two_stage():
         def body(v):
             return strategy_psum(v, ("dp", "sp"), strategy)
 
-        return np.asarray(jax.jit(shard_map_compat(
-            body, mesh, in_specs=P(("dp", "sp")), out_specs=P()))(x))
+        return np.asarray(jax.jit(jax.shard_map(
+            body, mesh=mesh, in_specs=P(("dp", "sp")), out_specs=P(),
+            check_vma=False))(x))
 
     want = run("ring")
     np.testing.assert_allclose(run("two_stage"), want, rtol=1e-6)
@@ -489,8 +488,6 @@ def test_error_feedback_cancels_bias():
     from jax.sharding import PartitionSpec as P
 
     from paddle_tpu.ops.collective_ops import quantized_psum
-    from paddle_tpu.parallel.mesh_utils import shard_map_compat
-
     n = 8
     mesh = make_mesh([n], ["dp"])
     rng = np.random.RandomState(7)
@@ -504,11 +501,12 @@ def test_error_feedback_cancels_bias():
     def step_plain(x):
         return quantized_psum(x, "dp", "int8")
 
-    f_ef = jax.jit(shard_map_compat(
-        step_ef, mesh, in_specs=(P("dp"), P("dp")),
-        out_specs=(P(), P("dp"))))
-    f_plain = jax.jit(shard_map_compat(
-        step_plain, mesh, in_specs=P("dp"), out_specs=P()))
+    f_ef = jax.jit(jax.shard_map(
+        step_ef, mesh=mesh, in_specs=(P("dp"), P("dp")),
+        out_specs=(P(), P("dp")), check_vma=False))
+    f_plain = jax.jit(jax.shard_map(
+        step_plain, mesh=mesh, in_specs=P("dp"), out_specs=P(),
+        check_vma=False))
 
     steps = 16
     r = jnp.zeros_like(jnp.asarray(base))

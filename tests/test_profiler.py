@@ -374,12 +374,23 @@ def test_flops_analytic_formulas():
 
 
 def test_mfu_est_normalization():
+    v5e = "TPU v5 lite"
+    assert prof.peak_flops(v5e) == 197e12
+    assert "TPU v5e" in prof.DEVICE_PEAKS[v5e]["source"]
     # one peak-flops-second of work in one second = MFU 1.0
-    assert prof.mfu_est(prof.peak_flops(False, 1), 1.0) == \
+    assert prof.mfu_est(prof.peak_flops(v5e), 1.0, v5e) == \
         pytest.approx(1.0)
-    assert prof.mfu_est(prof.peak_flops(True, 8), 2.0, bf16=True,
+    assert prof.mfu_est(prof.peak_flops(v5e, 8), 2.0, v5e,
                         n_devices=8) == pytest.approx(0.5)
-    assert prof.mfu_est(0, 1.0) is None
+    assert prof.mfu_est(0, 1.0, v5e) is None
+
+
+def test_no_peak_and_no_mfu_for_unlisted_device():
+    """A device the peaks table does not list — the CPU included —
+    gets no number, never another chip's peak."""
+    for kind in ("cpu", "TPU v9 imaginary"):
+        assert prof.peak_flops(kind) is None
+        assert prof.mfu_est(1e12, 1.0, kind) is None
 
 
 # -- span spooling ----------------------------------------------------------
@@ -616,12 +627,16 @@ def test_bench_profile_record_schema():
     bench = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(bench)
     rec = bench._profile_record(0.5, 1.97e12, {"matmul": 1.97e12},
-                                bf16=True, n_devices=8)
+                                n_devices=8, device_kind="TPU v5 lite")
     assert rec["flops_per_step"] == int(1.97e12)
     # 1.97e12 flops in 0.5s against 8 x 197e12 peak
     assert rec["mfu_est"] == pytest.approx(
         1.97e12 / 0.5 / (197e12 * 8))
     assert rec["n_devices"] == 8
+    # on the device this test runs on (cpu) there is no peak to
+    # normalize against
+    cpu = bench._profile_record(0.5, 1.97e12)
+    assert cpu["mfu_est"] is None and cpu["peak_flops"] is None
     # single- and multi-chip records share this schema; phase fields
     # appear only when phase profiling ran
     assert "phase_ms" not in rec

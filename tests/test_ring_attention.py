@@ -85,8 +85,6 @@ def test_ring_dp_sp_2d_mesh():
     """dp x sp 2-D mesh: batch and sequence sharded simultaneously."""
     from jax.sharding import PartitionSpec as P
 
-    from paddle_tpu.parallel.mesh_utils import shard_map_compat
-
     mesh2 = make_mesh([2, 4], ["dp", "sp"])
     q, k, v = _inputs(4)
 
@@ -94,8 +92,8 @@ def test_ring_dp_sp_2d_mesh():
         return ring_attention(q, k, v, "sp", causal=True, axis_size=4)
 
     spec = P("dp", None, "sp", None)
-    smap = shard_map_compat(local, mesh2, in_specs=(spec,) * 3,
-                            out_specs=spec)
+    smap = jax.shard_map(local, mesh=mesh2, in_specs=(spec,) * 3,
+                         out_specs=spec, check_vma=False)
     out = jax.jit(smap)(q, k, v)
     ref = reference_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),

@@ -33,7 +33,6 @@ from paddle_tpu.core import fusion
 from paddle_tpu.core.native_feed import AsyncDeviceFeeder
 from paddle_tpu.ops.pallas.fused_optimizer import (
     LANE_PAD, fused_optimizer_update)
-from paddle_tpu.ops.pallas.support import pallas_supported
 
 KNOBS = ("PADDLE_TPU_FUSED_OPTIMIZER", "PADDLE_TPU_FUSED_EPILOGUE",
          "PADDLE_TPU_ASYNC_FEED")
@@ -147,8 +146,6 @@ def test_pallas_kernel_matches_xla_path(op_type):
     """The pallas streaming kernel (interpret mode on CPU) is
     bit-identical to the XLA fallback on the same flat buffers — the
     two lowerings of the one update definition."""
-    if not pallas_supported(interpret=True):
-        pytest.skip("pallas interpret mode unavailable")
     sizes = [512, 321, 190]
     ps, gs, sts, flat, total, padded = _flat_inputs(op_type, sizes,
                                                     seed=3)

@@ -150,7 +150,7 @@ def test_wide_deep_sharded_table_mesh():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from paddle_tpu.parallel.mesh_utils import make_mesh, shard_map_compat
+    from paddle_tpu.parallel.mesh_utils import make_mesh
     from paddle_tpu.parallel.sharded_embedding import (
         build_sharded_table, sharded_embedding_lookup)
 
@@ -176,10 +176,10 @@ def test_wide_deep_sharded_table_mesh():
                 jnp.log1p(jnp.exp(-jnp.abs(logit)))
             return jax.lax.psum(ce.sum(), "dp")
 
-        smap = shard_map_compat(
-            f, mesh,
+        smap = jax.shard_map(
+            f, mesh=mesh,
             in_specs=(P("mp"), P("mp"), P(), P("dp"), P("dp")),
-            out_specs=P())
+            out_specs=P(), check_vma=False)
         return smap(blocks3, wblocks3, w_fc, ids_g, label_g)
 
     val, grads = jax.jit(jax.value_and_grad(loss_fn, argnums=(0, 1)))(

@@ -47,10 +47,6 @@ import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# private compile-cache dir: hermetic (a cache entry another process
-# corrupted mid-write must not fail — or pass — this gate)
-_CACHE = tempfile.mkdtemp(prefix="mc_smoke_cache_")
-
 
 def _run_config(extra_env):
     env = dict(os.environ)
@@ -58,7 +54,6 @@ def _run_config(extra_env):
         "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": (env.get("XLA_FLAGS", "").strip()
                       + " --xla_force_host_platform_device_count=8").strip(),
-        "PADDLE_TPU_COMPILE_CACHE": _CACHE,
     })
     env.update(extra_env)
     proc = subprocess.run(

@@ -36,7 +36,6 @@ import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-_CACHE = tempfile.mkdtemp(prefix="placement_smoke_cache_")
 _WORK = tempfile.mkdtemp(prefix="placement_smoke_")
 
 
@@ -60,7 +59,6 @@ def _run_config(extra_env, tag):
         "XLA_FLAGS": (env.get("XLA_FLAGS", "").strip()
                       + " --xla_force_host_platform_device_count=8"
                       ).strip(),
-        "PADDLE_TPU_COMPILE_CACHE": _CACHE,
         "PADDLE_TPU_SHARDED_UPDATE": "0",
     })
     env.update(extra_env)
