@@ -33,8 +33,10 @@ The set-up seconds and step milliseconds on the phase lines are SMOKE
 figures (a handful of steps, one window): evidence that the step runs
 and how long a cold or warm start takes, not benchmark measurements.
 
-The last stdout line is the result the driver reads:
-``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}``.
+The last stdout line is the result the driver reads, with exactly these
+keys: ``{"ok": true, "device": {"platform": "tpu", "kind": "...",
+"count": 1}}``. The run's wall seconds and XLA build totals are on the
+``summary`` phase line before it.
 """
 from __future__ import annotations
 
@@ -843,14 +845,14 @@ def main(argv=None) -> int:
     if len(devs) >= 4:
         phase_dp4(sizes, dev_rec, platform, xla)
 
-    result = {"ok": True,
-              "device": {"platform": platform,
-                         "kind": devs[0].device_kind, "count": len(devs)},
-              "wall_s": round(time.perf_counter() - t_all, 1)}
-    result.update(xla.snapshot())
-    if args.rehearse_cpu:
-        result["rehearsal"] = True
-    print(json.dumps(result), flush=True)
+    # what the whole run cost, as one more smoke line; the result line
+    # after it carries exactly the keys the driver reads and no others
+    _emit("summary", dev_rec, rehearsal=args.rehearse_cpu,
+          wall_s=round(time.perf_counter() - t_all, 1), **xla.snapshot())
+    print(json.dumps({"ok": True,
+                      "device": {"platform": platform,
+                                 "kind": devs[0].device_kind,
+                                 "count": len(devs)}}), flush=True)
     return 0
 
 

@@ -40,13 +40,21 @@ def test_rehearsal_runs_every_phase_on_cpu():
     assert proc.returncode == 0, proc.stderr[-3000:]
     lines = [json.loads(x) for x in proc.stdout.splitlines()
              if x.startswith("{")]
-    result = lines[-1]
-    assert result["ok"] is True and result["rehearsal"] is True
-    # never mistakable for a pass on the chip
+    # the LAST stdout line is the result, with exactly the keys the
+    # driver reads (an extra key gets the PR refused) ...
+    result = json.loads(proc.stdout.rstrip("\n").splitlines()[-1])
+    assert result == lines[-1]
+    assert set(result) == {"ok", "device"}
+    assert set(result["device"]) == {"platform", "kind", "count"}
+    assert result["ok"] is True
+    assert isinstance(result["device"]["kind"], str)
+    assert type(result["device"]["count"]) is int
+    # ... and never mistakable for a pass on the chip
     assert result["device"]["platform"] == "cpu"
     phases = [rec["phase"] for rec in lines[:-1]]
     assert phases == ["device", "train", "train", "train", "kernels",
-                      "dp4"], phases
+                      "dp4", "summary"], phases
+    assert lines[-2]["rehearsal"] is True and lines[-2]["wall_s"] > 0
     assert all(rec["platform"] == "cpu" and rec["smoke"]
                for rec in lines[:-1])
 
