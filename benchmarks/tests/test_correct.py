@@ -1,0 +1,161 @@
+"""How ``correct`` is decided, at the toy preset: the control (the reference
+with fp8 operands) must come out as not correct, and so must a run whose timed
+path is broken underneath."""
+import numpy as np
+import pytest
+
+from .conftest import PRESET, REPO
+
+
+@pytest.fixture(scope="module")
+def manifest():
+    from benchmarks.lib.manifest import Manifest
+
+    return Manifest(PRESET, REPO)
+
+
+def test_control_fails_and_sound_runs_pass(manifest):
+    import jax
+
+    from benchmarks import check_outputs
+
+    cell = manifest.cell("tiny_bert.static")
+    seeds = [31, 32, 33]
+    summary = check_outputs.readings(manifest, cell, jax.devices()[:1], seeds,
+                                     set(seeds), emit=lambda line: None)
+    limits = summary["limits"]
+    for name, limit in limits.items():
+        assert summary[name]["sound_max"] <= limit, (name, summary[name])
+    # the lower precision has to fail one of the cell's numbers, with room
+    assert summary["grad_norm"]["control_min"] > limits["grad_norm"]
+    assert summary["grad_norm"]["control_min"] > \
+        3 * summary["grad_norm"]["sound_max"]
+
+
+def resnet_readings(manifest):
+    import jax
+
+    from benchmarks import check_outputs
+
+    cell = manifest.cell("tiny_resnet.static")
+    summary = check_outputs.readings(manifest, cell, jax.devices()[:1], [41],
+                                     set(), emit=lambda line: None)
+    return {name: summary[name]["sound_max"] for name in summary["limits"]}
+
+
+def real_resnet_limits():
+    import json
+    import os
+
+    with open(os.path.join(REPO, "benchmarks", "traffic",
+                           "resnet50.static_b128.json")) as f:
+        return json.load(f)["limits"]
+
+
+def test_resnet_program_follows_its_reference(manifest):
+    """The resnet50 code at the toy preset's size (too slow on the CPU for a
+    whole run): the program's first steps stay inside the limits, which they
+    could not with one leaf mapped to the wrong parameter."""
+    got = resnet_readings(manifest)
+    limits = manifest.traffic(manifest.cell("tiny_resnet.static"))["limits"]
+    for name, limit in limits.items():
+        assert got[name] <= limit, (name, got)
+
+
+def test_resnet_with_half_the_learning_rate_is_not_correct(
+        manifest, monkeypatch):
+    """The worst leaf of this model swings too far from seed to seed for its
+    limit to catch a learning rate that is wrong by half; the median leaf's
+    does, at the real cell's limits."""
+    from benchmarks.drivers import static_executor
+
+    build = static_executor.Driver.build
+
+    def broken_build(self):
+        build(self)
+        names = [n for n in self.scope.local_var_names()
+                 if n.startswith("learning_rate")]
+        assert names
+        for n in names:
+            t = self.scope.find_var(n).get_tensor()
+            t.set(0.5 * np.asarray(t.array))
+
+    monkeypatch.setattr(static_executor.Driver, "build", broken_build)
+    got = resnet_readings(manifest)
+    limits = real_resnet_limits()
+    assert got["delta_norm_median"] == pytest.approx(0.5, abs=0.05)
+    assert got["delta_norm_median"] > 3 * limits["delta_norm_median"]
+    assert got["delta_norm"] <= limits["delta_norm"]   # the worst leaf's passes
+    assert got["grad_norm_median"] <= limits["grad_norm_median"]
+
+
+def test_resnet_with_half_the_batch_left_out_is_not_correct(
+        manifest, monkeypatch):
+    """Every step trains on the first half of its rows twice over: the
+    gradient the optimizer gets is another batch's."""
+    import jax.numpy as jnp
+
+    from benchmarks.drivers import static_executor
+
+    load = static_executor.Driver.load
+
+    def broken_load(self, params, pool):
+        def halved(v):
+            half = v.shape[0] // 2
+            return jnp.concatenate([v[:half], v[:half]])
+
+        load(self, params, [{k: halved(v) for k, v in batch.items()}
+                            for batch in pool])
+
+    monkeypatch.setattr(static_executor.Driver, "load", broken_load)
+    got = resnet_readings(manifest)
+    limits = real_resnet_limits()
+    assert got["loss"] > 3 * limits["loss"]
+    assert got["grad_norm_median"] > 3 * limits["grad_norm_median"]
+
+
+def test_a_step_that_leaves_its_state_unchanged_is_not_correct(
+        manifest, monkeypatch):
+    """Drives a whole run but for the look for a chip, with the optimizer's
+    learning rate zeroed underneath: every step returns the parameters it was
+    given."""
+    import time
+
+    import jax
+
+    from benchmarks.drivers import static_executor
+    from benchmarks.lib import harness
+
+    build = static_executor.Driver.build
+
+    def broken_build(self):
+        build(self)
+        names = [n for n in self.scope.local_var_names()
+                 if n.startswith("learning_rate")]
+        assert names
+        for n in names:
+            self.scope.find_var(n).get_tensor().set(
+                np.zeros((1,), np.float32))
+
+    monkeypatch.setattr(static_executor.Driver, "build", broken_build)
+    lines = []
+    monkeypatch.setattr(harness, "say", lambda *p: lines.append(" ".join(
+        str(x) for x in p)))
+    cell = manifest.cell("tiny_bert.static")
+    result = harness.run_cell(manifest, cell, 7, 1.0, False,
+                              jax.devices()[:1], None, time.perf_counter())
+    assert result["correct"] is False
+    failed = [l for l in lines if "FAILED" in l]
+    assert any("delta_norm" in l for l in failed), lines
+    assert not any("grad_norm" in l for l in failed), lines
+
+
+def test_worst_leaf_gap_uses_the_median_leaf_as_floor():
+    from benchmarks.lib import check
+
+    ref = {"a": 1.0, "b": 2.0, "tiny": 1e-9}
+    gap, leaf = check.worst_leaf_gap({"a": 1.1, "b": 2.0, "tiny": 5e-9}, ref)
+    assert leaf == "a" and gap == pytest.approx(0.1)
+    gap, leaf = check.worst_leaf_gap({"a": 1.0, "b": float("nan"),
+                                      "tiny": 1e-9}, ref)
+    assert gap == float("inf") and leaf == "b"
