@@ -21,7 +21,6 @@ for (SURVEY.md §7 step 3).
 """
 from __future__ import annotations
 
-import os
 from collections import OrderedDict
 from typing import Dict, List, Sequence, Set, Tuple
 
@@ -333,26 +332,6 @@ def _trace_block(block, env: Dict, step_seed) -> None:
     _trace_ops(block, block.ops, env, step_seed)
 
 
-# phase-annotation hook (observability.profiler): when installed, a
-# trace wraps each op in jax.named_scope("<phase>/<op_type>") so the
-# XPlane / Perfetto device trace shows forward/backward/collective/
-# optimizer regions. None (the default) costs exactly one branch per
-# _trace_ops call — trace-time only, never per step — and the traced
-# jaxpr is byte-identical to a pre-hook trace (the scope is never
-# entered). profiler.enable_annotation()/disable_annotation() toggle
-# it; PADDLE_TPU_PROFILE=1 arms it from the environment.
-_phase_annotator = None
-
-if os.environ.get("PADDLE_TPU_PROFILE", "").strip().lower() in (
-        "1", "true", "yes", "on"):
-    def _env_phase_annotator(block, ops):
-        from ..observability.profiler import trace_annotation
-
-        return trace_annotation(block, ops)
-
-    _phase_annotator = _env_phase_annotator
-
-
 def _trace_ops(block, ops, env: Dict, step_seed) -> None:
     """Trace a specific op sequence (a whole block, or one pipeline
     stage's slice of it) into the running jax trace.
@@ -360,7 +339,8 @@ def _trace_ops(block, ops, env: Dict, step_seed) -> None:
     Const-foldable host ops (range with constant bounds) are
     pre-evaluated on the host and embedded as XLA literals — applied
     here, not in a wrapper, so every trace entry point (whole program,
-    data-parallel shard, pipeline stage slice) gets the same treatment.
+    data-parallel shard, pipeline stage slice) gets the same treatment,
+    and the same op-role scopes.
     """
     infos = OpInfoMap.instance()
     fold_vals = [None]
@@ -432,19 +412,17 @@ def _trace_ops(block, ops, env: Dict, step_seed) -> None:
                 if n and v is not None:
                     env[n] = v
 
-    phases = (_phase_annotator(block, ops)
-              if _phase_annotator is not None else None)
-    if phases is not None:
-        import jax
+    import jax
 
-        for op, phase in zip(ops, phases):
-            # named_scope adds NO ops — only name-stack metadata — so
-            # the annotated jaxpr has the same equations as the plain
-            # trace, just phase-labeled for the device profile
-            with jax.named_scope("%s/%s" % (phase, op.type)):
-                trace_one(op)
-    else:
-        for op in ops:
+    from ..observability.profiler import classify_ops
+
+    # every op is traced inside jax.named_scope("<role>/<op_type>"),
+    # role = forward | backward | collective | optimizer, so a device
+    # trace of any compiled step says which part of the step an
+    # operation belongs to. named_scope adds NO equations, only
+    # name-stack metadata: trace-time cost, nothing per step.
+    for op, role in zip(ops, classify_ops(block, ops)):
+        with jax.named_scope("%s/%s" % (role, op.type)):
             trace_one(op)
 
 
@@ -523,6 +501,8 @@ def compile_program(program, feed_names: Tuple[str, ...],
                     fetch_names: Tuple[str, ...], state_names: Tuple[str, ...],
                     out_state_names: Tuple[str, ...], donate: bool = True):
     """Build (and cache) the jitted step function for this program."""
+    import time
+
     import jax
 
     key = (_program_version(program), feed_names, fetch_names, state_names,
@@ -551,8 +531,10 @@ def compile_program(program, feed_names: Tuple[str, ...],
         _obs.inc("executor.jit_traces")
         env = dict(state)
         env.update(feeds)
+        t_trace = time.perf_counter()
         try:
-            _trace_block(block, env, step_seed)
+            with _obs.tracing.span("executor/trace", cat="step"):
+                _trace_block(block, env, step_seed)
         except (NotImplementedError, TypeError) as e:
             # raised while TRACING the block (lax.while_loop rejecting
             # a varying carry raises TypeError); lowering and compiling
@@ -560,10 +542,16 @@ def compile_program(program, feed_names: Tuple[str, ...],
             raise UntraceableProgramError(
                 "program %s cannot be traced whole: %r"
                 % (program._uid, e)) from e
+        # the Python trace of the block: every process pays it before
+        # XLA's persistent cache can answer
+        _obs.inc("executor.trace_s", time.perf_counter() - t_trace)
         new_state = {n: env[n] for n in out_state_names if n in env}
         fetches = [env[n] for n in fetch_names]
         return fetches, new_state
 
+    from .compile_cache import scoped_name
+
+    step.__name__ = scoped_name("step")   # the name is in XLA's cache key
     fn = jax.jit(step, donate_argnums=(0,) if donate else ())
     _lru_put(_cache, key, fn, _CACHE_CAP)
     return fn
@@ -655,37 +643,36 @@ def lower_compiled_program(core, program, scope: Scope, feed: Dict,
 
 def run_compiled_program(core, program, scope: Scope, feed: Dict,
                          fetch_list: Sequence, return_numpy: bool = True):
+    """One compiled step. Each part of the host's work is a span of its
+    own (per-op detail lives in the XPlane device trace; the op-by-op
+    interpreter records per-op spans): under a live ``jax.profiler``
+    trace they say what the host was doing whenever the device idled."""
     import jax
-
-    import time
 
     from .. import observability as _obs
 
+    span = _obs.tracing.span
     device = core.place.jax_device()
-    fn, args, fetch_names = _stage_compiled_call(core, device, program,
-                                                 scope, feed, fetch_list)
-    # compiled path = ONE fused dispatch: a single step-level host span
-    # (per-op detail lives in the XPlane device trace; the op-by-op
-    # interpreter records per-op spans)
-    t_step = time.perf_counter() if _obs.enabled() else None
-    with jax.default_device(device), \
-            _obs.tracing.span("compiled_step", cat="step",
-                              path="compiled"):
+    with span("executor/stage", cat="step"):
+        fn, args, fetch_names = _stage_compiled_call(
+            core, device, program, scope, feed, fetch_list)
+    # returns once the step is enqueued (or, at a new shape, traced
+    # and compiled): from here to the first device operation
+    with jax.default_device(device), span("executor/launch", cat="step"):
         fetches, new_state = fn(*args)
     core.rng.advance()
-    if t_step is not None:
-        _obs.inc("executor.steps", path="compiled")
-        _obs.observe("executor.step_ms",
-                     (time.perf_counter() - t_step) * 1e3,
-                     path="compiled")
+    _obs.inc("executor.steps", path="compiled")
 
-    for n, v in new_state.items():
-        var = scope.var(n)
-        t = var.get_tensor()
-        t._array = v
-    results = []
-    for name, v in zip(fetch_names, fetches):
-        var = scope.var(name)
-        var.get_tensor()._array = v
-        results.append(np.asarray(v) if return_numpy else var.get_tensor())
-    return results
+    with span("executor/writeback", cat="step"):
+        for n, v in new_state.items():
+            scope.var(n).get_tensor()._array = v
+        tensors = []
+        for name, v in zip(fetch_names, fetches):
+            t = scope.var(name).get_tensor()
+            t._array = v
+            tensors.append(t)
+    if not return_numpy:
+        return tensors
+    # the wait for the device and the copy to the host
+    with span("executor/fetch", cat="step"):
+        return [np.asarray(v) for v in fetches]

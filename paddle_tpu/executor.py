@@ -31,9 +31,6 @@ def _as_place(place):
     return place
 
 
-_NO_FETCH = object()
-
-
 class Executor:
     def __init__(self, place=None):
         self.place = _as_place(place)
@@ -43,6 +40,7 @@ class Executor:
         self._compile_fallbacks: Dict = {}
         self._lod_lowered_cache: Dict = {}
         self._infer_clone_cache: Dict = {}
+        self._runs = 0   # the step= of this executor's spans
         self._closed = False
 
     def close(self):
@@ -73,71 +71,99 @@ class Executor:
         feed = feed or {}
         fetch_list = list(fetch_list or [])
 
+        from . import observability as _obs
+
+        # one span around the whole call; the spans opened inside it
+        # (by either path) inherit its step, the count of this
+        # executor's runs
+        self._runs += 1
+        with _obs.tracing.span("executor/run", cat="step",
+                               step=self._runs):
+            return self._run(program, scope, feed, fetch_list,
+                             return_numpy)
+
+    def _run(self, program, scope, feed, fetch_list, return_numpy):
         from .core.flags import flag as _flag
 
         # FLAGS_check_nan_inf needs the per-op interpreter (the check
         # runs after every op, reference operator.cc:1032)
         if not _flag("check_nan_inf"):
+            import time
+
+            from . import observability as _obs
             from .core.compiler_engine import (UntraceableProgramError,
-                                               _program_version,
                                                run_compiled_program)
 
-            # single-chip fusion rewrites (fused optimizer update /
-            # fused epilogues) — default-off knobs; the disabled path
-            # is two env reads (gate-4 budget), the enabled path is
-            # idempotent per program
-            from .core.fusion import maybe_rewrite_single_chip
+            t_run = time.perf_counter() if _obs.enabled() else None
+            with _obs.tracing.span("executor/prepare", cat="step"):
+                run_args = self._compiled_run_args(program, scope, feed,
+                                                   fetch_list)
+            if run_args is not None:
+                try:
+                    out = run_compiled_program(
+                        self._core, run_args[0], scope, run_args[1],
+                        fetch_list, return_numpy)
+                except UntraceableProgramError as e:
+                    # e.g. a while carry whose shape/dtype varies
+                    # across trips — valid for the host interpreter,
+                    # untraceable for lax.while_loop. Remember so
+                    # later steps skip the doomed trace attempt —
+                    # and SAY so: this is a large perf cliff that
+                    # must not be silent. A failure AFTER the trace
+                    # (kernel lowering, XLA compile) is not caught:
+                    # a traceable program that does not compile is
+                    # an error, not an interpreter run.
+                    import warnings
 
-            maybe_rewrite_single_chip(program, scope, self.place)
-            ver = _program_version(program)
-            if ver not in self._compile_fallbacks:
-                run_args = None
-                if self._can_whole_compile(program):
-                    run_args = (program, feed)
+                    from .core.compiler_engine import _program_version
+
+                    warnings.warn(
+                        "program %s falls back to op-by-op "
+                        "interpretation (whole-program compile "
+                        "failed: %r)" % (program._uid, e))
+                    self._compile_fallbacks[
+                        _program_version(program)] = repr(e)
+                    _obs.inc("executor.compile_fallbacks")
                 else:
-                    # LoD feeds + sequence ops: try the padded/masked
-                    # lowering (core/lod_lowering.py) so ragged text
-                    # programs still get the one-dispatch XLA path
-                    lowered = self._lod_lowered(program, feed, fetch_list)
-                    if lowered is not None:
-                        run_args = lowered
-                if run_args is not None:
-                    try:
-                        out = run_compiled_program(
-                            self._core, run_args[0], scope, run_args[1],
-                            fetch_list, return_numpy)
-                        # sampled in-production capture
-                        # (PADDLE_TPU_SAMPLE_EVERY): every Nth
-                        # successful compiled step re-profiles the
-                        # live program into a rolling report for the
-                        # steering daemon — default off, one branch
-                        from .observability import capture as _capture
+                    if t_run is not None:
+                        # host step latency: the whole call, fetch
+                        # included (short of the close of executor/run)
+                        _obs.observe("executor.step_ms",
+                                     (time.perf_counter() - t_run) * 1e3,
+                                     path="compiled")
+                    # sampled in-production capture
+                    # (PADDLE_TPU_SAMPLE_EVERY): every Nth
+                    # successful compiled step re-profiles the
+                    # live program into a rolling report for the
+                    # steering daemon — default off, one branch
+                    from .observability import capture as _capture
 
-                        _capture.maybe_sample_step(
-                            "executor", run_args[0], scope, run_args[1])
-                        return out
-                    except UntraceableProgramError as e:
-                        # e.g. a while carry whose shape/dtype varies
-                        # across trips — valid for the host interpreter,
-                        # untraceable for lax.while_loop. Remember so
-                        # later steps skip the doomed trace attempt —
-                        # and SAY so: this is a large perf cliff that
-                        # must not be silent. A failure AFTER the trace
-                        # (kernel lowering, XLA compile) is not caught:
-                        # a traceable program that does not compile is
-                        # an error, not an interpreter run.
-                        import warnings
-
-                        warnings.warn(
-                            "program %s falls back to op-by-op "
-                            "interpretation (whole-program compile "
-                            "failed: %r)" % (program._uid, e))
-                        self._compile_fallbacks[ver] = repr(e)
-                        from . import observability as _obs
-
-                        _obs.inc("executor.compile_fallbacks")
+                    _capture.maybe_sample_step(
+                        "executor", run_args[0], scope, run_args[1])
+                    return out
         return self._core.run_program(program, scope, feed, fetch_list,
                                       return_numpy)
+
+    def _compiled_run_args(self, program, scope, feed, fetch_list):
+        """(program, feed) to hand the whole-program compiler, or None
+        when this program takes the interpreter."""
+        from .core.compiler_engine import _program_version
+
+        # single-chip fusion rewrites (fused optimizer update /
+        # fused epilogues) — default-off knobs; the disabled path
+        # is two env reads (gate-4 budget), the enabled path is
+        # idempotent per program
+        from .core.fusion import maybe_rewrite_single_chip
+
+        maybe_rewrite_single_chip(program, scope, self.place)
+        if _program_version(program) in self._compile_fallbacks:
+            return None
+        if self._can_whole_compile(program):
+            return program, feed
+        # LoD feeds + sequence ops: try the padded/masked
+        # lowering (core/lod_lowering.py) so ragged text
+        # programs still get the one-dispatch XLA path
+        return self._lod_lowered(program, feed, fetch_list)
 
     def lower(self, program=None, feed=None, fetch_list=None,
               scope=None):

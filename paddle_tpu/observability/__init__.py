@@ -15,7 +15,11 @@ Metric families (see README "Runtime observability"):
 
 =====================================  ======================================
 ``executor.steps{path=...}``           counter: compiled | interpreter steps
-``executor.step_ms{path=...}``         histogram: host step latency
+``executor.step_ms{path=...}``         histogram: host step latency (compiled:
+                                       the whole Executor.run, fetch included)
+``executor.feed_ms``                   histogram: host feeds staged in a step
+``executor.trace_s``                   counter: seconds of Python tracing of
+                                       compiled steps (span executor/trace)
 ``executor.ops{type=...}``             counter: interpreter per-op executions
 ``executor.compiles``                  counter: whole-program (re)compiles
 ``executor.jit_traces``                counter: per-shape XLA (re)traces

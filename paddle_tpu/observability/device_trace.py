@@ -6,9 +6,9 @@ sync overhead and XLA's scheduler all sit between the host numbers and
 what the chip actually did. This module closes that gap:
 
 **Capture** (``capture_xspace`` / ``device_profile_step``). One bench
-step is re-jitted with phase annotation armed (the
-``jax.named_scope("<phase>/<op_type>")`` labels the PR-7 hook already
-injects at the shared trace entry in ``core/compiler_engine``) and run
+step is re-jitted (with the ``jax.named_scope("<phase>/<op_type>")``
+labels every trace gets at the shared trace entry in
+``core/compiler_engine``) and run
 a few times under ``jax.profiler`` — the same XPlane capture
 TensorBoard's profiler plugin consumes. Compilation happens *before*
 the trace starts, so the capture holds steady-state steps only.
@@ -576,9 +576,8 @@ def device_profile_step(program, scope, feed, mesh=None,
     program (same contract as ``profiler.profile_step``: startup run,
     rewrites applied; state is read, never written back).
 
-    The step is re-jitted with phase annotation armed — prior
-    annotation state is restored afterwards, so a default-off process
-    stays default-off — compiled before the capture window, then run
+    The step is re-jitted (its ops carry their phase scopes, as every
+    trace's do), compiled before the capture window, then run
     ``steps`` times under the XPlane trace. Returns the folded report,
     or None when the trace carried no phase-attributed device events
     (the caller keeps the host-measured numbers)."""
@@ -591,30 +590,14 @@ def device_profile_step(program, scope, feed, mesh=None,
                                 axis_name=axis_name)
     args = (ctx["state"], ctx["feed_vals"], jnp.uint32(seed))
     sync = profiler._whole_sync(ctx["ops"], ctx["persist_written"])
-    was_on = profiler.annotating()
-    profiler.enable_annotation()
-    # the persistent XLA compile cache keys on the computation, NOT its
-    # metadata — an executable cached from an UNANNOTATED compile of
-    # the same step (bench warmup, a previous run) would be served for
-    # the annotated trace and its XPlane would carry no phase scopes.
-    # Bypass the cache for this one compile; restore after.
-    cache_dir = getattr(jax.config, "jax_compilation_cache_dir", None)
-    try:
-        if cache_dir:
-            jax.config.update("jax_compilation_cache_dir", None)
-        fn = ctx["make_fn"](ctx["ops"], sync)
-        jax.block_until_ready(fn(*args))   # compile OUTSIDE the capture
+    fn = ctx["make_fn"](ctx["ops"], sync)
+    jax.block_until_ready(fn(*args))   # compile OUTSIDE the capture
 
-        def run():
-            for _ in range(max(1, steps)):
-                jax.block_until_ready(fn(*args))
+    def run():
+        for _ in range(max(1, steps)):
+            jax.block_until_ready(fn(*args))
 
-        space = capture_xspace(run, trace_dir)
-    finally:
-        if cache_dir:
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-        if not was_on:
-            profiler.disable_annotation()
+    space = capture_xspace(run, trace_dir)
     dev = fold_device_phases(space, steps=steps)
     if dev is not None:
         _emit_device_profile(dev)

@@ -16,15 +16,13 @@ update ops + anything after them). The classification is positional
 and name-based (``@GRAD`` outputs), mirroring the reference's op-role
 attr without carrying one.
 
-**Phase annotation** (``trace_annotation``). When armed
-(``PADDLE_TPU_PROFILE=1`` / ``enable_annotation()``), every trace
-entry point (``core.compiler_engine._trace_ops`` — shared by the
-executor, the mesh engine and the pipeline stage slices) wraps each
-op in ``jax.named_scope("<phase>/<op_type>")``, so an XPlane /
-Perfetto device trace shows phase-labeled regions. Default-off: the
-disabled path is one module-global check per trace — jaxprs are
-byte-identical to an unannotated trace (named_scope adds no ops, and
-the disabled branch never enters it).
+**Phase annotation**. Every trace entry point
+(``core.compiler_engine._trace_ops`` — shared by the executor, the
+mesh engine and the pipeline stage slices) wraps each op in
+``jax.named_scope("<phase>/<op_type>")`` by ``classify_ops``, always,
+so an XPlane / Perfetto device trace of any compiled step shows
+phase-labeled regions. named_scope adds no equation to the jaxpr: it
+costs a little at trace time and nothing per step.
 
 **Measured phase breakdown** (``profile_step``). Host-side timing of
 a compiled program by *phase-sliced re-execution*: the op list minus
@@ -75,9 +73,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 __all__ = [
-    # phase classification / annotation
-    "classify_ops", "enable_annotation", "disable_annotation",
-    "annotating", "trace_annotation",
+    # phase classification
+    "classify_ops",
     # measured profiling + analysis
     "build_phase_plan", "profile_step", "analyze_timeline",
     # FLOP accounting
@@ -142,53 +139,6 @@ def classify_ops(block, ops=None) -> List[str]:
         phases.append("optimizer" if seen_opt
                       else ("backward" if seen_bwd else "forward"))
     return phases
-
-
-# -- phase annotation (named_scope tagging at trace time) -------------------
-
-_annotating = os.environ.get("PADDLE_TPU_PROFILE", "").lower() in (
-    "1", "true", "yes", "on")
-
-
-def annotating() -> bool:
-    return _annotating
-
-
-def enable_annotation() -> None:
-    """Arm phase annotation: every subsequent program (re)trace wraps
-    its ops in ``jax.named_scope("<phase>/<op_type>")``. Only NEW
-    traces are annotated — already-compiled programs keep their cached
-    executables (bump the program version or clear the jit caches to
-    re-annotate a live program)."""
-    global _annotating
-    _annotating = True
-    from ..core import compiler_engine
-
-    compiler_engine._phase_annotator = trace_annotation
-
-
-def disable_annotation() -> None:
-    global _annotating
-    _annotating = False
-    import sys
-
-    ce = sys.modules.get(
-        __package__.rsplit(".", 1)[0] + ".core.compiler_engine")
-    if ce is not None:
-        ce._phase_annotator = None
-
-
-def trace_annotation(block, ops) -> Optional[List[str]]:
-    """Per-op phase labels for ``_trace_ops`` to wrap ops in
-    ``jax.named_scope`` — or None when annotation is off (the one
-    branch the disabled path pays; the jaxpr is then byte-identical
-    to a pre-annotation trace)."""
-    if not _annotating:
-        return None
-    try:
-        return classify_ops(block, ops)
-    except Exception:
-        return None
 
 
 # -- timeline analyzer (pure) -----------------------------------------------

@@ -19,17 +19,32 @@ disabled layer is one module-attribute load and one branch.
 
 Span records are tuples ``(name, ts_us, dur_us, tid, cat, args)``
 (args may be None) — directly convertible to chrome ``trace_event``
-"X" entries for Perfetto / chrome://tracing.
+"X" entries for Perfetto / chrome://tracing. A span's parent is the
+span that encloses it on the same thread; a span opened with a
+``step=`` argument hands it to every span opened inside it, so the
+spans of one ``Executor.run`` share one ``step``; ``nest()`` rebuilds
+the tree and gives each span its self time.
+
+Two sinks, one primitive: an armed span is also a
+``jax.profiler.TraceAnnotation`` named ``"pt:" + name``. Under a live
+``jax.profiler`` trace that puts every program span into the XPlane's
+host plane, on the clock of the device operations; with no trace
+running the annotation is one check of a flag. JAX is not imported for
+it: the class is taken once ``jax`` is in ``sys.modules``.
 """
 from __future__ import annotations
 
 import json
+import sys
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
-__all__ = ["span", "active", "trace_events", "chrome_trace",
-           "write_chrome_trace", "clear"]
+__all__ = ["span", "active", "trace_events", "nest", "chrome_trace",
+           "write_chrome_trace", "clear", "ANNOTATION_PREFIX"]
+
+# what a program span is called in a jax.profiler trace
+ANNOTATION_PREFIX = "pt:"
 
 _MAX_EVENTS = 65536
 
@@ -89,8 +104,21 @@ class _NullSpan:
 _NULL = _NullSpan()
 
 
+_open = threading.local()   # .span: the innermost open span of the thread
+_annotation = None          # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+def _annotation_class():
+    global _annotation
+    if _annotation is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation
+
+
 class Span:
-    __slots__ = ("name", "cat", "args", "_t0")
+    __slots__ = ("name", "cat", "args", "_t0", "_outer", "_note")
 
     def __init__(self, name: str, cat: str, args: Optional[Dict]):
         self.name = name
@@ -98,12 +126,27 @@ class Span:
         self.args = args
 
     def __enter__(self):
+        outer = self._outer = getattr(_open, "span", None)
+        if outer is not None and outer.args and "step" in outer.args:
+            if self.args is None:
+                self.args = {"step": outer.args["step"]}
+            else:
+                self.args.setdefault("step", outer.args["step"])
+        _open.span = self
+        note = _annotation or _annotation_class()
+        if note is not None:
+            note = note(ANNOTATION_PREFIX + self.name)
+            note.__enter__()
+        self._note = note
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
+        dur = time.perf_counter() - self._t0
+        if self._note is not None:
+            self._note.__exit__(*exc)
+        _open.span = self._outer
         if active():   # session may have stopped mid-span; drop then
-            dur = time.perf_counter() - self._t0
             _record(self.name, self._t0 * 1e6, dur * 1e6,
                     self.cat, self.args)
         return False
@@ -113,7 +156,8 @@ def span(name: str, cat: str = "op", **args):
     """Context manager timing a host span. No-op unless the layer is
     armed. Nesting works naturally (inner spans simply record shorter,
     later-starting intervals on the same thread id — chrome tracing
-    reconstructs the stack from containment)."""
+    and ``nest()`` reconstruct the stack from containment). ``step=``
+    is inherited by the spans opened inside."""
     if not (_metrics_on or _profiler_on):
         return _NULL
     return Span(name, cat, args or None)
@@ -153,6 +197,29 @@ def trace_events() -> List[Tuple]:
     session)."""
     with _lock:
         return list(_events)
+
+
+def nest(events) -> List[Dict]:
+    """The span records of ``events`` as dicts in order of start, each
+    with its ``parent`` (index into the result of the enclosing span on
+    the same thread, or None), its ``depth`` and its ``self_us``: the
+    duration less what its children cover."""
+    out: List[Dict] = []
+    stacks: Dict[int, List[int]] = {}
+    for name, ts, dur, tid, cat, args in sorted(
+            events, key=lambda e: (e[1], -e[2])):
+        stack = stacks.setdefault(tid, [])
+        while stack and ts >= (out[stack[-1]]["ts_us"]
+                               + out[stack[-1]]["dur_us"]):
+            stack.pop()
+        parent = stack[-1] if stack else None
+        if parent is not None:
+            out[parent]["self_us"] -= dur
+        out.append({"name": name, "ts_us": ts, "dur_us": dur, "tid": tid,
+                    "cat": cat, "args": args, "parent": parent,
+                    "depth": len(stack), "self_us": dur})
+        stack.append(len(out) - 1)
+    return out
 
 
 def clear() -> None:

@@ -69,6 +69,25 @@ def main():
     ok = all(c < PRIMITIVE_BUDGET_US
              for c in (null_span, enabled_chk, guarded_inc))
 
+    # what one whole site costs, off and armed (armed: a record in the
+    # buffer and a jax.profiler.TraceAnnotation with no trace running).
+    # The armed figure is reported, not gated: it is what a run with
+    # observability.enable() pays for each span of each step.
+    def _site():
+        with obs.tracing.span("x"):
+            pass
+
+    site_off = _bench_primitive(_site)
+    obs.enable()
+    try:
+        site_armed = _bench_primitive(_site, n=20000)
+    finally:
+        obs.disable()
+        obs.reset()
+    print("span site cost: off=%.3fus armed=%.3fus"
+          % (site_off, site_armed))
+    ok = ok and site_off < PRIMITIVE_BUDGET_US
+
     # ISSUE 5 paths. Disabled trace propagation must degenerate to a
     # branch (inject stamps nothing, child_span yields the shared
     # no-op); the flight ring is ALWAYS-ON by design (a black box that
@@ -90,24 +109,6 @@ def main():
           % (inject_cost, child_cost, flight_cost, PRIMITIVE_BUDGET_US))
     ok = ok and all(c < PRIMITIVE_BUDGET_US
                     for c in (inject_cost, child_cost, flight_cost))
-
-    # ISSUE 7: the step profiler's disabled path. Phase annotation off
-    # must stay one module-flag check (the per-trace hook is a single
-    # `is None` branch, and compiled programs are byte-identical — the
-    # jaxpr claim is test-gated in tests/test_profiler.py; this bounds
-    # the primitive), and the profiler must not have armed itself.
-    from paddle_tpu.observability import profiler as prof
-
-    assert not prof.annotating(), \
-        "phase annotation must default off (PADDLE_TPU_PROFILE unset)"
-    from paddle_tpu.core import compiler_engine as _ce
-
-    assert _ce._phase_annotator is None, \
-        "trace-time phase hook must be uninstalled by default"
-    annot_cost = _bench_primitive(prof.annotating)
-    print("profiler disabled cost: annotating()=%.3fus "
-          "(budget %.1fus)" % (annot_cost, PRIMITIVE_BUDGET_US))
-    ok = ok and annot_cost < PRIMITIVE_BUDGET_US
 
     # ISSUE 10: XPlane device-trace capture must default OFF — the
     # bench/runtime only consult one env read, nothing armed, no
@@ -236,12 +237,12 @@ def main():
         exe.run(main_p, feed=feed, fetch_list=[out])
     step_us = (time.perf_counter() - t0) / iters * 1e6
 
-    # compiled path: ~4 instrumentation touches per step (span + two
-    # guarded metric calls + enabled check); interpreter path: ~2/op.
-    # Use a conservative 4 + 2*ops bound.
+    # compiled path: ~9 instrumentation touches per step (the six
+    # executor/* spans, a guarded counter and two enabled checks);
+    # interpreter path: ~2/op. Use a conservative 10 + 2*ops bound.
     n_ops = len(main_p.global_block().ops)
     site_cost = max(null_span, enabled_chk, guarded_inc)
-    projected_us = (4 + 2 * n_ops) * site_cost
+    projected_us = (10 + 2 * n_ops) * site_cost
     frac = projected_us / step_us
     print("tiny step: %.1fus; projected disabled-obs cost: %.2fus "
           "(%.4f%% of step, budget %.1f%%)"

@@ -3,10 +3,12 @@
 Parity: /root/reference/tools/timeline.py (profile proto -> chrome
 trace). Host-side events recorded by fluid.profiler convert directly:
 per-OP events when the interpreter executes (host/LoD programs,
-FLAGS_check_nan_inf), one "compiled_step" event per dispatch on the
-whole-compiled path (a compiled step IS one fused kernel — per-op
-device detail lives in the jax.profiler XPlane trace dir for
-TensorBoard/Perfetto, which replaces the CUPTI DeviceTracer path).
+FLAGS_check_nan_inf), the "executor/run" span and its parts (stage,
+launch, writeback, fetch) per step on the whole-compiled path (a
+compiled step IS one fused kernel — per-op device detail lives in the
+jax.profiler XPlane trace dir for TensorBoard/Perfetto, which
+replaces the CUPTI DeviceTracer path; the same spans are in it as
+"pt:executor/*").
 
 Usage:
     with fluid.profiler.profiler():

@@ -28,7 +28,6 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu.observability import device_trace as dtr
-from paddle_tpu.observability import profiler as prof
 
 MS = 1_000_000_000   # ps per ms
 
@@ -247,10 +246,8 @@ def test_device_profile_step_end_to_end(tmp_path):
         feed = {"dx": rng.rand(16, 8).astype("float32"),
                 "dlbl": rng.randint(0, 10, (16, 1)).astype("int64")}
         exe.run(main, feed=feed, fetch_list=[loss])
-        assert not prof.annotating()   # default off before...
         dev = dtr.device_profile_step(main, scope, feed, steps=2,
                                       trace_dir=str(tmp_path))
-        assert not prof.annotating()   # ...and restored after
     assert dev is not None, "real capture folded to empty"
     assert dev["n_attributed"] > 0
     assert set(dev["device_phase_ms"]) <= set(dtr.PHASES)

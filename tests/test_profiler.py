@@ -29,7 +29,6 @@ def _clean_obs():
     obs.reset()
     obs.disable()
     tracing._set_spool(None)
-    prof.disable_annotation()
 
 
 def _small_program(batch=64, hidden=64):
@@ -221,7 +220,7 @@ def test_profile_step_emits_metrics_and_phase_spans():
     assert "phase" in cats  # chrome rows ride the normal span pipeline
 
 
-# -- phase annotation: off = byte-identical jaxpr, on = zero new ops -------
+# -- phase annotation: always there, and it adds zero ops -------------------
 
 
 def _jaxpr_of(main, state, loss_name):
@@ -243,11 +242,23 @@ def _jaxpr_of(main, state, loss_name):
                              jnp.asarray(feed["py"]))
 
 
-def test_annotation_off_is_inert_and_on_adds_no_ops():
-    from paddle_tpu.core import compiler_engine as ce
+def _roles_in(jaxpr):
+    return {str(e.source_info.name_stack).split("/")[0]
+            for e in jaxpr.jaxpr.eqns}
 
-    assert ce._phase_annotator is None  # default-off contract
+
+def test_roles_are_there_by_default_and_add_no_ops(monkeypatch):
+    import contextlib
+
+    import jax
+
+    from paddle_tpu.core import compiler_engine as ce
     from paddle_tpu.core.compiler_engine import _analyze
+
+    # the knob and its plumbing are gone: there is nothing to arm
+    assert not hasattr(ce, "_phase_annotator")
+    assert not hasattr(prof, "enable_annotation")
+    assert not hasattr(prof, "annotating")
 
     main, startup, loss = _small_program(batch=8)
     scope = fluid.Scope()
@@ -264,21 +275,49 @@ def test_annotation_off_is_inert_and_on_adds_no_ops():
         # params); the GRAPH must be identical, the addresses can't be
         return re.sub(r"0x[0-9a-f]+", "0xADDR", str(jx))
 
-    base1 = _jaxpr_of(main, state, loss.name)
-    base2 = _jaxpr_of(main, state, loss.name)
-    # off: tracing is deterministic — byte-identical jaxpr, no hook
-    assert norm(base1) == norm(base2)
-    try:
-        prof.enable_annotation()
-        assert ce._phase_annotator is not None
-        annotated = _jaxpr_of(main, state, loss.name)
-    finally:
-        prof.disable_annotation()
-    assert ce._phase_annotator is None
-    # on: named_scope adds NO equations — same op graph, only names
-    assert len(annotated.jaxpr.eqns) == len(base1.jaxpr.eqns)
+    annotated = _jaxpr_of(main, state, loss.name)
+    # tracing is deterministic — byte-identical jaxpr
+    assert norm(annotated) == norm(_jaxpr_of(main, state, loss.name))
+    # every equation of the default trace carries its op's role
+    assert _roles_in(annotated) == {"forward", "backward", "optimizer"}
+    # the same trace with the scopes taken out: named_scope adds NO
+    # equations — same op graph, only names
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = _jaxpr_of(main, state, loss.name)
+    monkeypatch.undo()
+    assert not _roles_in(plain) & {"forward", "backward", "optimizer"}
+    assert len(annotated.jaxpr.eqns) == len(plain.jaxpr.eqns)
     assert [e.primitive.name for e in annotated.jaxpr.eqns] == \
-        [e.primitive.name for e in base1.jaxpr.eqns]
+        [e.primitive.name for e in plain.jaxpr.eqns]
+
+
+def test_lowered_step_names_all_three_roles():
+    """What XLA is given: the lowered text of a compiled step carries
+    forward/, backward/ and optimizer/ scopes with no knob set."""
+    import re
+
+    main, startup, loss = _small_program(batch=8)
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        exe.run(main, feed=_feed(8), fetch_list=[loss])
+        text = exe.lower(main, feed=_feed(8),
+                         fetch_list=[loss]).as_text(debug_info=True)
+    scopes = set(re.findall(
+        r"\b(forward|backward|optimizer|collective)/([a-z_0-9]+)", text))
+    assert {r for r, _ in scopes} == {"forward", "backward", "optimizer"}
+    assert ("forward", "mul") in scopes
+    assert ("backward", "mul_grad") in scopes
+    assert ("optimizer", "momentum") in scopes
+    # JAX's persistent-cache key leaves scopes out, a function's name
+    # in: the step's name carries the version of what traces record, so
+    # a cache warmed before roles existed cannot serve this step
+    from paddle_tpu.core import compile_cache
+
+    assert compile_cache.scoped_name("step") == "step_s1"
+    assert "module @jit_step_s1 " in text
 
 
 @pytest.mark.slow
@@ -293,7 +332,7 @@ def test_gate4_overhead_guard_passes():
     # the same -u list ci/check.sh gate 4 uses
     env = {k: v for k, v in os.environ.items()
            if k not in ("PADDLE_TPU_METRICS", "FLAGS_tpu_metrics",
-                        "PADDLE_TPU_METRICS_DIR", "PADDLE_TPU_PROFILE",
+                        "PADDLE_TPU_METRICS_DIR",
                         "PADDLE_TPU_DEVICE_TRACE",
                         "PADDLE_TPU_VERIFY_IR",
                         "PADDLE_TPU_FUSED_OPTIMIZER",
@@ -309,7 +348,7 @@ def test_gate4_overhead_guard_passes():
         if proc.returncode == 0:
             break
     assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "annotating()" in proc.stdout
+    assert "span site cost: off=" in proc.stdout
 
 
 # -- analytic FLOP accounting ----------------------------------------------
