@@ -766,9 +766,9 @@ def bench_dygraph_bert(batch=32, seq_len=128, iters=8, n_layers=12,
 def _build_gpt_long(batch, seq_len, d_model=1024, n_heads=16,
                     n_layers=2, vocab=8192, use_bf16=True):
     """Small causal LM at LONG sequence — the config that exists to
-    exercise the pallas flash-attention training kernels (BASELINE.md
-    round-4 table: at seq 4096 flash fwd+bwd measures 2.3x XLA's dense
-    lowering, and beyond 8k dense does not compile at all)."""
+    exercise the STREAMING pallas flash-attention training kernels (T
+    beyond the op's short path; what the kernels measure on the v5e
+    since PR 21 is in PERF.md section 6, "PR 25")."""
     import paddle_tpu as fluid
     from paddle_tpu import layers
 

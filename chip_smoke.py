@@ -62,6 +62,7 @@ FULL = {
                 seq=4096, steps=3),
     "flash": dict(b=1, h=4, s=4096, d=64),
     "masked": dict(b=64, h=8, s=256, d=64),
+    "short": dict(b=32, h=12, s=512, d=64),
     "opt_elems": 2 * 1024 * 1024,
     "conv": dict(batch=8, hw=28, cin=128, cout_1x1=512, cout_3x3=128),
     "decode": dict(streams=5, max_tokens=12),
@@ -74,6 +75,7 @@ REHEARSAL = {
                 seq=256, steps=2),
     "flash": dict(b=1, h=2, s=256, d=16),
     "masked": dict(b=2, h=2, s=128, d=16),
+    "short": dict(b=2, h=2, s=128, d=16),
     "opt_elems": 4096,
     "conv": dict(batch=1, hw=8, cin=128, cout_1x1=128, cout_3x3=128),
     "decode": dict(streams=3, max_tokens=6),
@@ -486,8 +488,14 @@ def phase_kernels(sizes, dev_rec, platform, xla):
 
     rng = np.random.RandomState(7)
 
-    def flash_case(name, c, causal, lengths, tol):
+    def flash_case(name, c, causal, lengths, tol, path, block=None):
+        """``path``: the kernels the shapes must select ("stream": fwd,
+        dQ, dK+dV; "short": fwd and one backward kernel). ``block``
+        smaller than the sequence keeps a short sequence on the
+        streaming kernels."""
         shape = (c["b"], c["h"], c["s"], c["d"])
+        blocks = {} if block is None else {"block_q": block,
+                                           "block_k": block}
         q, k, v, w = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
                       for _ in range(4))
         scale = float(c["d"]) ** -0.5
@@ -496,7 +504,8 @@ def phase_kernels(sizes, dev_rec, platform, xla):
         def kernel(q, k, v):
             def f(q, k, v):
                 o = fa.flash_attention(q, k, v, causal=causal,
-                                       force_pallas=True, lengths=lens)
+                                       force_pallas=True, lengths=lens,
+                                       **blocks)
                 return jnp.sum(o.astype(jnp.float32)
                                * w.astype(jnp.float32)), o
             (_, o), g = jax.value_and_grad(f, argnums=(0, 1, 2),
@@ -513,22 +522,33 @@ def phase_kernels(sizes, dev_rec, platform, xla):
                                                has_aux=True)(q, k, v)
             return (o,) + g
 
-        n = check_mosaic(name, kernel, (q, k, v), 3)
+        took = fa.attention_path(q, k, force_pallas=True, **blocks)
+        assert took == path, (name, took)
+        n = check_mosaic(name, kernel, (q, k, v),
+                         3 if path == "stream" else 2)
         got = jax.jit(kernel)(q, k, v)
         ref = jax.jit(reference)(q, k, v)
         errs = {t: _rel_err(g, r)
                 for t, g, r in zip(("out", "dq", "dk", "dv"), got, ref)}
         assert max(errs.values()) < tol, (name, errs)
-        report[name] = {"mosaic_calls": n, "rel_err": errs, "tol": tol}
+        report[name] = {"mosaic_calls": n, "path": took, "rel_err": errs,
+                        "tol": tol}
 
     # bf16 in and out: the output and the three gradients are each
     # rounded to bf16 (2^-9 of their magnitude) and P / dS pass through
     # one bf16 MXU pass inside the kernel where the reference keeps
     # f32 — 2e-2 of the tensor's max leaves ~4x over what that predicts
-    flash_case("flash_causal", sizes["flash"], True, None, 2e-2)
+    f = sizes["flash"]   # the rehearsal's toy length would go short
+    flash_case("flash_causal", f, True, None, 2e-2, "stream",
+               block=None if f["s"] > 1024 else f["s"] // 2)
     m = sizes["masked"]
     lengths = rng.randint(m["s"] // 2, m["s"] + 1, (m["b"],))
-    flash_case("flash_masked", m, False, lengths, 2e-2)
+    flash_case("flash_masked", m, False, lengths, 2e-2, "stream",
+               block=m["s"] // 2)
+    # the short path: BERT's attention as the benchmark's cell runs it,
+    # and transformer_wmt's (causal + lengths) as the model routes it
+    flash_case("flash_short", sizes["short"], False, None, 2e-2, "short")
+    flash_case("flash_short_masked", m, True, lengths, 2e-2, "short")
 
     # -- fused optimizer over a flat buffer vs _update_math ----------------
     n_el = sizes["opt_elems"]

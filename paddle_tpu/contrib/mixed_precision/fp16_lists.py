@@ -48,6 +48,9 @@ white_list = {
     "conv2d_transpose",
     "matmul",
     "mul",
+    # q, k, v reach the kernels in bf16 (MXU operands); scores, softmax
+    # statistics and accumulators are float32 inside them
+    "flash_attention",
 }
 
 # numerically sensitive reductions/losses/normalizations: keep f32

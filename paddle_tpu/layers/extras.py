@@ -960,18 +960,28 @@ __all__ += ["sequence_slice", "sequence_unpad", "im2sequence",
 def flash_attention(q, k, v, causal=False, scale=0.0, lengths=None):
     """Fused attention over [B, H, S, D] (the multihead hot path —
     reference fused/multihead_matmul_op.cu). Lowers to the Pallas flash
-    kernel on TPU; ``apply_sequence_parallel`` rewrites it to ring
-    attention over an 'sp' mesh axis for long-context training.
-    ``lengths`` ([B] int) masks padded keys inside the kernel."""
+    kernels on TPU (which ones is the op's choice, from the shapes);
+    ``apply_sequence_parallel`` rewrites it to ring attention over an
+    'sp' mesh axis for long-context training. ``lengths`` ([B] int)
+    masks padded keys inside the kernel. The op also binds ``LSE``, the
+    residual its grad op reads, so the backward runs no second
+    forward."""
     helper = LayerHelper("flash_attention", input=q)
     out = helper.create_variable_for_type_inference(q.dtype)
+    lse = helper.create_variable_for_type_inference("float32",
+                                                    stop_gradient=True)
     ins = {"Q": [q], "K": [k], "V": [v]}
     if lengths is not None:
         ins["Lengths"] = [lengths]
+    # no shape inference: it would trace the kernels at build time (and
+    # count a path that no step runs); Out has Q's shape
     helper.append_op(
         "flash_attention", inputs=ins,
-        outputs={"Out": [out]},
-        attrs={"causal": bool(causal), "scale": float(scale)})
+        outputs={"Out": [out], "LSE": [lse]},
+        attrs={"causal": bool(causal), "scale": float(scale)},
+        infer_shape=False)
+    if not framework.in_dygraph_mode():
+        out.shape = tuple(q.shape)
     return out
 
 

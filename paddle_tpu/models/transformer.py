@@ -47,15 +47,14 @@ def multi_head_attention(q_in, num_heads, d_model, dropout=0.0,
     (decoder self-attention). Use ``attn_bias`` only for masks that
     are not expressible as (causal x per-row-length).
 
-    ``use_flash``: None = auto — the pallas flash path for unmasked
-    INFERENCE at any length, for masked (kv_lengths) attention at any
-    length, and for unmasked dropout-free TRAINING when T >= 2048:
-    with tuned 512x1024 blocks the kernels measure 1.45x (S=2048) to
-    2.32x (S=4096) FASTER than XLA's dense lowering on v5e fwd+bwd,
-    and at S=8192/16384 they train in 68/190 ms/step where dense does
-    not compile at all; at T <= 1024 the two are within variance, so
-    short unmasked sequences keep the dense path (bench
-    comparability). True/False force."""
+    ``use_flash``: None = auto — the ``flash_attention`` op wherever
+    the mask is expressible: self-attention with no additive
+    ``attn_bias`` and no attention dropout in training, at ANY length.
+    The op sees the shapes and picks the kernels (whole-tile "short"
+    kernels up to T = 1024, streaming ones beyond, dense math off the
+    TPU; ops/pallas/flash_attention.py), so no T x T matrix reaches HBM
+    in either direction. What each path measures on the v5e: PERF.md
+    section 6, "PR 25". True/False force / forbid."""
     B, T, D = q_in.shape
     kv = q_in if kv_in is None else kv_in
     T_kv = kv.shape[1]
@@ -73,8 +72,7 @@ def multi_head_attention(q_in, num_heads, d_model, dropout=0.0,
     if use_flash is None:
         # self-attention only: the kernel grid assumes T_q == T_kv
         use_flash = attn_bias is None and kv_in is None and (
-            is_test or dropout == 0) and (
-            kv_lengths is not None or is_test or T >= 2048)
+            is_test or dropout == 0)
     elif use_flash:
         # honor the force or say why it cannot be honored — silently
         # falling back would invalidate kernel benchmarks/debugging
@@ -88,27 +86,15 @@ def multi_head_attention(q_in, num_heads, d_model, dropout=0.0,
                 "use_flash=True: attention dropout is not supported in "
                 "the flash kernel; set dropout=0")
     if use_flash and attn_bias is None and (is_test or dropout == 0):
-        # no additive mask -> the flash path (pallas kernels on TPU:
-        # the T x T score matrix never hits HBM in EITHER direction —
-        # the backward recomputes probabilities blockwise from the
-        # saved logsumexp, so training memory is O(T·D)). Attention
-        # dropout keeps the dense lowering (no dropout state in the
-        # kernel). kv_lengths rides into the kernel as the padding
-        # mask.
-        from ..layer_helper import LayerHelper
-
-        helper = LayerHelper("flash_attention", input=q_in)
-        ctx = helper.create_variable_for_type_inference(q_in.dtype)
-        ins = {"Q": [q], "K": [k], "V": [v]}
-        if kv_lengths is not None:
-            ins["Lengths"] = [kv_lengths]
-        helper.append_op("flash_attention",
-                         inputs=ins,
-                         outputs={"Out": [ctx]},
-                         attrs={"causal": bool(causal),
-                                "scale": float(head) ** -0.5},
-                         infer_shape=False)
-        ctx.shape = (B, num_heads, T, head)
+        # no additive mask -> the flash_attention op: the T x T score
+        # matrix never hits HBM in EITHER direction (the backward
+        # recomputes the probabilities from the saved logsumexp).
+        # Attention dropout keeps the dense lowering (no dropout state
+        # in the kernels). kv_lengths rides into the kernel as the
+        # padding mask.
+        ctx = layers.flash_attention(q, k, v, causal=causal,
+                                     scale=float(head) ** -0.5,
+                                     lengths=kv_lengths)
     else:
         q = layers.scale(q, scale=float(head) ** -0.5)
         scores = layers.matmul(q, k, transpose_y=True)  # [B, H, T, T]

@@ -21,6 +21,8 @@ Metric families (see README "Runtime observability"):
 ``executor.trace_s``                   counter: seconds of Python tracing of
                                        compiled steps (span executor/trace)
 ``executor.ops{type=...}``             counter: interpreter per-op executions
+``kernels.flash_attention{path=...}``  counter: traces of the flash_attention
+                                       op, by kernels: short | stream | dense
 ``executor.compiles``                  counter: whole-program (re)compiles
 ``executor.jit_traces``                counter: per-shape XLA (re)traces
 ``executor.compile_fallbacks``         counter: compiled -> interpreter drops

@@ -189,23 +189,74 @@ def _fused_residual_layer_norm(ins, attrs):
             "Variance": ln["Variance"]}
 
 
+def _flash_attention_grad(ins, attrs):
+    """dQ, dK, dV from the forward op's own ``Out`` and ``LSE``: the
+    backward kernels alone. XLA does not merge the kernel a ``jax.vjp``
+    re-run of the forward makes with the forward op's (two custom calls
+    of different names), so the generic auto-VJP grad would run the
+    forward twice a layer. A program whose forward op bound no ``LSE``
+    (or ran the dense math, which has none) re-runs the forward under
+    ``jax.vjp`` as every auto-VJP grad op does."""
+    from .pallas.flash_attention import (flash_attention,
+                                         flash_attention_bwd)
+
+    q, k, v = ins["Q"], ins["K"], ins["V"]
+    lengths = ins.get("Lengths")
+    causal = bool(attrs.get("causal"))
+    scale = attrs.get("scale", 0.0) or float(q.shape[-1]) ** -0.5
+    g = ins["Out@GRAD"].astype(q.dtype)
+    if ins.get("LSE") is not None:
+        dq, dk, dv = flash_attention_bwd(
+            q, k, v, lengths, ins["Out"], ins["LSE"], g, causal, scale)
+    else:
+        _, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=causal, scale=scale, lengths=lengths),
+            q, k, v)
+        dq, dk, dv = vjp(g)
+    return {"Q@GRAD": dq, "K@GRAD": dk, "V@GRAD": dv}
+
+
+# registered before its forward op, so that no auto-VJP grad op is made
+register_op(
+    "flash_attention_grad",
+    inputs=[In("Q"), In("K"), In("V"),
+            In("Lengths", dispensable=True, no_grad=True),
+            In("Out", dispensable=True), In("LSE", dispensable=True),
+            In("Out@GRAD")],
+    outputs=[Out("Q@GRAD", dispensable=True),
+             Out("K@GRAD", dispensable=True),
+             Out("V@GRAD", dispensable=True)],
+    attrs={"causal": False, "scale": 0.0},
+    grad=None,
+)(_flash_attention_grad)
+
+
 @register_op(
     "flash_attention",
     inputs=[In("Q"), In("K"), In("V"),
             In("Lengths", dispensable=True, no_grad=True)],
-    outputs=[Out("Out")],
+    outputs=[Out("Out"), Out("LSE", dispensable=True, no_grad=True)],
     attrs={"causal": False, "scale": 0.0},
 )
 def _flash_attention(ins, attrs):
-    """Flash attention over [B, H, S, D] (pallas kernel on TPU, exact
-    dense math elsewhere; see ops/pallas/flash_attention.py).
-    ``Lengths`` [B] int: per-row valid-KV count — the kernel-side
-    padding mask (reference's additive src_slf_attn_bias)."""
-    from .pallas import flash_attention
+    """Attention over [B, H, S, D] with no S x S matrix in HBM: Pallas
+    kernels where the computation runs on a TPU (which ones is decided
+    from the shapes, see ops/pallas/flash_attention.py), the same dense
+    math elsewhere. ``Lengths`` [B] int: per-row valid-KV count — the
+    kernel-side padding mask (reference's additive src_slf_attn_bias).
+    ``LSE`` is the log-sum-exp residual ``flash_attention_grad`` reads
+    (None where the dense math ran). Each trace of the op counts the
+    path it took: ``kernels.flash_attention{path=short|stream|dense}``."""
+    from .. import observability as _obs
+    from .pallas.flash_attention import (attention_path,
+                                         flash_attention_with_lse)
 
     q, k, v = ins["Q"], ins["K"], ins["V"]
+    if _obs.enabled():
+        _obs.inc("kernels.flash_attention", path=attention_path(q, k))
     scale = attrs.get("scale", 0.0) or None
-    return {"Out": flash_attention(q, k, v,
-                                   causal=bool(attrs.get("causal")),
-                                   scale=scale,
-                                   lengths=ins.get("Lengths"))}
+    out, lse = flash_attention_with_lse(
+        q, k, v, causal=bool(attrs.get("causal")), scale=scale,
+        lengths=ins.get("Lengths"))
+    return {"Out": out, "LSE": lse}

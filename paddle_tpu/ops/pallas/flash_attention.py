@@ -3,7 +3,10 @@
 Parity intent: the reference hand-fuses attention for inference in CUDA
 (operators/fused/multihead_matmul_op.cu, math/bert_encoder_functor.cu);
 this is the TPU-native equivalent, done the flash way so the S x S
-score matrix never materializes in HBM:
+score matrix never materializes in HBM. Two sets of kernels, chosen by
+``flash_attention`` from the shapes it is given (``_plan``):
+
+**Streaming** (long S):
 
 - forward: grid = (batch*heads, q_blocks, k_blocks) with the K
   dimension iterated sequentially ("arbitrary") so the running-softmax
@@ -18,6 +21,17 @@ score matrix never materializes in HBM:
   Two kernels: dQ iterates K blocks per Q block; dK/dV iterates Q
   blocks per K block; both consume the dense precomputed
   delta = rowsum(dO ∘ O) (an elementwise pass XLA fuses).
+
+**Short** (S <= 1024 with the default blocks: a head's whole [S, S]
+score tile fits VMEM): grid = (batch*heads / heads-per-step,); one
+block over K means a plain softmax (no running max, no rescale); MXU
+operands stay in the dtype they arrive in (bf16 under AMP) with
+float32 accumulation, and scores, max, sum, LSE are float32 in VMEM;
+the per-head code of a step is unrolled so that one head's VPU passes
+overlap the next one's matmuls; ONE backward kernel yields dQ, dK, dV
+from one S/P/dP (five matmuls a head instead of seven). Both kernels
+work on the transposed tile [Tk, Tq], which keeps LSE and delta
+lane-dense rows and transposes only [S, D]-sized operands.
 
 Where the computation runs on a TPU (``core.place.compute_platform``)
 the public entry builds the kernels, and a kernel Mosaic refuses
@@ -450,25 +464,239 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
 
 
 # ---------------------------------------------------------------------------
+# short sequences: the whole score tile of a head lives in VMEM
+# ---------------------------------------------------------------------------
+
+# What the short path may ask of VMEM (v5e has 128 MiB; Mosaic's default
+# scoped limit of 16 MiB is raised to what the shapes need).
+SHORT_VMEM_BUDGET = 64 << 20
+# A grid step takes up to this many query rows (heads x T) and heads: the
+# per-head code is unrolled, so that one head's VPU passes overlap the
+# next one's matmuls. Measured on the v5e (PERF.md section 6, PR 25):
+# 16 heads at T = 128, 8 at 512, 4 at 1024; more is slower at 1024.
+_SHORT_ROWS = 4096
+_SHORT_MAX_HEADS = 16
+
+
+def _short_vmem_bytes(heads, T, D, itemsize):
+    """VMEM the backward kernel needs (the forward needs less): eight
+    [heads, T, D] blocks, double-buffered, and one head's float32
+    [T, T] temporaries (S, P, dP, dS and their operand-dtype copies)."""
+    return 2 * 8 * heads * T * D * itemsize + 6 * T * T * 4
+
+
+def _short_heads(BH, T, D, itemsize):
+    """Heads a grid step works on, or 0 where the short path does not
+    apply: the largest divisor of ``BH`` within ``_SHORT_ROWS`` rows,
+    ``_SHORT_MAX_HEADS`` and the VMEM budget."""
+    for heads in range(min(_SHORT_MAX_HEADS, max(1, _SHORT_ROWS // T), BH),
+                       0, -1):
+        if BH % heads == 0 and _short_vmem_bytes(
+                heads, T, D, itemsize) <= SHORT_VMEM_BUDGET:
+            return heads
+    return 0
+
+
+def _short_mask(st, causal, len_val):
+    """Both masks on a whole-sequence TRANSPOSED score tile [Tk, Tq]."""
+    k_pos = jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
+    if causal:
+        q_pos = jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
+        st = jnp.where(q_pos >= k_pos, st, NEG_INF)
+    if len_val is not None:
+        st = jnp.where(k_pos < len_val, st, NEG_INF)
+    return st
+
+
+def _short_scores(q_ref, k_ref, len_ref, g, base, scale, causal):
+    """One head's masked scores, transposed: K (scale Q)^T, float32
+    [Tk, Tq], with (scale Q) and K. The softmax scale rides on the
+    [T, D] operand, in its dtype, not on the [T, T] tile (for bf16 and a
+    power-of-two scale, head dim 64 among them, the product is exact;
+    the dense lowering scales q the same way). Both short kernels work
+    in this orientation: the softmax statistics, the LSE and delta are
+    lane-dense rows [1, Tq], reductions run down the sublanes, and only
+    [T, D]-sized operands are ever transposed."""
+    q, k = q_ref[g], k_ref[g]
+    q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    st = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    len_val = None if len_ref is None else len_ref[base + g, 0]
+    if causal or len_val is not None:
+        st = _short_mask(st, causal, len_val)
+    return st, q, k, len_val
+
+
+def _short_fwd_kernel(*refs, scale, causal, has_len, heads):
+    from jax.experimental import pallas as pl
+
+    if has_len:
+        q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref = refs
+    else:
+        (q_ref, k_ref, v_ref, o_ref, lse_ref), len_ref = refs, None
+    base = pl.program_id(0) * heads
+    for g in range(heads):
+        st, _, _, len_val = _short_scores(q_ref, k_ref, len_ref, g, base,
+                                          scale, causal)
+        v = v_ref[g]
+        m = jnp.max(st, axis=0, keepdims=True)             # [1, Tq]
+        pt = jnp.exp(st - m)                               # plain softmax
+        l = jnp.sum(pt, axis=0, keepdims=True)             # >= 1
+        ot = jax.lax.dot_general(                          # V^T P^T
+            v, pt.astype(v.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) / l        # [D, Tq]
+        lse = m + jnp.log(l)
+        if has_len:
+            # a row with no visible key puts out zeros and takes no
+            # gradient (P recomputed from this LSE is 0), as in the
+            # streaming kernels
+            ot = jnp.where(len_val > 0, ot, 0.0)
+            lse = jnp.where(len_val > 0, lse, -NEG_INF)
+        o_ref[g] = jnp.transpose(ot).astype(o_ref.dtype)
+        lse_ref[g] = lse
+
+
+def _short_bwd_kernel(*refs, scale, causal, has_len, heads):
+    """dQ, dK, dV of ``heads`` heads from ONE S/P/dP each: five matmuls
+    a head, where the streaming dQ and dK+dV kernels make seven."""
+    from jax.experimental import pallas as pl
+
+    if has_len:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, len_ref,
+         dq_ref, dk_ref, dv_ref) = refs
+    else:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+         dq_ref, dk_ref, dv_ref), len_ref = refs, None
+    base = pl.program_id(0) * heads
+    f32 = jnp.float32
+    for g in range(heads):
+        st, q, k, _ = _short_scores(q_ref, k_ref, len_ref, g, base, scale,
+                                    causal)
+        v, do = v_ref[g], do_ref[g]
+        pt = jnp.exp(st - lse_ref[g])                      # [Tk, Tq]
+        dv_ref[g] = jax.lax.dot_general(                   # P^T dO
+            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+            preferred_element_type=f32).astype(dv_ref.dtype)
+        dpt = jax.lax.dot_general(                         # V dO^T
+            v, do, (((1,), (1,)), ((), ())), preferred_element_type=f32)
+        dst = (pt * (dpt - delta_ref[g])).astype(q.dtype)
+        dk_ref[g] = jax.lax.dot_general(                   # dS^T (scale Q)
+            dst, q, (((1,), (0,)), ((), ())),
+            preferred_element_type=f32).astype(dk_ref.dtype)
+        dqt = jax.lax.dot_general(                         # K^T dS^T
+            k, dst, (((0,), (0,)), ((), ())),
+            preferred_element_type=f32)                    # [D, Tq]
+        dq_ref[g] = (scale * jnp.transpose(dqt)).astype(dq_ref.dtype)
+
+
+def _short_params(heads, T, D, itemsize):
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",),
+        vmem_limit_bytes=_short_vmem_bytes(heads, T, D, itemsize)
+        + (8 << 20))
+
+
+def _short_forward(q, k, v, causal, scale, heads, interpret,
+                   lengths=None):
+    """Returns (out [B,H,T,D], lse [B*H, 1, T] float32)."""
+    from jax.experimental import pallas as pl
+
+    B, H, T, D = q.shape
+    BH = B * H
+    block = pl.BlockSpec((heads, T, D), lambda b: (b, 0, 0))
+    row = pl.BlockSpec((heads, 1, T), lambda b: (b, 0, 0))
+    has_len = lengths is not None
+    in_specs = [block, block, block]
+    args = [x.reshape(BH, T, D) for x in (q, k, v)]
+    if has_len:
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        args.append(_len_bh(lengths, B, H))
+    out, lse = pl.pallas_call(
+        functools.partial(_short_fwd_kernel, scale=scale, causal=causal,
+                          has_len=has_len, heads=heads),
+        grid=(BH // heads,),
+        in_specs=in_specs,
+        out_specs=[block, row],
+        out_shape=[jax.ShapeDtypeStruct((BH, T, D), q.dtype),
+                   jax.ShapeDtypeStruct((BH, 1, T), jnp.float32)],
+        compiler_params=_short_params(heads, T, D, q.dtype.itemsize),
+        interpret=interpret,
+        name="flash_short_fwd",
+    )(*args)
+    return out.reshape(B, H, T, D), lse
+
+
+def _short_backward(q, k, v, out, lse, g, causal, scale, heads,
+                    interpret, lengths=None):
+    from jax.experimental import pallas as pl
+
+    B, H, T, D = q.shape
+    BH = B * H
+    # delta = rowsum(dO * O), one fused pass of XLA's, kept as rows
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1).reshape(BH, 1, T)
+    block = pl.BlockSpec((heads, T, D), lambda b: (b, 0, 0))
+    row = pl.BlockSpec((heads, 1, T), lambda b: (b, 0, 0))
+    has_len = lengths is not None
+    in_specs = [block, block, block, block, row, row]
+    args = [x.reshape(BH, T, D) for x in (q, k, v, g)] + [lse, delta]
+    if has_len:
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        args.append(_len_bh(lengths, B, H))
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_short_bwd_kernel, scale=scale, causal=causal,
+                          has_len=has_len, heads=heads),
+        grid=(BH // heads,),
+        in_specs=in_specs,
+        out_specs=[block, block, block],
+        out_shape=[jax.ShapeDtypeStruct((BH, T, D), x.dtype)
+                   for x in (q, k, v)],
+        compiler_params=_short_params(heads, T, D, q.dtype.itemsize),
+        interpret=interpret,
+        name="flash_short_bwd",
+    )(*args)
+    shape = (B, H, T, D)
+    return dq.reshape(shape), dk.reshape(shape), dv.reshape(shape)
+
+
+# ---------------------------------------------------------------------------
 # custom VJP plumbing
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
-    out, _lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                               interpret)
-    return out
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, lengths, causal, scale, block_q, block_k, heads,
+           interpret):
+    """(out, lse). ``lengths`` is None or [B] int. ``heads`` > 0: the
+    short path, that many heads a grid step (lse [B*H, 1, S]); 0: the
+    streaming kernels (lse [B*H, S, 1]). The LSE is a residual for the
+    backward: it takes no cotangent."""
+    if heads:
+        return _short_forward(q, k, v, causal, scale, heads, interpret,
+                              lengths)
+    return _flash_forward(q, k, v, causal, scale, block_q, block_k,
+                          interpret, lengths)
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
-    out, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                              interpret)
-    return out, (q, k, v, out, lse)
+def _flash_fwd(q, k, v, lengths, causal, scale, block_q, block_k, heads,
+               interpret):
+    out, lse = _flash(q, k, v, lengths, causal, scale, block_q, block_k,
+                      heads, interpret)
+    return (out, lse), (q, k, v, lengths, out, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
-    q, k, v, out, lse = res
+def _flash_bwd(causal, scale, block_q, block_k, heads, interpret, res,
+               cts):
+    from jax.dtypes import float0
+
+    q, k, v, lengths, out, lse = res
+    g = cts[0]
+    # an int argument has no tangent
+    dlen = (None if lengths is None
+            else np.zeros(lengths.shape, dtype=float0))
+    if heads:
+        return _short_backward(q, k, v, out, lse, g, causal, scale,
+                               heads, interpret, lengths) + (dlen,)
     S = q.shape[2]
     bq = min(block_q, S)
     bk = min(block_k, S)
@@ -476,51 +704,15 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
         # ragged tail / rectangular: dense VJP (matches the forward's
         # own fallback)
         _, vjp = jax.vjp(
-            lambda q, k, v: _dense_attention(q, k, v, causal, scale),
-            q, k, v)
-        return vjp(g)
-    return _flash_backward(q, k, v, out, lse, g, causal, scale,
-                           block_q, block_k, interpret)
-
-
-_flash.defvjp(_flash_fwd, _flash_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash_masked(q, k, v, lengths, causal, scale, block_q, block_k,
-                  interpret):
-    out, _lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                               interpret, lengths=lengths)
-    return out
-
-
-def _flash_masked_fwd(q, k, v, lengths, causal, scale, block_q, block_k,
-                      interpret):
-    out, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                              interpret, lengths=lengths)
-    return out, (q, k, v, lengths, out, lse)
-
-
-def _flash_masked_bwd(causal, scale, block_q, block_k, interpret, res, g):
-    from jax.dtypes import float0
-
-    q, k, v, lengths, out, lse = res
-    S = q.shape[2]
-    bq = min(block_q, S)
-    bk = min(block_k, S)
-    dlen = np.zeros(lengths.shape, dtype=float0)  # int arg: no tangent
-    if S != k.shape[2] or S % bq or S % bk:
-        _, vjp = jax.vjp(
             lambda q, k, v: _dense_attention(q, k, v, causal, scale,
                                              lengths), q, k, v)
         return vjp(g) + (dlen,)
-    dq, dk, dv = _flash_backward(q, k, v, out, lse, g, causal, scale,
-                                 block_q, block_k, interpret,
-                                 lengths=lengths)
-    return (dq, dk, dv, dlen)
+    return _flash_backward(q, k, v, out, lse, g, causal, scale,
+                           block_q, block_k, interpret,
+                           lengths=lengths) + (dlen,)
 
 
-_flash_masked.defvjp(_flash_masked_fwd, _flash_masked_bwd)
+_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def _fit_block(S, block):
@@ -537,53 +729,102 @@ def _fit_block(S, block):
     return 0
 
 
+def _plan(q, k, block_q, block_k):
+    """Which kernels a call takes, from what it can see: sequence
+    lengths, head dim, dtype and the VMEM the short path would need.
+    Returns (heads, block_q, block_k); heads > 0 is the short path."""
+    B, H, S, D = q.shape
+    if S != k.shape[2]:
+        return 0, block_q, block_k   # rectangular: the dense fallback
+    if S % 128 == 0 and S <= block_k:
+        # the caller's K block holds the whole sequence
+        heads = _short_heads(B * H, S, D, q.dtype.itemsize)
+        if heads:
+            return heads, block_q, block_k
+    # S not a multiple of the tuned blocks (e.g. 2560 % 1024): shrink to
+    # the largest aligned divisor rather than silently dropping to the
+    # dense O(S^2) path
+    bq, bk = _fit_block(S, block_q), _fit_block(S, block_k)
+    if bq and bk:
+        return 0, bq, bk
+    warnings.warn(
+        "flash_attention: seq_len %d has no 128-aligned block "
+        "divisor; using dense O(S^2) attention" % S)
+    return 0, block_q, block_k
+
+
+def attention_path(q, k, block_q: int = 512, block_k: int = 1024,
+                   force_pallas: bool = False) -> str:
+    """"short" | "stream" | "dense": what ``flash_attention`` runs for
+    these arguments where the computation is placed now."""
+    if not (force_pallas or compute_platform() == "tpu"):
+        return "dense"
+    return "short" if _plan(q, k, block_q, block_k)[0] else "stream"
+
+
+def flash_attention_with_lse(q, k, v, causal: bool = False,
+                             scale: Optional[float] = None,
+                             block_q: int = 512, block_k: int = 1024,
+                             force_pallas: bool = False, lengths=None):
+    """``flash_attention`` and the residual its backward needs: (out,
+    lse), differentiable (the LSE takes no cotangent). ``lse`` is None
+    where the dense math ran (off the TPU)."""
+    if scale is None:
+        scale = float(q.shape[-1]) ** -0.5
+    on_tpu = compute_platform() == "tpu"
+    if not (on_tpu or force_pallas):
+        return _dense_attention(q, k, v, causal, scale, lengths), None
+    heads, block_q, block_k = _plan(q, k, block_q, block_k)
+    return _flash(q, k, v, lengths, causal, scale, block_q, block_k,
+                  heads, not on_tpu)
+
+
+def flash_attention_bwd(q, k, v, lengths, out, lse, g, causal: bool,
+                        scale: float, block_q: int = 512,
+                        block_k: int = 1024):
+    """(dq, dk, dv) from the forward's own ``out`` and ``lse`` (as
+    ``flash_attention_with_lse`` returned them for the same arguments):
+    the backward kernels alone, no second forward."""
+    heads, block_q, block_k = _plan(q, k, block_q, block_k)
+    return _flash_bwd(causal, scale, block_q, block_k, heads,
+                      compute_platform() != "tpu",
+                      (q, k, v, lengths, out, lse), (g, None))[:3]
+
+
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None, block_q: int = 512,
                     block_k: int = 1024, force_pallas: bool = False,
                     lengths=None):
-    """Flash attention over ``[B, H, S, D]`` tensors — differentiable:
-    the backward runs the pallas dQ / dK+dV kernels with blockwise
-    probability recomputation from the saved logsumexp (O(S·D) training
-    memory; no S×S matrix in HBM in either direction).
+    """Flash attention over ``[B, H, S, D]`` tensors — differentiable,
+    and no S x S matrix in HBM in either direction: the backward
+    recomputes the probabilities from the saved logsumexp.
+
+    Which kernels run is decided here, from the shapes (``_plan``):
+
+    - **short** — S a multiple of 128 that fits the K block (S <= 1024
+      with the defaults) and whose float32 [S, S] tiles fit
+      ``SHORT_VMEM_BUDGET``: a head's whole score tile lives in VMEM,
+      so the softmax is plain (no running max), several heads share a
+      grid step, MXU operands stay in the input dtype, and ONE backward
+      kernel yields dQ, dK and dV from one S/P/dP.
+    - **stream** — longer S: K blocks stream past each Q block with a
+      running softmax; the backward is a dQ and a dK+dV kernel.
+      ``block_q`` x ``block_k`` = 512 x 1024 by default; blocks shrink
+      to an aligned divisor of S.
+    - **dense** — where the computation does not run on a TPU (and
+      ``force_pallas``, interpret mode, was not asked for): the same
+      math in plain XLA.
 
     ``lengths`` ([B] int) is the padding mask: row b attends only to
-    its first ``lengths[b]`` keys (key blocks past the tail are skipped
-    entirely) — the kernel-side equivalent of the reference's additive
-    src_slf_attn_bias over padded positions, composable with
-    ``causal``. Padded QUERY rows produce zeros/garbage exactly like
-    the additive-mask formulation; mask the loss, as seq2seq training
-    already does.
+    its first ``lengths[b]`` keys — the kernel-side equivalent of the
+    reference's additive src_slf_attn_bias over padded positions,
+    composable with ``causal``; both masks run on either kernel path
+    (the streaming kernels also skip key blocks past the tail). Padded
+    QUERY rows produce zeros/garbage exactly like the additive-mask
+    formulation; mask the loss, as seq2seq training already does.
 
-    Uses the pallas kernels where the computation runs on a TPU (or
-    when ``force_pallas`` — interpret mode off-TPU — is requested, e.g.
-    in tests); dense math elsewhere.
-
-    Block defaults are tuned on v5e (b4 h16 d64, causal, fwd+bwd):
-    512x1024 blocks turn the 128x128 default's 0.6-0.9x vs XLA dense
-    into 1.0-2.3x FASTER (S=512..4096), and at S=8192/16384 flash
-    trains in 68/190 ms/step where the dense lowering does not compile
-    at all. Blocks auto-cap to S for short sequences.
+    Timings on the v5e: PERF.md section 6, "PR 25"
+    (``tools/attn_bench.py`` repeats them).
     """
-    if scale is None:
-        scale = float(q.shape[-1]) ** -0.5
-    S, S_kv = q.shape[2], k.shape[2]
-    if S == S_kv:
-        # S not a multiple of the tuned blocks (e.g. 2560 % 1024):
-        # shrink to the largest aligned divisor rather than silently
-        # dropping to the dense O(S^2) path
-        bq, bk = _fit_block(S, block_q), _fit_block(S, block_k)
-        if bq and bk:
-            block_q, block_k = bq, bk
-        else:
-            warnings.warn(
-                "flash_attention: seq_len %d has no 128-aligned block "
-                "divisor; using dense O(S^2) attention" % S)
-    on_tpu = compute_platform() == "tpu"
-    interpret = not on_tpu
-    if on_tpu or force_pallas:
-        if lengths is not None:
-            return _flash_masked(q, k, v, lengths, causal, scale,
-                                 block_q, block_k, interpret)
-        return _flash(q, k, v, causal, scale, block_q, block_k,
-                      interpret)
-    return _dense_attention(q, k, v, causal, scale, lengths)
+    return flash_attention_with_lse(q, k, v, causal, scale, block_q,
+                                    block_k, force_pallas, lengths)[0]

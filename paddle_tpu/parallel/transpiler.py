@@ -260,6 +260,7 @@ def apply_sequence_parallel(program, axis: str = "sp", degree: int = 0,
                     "divisible by sp degree %d (Q=%r)"
                     % (q.shape[2], degree, op.input("Q")[0]))
         op.type = "c_ring_attention"
+        op.outputs.pop("LSE", None)   # the flash kernels' residual
         op.attrs = {"shard_axis": axis,
                     "causal": bool(op.attrs.get("causal")),
                     "scale": float(op.attrs.get("scale", 0.0))}

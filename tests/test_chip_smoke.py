@@ -140,16 +140,45 @@ def test_kernel_cross_lowers_for_tpu(case):
     assert lowered.as_text().count("tpu_custom_call") >= 1, name
 
 
-def test_kernels_compile_for_v5e():
+@pytest.fixture(scope="module")
+def v5e_compile():
     """The real thing minus the chip: libtpu's Mosaic and XLA:TPU back
-    ends compile every kernel for a compile-only v5e topology — VMEM
-    and layout refusals included. In a process of its own (it loads
-    libtpu); skipped where no TPU compiler can be set up."""
+    ends compile every kernel, and a BERT step, for a compile-only v5e
+    topology — VMEM and layout refusals included. In a process of its
+    own (it loads libtpu), once for the tests that read it; they skip
+    where no TPU compiler can be set up."""
     proc = _run([os.path.join(ROOT, "tests", "tpu_kernel_cases.py")],
                 devices=1, pythonpath=ROOT)
     if proc.returncode == 3:
         pytest.skip("no compile-only TPU topology here: %s"
                     % proc.stdout[-300:])
+    return proc
+
+
+def test_kernels_compile_for_v5e(v5e_compile):
+    proc = v5e_compile
     ok = [x for x in proc.stdout.splitlines() if x.startswith("OK ")]
     assert proc.returncode == 0, proc.stdout[-3000:]
     assert len(ok) == len(list(tpu_kernel_cases.cases())), proc.stdout
+
+
+def test_bert_step_for_v5e_holds_one_forward_kernel_a_layer(v5e_compile):
+    """A 2-layer BERT step at T = 512: the program's own forward kernel
+    once a layer (the grad op reads the forward op's Out and LSE; XLA
+    would not merge a re-run), one backward kernel a layer, no Mosaic
+    call the program did not write, no [*, *, 512, 512] buffer."""
+    (line,) = [x for x in v5e_compile.stdout.splitlines()
+               if x.startswith("BERT_STEP ")]
+    assert line == "BERT_STEP fwd=2 bwd=2 other_mosaic=0 tt_buffers=0"
+
+
+def test_bert_step_report_counts_what_it_names():
+    hlo = """
+  %flash_short_fwd.2 = (bf16[48,512,64]{2,1,0}) custom-call(%a), custom_call_target="tpu_custom_call"
+  %flash_short_bwd.2 = (bf16[48,512,64]{2,1,0}) custom-call(%a), custom_call_target="tpu_custom_call"
+  %custom-call.7 = f32[4,12,512,64]{3,2,1,0} custom-call(%a), custom_call_target="tpu_custom_call"
+  %fusion.1 = bf16[4,12,512,512]{3,2,1,0} fusion(%b), kind=kOutput
+  %fusion.2 = f32[4,12,512,512]{3,2,1,0} fusion(%fusion.1), kind=kLoop
+"""
+    assert tpu_kernel_cases.bert_step_report(hlo) == \
+        "BERT_STEP fwd=1 bwd=1 other_mosaic=1 tt_buffers=2"

@@ -130,9 +130,10 @@ def test_transformer_model_uses_flash_path():
 
 
 def test_training_path_uses_flash_when_unmasked():
-    """With the pallas backward kernels, TRAINING attention (no mask,
-    no attention dropout) also routes through flash_attention, and a
-    grad op for it lands in the program."""
+    """TRAINING attention (no additive mask, no attention dropout)
+    routes through flash_attention at any length with no force, and a
+    grad op for it lands in the program, reading the forward op's own
+    Out and LSE."""
     import paddle_tpu as fluid
     from paddle_tpu import models
 
@@ -141,13 +142,16 @@ def test_training_path_uses_flash_when_unmasked():
     with fluid.program_guard(prog, startup):
         x = fluid.data(name="x", shape=[Bm, T, Dm], dtype="float32")
         out = models.transformer.multi_head_attention(
-            x, num_heads=2, d_model=Dm, dropout=0.0, is_test=False,
-            use_flash=True)  # auto only kicks in at T >= 2048
+            x, num_heads=2, d_model=Dm, dropout=0.0, is_test=False)
         loss = fluid.layers.reduce_mean(out)
         fluid.optimizer.SGD(0.1).minimize(loss)
     types = [op.type for op in prog.global_block().ops]
     assert "flash_attention" in types
     assert "flash_attention_grad" in types
+    assert "softmax" not in types and "matmul" not in types
+    (grad_op,) = [op for op in prog.global_block().ops
+                  if op.type == "flash_attention_grad"]
+    assert {"Out", "LSE"} <= set(grad_op.inputs)
     scope = fluid.Scope()
     with fluid.scope_guard(scope):
         exe = fluid.Executor(fluid.CPUPlace())
@@ -286,6 +290,10 @@ def test_masked_training_routes_flash():
     types = [op.type for op in prog.global_block().ops]
     assert "flash_attention" in types
     assert "flash_attention_grad" in types
+    assert "softmax" not in types and "matmul" not in types
+    (grad_op,) = [op for op in prog.global_block().ops
+                  if op.type == "flash_attention_grad"]
+    assert {"Out", "LSE"} <= set(grad_op.inputs)
     scope = fluid.Scope()
     with fluid.scope_guard(scope):
         exe = fluid.Executor(fluid.CPUPlace())

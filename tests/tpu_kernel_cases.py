@@ -31,27 +31,39 @@ def cases():
     pa = importlib.import_module("paddle_tpu.ops.pallas.paged_attention")
     cv = importlib.import_module("paddle_tpu.ops.pallas.conv")
 
-    # flash fwd + dQ + dK/dV, causal, the gpt_long shape
-    def flash(q, k, v):
-        def loss(q, k, v):
-            return jnp.sum(fa._flash(q, k, v, True, 0.125, 512, 1024,
-                                     False).astype(f32))
-        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    def flash(causal, block_q, block_k):
+        """Forward + backward of the kernels ``flash_attention`` picks
+        for the shapes (``_plan``), as the grad op runs them."""
+        def both(q, k, v, *lengths):
+            heads, bq, bk = fa._plan(q, k, block_q, block_k)
+            out, vjp = jax.vjp(
+                lambda q, k, v: fa._flash(q, k, v, *(lengths or (None,)),
+                                          causal, 0.125, bq, bk, heads,
+                                          False)[0], q, k, v)
+            return (out,) + vjp(out)
+        return both
 
+    # streaming fwd + dQ + dK/dV, causal, the gpt_long shape
     qkv = ((2, 16, 4096, 64), bf16)
-    yield "flash_causal_s4096", flash, [qkv, qkv, qkv]
+    yield "flash_causal_s4096", flash(True, 512, 1024), [qkv, qkv, qkv]
 
-    # masked flash fwd+bwd, the transformer_wmt shape: the whole
-    # [B*H, 1] lengths operand rides in SMEM (512 rows here)
-    def masked(q, k, v, lengths):
-        def loss(q, k, v):
-            return jnp.sum(fa._flash_masked(
-                q, k, v, lengths, False, 0.125, 256, 256,
-                False).astype(f32))
-        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-
+    # masked streaming fwd+bwd at the transformer_wmt shape with blocks
+    # smaller than the sequence: the whole [B*H, 1] lengths operand
+    # rides in SMEM (512 rows here)
     qkv = ((64, 8, 256, 64), bf16)
-    yield "flash_masked_b64_s256", masked, [qkv, qkv, qkv, ((64,), i32)]
+    yield ("flash_masked_b64_s256", flash(False, 128, 128),
+           [qkv, qkv, qkv, ((64,), i32)])
+
+    # the short path (whole score tile in VMEM, one backward kernel):
+    # BERT's cell, the dp4 shape, and transformer_wmt as the model
+    # routes it now (causal + lengths, default blocks)
+    qkv = ((32, 12, 512, 64), bf16)
+    yield "flash_short_b32_s512", flash(False, 512, 1024), [qkv, qkv, qkv]
+    qkv = ((128, 12, 128, 64), bf16)
+    yield "flash_short_b128_s128", flash(False, 512, 1024), [qkv, qkv, qkv]
+    qkv = ((64, 8, 256, 64), bf16)
+    yield ("flash_short_masked_b64_s256", flash(True, 512, 1024),
+           [qkv, qkv, qkv, ((64,), i32)])
 
     # fused optimizer over a BERT-base-sized flat buffer: adam streams
     # 4 inputs + 3 outputs of 2048x128 f32, double-buffered ~14 MiB of
@@ -93,10 +105,76 @@ def cases():
         yield name, conv, [((8, 28, 28, 128), bf16), (w_shape, bf16)]
 
 
+def bert_step(topo_sharding, layers=2, batch=4, seq=512):
+    """A BERT-base-wide training step of ``layers`` layers (bf16 AMP,
+    Adam) at T = ``seq``, compiled for the described chip. Returns the
+    optimized HLO. The program asks ``compute_platform()`` where it
+    runs and would take the dense path on this CPU host: the test
+    steers that one question, as the chip would answer it."""
+    from unittest import mock
+
+    import numpy as np
+
+    import paddle_tpu as fluid
+    from paddle_tpu import models
+    from paddle_tpu.contrib import mixed_precision as mp
+    from paddle_tpu.core.compiler_engine import _stage_compiled_call
+    from paddle_tpu.core.tensor import LoDTensor
+
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    b, t, m, v = batch, seq, 8, 512
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        src = fluid.data(name="src", shape=[b, t], dtype="int64")
+        pos = fluid.data(name="pos", shape=[b, t], dtype="int64")
+        mpos = fluid.data(name="mpos", shape=[b, m], dtype="int64")
+        labels = fluid.data(name="labels", shape=[b, m, 1], dtype="int64")
+        logits = models.bert_base_pretrain(
+            src, pos, mpos, vocab_size=v, max_len=t, num_layers=layers,
+            num_heads=12, d_model=768, d_ff=3072)
+        loss = fluid.layers.mean(fluid.layers.softmax_with_cross_entropy(
+            fluid.layers.reshape(logits, [b * m, v]),
+            fluid.layers.reshape(labels, [b * m, 1])))
+        mp.decorate(fluid.optimizer.AdamOptimizer(1e-4)).minimize(loss)
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        feed = {name: LoDTensor(jnp.asarray(np.zeros(shape, "int64")))
+                for name, shape in (("src", (b, t)), ("pos", (b, t)),
+                                    ("mpos", (b, m)),
+                                    ("labels", (b, m, 1)))}
+        fn, args, _ = _stage_compiled_call(
+            exe._core, jax.devices()[0], main, scope, feed, [loss])
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=topo_sharding), args)
+    with mock.patch.object(fa, "compute_platform", lambda: "tpu"):
+        lowered = fn.lower(*shapes)
+    return lowered.compile().as_text()
+
+
+def bert_step_report(hlo, seq=512) -> str:
+    """``BERT_STEP fwd=<n> bwd=<n> other_mosaic=<n> tt_buffers=<n>``:
+    the program's own forward and backward kernels by their names, the
+    Mosaic calls that are neither (XLA's own attention rewrite made
+    such), and the distinct [*, *, T, T] buffers of any dtype."""
+    import re
+
+    calls = [x for x in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in x]
+    fwd = sum("flash_short_fwd" in x.split("=")[0] for x in calls)
+    bwd = sum("flash_short_bwd" in x.split("=")[0] for x in calls)
+    tt = set(re.findall(r"\w+\[\d+,\d+,%d,%d\]" % (seq, seq), hlo))
+    return "BERT_STEP fwd=%d bwd=%d other_mosaic=%d tt_buffers=%d" % (
+        fwd, bwd, len(calls) - fwd - bwd, len(tt))
+
+
 def compile_all_for_v5e() -> int:
-    """Compile every case for a compile-only v5e topology; print one
-    OK/FAIL line each. Exit codes: 0 all compiled, 1 a kernel was
-    refused, 3 no TPU compiler could be set up on this host."""
+    """Compile every case and the BERT step for a compile-only v5e
+    topology; print one OK/FAIL line each and the BERT_STEP line. Exit
+    codes: 0 all compiled, 1 something was refused, 3 no TPU compiler
+    could be set up on this host."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
@@ -120,6 +198,12 @@ def compile_all_for_v5e() -> int:
             continue
         print("OK %s mosaic_calls=%d"
               % (name, compiled.as_text().count("tpu_custom_call")))
+    try:
+        print(bert_step_report(bert_step(sharding)))
+    except Exception as e:  # noqa: BLE001 — reported like a case
+        failed += 1
+        print("FAIL bert_step %s: %s" % (type(e).__name__,
+                                         str(e)[:800].replace("\n", " | ")))
     return 1 if failed else 0
 
 

@@ -1,0 +1,165 @@
+"""Attention's core alone, forward + backward, on the chip: the short-path
+kernels of ``ops/pallas/flash_attention.py`` over the heads a grid step takes,
+against the streaming kernels and XLA's dense lowering on the same inputs.
+
+    chiprun --chips 1 -- python3 tools/attn_bench.py            # times, on the chip
+    JAX_PLATFORMS=cpu python3 tools/attn_bench.py --compile-only  # v5e compiler, no chip
+
+``short_h<n>`` takes n heads a grid step; the one ``_short_heads`` picks for
+the shape is starred. One line a variant: milliseconds of one forward + backward and of the forward
+alone, and the relative error of out, dq, dk, dv against the dense float32
+math.
+"""
+from __future__ import annotations
+
+import argparse
+import importlib
+import os
+import statistics
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+
+fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+f32 = jnp.float32
+
+SHAPES = {   # name: (B, H, T, D, causal, with lengths)
+    "bert_s512": (32, 12, 512, 64, False, False),
+    "bert_dp4_s128": (128, 12, 128, 64, False, False),
+    "wmt_s256": (64, 8, 256, 64, True, True),
+    "s1024": (8, 12, 1024, 64, False, False),
+}
+
+
+def variants(B, H, T, D, causal, lengths):
+    scale = float(D) ** -0.5
+
+    def grads(attn):
+        def both(q, k, v, ct):   # one forward, one backward
+            out, vjp = jax.vjp(attn, q, k, v)
+            return (out,) + vjp(ct.astype(out.dtype))
+        both.forward = lambda q, k, v, ct: attn(q, k, v)
+        return both
+
+    yield "dense", grads(lambda q, k, v: fa._dense_attention(
+        q, k, v, causal, scale, lengths))
+    # what the model wrote before: bf16 scores, float32 softmax
+    if not causal and lengths is None:
+        def xla(q, k, v):
+            s = jnp.einsum("bhqd,bhkd->bhqk", q * scale, k)
+            p = jax.nn.softmax(s.astype(f32), axis=-1).astype(q.dtype)
+            return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+        yield "xla_bf16_scores", grads(xla)
+    yield "stream", grads(lambda q, k, v: fa._flash(
+        q, k, v, lengths, causal, scale, min(512, T), min(1024, T), 0,
+        False)[0])
+    chosen = fa._short_heads(B * H, T, D, 2)
+    for heads in (1, 2, 4, 8, 16):
+        if (B * H) % heads == 0 and fa._short_vmem_bytes(
+                heads, T, D, 2) <= fa.SHORT_VMEM_BUDGET:
+            yield ("short_h%d%s" % (heads, "*" if heads == chosen else ""),
+                   grads(lambda q, k, v, heads=heads: fa._flash(
+                       q, k, v, lengths, causal, scale, 512, 1024, heads,
+                       False)[0]))
+
+
+def timed(step, args, reps, inner=20):
+    """Median milliseconds of one ``step``, from ``reps`` calls of a jitted
+    loop that runs it ``inner`` times on the device, each iteration on the
+    last one's results (``step`` maps its arguments to as many values of the
+    same shapes), so that the host's part of a call is not in it: one launch
+    costs this host 0.3-0.7 ms, as much as a kernel."""
+    looped = jax.jit(lambda *a: jax.lax.fori_loop(
+        0, inner, lambda _, c: tuple(step(*c)), tuple(a)))
+    jax.block_until_ready(looped(*args))
+    times = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(looped(*args))
+        times.append((time.perf_counter() - t0) / inner)
+    return 1e3 * statistics.median(times)
+
+
+def rel(a, b):
+    a, b = a.astype(f32), b.astype(f32)
+    return float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-30))
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--compile-only", action="store_true")
+    ap.add_argument("--shapes", default=",".join(SHAPES))
+    ap.add_argument("--reps", type=int, default=5)
+    args = ap.parse_args()
+
+    sharding = None
+    if args.compile_only:
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                            platform="tpu")
+        sharding = SingleDeviceSharding(topo.devices[0])
+    else:
+        print("# device %s" % jax.devices()[0].device_kind, flush=True)
+
+    for name in args.shapes.split(","):
+        B, H, T, D, causal, with_len = SHAPES[name]
+        shape = (B, H, T, D)
+        if args.compile_only:
+            lengths = (jax.ShapeDtypeStruct((B,), jnp.int32, sharding=sharding)
+                       if with_len else None)
+            spec = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
+            for vname, fn in variants(B, H, T, D, causal, None):
+                if with_len:
+                    continue   # lengths is closed over: needs an array
+                t0 = time.perf_counter()
+                try:
+                    c = jax.jit(fn).lower(spec, spec, spec, spec).compile()
+                    m = c.memory_analysis()
+                    print("%s %s OK %.1f s mosaic=%d temp=%.1f MiB" % (
+                        name, vname, time.perf_counter() - t0,
+                        c.as_text().count("tpu_custom_call"),
+                        m.temp_size_in_bytes / 2**20), flush=True)
+                except Exception as e:  # noqa: BLE001 — reported per variant
+                    print("%s %s FAIL %s" % (
+                        name, vname, str(e)[:600].replace("\n", " | ")),
+                        flush=True)
+            continue
+        keys = jax.random.split(jax.random.key(T), 5)
+        q, k, v, ct = (jax.random.normal(kk, shape, f32).astype(jnp.bfloat16)
+                       for kk in keys[:4])
+        lengths = (jax.random.randint(keys[4], (B,), 1, T + 1)
+                   if with_len else None)
+        ref = None
+        for vname, fn in variants(B, H, T, D, causal, lengths):
+            args_ = (q, k, v, ct)
+            if vname == "dense":
+                args_ = tuple(x.astype(f32) for x in args_)
+            try:
+                jitted = jax.jit(fn)
+                out = jax.block_until_ready(jitted(*args_))
+                # (q, k, v, ct) <- (dq, dk, dv, out); forward: q <- out
+                ms = timed(lambda *a: (lambda r: r[1:] + r[:1])(fn(*a)),
+                           args_, args.reps)
+                fwd_ms = timed(lambda *a: (fn.forward(*a),) + a[1:], args_,
+                               args.reps)
+            except Exception as e:  # noqa: BLE001 — reported per variant
+                print("%s %s FAIL %s" % (name, vname,
+                                         str(e)[:600].replace("\n", " | ")),
+                      flush=True)
+                continue
+            if ref is None:
+                ref = out
+            print("%s %s %.3f ms (forward alone %.3f)  rel err out/dq/dk/dv %s"
+                  % (name, vname, ms, fwd_ms,
+                     " ".join("%.4f" % rel(a, b) for a, b in zip(out, ref))),
+                  flush=True)
+
+
+if __name__ == "__main__":
+    main()
