@@ -11,6 +11,13 @@ the cells multiply in) and must come out as not correct.
 No window is measured: training's readings need none. Each seed prints one
 JSON line; the last line is the summary. Run on the chip at the cell's own
 size; ``benchmarks/tests`` runs it at the test preset's size on the CPU.
+
+    python3 benchmarks/check_outputs.py --stand-in <file> [--first-seed 1000]
+
+runs the reference's ``follow()`` alone over a stand-in for a configuration
+that does not exist yet (``benchmarks/lib/standin.py``), twice, and prints the
+device's peak beside the seconds each took: what
+``benchmarks/aot_sizing.py --stand-in`` sized, on the chip.
 """
 import argparse
 import gc
@@ -39,7 +46,7 @@ def readings(manifest, cell, devices, seeds, control_seeds, emit=print):
 
     def reference_run(seed, cast):
         return follow(loss_fn, cfg["optimizer"],
-                      harness.make_params(reference, cfg, seed),
+                      *harness.start_of(reference, cfg, seed),
                       harness.make_pool(reference, cfg, traffic, seed,
                                         harness.FIRST_STEPS),
                       traffic.get("reference_rows_per_block"), cast)
@@ -53,7 +60,7 @@ def readings(manifest, cell, devices, seeds, control_seeds, emit=print):
                     harness.make_pool(reference, cfg, traffic, seed,
                                       harness.FIRST_STEPS))
         program, _ = harness.drive_first_steps(
-            driver, harness.make_params(reference, cfg, seed))
+            driver, *harness.start_of(reference, cfg, seed))
         driver.close()
         del driver
         gc.collect()
@@ -77,9 +84,41 @@ def readings(manifest, cell, devices, seeds, control_seeds, emit=print):
     return summary
 
 
+def stand_in_readings(path, devices, seed, emit=print):
+    """``follow()`` over the stand-in, twice: the first pass compiles or
+    loads its programs, the second is what a warm run pays. The device's peak
+    is the sum of the two parts printed (``harness.peak_bytes``)."""
+    import statistics
+    import time
+
+    from benchmarks.lib import harness, standin
+    from benchmarks.lib.reference_train import follow, identity
+
+    cfg, traffic = standin.load(path)
+    out = {"stand_in": cfg["name"],
+           "parameters": standin.parameter_count(cfg), "tokens": cfg["tokens"]}
+    for name in ("first", "again"):
+        t0 = time.perf_counter()
+        ref = follow(lambda p, b, cast: standin.loss(p, b, cfg, cast),
+                     cfg["optimizer"], *harness.start_of(standin, cfg, seed),
+                     harness.make_pool(standin, cfg, traffic, seed,
+                                       harness.FIRST_STEPS),
+                     traffic["reference_rows_per_block"], identity)
+        out["seconds_" + name] = time.perf_counter() - t0
+        stats = devices[0].memory_stats() or {}
+        out["peak_bytes_in_use_" + name] = stats.get("peak_bytes_in_use")
+        out["peak_bytes_reserved_" + name] = stats.get("peak_bytes_reserved")
+    out["losses"] = ref["losses"]
+    out["delta_norm_median"] = statistics.median(ref["delta_norms"].values())
+    emit(json.dumps(out))
+    return out
+
+
 def main(argv):
     p = argparse.ArgumentParser()
-    p.add_argument("--workload", required=True)
+    what = p.add_mutually_exclusive_group(required=True)
+    what.add_argument("--workload")
+    what.add_argument("--stand-in", dest="stand_in")
     p.add_argument("--seeds", type=int, default=12)
     p.add_argument("--control-seeds", type=int, default=3)
     p.add_argument("--first-seed", type=int, default=1000)
@@ -89,6 +128,11 @@ def main(argv):
     from benchmarks.lib.manifest import Manifest
 
     harness.enable_cache()
+    if args.stand_in:
+        devices, _ = harness.find_devices(1)
+        stand_in_readings(args.stand_in, devices, args.first_seed,
+                          emit=lambda line: print(line, flush=True))
+        return
     manifest = Manifest(harness.MANIFEST, harness.REPO)
     cell = manifest.cell(args.workload)
     devices, _ = harness.find_devices(cell["chips"])

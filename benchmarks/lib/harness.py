@@ -17,6 +17,7 @@ import math
 import os
 import shutil
 import statistics
+import sys
 import time
 
 from . import check, timing, trace as trace_lib
@@ -93,13 +94,22 @@ def seeded_key(seed):
     return jax.random.fold_in(jax.random.key(seed & 0xFFFFFFFF), seed >> 32)
 
 
-def make_params(reference, cfg, seed):
-    """The seeded weights, on the device, in one jitted call. Called again
-    wherever the starting point is needed: the program's steps donate it."""
+def start_of(reference, cfg, seed):
+    """(init_fn, key): ``init_fn(key)`` is the seeded weights, and traces
+    inside whatever program needs the starting point, so that the point is
+    made where it is used and never kept: the program's steps donate it."""
     import jax
 
-    key = jax.random.fold_in(seeded_key(seed), 0)
-    return jax.jit(lambda k: reference.init_params(k, cfg))(key)
+    return (lambda key: reference.init_params(key, cfg),
+            jax.random.fold_in(seeded_key(seed), 0))
+
+
+def make_params(reference, cfg, seed):
+    """The seeded weights, on the device, in one jitted call."""
+    import jax
+
+    init_fn, key = start_of(reference, cfg, seed)
+    return jax.jit(init_fn)(key)
 
 
 def make_pool(reference, cfg, traffic, seed, n):
@@ -120,12 +130,13 @@ def norms_of(tree, scale=1.0):
     return {k: scale * float(v) for k, v in jax.jit(leaf_norms)(tree).items()}
 
 
-def drive_first_steps(driver, start_params):
+def drive_first_steps(driver, init_fn, key):
     """The window's own call on the pool's first batches. Returns what the
-    reference is compared with, and the wall time of each step."""
-    import jax
-
-    from .reference_train import diff_norms
+    reference is compared with, and the wall time of each step. The change of
+    the parameters is taken against ``init_fn(key)``, made inside the program
+    that subtracts it: the benchmark holds no second tree of weights beside
+    the program's state."""
+    from .reference_train import change_program
 
     out = {"losses": []}
     times = []
@@ -137,7 +148,7 @@ def drive_first_steps(driver, start_params):
             moments, scale = driver.first_moment()
             out["grad_norms"] = norms_of(moments, scale)
             del moments
-    delta = jax.jit(diff_norms)(driver.params(), start_params)
+    delta = change_program(init_fn)(driver.params(), key)
     out["delta_norms"] = {k: float(v) for k, v in delta.items()}
     return out, times
 
@@ -186,8 +197,9 @@ def peak_bytes(devices):
     """Peak of device memory on the fullest chip. The allocator's
     ``peak_bytes_in_use`` leaves out what loaded programs reserve for their
     temporaries (``peak_bytes_reserved``, most of a training step's memory),
-    so the two are added; for a static step the sum equals the AOT
-    ``memory_analysis()`` total."""
+    so the two are added. Both are peaks of the process: the sum is the AOT
+    ``memory_analysis()`` total of the step plus whatever the process ever
+    held beside it (the pool of batches; nothing of the benchmark's own)."""
     peaks = []
     for d in devices:
         stats = d.memory_stats() or {}
@@ -229,10 +241,10 @@ def run_cell(manifest, cell, seed, seconds, traced, devices, peaks, t_start):
     driver.build()
     build_s = time.perf_counter() - t0
 
+    init_fn, key = start_of(reference, cfg, seed)
     driver.load(make_params(reference, cfg, seed),
                 make_pool(reference, cfg, traffic, seed, traffic["pool"]))
-    program, first_times = drive_first_steps(
-        driver, make_params(reference, cfg, seed))
+    program, first_times = drive_first_steps(driver, init_fn, key)
     warm_times = [first_times[-1]]
     for i in range(FIRST_STEPS, WARM_STEPS):
         t0 = time.perf_counter()
@@ -276,15 +288,12 @@ def run_cell(manifest, cell, seed, seconds, traced, devices, peaks, t_start):
     t0 = time.perf_counter()
     builds0, build_s0 = xla.builds, xla.seconds
     ref = follow(lambda p, b, cast: reference.loss(p, b, cfg, cast),
-                 cfg["optimizer"], make_params(reference, cfg, seed),
+                 cfg["optimizer"], init_fn, key,
                  make_pool(reference, cfg, traffic, seed, FIRST_STEPS),
                  traffic.get("reference_rows_per_block"), identity)
     reference_s = time.perf_counter() - t0
 
     rows = check.compare(program, ref, traffic["limits"])
-    for name, value, limit, ok, note in rows:
-        say("# compared %-17s %.6g  limit %.6g  %s  (%s)"
-            % (name, value, limit, "ok" if ok else "FAILED", note))
     failed = sum(1 for x in losses if not math.isfinite(x))
     n = len(losses)
     quarter = max(1, n // 4)
@@ -307,6 +316,10 @@ def run_cell(manifest, cell, seed, seconds, traced, devices, peaks, t_start):
               "count": len(devices), "memory_peak_bytes": memory_peak}
     result = {"correct": bool(correct), "attempted": n, "failed": failed,
               "metrics": {}, "device": device}
+    # each number compared beside its limit: the result's last key
+    compared = {name: {"value": value, "limit": limit, "ok": bool(ok),
+                       "at": note}
+                for name, value, limit, ok, note in rows}
     if not traced:
         values = {"setup_s": setup_s,
                   traffic["throughput_metric"]: timing.rate(
@@ -314,6 +327,7 @@ def run_cell(manifest, cell, seed, seconds, traced, devices, peaks, t_start):
         for m in manifest.end_to_end(cell):
             result["metrics"][m["name"]] = {"value": values[m["name"]],
                                             "unit": m["unit"]}
+        result["compared"] = compared
         return result
 
     reduction = None
@@ -332,6 +346,7 @@ def run_cell(manifest, cell, seed, seconds, traced, devices, peaks, t_start):
            "compile_s": setup_compile_s, "memory_peak_bytes": memory_peak,
            "flops_per_step": parts["flops"].flops_per_step(cfg, traffic)}
     result["metrics"] = read_layer_metrics(manifest.per_layer(cell), ctx)
+    result["compared"] = compared
     return result
 
 
@@ -348,4 +363,10 @@ def main(argv, t_start):
     result = run_cell(manifest, cell, args.seed, args.seconds,
                       bool(args.trace), devices, peaks, t_start)
     say(json.dumps(result))
+    # and as the last lines of standard error: the driver's record of a run
+    # that is not correct keeps the end of that and of the result's line
+    for name, c in result["compared"].items():
+        print("# compared %-17s %.6g  limit %.6g  %s  (%s)" % (
+            name, c["value"], c["limit"], "ok" if c["ok"] else "FAILED",
+            c["at"]), file=sys.stderr, flush=True)
     return 0

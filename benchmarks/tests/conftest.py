@@ -47,7 +47,9 @@ def preset_run(monkeypatch, capsys):
         rc = harness.main(["--workload", cell, "--seed", str(seed),
                            "--seconds", str(seconds), "--trace", str(trace)],
                           time.perf_counter())
-        lines = capsys.readouterr().out.strip().splitlines()
+        captured = capsys.readouterr()
+        lines = captured.out.strip().splitlines()
+        run.err_lines = captured.err.strip().splitlines()
         assert rc == 0
         return json.loads(lines[-1]), lines
 

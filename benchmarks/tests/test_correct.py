@@ -32,14 +32,20 @@ def test_control_fails_and_sound_runs_pass(manifest):
         3 * summary["grad_norm"]["sound_max"]
 
 
-def resnet_readings(manifest):
+def resnet_readings(manifest, steps=None):
+    from unittest import mock
+
     import jax
 
     from benchmarks import check_outputs
+    from benchmarks.lib import harness
 
     cell = manifest.cell("tiny_resnet.static")
-    summary = check_outputs.readings(manifest, cell, jax.devices()[:1], [41],
-                                     set(), emit=lambda line: None)
+    with mock.patch.object(harness, "FIRST_STEPS",
+                           steps or harness.FIRST_STEPS):
+        summary = check_outputs.readings(
+            manifest, cell, jax.devices()[:1], [41], set(),
+            emit=lambda line: None)
     return {name: summary[name]["sound_max"] for name in summary["limits"]}
 
 
@@ -83,7 +89,13 @@ def test_resnet_with_half_the_learning_rate_is_not_correct(
     monkeypatch.setattr(static_executor.Driver, "build", broken_build)
     got = resnet_readings(manifest)
     limits = real_resnet_limits()
-    assert got["delta_norm_median"] == pytest.approx(0.5, abs=0.05)
+    # read after one step, where every leaf has moved by half of the
+    # reference's change: the median leaf's gap is 0.450 (leaves under the
+    # median are measured against the median leaf's norm) to 0.001 however
+    # the reference is compiled. After three steps the toy's reference is
+    # determinate to a tenth only (PERF.md, PR 26, "the witness").
+    first = resnet_readings(manifest, steps=1)
+    assert first["delta_norm_median"] == pytest.approx(0.45, abs=0.01)
     assert got["delta_norm_median"] > 3 * limits["delta_norm_median"]
     assert got["delta_norm"] <= limits["delta_norm"]   # the worst leaf's passes
     assert got["grad_norm_median"] <= limits["grad_norm_median"]
@@ -145,9 +157,9 @@ def test_a_step_that_leaves_its_state_unchanged_is_not_correct(
     result = harness.run_cell(manifest, cell, 7, 1.0, False,
                               jax.devices()[:1], None, time.perf_counter())
     assert result["correct"] is False
-    failed = [l for l in lines if "FAILED" in l]
-    assert any("delta_norm" in l for l in failed), lines
-    assert not any("grad_norm" in l for l in failed), lines
+    failed = [name for name, c in result["compared"].items() if not c["ok"]]
+    assert "delta_norm" in failed, result["compared"]
+    assert "grad_norm" not in failed, result["compared"]
 
 
 def test_worst_leaf_gap_uses_the_median_leaf_as_floor():

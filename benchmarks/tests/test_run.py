@@ -11,7 +11,8 @@ import pytest
 
 from .conftest import PRESET, REPO
 
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
@@ -39,8 +40,13 @@ def test_cell_runs_end_to_end(preset_run, cell, throughput):
         items = json.load(f)["items_per_step"]
     assert out["metrics"][throughput]["value"] == pytest.approx(
         items * steps / seconds, rel=1e-3)
-    # each number compared is printed beside its limit
-    assert sum(l.startswith("# compared") for l in lines) == 3
+    # each number compared is printed beside its limit: the last lines of
+    # standard error, and the last key of the result's line
+    assert all(l.startswith("# compared") for l in preset_run.err_lines[-3:])
+    assert not any(l.startswith("# compared") for l in lines)
+    assert list(out)[-1] == "compared" and len(out["compared"]) == 3
+    for c in out["compared"].values():
+        assert c["ok"] is True and c["value"] <= c["limit"]
 
 
 def test_traced_run_without_a_chip_prints_no_device_metric(preset_run):
