@@ -147,18 +147,51 @@ def grad_name_for(n: str) -> str:
 
 
 def _emit_recompute_ops(block, path, checkpoints) -> Dict[str, str]:
-    """Append renamed copies of the forward path ops (checkpoint vars and
-    externally-produced vars are read as-is). Returns the old->new name
-    map the grad binding uses for forward-value references."""
+    """Append renamed copies of the forward path ops (externally-produced
+    vars are read as-is). Returns the old->new name map the grad binding
+    uses for forward-value references.
+
+    A checkpoint value the copies read passes through a
+    ``recompute_barrier`` op (``jax.lax.optimization_barrier``) first. The
+    whole step is one XLA program: without it a copy is the same operations
+    on the same inputs as its original, CSE folds the two, the original's
+    intermediates live on until the backward reads them and recomputation
+    saves nothing (the compiled step of a 9-layer model measured the same
+    temporaries with and without checkpoints; PERF.md section 6, PR 27).
+
+    Ops after the last checkpoint are not copied: the backward starts with
+    them, so their values are still there when it reads them."""
     keep = {c.name if hasattr(c, "name") else str(c) for c in checkpoints}
+    last = max((k for k, idx in enumerate(path)
+                if keep.intersection(block.ops[idx].output_arg_names)),
+               default=-1)
     rename: Dict[str, str] = {}
-    for idx in path:
+    behind_barrier: Dict[str, str] = {}
+
+    def read(n):
+        if n in rename:
+            return rename[n]
+        if n not in keep:
+            return n
+        if n not in behind_barrier:
+            v = block._find_var_recursive(n)
+            nv = block.create_var(
+                name=n + "@RECOMPUTE@IN",
+                shape=None if v is None else v.shape,
+                dtype="float32" if v is None else v.dtype)
+            nv.stop_gradient = True
+            block.append_op("recompute_barrier", inputs={"X": [n]},
+                            outputs={"Out": [nv.name]}, infer_shape=False)
+            behind_barrier[n] = nv.name
+        return behind_barrier[n]
+
+    for idx in path[:last + 1]:
         op = block.ops[idx]
         outs_to_rename = [n for n in op.output_arg_names
                           if n and n not in keep]
         if not outs_to_rename:
             continue  # only checkpoint outputs: stored, not recomputed
-        new_inputs = {slot: [rename.get(n, n) for n in names]
+        new_inputs = {slot: [read(n) for n in names]
                       for slot, names in op.inputs.items()}
         new_outputs = {}
         for slot, names in op.outputs.items():
@@ -246,9 +279,9 @@ def _append_backward_impl(loss, block, program, parameter_list=None,
     # with renamed outputs; grad ops then read the RECOMPUTED values, so
     # the original intermediates have no backward consumers and die
     # early. RNG ops re-emit with the original op's seed stream so
-    # dropout masks match. (Under whole-program compilation XLA may CSE
-    # a re-emitted op back onto its original when that is cheaper —
-    # memory behavior is then the compiler's call, never worse.)
+    # dropout masks match. An optimization barrier on the checkpoint
+    # values the copies read keeps XLA from folding a copy back onto its
+    # original (see _emit_recompute_ops).
     recompute_rename: Dict[str, str] = {}
     if checkpoints:
         recompute_rename = _emit_recompute_ops(block, path, checkpoints)

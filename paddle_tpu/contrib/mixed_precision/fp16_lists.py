@@ -20,6 +20,7 @@ class AutoMixedPrecisionLists:
         self.white_list = copy.copy(white_list)
         self.black_list = copy.copy(black_list)
         self.gray_list = copy.copy(gray_list)
+        self.fp32_slots = fp32_slots
         self._update_list()
 
     def _update_list(self):
@@ -51,6 +52,17 @@ white_list = {
     # q, k, v reach the kernels in bf16 (MXU operands); scores, softmax
     # statistics and accumulators are float32 inside them
     "flash_attention",
+    # x, B, C are the scan's MXU operands; see fp32_slots for the rest
+    "ssd_chunk_scan",
+    # the held experts' grouped products; see fp32_slots for the router
+    "moe_topk",
+}
+
+# input slots of white-list ops that stay float32: what sets a decay or a
+# choice must not pass through the low type
+fp32_slots = {
+    "ssd_chunk_scan": frozenset(("A", "D", "DtBias")),
+    "moe_topk": frozenset(("X", "RouterW", "Bias")),
 }
 
 # numerically sensitive reductions/losses/normalizations: keep f32
@@ -71,6 +83,7 @@ black_list = {
     "cross_entropy2",
     "batch_norm",
     "layer_norm",
+    "rms_norm",
     "instance_norm",
     "group_norm",
 }
@@ -113,4 +126,5 @@ gray_list = {
     "get_tensor_from_selected_rows",
     "sign",
     "cast",
+    "causal_conv1d",
 }

@@ -47,8 +47,9 @@ def insert_cast_op(block, new_ops, var, dest, cast_cache):
 
 def rewrite_program(main_prog, amp_lists, dest_dtype: str = "bfloat16"):
     """Walk the forward block, casting white-list op inputs to
-    ``dest_dtype`` and black-list op inputs back to float32; gray ops
-    follow their producers. Output var dtypes are updated in place."""
+    ``dest_dtype`` (but for the slots ``amp_lists.fp32_slots`` names for
+    that op, which stay float32) and black-list op inputs back to float32;
+    gray ops follow their producers. Output var dtypes are updated in place."""
     block = main_prog.global_block()
     ops = list(block.ops)
     new_ops = []
@@ -79,13 +80,15 @@ def rewrite_program(main_prog, amp_lists, dest_dtype: str = "bfloat16"):
             # unknown/unsupported op: force float32 like reference black
             target = "float32"
 
+        keep_fp32 = getattr(amp_lists, "fp32_slots", {}).get(t, ())
         for slot, names in op.inputs.items():
+            want = "float32" if slot in keep_fp32 else target
             for i, name in enumerate(names):
                 v = block._find_var_recursive(name)
                 if v is None or not _is_float(v.dtype):
                     continue
-                if v.dtype != target:
-                    names[i] = insert_cast_op(block, new_ops, v, target,
+                if v.dtype != want:
+                    names[i] = insert_cast_op(block, new_ops, v, want,
                                               cast_cache)
         for name in op.output_arg_names:
             v = block._find_var_recursive(name)

@@ -958,7 +958,8 @@ __all__ += ["sequence_slice", "sequence_unpad", "im2sequence",
 
 
 def flash_attention(q, k, v, causal=False, scale=0.0, lengths=None):
-    """Fused attention over [B, H, S, D] (the multihead hot path —
+    """Fused attention over q [B, H, S, D] and k, v [B, H_kv, S, D], H_kv
+    dividing H (the multihead hot path; fewer K/V heads are shared —
     reference fused/multihead_matmul_op.cu). Lowers to the Pallas flash
     kernels on TPU (which ones is the op's choice, from the shapes);
     ``apply_sequence_parallel`` rewrites it to ring attention over an
@@ -1017,6 +1018,137 @@ def switch_moe(input, num_experts, hidden_dim, capacity_factor=1.0,
 
 
 __all__ += ["flash_attention", "switch_moe"]
+
+
+def rms_norm(input, scale=True, epsilon=1e-5, groups=1, gate=None,
+             param_attr=None, name=None):
+    """Root-mean-square norm over the last axis: ``x * rsqrt(mean(x^2) +
+    epsilon) * Scale`` (no mean, no bias). ``groups`` > 1 norms each of
+    that many equal groups of the last axis; with ``gate`` the normed value
+    is ``x * silu(gate)`` (Mamba-2's gated norm). Float32 statistics under
+    AMP (black list)."""
+    from ..initializer import ConstantInitializer
+
+    helper = LayerHelper("rms_norm", input=input, param_attr=param_attr,
+                         name=name)
+    inputs = {"X": [input]}
+    if scale:
+        inputs["Scale"] = [helper.create_parameter(
+            attr=helper.param_attr, shape=[int(input.shape[-1])],
+            dtype=input.dtype,
+            default_initializer=ConstantInitializer(1.0))]
+    if gate is not None:
+        inputs["Gate"] = [gate]
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op("rms_norm", inputs=inputs, outputs={"Y": [out]},
+                     attrs={"epsilon": float(epsilon),
+                            "groups": int(groups)})
+    return out
+
+
+def causal_conv1d(input, kernel_size=4, bias=True, act=None,
+                  param_attr=None, bias_attr=None, name=None):
+    """Depthwise causal convolution along time over [B, T, C]: weight
+    [C, kernel_size] (its last tap weighs the current position), bias [C];
+    ``act`` None or "silu"."""
+    helper = LayerHelper("causal_conv1d", input=input,
+                         param_attr=param_attr, bias_attr=bias_attr,
+                         name=name)
+    c = int(input.shape[-1])
+    inputs = {"X": [input], "W": [helper.create_parameter(
+        attr=helper.param_attr, shape=[c, int(kernel_size)],
+        dtype=input.dtype)]}
+    if bias:
+        inputs["Bias"] = [helper.create_parameter(
+            attr=helper.bias_attr, shape=[c], dtype=input.dtype,
+            is_bias=True)]
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op("causal_conv1d", inputs=inputs,
+                     outputs={"Out": [out]},
+                     attrs={"activation": act or ""})
+    return out
+
+
+def ssd_chunk_scan(x, dt, A, B, C, D=None, dt_bias=None, chunk=128):
+    """Mamba-2's selective scan by chunks (ops/ssm_ops.py): x [B, T, H, P],
+    raw step sizes dt [B, T, H], A / D / dt_bias [H] (A negative), B and C
+    [B, T, G, N]; from a zero state
+    ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t C_t + D x_t``
+    with ``dt_t = softplus(dt + dt_bias)``, taken inside the op in float32.
+    The gradient op keeps nothing but these inputs and recomputes inside."""
+    helper = LayerHelper("ssd_chunk_scan", input=x)
+    ins = {"X": [x], "Dt": [dt], "A": [A], "B": [B], "C": [C]}
+    if D is not None:
+        ins["D"] = [D]
+    if dt_bias is not None:
+        ins["DtBias"] = [dt_bias]
+    out = helper.create_variable_for_type_inference(x.dtype)
+    # no shape inference: it would trace (and count) the op at build time
+    helper.append_op("ssd_chunk_scan", inputs=ins, outputs={"Out": [out]},
+                     attrs={"chunk": int(chunk)}, infer_shape=False)
+    if not framework.in_dygraph_mode():
+        out.shape = tuple(x.shape)
+    return out
+
+
+def moe_topk(input, num_experts, k, hidden_dim, held=None, scaling=1.0,
+             norm_topk=True, correction_bias=None, return_load=False,
+             param_attr=None, name=None):
+    """Routed experts over [T, D] tokens, without drops: sigmoid scores
+    over all ``num_experts``, the ``k`` largest of score + correction
+    bias chosen (the bias enters the choice only), weights ``scaling *
+    s / sum of the chosen s`` (``norm_topk``), expert ``relu(u W1)^2 W2``
+    (not gated). ``held = [first, count]`` are the experts this program
+    holds (all by default): their weights alone are created and their
+    part of the sum alone is computed, which is what one rank of an
+    expert-parallel deployment does (ops/moe_ops.py). ``correction_bias``
+    is an array [num_experts] or None (zeros): a buffer, not a parameter.
+    A program that holds part of the experts sees only their pull on the
+    router, so there the router's weight takes a zero gradient and stays
+    where it is. With ``return_load`` also returns ``Load`` [count + 1] int32: the
+    slots each held expert received and whether the exact slower branch
+    ran, for a fetch."""
+    import numpy as np
+
+    from ..initializer import NumpyArrayInitializer
+
+    helper = LayerHelper("moe_topk", input=input, param_attr=param_attr,
+                         name=name)
+    dtype = helper.input_dtype()
+    t, d = int(input.shape[0]), int(input.shape[-1])
+    first, count = held if held is not None else (0, num_experts)
+    router_w = helper.create_parameter(
+        attr=helper.param_attr, shape=[d, num_experts], dtype=dtype)
+    w1 = helper.create_parameter(
+        attr=helper.param_attr, shape=[count, d, hidden_dim], dtype=dtype)
+    w2 = helper.create_parameter(
+        attr=helper.param_attr, shape=[count, hidden_dim, d], dtype=dtype)
+    bias = helper.create_global_variable(
+        name=framework.unique_name.generate("moe_correction_bias"),
+        shape=[num_experts], dtype=dtype, persistable=True)
+    bias.stop_gradient = True
+    value = (np.zeros([num_experts]) if correction_bias is None
+             else np.asarray(correction_bias))
+    helper.set_variable_initializer(
+        bias, NumpyArrayInitializer(value.astype(dtype)))
+    out = helper.create_variable_for_type_inference(dtype)
+    load = helper.create_variable_for_type_inference("int32",
+                                                     stop_gradient=True)
+    # no shape inference: it would trace (and count) the op at build time
+    helper.append_op(
+        "moe_topk",
+        inputs={"X": [input], "RouterW": [router_w], "Bias": [bias],
+                "W1": [w1], "W2": [w2]},
+        outputs={"Out": [out], "Load": [load]},
+        attrs={"k": int(k), "held": [int(first), int(count)],
+               "scaling": float(scaling), "norm_topk": bool(norm_topk)},
+        infer_shape=False)
+    if not framework.in_dygraph_mode():
+        out.shape, load.shape = (t, d), (count + 1,)
+    return (out, load) if return_load else out
+
+
+__all__ += ["rms_norm", "causal_conv1d", "ssd_chunk_scan", "moe_topk"]
 
 
 def chunk_eval(input, label, chunk_scheme, num_chunk_types,

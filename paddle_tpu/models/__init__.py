@@ -8,6 +8,12 @@ from .lenet import lenet  # noqa: F401
 from .mlp import mlp  # noqa: F401
 from .resnet import resnet, resnet50, resnet_cifar  # noqa: F401
 from .wide_deep import wide_deep  # noqa: F401
+from .hybrid_ssm_moe import (  # noqa: F401
+    gqa_mixer,
+    hybrid_ssm_moe,
+    mamba2_mixer,
+    moe_mixer,
+)
 from .transformer import (  # noqa: F401
     bert_base_pretrain,
     encoder_layer,

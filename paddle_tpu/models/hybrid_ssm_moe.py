@@ -1,0 +1,136 @@
+"""Hybrid state-space / mixture-of-experts decoder (the ``nemotron_h``
+layout): pre-norm residual layers whose mixer is chosen by a pattern
+string, one letter a layer:
+
+- ``M``  Mamba-2: one input projection to gate, convolved ``x | B | C`` and
+  step sizes; causal depthwise convolution + silu; the chunked selective
+  scan; gated RMS norm over groups; output projection;
+- ``E``  routed experts, top-k of many without drops over the experts this
+  program holds (``layers.moe_topk``), beside a shared expert that every
+  token passes;
+- ``*``  causal grouped-query attention on the ``flash_attention`` op, no
+  positional encoding (as ``NemotronHAttention`` has none).
+
+Built from ``fluid.layers`` ops; nothing here knows a model's name, the
+sizes are arguments.
+"""
+from __future__ import annotations
+
+from .. import layers
+
+
+def _proj(x, size):
+    return layers.fc(x, size=size, num_flatten_dims=2, bias_attr=False)
+
+
+def _relu2(x):
+    r = layers.relu(x)
+    return layers.elementwise_mul(r, r)
+
+
+def mamba2_mixer(u, hidden, num_heads, head_dim, n_groups, state_size,
+                 conv_kernel=4, chunk=128, eps=1e-5):
+    """u [B, T, hidden] (normed) -> [B, T, hidden]."""
+    B, T, _ = u.shape
+    inner = num_heads * head_dim
+    bc = n_groups * state_size
+    zxbcdt = _proj(u, 2 * inner + 2 * bc + num_heads)
+
+    def cut(x, lo, hi):
+        return layers.slice(x, axes=[2], starts=[lo], ends=[hi])
+
+    z = cut(zxbcdt, 0, inner)
+    xbc = layers.causal_conv1d(cut(zxbcdt, inner, 2 * inner + 2 * bc),
+                               kernel_size=conv_kernel, act="silu")
+    dt = cut(zxbcdt, 2 * inner + 2 * bc, 2 * inner + 2 * bc + num_heads)
+    x = layers.reshape(cut(xbc, 0, inner), [B, T, num_heads, head_dim])
+    b = layers.reshape(cut(xbc, inner, inner + bc),
+                       [B, T, n_groups, state_size])
+    c = layers.reshape(cut(xbc, inner + bc, inner + 2 * bc),
+                       [B, T, n_groups, state_size])
+    dt_bias = layers.create_parameter([num_heads], "float32")
+    a_log = layers.create_parameter([num_heads], "float32")
+    d_skip = layers.create_parameter([num_heads], "float32")
+    a = layers.scale(layers.exp(a_log), scale=-1.0)
+    y = layers.ssd_chunk_scan(x, dt, a, b, c, D=d_skip, dt_bias=dt_bias,
+                              chunk=chunk)
+    y = layers.rms_norm(layers.reshape(y, [B, T, inner]), gate=z,
+                        groups=n_groups, epsilon=eps)
+    return _proj(y, hidden)
+
+
+def moe_mixer(u, hidden, num_experts, top_k, expert_dim, shared_dim,
+              held=None, scaling=1.0, correction_bias=None, loads=None):
+    """u [B, T, hidden] (normed) -> routed (held experts' part) + shared."""
+    B, T, _ = u.shape
+    routed, load = layers.moe_topk(
+        layers.reshape(u, [B * T, hidden]), num_experts, top_k, expert_dim,
+        held=held, scaling=scaling, correction_bias=correction_bias,
+        return_load=True)
+    if loads is not None:
+        loads.append(load)
+    out = layers.reshape(routed, [B, T, hidden])
+    if shared_dim:
+        out = layers.elementwise_add(
+            out, _proj(_relu2(_proj(u, shared_dim)), hidden))
+    return out
+
+
+def gqa_mixer(u, hidden, num_heads, num_kv_heads, head_dim):
+    """u [B, T, hidden] (normed) -> causal grouped-query attention."""
+    B, T, _ = u.shape
+
+    def heads(x, n):
+        return layers.transpose(layers.reshape(x, [B, T, n, head_dim]),
+                                [0, 2, 1, 3])
+
+    q = heads(_proj(u, num_heads * head_dim), num_heads)
+    k = heads(_proj(u, num_kv_heads * head_dim), num_kv_heads)
+    v = heads(_proj(u, num_kv_heads * head_dim), num_kv_heads)
+    ctx = layers.flash_attention(q, k, v, causal=True,
+                                 scale=float(head_dim) ** -0.5)
+    ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
+                         [B, T, num_heads * head_dim])
+    return _proj(ctx, hidden)
+
+
+def hybrid_ssm_moe(ids, pattern, vocab_rows, hidden, *, mamba_heads=64,
+                   mamba_head_dim=64, n_groups=8, state_size=128,
+                   conv_kernel=4, chunk=128, num_experts=128, top_k=6,
+                   expert_dim=1856, shared_dim=3712, held=None,
+                   routed_scaling=2.5, correction_bias=None, num_heads=32,
+                   num_kv_heads=2, head_dim=128, eps=1e-5, loads=None,
+                   checkpoints=None):
+    """Logits [B, T, vocab_rows] over int64 ids [B, T].
+
+    ``pattern``: one letter a layer (see the module's docstring).
+    ``vocab_rows``: the rows of the vocabulary this program holds (ids and
+    loss are over them). ``held = [first, count]``: the experts of every
+    ``E`` layer held here. ``correction_bias``: None, or one array
+    [num_experts] for each ``E`` layer in order. ``loads``: a list that
+    receives each ``E`` layer's ``Load`` variable, for a fetch.
+    ``checkpoints``: a list that receives each layer's input and the last
+    layer's output, which is what
+    ``RecomputeOptimizer._set_checkpoints`` takes to recompute a layer's
+    activations from its input alone."""
+    x = layers.embedding(ids, size=[vocab_rows, hidden])
+    biases = iter(correction_bias or ())
+    for kind in pattern:
+        if checkpoints is not None:
+            checkpoints.append(x)
+        u = layers.rms_norm(x, epsilon=eps)
+        if kind == "M":
+            y = mamba2_mixer(u, hidden, mamba_heads, mamba_head_dim,
+                             n_groups, state_size, conv_kernel, chunk, eps)
+        elif kind == "E":
+            y = moe_mixer(u, hidden, num_experts, top_k, expert_dim,
+                          shared_dim, held, routed_scaling,
+                          next(biases, None), loads)
+        elif kind == "*":
+            y = gqa_mixer(u, hidden, num_heads, num_kv_heads, head_dim)
+        else:
+            raise ValueError("hybrid_ssm_moe: no layer kind %r" % kind)
+        x = layers.elementwise_add(x, y)
+    if checkpoints is not None:
+        checkpoints.append(x)
+    return _proj(layers.rms_norm(x, epsilon=eps), vocab_rows)

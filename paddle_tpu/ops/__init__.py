@@ -42,3 +42,5 @@ from . import ctr_ops  # noqa: F401
 from . import tail_ops3  # noqa: F401
 from . import text_match_ops  # noqa: F401
 from . import eval_ops  # noqa: F401
+from . import ssm_ops  # noqa: F401
+from . import moe_ops  # noqa: F401

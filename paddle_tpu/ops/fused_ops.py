@@ -240,7 +240,8 @@ register_op(
     attrs={"causal": False, "scale": 0.0},
 )
 def _flash_attention(ins, attrs):
-    """Attention over [B, H, S, D] with no S x S matrix in HBM: Pallas
+    """Attention over Q [B, H, S, D] and K, V [B, H_kv, S, D] (H_kv
+    dividing H: shared K/V heads) with no S x S matrix in HBM: Pallas
     kernels where the computation runs on a TPU (which ones is decided
     from the shapes, see ops/pallas/flash_attention.py), the same dense
     math elsewhere. ``Lengths`` [B] int: per-row valid-KV count — the

@@ -911,3 +911,17 @@ def _partial_sum(ins, attrs):
     for p in parts[1:]:
         out = out + p
     return {"Out": out}
+
+
+@register_op(
+    "recompute_barrier",
+    inputs=[In("X", no_grad=True)],
+    outputs=[Out("Out", no_grad=True)],
+    grad=None,
+)
+def _recompute_barrier(ins, attrs):
+    """The identity behind ``jax.lax.optimization_barrier``: what
+    ``backward._emit_recompute_ops`` puts between a checkpoint value and the
+    re-emitted segment that reads it, so that XLA cannot fold the segment's
+    copy into its original."""
+    return {"Out": jax.lax.optimization_barrier(ins["X"])}

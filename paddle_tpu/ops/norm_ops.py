@@ -239,3 +239,32 @@ def _l2_normalize(ins, attrs):
     x = ins["X"]
     sq = jnp.sum(jnp.square(x), axis=attrs.get("axis", -1), keepdims=True)
     return {"Out": x * jax.lax.rsqrt(jnp.maximum(sq, attrs.get("epsilon", 1e-10)))}
+
+
+@register_op(
+    "rms_norm",
+    inputs=[In("X"), In("Scale", dispensable=True),
+            In("Gate", dispensable=True)],
+    outputs=[Out("Y")],
+    attrs={"epsilon": 1e-5, "groups": 1},
+)
+def _rms_norm(ins, attrs):
+    """Root-mean-square norm over the last axis (no mean, no bias):
+    ``x * rsqrt(mean(x^2) + eps) * Scale``. With ``Gate`` the normed value
+    is ``x * silu(Gate)`` (Mamba-2's gated norm); with ``groups`` > 1 the
+    mean runs over each of that many equal groups of the last axis. The
+    statistics are float32 whatever the input's type (AMP black list)."""
+    x = ins["X"]
+    dtype = x.dtype
+    x = x.astype(jnp.float32)
+    if ins.get("Gate") is not None:
+        x = x * jax.nn.silu(ins["Gate"].astype(jnp.float32))
+    groups = int(attrs.get("groups", 1) or 1)
+    shape = x.shape
+    g = x.reshape(shape[:-1] + (groups, shape[-1] // groups))
+    g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), -1, keepdims=True)
+                          + attrs.get("epsilon", 1e-5))
+    y = g.reshape(shape)
+    if ins.get("Scale") is not None:
+        y = y * ins["Scale"].astype(jnp.float32)
+    return {"Y": y.astype(dtype)}

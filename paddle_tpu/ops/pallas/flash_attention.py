@@ -33,6 +33,14 @@ from one S/P/dP (five matmuls a head instead of seven). Both kernels
 work on the transposed tile [Tk, Tq], which keeps LSE and delta
 lane-dense rows and transposes only [S, D]-sized operands.
 
+**Shared K/V heads** (grouped-query attention): K and V may have fewer
+heads than Q, ``H_kv`` dividing ``H``. The streaming kernels' index maps
+read the shared head (row ``b // group`` of the [B * H_kv] rows), nothing
+is repeated in HBM, and the dK/dV kernel runs over the group's query
+heads in its last grid axis so that their sums happen in its
+accumulators. Such a call takes the streaming kernels at any length (the
+short ones take one head count).
+
 Where the computation runs on a TPU (``core.place.compute_platform``)
 the public entry builds the kernels, and a kernel Mosaic refuses
 raises. Elsewhere it computes the identical dense math, so programs
@@ -89,7 +97,20 @@ def _kv_len_mask(s, ki, block_k, len_val):
     return jnp.where(k_pos < len_val, s, NEG_INF)
 
 
+def _group(q, k):
+    """Query heads that share one K/V head: ``H / H_kv`` (1 = plain
+    multi-head attention)."""
+    H, H_kv = q.shape[1], k.shape[1]
+    if H % H_kv:
+        raise ValueError("flash_attention: %d query heads over %d key/value "
+                         "heads" % (H, H_kv))
+    return H // H_kv
+
+
 def _dense_attention(q, k, v, causal, scale, lengths=None):
+    group = _group(q, k)
+    if group > 1:   # the dense math repeats the shared heads; no kernel does
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if causal:
@@ -205,21 +226,25 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
 
     B, H, S, D = q.shape
     S_kv = k.shape[2]
+    group = _group(q, k)
     bq = min(block_q, S)
     bk = min(block_k, S)
     if S != S_kv or S % bq or S % bk:
         # ragged tail, or rectangular cross-attention Q/K — the kernel
         # grid assumes square S; dense math handles both exactly
         q3 = q.reshape(B * H, S, D)
-        k3 = k.reshape(B * H, S_kv, D)
+        k3 = jnp.repeat(k, group, axis=1).reshape(B * H, S_kv, D)
         lbh = (None if lengths is None
                else jnp.repeat(lengths.astype(jnp.int32), H))
         return (_dense_attention(q, k, v, causal, scale, lengths),
                 _dense_lse(q3, k3, causal, scale, lbh))
     nq, nk = S // bq, S // bk
     q3 = q.reshape(B * H, S, D)
-    k3 = k.reshape(B * H, S, D)
-    v3 = v.reshape(B * H, S, D)
+    # row b of q3 is head b % H of batch row b // H; its K/V head is row
+    # b // group of the [B * H_kv] rows: the index map reads the shared
+    # head, nothing is repeated in HBM
+    k3 = k.reshape(B * H // group, S, D)
+    v3 = v.reshape(B * H // group, S, D)
 
     has_len = lengths is not None
     kernel = functools.partial(_flash_kernel, scale=scale, causal=causal,
@@ -227,8 +252,8 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
                                has_len=has_len)
     in_specs = [
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bk, D), lambda b, i, j: (b // group, j, 0)),
+            pl.BlockSpec((1, bk, D), lambda b, i, j: (b // group, j, 0)),
         ]
     args = [q3, k3, v3]
     if has_len:
@@ -322,7 +347,10 @@ def _flash_bwd_dq_kernel(*refs, scale, causal, block_q, block_k, nk,
 
 
 def _flash_bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, nq,
-                          has_len):
+                          has_len, group=1):
+    """dK and dV of one K/V head: the last grid axis runs over the
+    ``group`` query heads that share it and, inside each, the Q blocks, so
+    the sums over the group happen in the accumulators."""
     from jax.experimental import pallas as pl
 
     if has_len:
@@ -332,11 +360,12 @@ def _flash_bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, nq,
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
         len_ref = None
-    qi = pl.program_id(2)
+    step = pl.program_id(2)
+    qi = step % nq if group > 1 else step
     ki = pl.program_id(1)
     bi = pl.program_id(0)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -374,7 +403,7 @@ def _flash_bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, nq,
     else:
         _accumulate()
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == group * nq - 1)
     def _finish():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -385,12 +414,14 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
     from jax.experimental import pallas as pl
 
     B, H, S, D = q.shape
+    group = _group(q, k)
+    H_kv = H // group
     bq = min(block_q, S)
     bk = min(block_k, S)
     nq, nk = S // bq, S // bk
     q3 = q.reshape(B * H, S, D)
-    k3 = k.reshape(B * H, S, D)
-    v3 = v.reshape(B * H, S, D)
+    k3 = k.reshape(B * H_kv, S, D)
+    v3 = v.reshape(B * H_kv, S, D)
     do3 = g.reshape(B * H, S, D)
     o3 = out.reshape(B * H, S, D)
     # delta = rowsum(dO ∘ O): one fused elementwise pass, O(S·D)
@@ -398,13 +429,13 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
                     axis=-1, keepdims=True)            # [BH, S, 1]
 
     has_len = lengths is not None
-    extra_args = []
-    dq_len_specs = []
-    dkv_len_specs = []
+    dq_len, dkv_len = [], []
+    len_specs = []
     if has_len:
-        extra_args.append(_len_bh(lengths, B, H))
-        dq_len_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]
-        dkv_len_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]
+        # one row a grid batch step: query heads for dQ, K/V heads for dK/dV
+        dq_len, dkv_len = ([_len_bh(lengths, B, H)],
+                           [_len_bh(lengths, B, H_kv)])
+        len_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]
 
     dq_kernel = functools.partial(
         _flash_bwd_dq_kernel, scale=scale, causal=causal, block_q=bq,
@@ -414,41 +445,46 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
         grid=(B * H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bk, D), lambda b, i, j: (b // group, j, 0)),
+            pl.BlockSpec((1, bk, D), lambda b, i, j: (b // group, j, 0)),
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-        ] + dq_len_specs,
+        ] + len_specs,
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q3, k3, v3, do3, lse, delta, *extra_args)
+    )(q3, k3, v3, do3, lse, delta, *dq_len)
 
     dkv_kernel = functools.partial(
         _flash_bwd_dkv_kernel, scale=scale, causal=causal, block_q=bq,
-        block_k=bk, nq=nq, has_len=has_len)
+        block_k=bk, nq=nq, has_len=has_len, group=group)
+
+    def q_rows(b, j, t):
+        # K/V head b, step t: query head t // nq of its group, Q block t % nq
+        return (b * group + t // nq, t % nq, 0) if group > 1 else (b, t, 0)
+
     dk, dv = pl.pallas_call(
         dkv_kernel,
-        grid=(B * H, nk, nq),
+        grid=(B * H_kv, nk, group * nq),
         in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),
-        ] + dkv_len_specs,
+            pl.BlockSpec((1, bq, D), q_rows),
+            pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0)),
+            pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0)),
+            pl.BlockSpec((1, bq, D), q_rows),
+            pl.BlockSpec((1, bq, 1), q_rows),
+            pl.BlockSpec((1, bq, 1), q_rows),
+        ] + len_specs,
         out_specs=[
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0)),
+            pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, S, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, S, D), v.dtype),
+            jax.ShapeDtypeStruct((B * H_kv, S, D), k.dtype),
+            jax.ShapeDtypeStruct((B * H_kv, S, D), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, D), jnp.float32),
@@ -457,10 +493,9 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q3, k3, v3, do3, lse, delta, *extra_args)
+    )(q3, k3, v3, do3, lse, delta, *dkv_len)
 
-    shape = (B, H, S, D)
-    return (dq.reshape(shape), dk.reshape(shape), dv.reshape(shape))
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
 
 # ---------------------------------------------------------------------------
@@ -736,8 +771,9 @@ def _plan(q, k, block_q, block_k):
     B, H, S, D = q.shape
     if S != k.shape[2]:
         return 0, block_q, block_k   # rectangular: the dense fallback
-    if S % 128 == 0 and S <= block_k:
-        # the caller's K block holds the whole sequence
+    if S % 128 == 0 and S <= block_k and H == k.shape[1]:
+        # the caller's K block holds the whole sequence (the short kernels
+        # take one head count: shared K/V heads stream)
         heads = _short_heads(B * H, S, D, q.dtype.itemsize)
         if heads:
             return heads, block_q, block_k
@@ -797,7 +833,9 @@ def flash_attention(q, k, v, causal: bool = False,
                     lengths=None):
     """Flash attention over ``[B, H, S, D]`` tensors — differentiable,
     and no S x S matrix in HBM in either direction: the backward
-    recomputes the probabilities from the saved logsumexp.
+    recomputes the probabilities from the saved logsumexp. ``k`` and
+    ``v`` may be ``[B, H_kv, S, D]`` with ``H_kv`` dividing ``H`` (shared
+    K/V heads; always the streaming kernels on the TPU).
 
     Which kernels run is decided here, from the shapes (``_plan``):
 

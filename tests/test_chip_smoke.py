@@ -172,6 +172,22 @@ def test_bert_step_for_v5e_holds_one_forward_kernel_a_layer(v5e_compile):
     assert line == "BERT_STEP fwd=2 bwd=2 other_mosaic=0 tt_buffers=0"
 
 
+def test_recomputation_lowers_the_compiled_steps_temporaries(v5e_compile):
+    """A six-layer hybrid state-space / MoE step with
+    ``RecomputeOptimizer`` over the layers' inputs holds fewer temporaries
+    than without: the barrier on the checkpoint values keeps XLA from
+    folding each re-emitted segment back onto its original (without it the
+    real configuration's step compiled to the same 7.898 GiB either way,
+    PERF.md section 6, PR 27). The step also holds the streaming attention
+    kernels for shared K/V heads and the grouped-product kernels of the
+    experts (megablox on the TPU, so no ``ragged-dot`` is left)."""
+    (line,) = [x for x in v5e_compile.stdout.splitlines()
+               if x.startswith("HYBRID_STEP ")]
+    got = dict(kv.split("=") for kv in line.split()[2:])
+    assert int(got["checkpoints"]) < 0.8 * int(got["plain"]), line
+    assert int(got["mosaic_calls"]) >= 3 + 6 and int(got["ragged_dots"]) == 0
+
+
 def test_bert_step_report_counts_what_it_names():
     hlo = """
   %flash_short_fwd.2 = (bf16[48,512,64]{2,1,0}) custom-call(%a), custom_call_target="tpu_custom_call"

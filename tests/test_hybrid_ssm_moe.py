@@ -1,0 +1,425 @@
+"""The ops of the hybrid state-space / mixture-of-experts model against
+plain definitions, at small sizes on the CPU: ``rms_norm``,
+``causal_conv1d``, ``ssd_chunk_scan`` and its gradient op against the
+literal recurrence, ``moe_topk`` against a dense loop over all experts
+(shares add up, full skew, no held expert), grouped-query heads on the
+streaming ``flash_attention`` kernels in interpret mode, the AMP rewrite's
+float32 slots, and the tiny model through ``fluid.Executor`` with AMP and
+Adam against the benchmark's plain reference."""
+import importlib
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from paddle_tpu.core.registry import OpInfoMap
+from paddle_tpu.ops.moe_ops import buffer_rows, moe_topk
+from paddle_tpu.ops.ssm_ops import ssd_chunk_scan
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+
+def op(name):
+    return OpInfoMap.instance().get(name).fn
+
+
+def keys(n, seed=0):
+    return jax.random.split(jax.random.key(seed), n)
+
+
+def rel(a, b):
+    return float(jnp.max(jnp.abs(a.astype(jnp.float32) - b))
+                 / (jnp.max(jnp.abs(b)) + 1e-30))
+
+
+# -- rms_norm, causal_conv1d --------------------------------------------------
+
+@pytest.mark.parametrize("groups,gated", [(1, False), (4, False), (4, True)])
+def test_rms_norm(groups, gated):
+    k = keys(3)
+    x = jax.random.normal(k[0], (2, 5, 32))
+    w = 1 + 0.1 * jax.random.normal(k[1], (32,))
+    z = jax.random.normal(k[2], (2, 5, 32)) if gated else None
+    got = op("rms_norm")({"X": x, "Scale": w, "Gate": z},
+                         {"epsilon": 1e-5, "groups": groups})["Y"]
+    v = np.asarray(x if z is None else x * z / (1 + np.exp(-np.asarray(z))),
+                   np.float64).reshape(2, 5, groups, -1)
+    want = (v / np.sqrt((v ** 2).mean(-1, keepdims=True) + 1e-5)
+            ).reshape(2, 5, 32) * np.asarray(w)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    # a bf16 input keeps its type, the statistics are float32 inside
+    low = op("rms_norm")({"X": x.astype(jnp.bfloat16), "Scale": w,
+                          "Gate": z}, {"groups": groups})["Y"]
+    assert low.dtype == jnp.bfloat16 and rel(low, want) < 2e-2
+
+
+@pytest.mark.parametrize("act", ["", "silu"])
+def test_causal_conv1d_and_its_gradient(act):
+    k = keys(3, 1)
+    x = jax.random.normal(k[0], (2, 9, 6))
+    w = jax.random.normal(k[1], (6, 4))
+    b = jax.random.normal(k[2], (6,))
+
+    def plain(x, w, b):
+        rows = []
+        for t in range(x.shape[1]):
+            acc = b
+            for j in range(4):
+                if t - 3 + j >= 0:
+                    acc = acc + w[:, j] * x[:, t - 3 + j]
+            rows.append(acc)
+        y = jnp.stack(rows, 1)
+        return jax.nn.silu(y) if act else y
+
+    fn = op("causal_conv1d")
+    got = fn({"X": x, "W": w, "Bias": b}, {"activation": act})["Out"]
+    np.testing.assert_allclose(got, plain(x, w, b), rtol=1e-5, atol=1e-6)
+    g = jax.random.normal(keys(1, 2)[0], got.shape)
+    grads = op("causal_conv1d_grad")(
+        {"X": x, "W": w, "Bias": b, "Out@GRAD": g}, {"activation": act})
+    want = jax.grad(lambda *a: jnp.sum(plain(*a) * g), (0, 1, 2))(x, w, b)
+    for name, ref in zip(("X@GRAD", "W@GRAD", "Bias@GRAD"), want):
+        np.testing.assert_allclose(grads[name], ref, rtol=1e-4, atol=1e-5)
+
+
+# -- the selective scan -------------------------------------------------------
+
+def recurrence(x, raw_dt, a, b, c, d):
+    """The definition, position by position."""
+    dt = jax.nn.softplus(raw_dt)
+    bsz, t, h, p = x.shape
+    r = h // b.shape[2]
+
+    def step(state, inp):
+        xt, dtt, bt, ct = inp
+        bt, ct = jnp.repeat(bt, r, 1), jnp.repeat(ct, r, 1)
+        state = (jnp.exp(dtt * a)[..., None, None] * state
+                 + (dtt[..., None] * xt)[..., None] * bt[:, :, None, :])
+        return state, jnp.sum(state * ct[:, :, None, :], -1) + d[:, None] * xt
+
+    zero = jnp.zeros((bsz, h, p, b.shape[3]))
+    _, ys = jax.lax.scan(step, zero, tuple(jnp.moveaxis(z, 1, 0)
+                                           for z in (x, dt, b, c)))
+    return jnp.moveaxis(ys, 0, 1)
+
+
+def scan_inputs(t, seed=0):
+    """x, raw step sizes (the op takes their softplus), A, B, C, D."""
+    k = keys(6, seed)
+    bsz, h, p, g, n = 2, 4, 8, 2, 16
+    return (jax.random.normal(k[0], (bsz, t, h, p)),
+            jax.random.normal(k[1], (bsz, t, h)) - 2,
+            -jnp.exp(jax.random.normal(k[2], (h,))),
+            jax.random.normal(k[3], (bsz, t, g, n)),
+            jax.random.normal(k[4], (bsz, t, g, n)),
+            1 + 0.1 * jax.random.normal(k[5], (h,)))
+
+
+# several chunk counts, a length that is no multiple of the chunk, one chunk
+@pytest.mark.parametrize("t,chunk", [(32, 16), (96, 16), (50, 16), (24, 128)])
+def test_ssd_chunk_scan_is_the_recurrence(t, chunk):
+    args = scan_inputs(t)
+    with jax.default_matmul_precision("highest"):
+        got = ssd_chunk_scan(*args, chunk=chunk)
+        want = recurrence(*args)
+    assert rel(got, want) < 1e-5
+    # bf16 operands, float32 decays and state: the MXU's rounding of x, B, C
+    # (2^-9 each, three products deep) and no more
+    low = ssd_chunk_scan(args[0].astype(jnp.bfloat16), args[1], args[2],
+                         args[3].astype(jnp.bfloat16),
+                         args[4].astype(jnp.bfloat16), args[5], chunk=chunk)
+    assert low.dtype == jnp.bfloat16 and rel(low, want) < 3e-2
+
+
+@pytest.mark.parametrize("t,chunk", [(32, 16), (50, 16)])
+def test_ssd_chunk_scan_grad_op_is_the_recurrences_gradient(t, chunk):
+    x, dt, a, b, c, d = scan_inputs(t, 3)
+    g = jax.random.normal(keys(1, 4)[0], x.shape)
+    with jax.default_matmul_precision("highest"):
+        got = op("ssd_chunk_scan_grad")(
+            {"X": x, "Dt": dt, "A": a, "B": b, "C": c, "D": d,
+             "Out@GRAD": g}, {"chunk": chunk})
+        want = jax.grad(lambda *v: jnp.sum(recurrence(*v) * g),
+                        tuple(range(6)))(x, dt, a, b, c, d)
+    for name, ref in zip(("X", "Dt", "A", "B", "C", "D"), want):
+        assert rel(got[name + "@GRAD"], ref) < 1e-5, name
+
+
+def test_ssd_chunk_scan_op_adds_the_step_sizes_bias():
+    x, dt, a, b, c, d = scan_inputs(32, 5)
+    raw = jax.random.normal(keys(1, 6)[0], dt.shape)
+    bias = jnp.linspace(-3.0, -1.0, 4)
+    with jax.default_matmul_precision("highest"):
+        got = op("ssd_chunk_scan")(
+            {"X": x, "Dt": raw, "A": a, "B": b, "C": c, "D": d,
+             "DtBias": bias}, {"chunk": 16})["Out"]
+        want = recurrence(x, raw + bias, a, b, c, d)
+    assert rel(got, want) < 1e-5
+
+
+# -- top-k routing over the experts held here ---------------------------------
+
+T, D, E, F, K = 256, 16, 16, 24, 3
+
+
+def moe_inputs(seed=0):
+    k = keys(5, seed)
+    return (jax.random.normal(k[0], (T, D)), jax.random.normal(k[1], (D, E)),
+            0.1 * jax.random.normal(k[2], (E,)),
+            0.3 * jax.random.normal(k[3], (E, D, F)),
+            0.3 * jax.random.normal(k[4], (E, F, D)))
+
+
+def every_expert(x, rw, bias, w1, w2, held=(0, E)):
+    """The layer as written: each chosen expert that is held, weighed."""
+    s = jax.nn.sigmoid(x @ rw)
+    _, idx = jax.lax.top_k(s + bias, K)
+    w = jnp.take_along_axis(s, idx, -1)
+    w = 2.5 * w / w.sum(-1, keepdims=True)
+    out = jnp.zeros_like(x)
+    for e in range(held[0], held[0] + held[1]):
+        m = jnp.sum(jnp.where(idx == e, w, 0.0), -1)
+        out = out + m[:, None] * (jnp.square(jax.nn.relu(x @ w1[e])) @ w2[e])
+    return out
+
+
+def held_part(x, rw, bias, w1, w2, first, count):
+    return moe_topk(x, rw, bias, w1[first:first + count],
+                    w2[first:first + count], K, [first, count], 2.5)
+
+
+def test_the_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
+    x, rw, bias, w1, w2 = moe_inputs()
+    shared = jnp.square(jax.nn.relu(x @ w1[0])) @ w2[0]   # any dense expert
+    with jax.default_matmul_precision("highest"):
+        whole = every_expert(x, rw, bias, w1, w2) + shared
+        parts, loads = [], []
+        for first in range(0, E, 2):                      # 8 shares of 2
+            out, load = held_part(x, rw, bias, w1, w2, first, 2)
+            parts.append(out)
+            loads.append(load)
+    assert rel(sum(parts) + shared, whole) < 1e-5
+    # every routed slot landed in exactly one share, none in a slow branch
+    assert int(sum(l[:2].sum() for l in loads)) == T * K
+    assert not any(int(l[2]) for l in loads)
+
+
+@pytest.mark.parametrize("favoured,slow", [([1], 0), ([0, 1, 2], 1)])
+def test_full_skew_drops_no_slot(favoured, slow):
+    """Every token to one held expert and to no other held one (256 slots,
+    inside the row buffer) and to three (768 slots against a buffer of
+    384: the exact slower branch)."""
+    x, rw, bias, w1, w2 = moe_inputs(1)
+    bias = bias.at[:4].set(-100.0).at[jnp.array(favoured)].set(100.0)
+    with jax.default_matmul_precision("highest"):
+        got, load = held_part(x, rw, bias, w1, w2, 0, 4)
+        want = every_expert(x, rw, bias, w1, w2, (0, 4))
+    assert buffer_rows(T, K, E, 4) == 384
+    assert [int(load[e]) for e in favoured] == [T] * len(favoured)
+    assert int(load[4]) == slow
+    assert rel(got, want) < 1e-5
+
+
+def test_no_held_expert_chosen_gives_zero():
+    x, rw, bias, w1, w2 = moe_inputs(2)
+    got, load = held_part(x, rw, bias.at[:4].set(-100.0), w1, w2, 0, 4)
+    assert not np.asarray(load).any() and not np.asarray(got).any()
+
+
+@pytest.mark.parametrize("count", [E, 4])
+@pytest.mark.parametrize("skewed", [False, True])
+def test_moe_topk_grad_op_is_the_layers_gradient(skewed, count):
+    """With every expert held, the layer's gradient; with part of them, the
+    same but for the router's weight, which then takes none."""
+    x, rw, bias, w1, w2 = moe_inputs(3)
+    if skewed:
+        bias = bias.at[:3].set(100.0)
+    g = jax.random.normal(keys(1, 9)[0], x.shape)
+    attrs = {"k": K, "held": [0, count], "scaling": 2.5, "norm_topk": True}
+    with jax.default_matmul_precision("highest"):
+        got = op("moe_topk_grad")(
+            {"X": x, "RouterW": rw, "Bias": bias, "W1": w1[:count],
+             "W2": w2[:count], "Out@GRAD": g}, attrs)
+        want = jax.grad(
+            lambda x, rw, a, b: jnp.sum(every_expert(
+                x, rw, bias, a, b, (0, count)) * g), (0, 1, 2, 3))(
+                    x, rw, w1[:count], w2[:count])
+    assert "Bias@GRAD" not in got          # a buffer: no gradient
+    if count < E:
+        assert not np.asarray(got.pop("RouterW@GRAD")).any()
+        want = (want[0], None) + want[2:]
+    for name, ref in zip(("X", "RouterW", "W1", "W2"), want):
+        assert ref is None or rel(got[name + "@GRAD"], ref) < 1e-5, name
+
+
+@pytest.mark.parametrize("favoured", [[], [1]])
+def test_the_tpus_grouped_kernels_give_the_layers_result_and_gradient(
+        monkeypatch, favoured):
+    """Where the computation runs on a TPU the sorted slots go through the
+    megablox kernels (here in interpret mode, tiles smaller than the
+    widths, neither width a whole number of tiles): the held experts' rows
+    only, the rows past the held slots owned by a group no kernel visits."""
+    import functools
+
+    from paddle_tpu.ops import moe_ops
+
+    gmm = moe_ops._megablox()
+    monkeypatch.setattr(moe_ops._fa, "compute_platform", lambda: "tpu")
+    monkeypatch.setattr(moe_ops, "TILING", (128, 128, 128))
+    monkeypatch.setattr(gmm, "gmm", functools.partial(gmm.gmm,
+                                                      interpret=True))
+    monkeypatch.setattr(gmm, "tgmm", functools.partial(gmm.tgmm,
+                                                       interpret=True))
+    d, f = 192, 160
+    k = keys(6, 5)
+    x, rw = jax.random.normal(k[0], (T, d)), jax.random.normal(k[1], (d, E))
+    bias = 0.1 * jax.random.normal(k[2], (E,))
+    bias = bias.at[jnp.array(favoured, jnp.int32)].set(100.0)
+    w1 = 0.1 * jax.random.normal(k[3], (E, d, f))
+    w2 = 0.1 * jax.random.normal(k[4], (E, f, d))
+    g = jax.random.normal(k[5], x.shape)
+    assert moe_ops.grouped_path(buffer_rows(T, K, E, 4)) == "megablox"
+
+    def mine(x, a, b):
+        return jnp.sum(moe_topk(x, rw, bias, a, b, K, [0, 4], 2.5)[0] * g)
+
+    def plain(x, a, b):
+        return jnp.sum(every_expert(x, rw, bias, a, b, (0, 4)) * g)
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(mine, (0, 1, 2))(x, w1[:4], w2[:4])
+        want = jax.value_and_grad(plain, (0, 1, 2))(x, w1[:4], w2[:4])
+        load = moe_topk(x, rw, bias, w1[:4], w2[:4], K, [0, 4], 2.5)[1]
+    assert int(load[4]) == 0 and all(int(load[e]) == T for e in favoured)
+    assert abs(float(got[0] - want[0])) < 1e-4 * abs(float(want[0]))
+    for a, b in zip(got[1], want[1]):
+        assert rel(a, b) < 1e-5
+
+
+# -- grouped-query heads on the streaming kernels -----------------------------
+
+@pytest.mark.parametrize("heads,kv_heads", [(4, 1), (8, 2)])
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_attention_with_shared_kv_heads(heads, kv_heads, causal):
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    k = keys(4, 7)
+    b, t, d = 2, 256, 128
+    q = jax.random.normal(k[0], (b, heads, t, d))
+    kk = jax.random.normal(k[1], (b, kv_heads, t, d))
+    v = jax.random.normal(k[2], (b, kv_heads, t, d))
+    g = jax.random.normal(k[3], q.shape)
+
+    def dense(q, kk, v):
+        r = heads // kv_heads
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(kk, r, 1)) * d ** -0.5
+        if causal:
+            s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1),
+                          jnp.repeat(v, r, 1))
+
+    assert fa.attention_path(q, kk, force_pallas=True) == "stream"
+    with jax.default_matmul_precision("highest"):
+        out, vjp = jax.vjp(lambda *a: fa.flash_attention(
+            *a, causal=causal, block_q=128, block_k=128, force_pallas=True),
+            q, kk, v)
+        want, ref_vjp = jax.vjp(dense, q, kk, v)
+        assert rel(out, want) < 1e-5
+        for got, ref in zip(vjp(g), ref_vjp(g)):
+            assert got.shape == ref.shape and rel(got, ref) < 1e-5
+        # the dense math off the TPU takes the same arguments
+        assert rel(fa.flash_attention(q, kk, v, causal=causal), want) < 1e-5
+
+
+# -- the AMP rewrite and the model --------------------------------------------
+
+def tiny():
+    from benchmarks.configs.nemotron3_nano_ep16 import model, reference
+
+    preset = os.path.join(ROOT, "benchmarks", "tests", "preset")
+    with open(os.path.join(preset, "configs", "tiny_nemotron",
+                           "config.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(preset, "traffic",
+                           "tiny_nemotron.static.json")) as f:
+        traffic = json.load(f)
+    return cfg, traffic, model, reference
+
+
+def test_amp_keeps_the_routers_and_the_decays_slots_float32():
+    cfg, traffic, model, _ = tiny()
+    block = model.build_static(cfg, traffic)["main"].global_block()
+
+    def dtypes(op_type):
+        o = next(o for o in block.ops if o.type == op_type)
+        return {slot: str(block._find_var_recursive(names[0]).dtype)
+                for slot, names in o.inputs.items()}
+
+    assert dtypes("moe_topk") == {
+        "X": "float32", "RouterW": "float32", "Bias": "float32",
+        "W1": "bfloat16", "W2": "bfloat16"}
+    assert dtypes("ssd_chunk_scan") == {
+        "X": "bfloat16", "Dt": "bfloat16", "B": "bfloat16", "C": "bfloat16",
+        "A": "float32", "D": "float32", "DtBias": "float32"}
+    assert dtypes("rms_norm")["X"] == "float32"
+    types = [o.type for o in block.ops]
+    for grad in ("ssd_chunk_scan_grad", "moe_topk_grad",
+                 "flash_attention_grad", "rms_norm_grad",
+                 "causal_conv1d_grad"):
+        assert grad in types
+
+
+@pytest.mark.parametrize("recompute", [False, True])
+def test_tiny_model_follows_the_plain_reference_for_three_steps(recompute):
+    """``models.hybrid_ssm_moe`` through ``fluid.Executor`` with bf16 AMP and
+    Adam, with and without recomputation, against the float32 reference's
+    three steps: the comparison that decides a cell's ``correct``."""
+    from benchmarks.lib import check
+    from benchmarks.lib.reference_train import follow, identity
+
+    cfg, traffic, model, reference = tiny()
+    loads = []
+    built = model.build_static(cfg, dict(traffic, recompute=recompute), loads)
+    types = [o.type for o in built["main"].global_block().ops]
+    assert ("recompute_barrier" in types) == recompute
+    key = jax.random.key(3)
+    start = reference.init_params(key, cfg)
+    kept = {k: np.asarray(v) for k, v in start.items()}
+    batches = [reference.make_batch(k, cfg, traffic) for k in keys(3, 4)]
+    scope, exe = fluid.Scope(), fluid.Executor(fluid.CPUPlace())
+    losses = []
+    with fluid.scope_guard(scope):
+        exe.run(built["startup"])
+
+        def array(name):
+            return jnp.asarray(scope.find_var(name).get_tensor().array)
+
+        for leaf, name in built["leaves"].items():
+            assert array(name).shape == kept[leaf].shape, leaf
+            scope.find_var(name).get_tensor().set(start[leaf])
+        for i, batch in enumerate(batches):
+            feed = {k: np.asarray(v) for k, v in model.to_feed(batch).items()}
+            out = exe.run(built["main"], feed=feed,
+                          fetch_list=[built["loss"]] + loads)
+            losses.append(float(np.mean(out[0])))
+            if i == 0:
+                (load,) = out[1:]
+                grads = {leaf: built["moment_scale"] * float(jnp.linalg.norm(
+                    array(built["moment"] % name)))
+                    for leaf, name in built["leaves"].items()}
+        delta = {leaf: float(jnp.linalg.norm(array(name) - kept[leaf]))
+                 for leaf, name in built["leaves"].items()}
+    # 2 x 24 tokens x 3 slots x 4 of 16 experts = 36 expected
+    assert 10 < int(load[:4].sum()) < 80 and int(load[4]) == 0
+    ref = follow(lambda p, b, cast: reference.loss(p, b, cfg, cast),
+                 cfg["optimizer"], lambda k: reference.init_params(k, cfg),
+                 key, batches, None, identity)
+    rows = check.compare({"losses": losses, "grad_norms": grads,
+                          "delta_norms": delta}, ref, traffic["limits"])
+    assert all(ok for *_, ok, _ in rows), rows

@@ -65,6 +65,26 @@ def cases():
     yield ("flash_short_masked_b64_s256", flash(True, 512, 1024),
            [qkv, qkv, qkv, ((64,), i32)])
 
+    # shared K/V heads (32 over 2) at head dim 128, causal, T = 8192: the
+    # streaming kernels read the shared head through their index maps and
+    # the dK/dV kernel sums over the group
+    q, kv = ((1, 32, 8192, 128), bf16), ((1, 2, 8192, 128), bf16)
+    yield "flash_gqa_causal_s8192_d128", flash(True, 512, 1024), [q, kv, kv]
+
+    # the held experts' grouped products on the megablox kernels, forward
+    # and both backward kernels, at the hybrid cell's shapes: a buffer of
+    # 6,144 sorted slots, 8 experts of 2688 x 1856 and back
+    mo = importlib.import_module("paddle_tpu.ops.moe_ops")
+
+    def grouped(xs, w, sizes):
+        out, vjp = jax.vjp(lambda xs, w: mo._megablox_dot(xs, w, sizes),
+                           xs, w)
+        return (out,) + vjp(out)
+
+    for name, k, n in (("up", 2688, 1856), ("down", 1856, 2688)):
+        yield ("moe_grouped_r6144_" + name, grouped,
+               [((6144, k), bf16), ((8, k, n), bf16), ((8,), i32)])
+
     # fused optimizer over a BERT-base-sized flat buffer: adam streams
     # 4 inputs + 3 outputs of 2048x128 f32, double-buffered ~14 MiB of
     # the 16 MiB scoped VMEM
@@ -105,23 +125,43 @@ def cases():
         yield name, conv, [((8, 28, 28, 128), bf16), (w_shape, bf16)]
 
 
-def bert_step(topo_sharding, layers=2, batch=4, seq=512):
-    """A BERT-base-wide training step of ``layers`` layers (bf16 AMP,
-    Adam) at T = ``seq``, compiled for the described chip. Returns the
-    optimized HLO. The program asks ``compute_platform()`` where it
-    runs and would take the dense path on this CPU host: the test
-    steers that one question, as the chip would answer it."""
+def compile_step(main, startup, loss, feeds, topo_sharding):
+    """The compiled training step of ``main`` for the described chip, fed
+    zeros of ``feeds`` {name: (shape, dtype)}. The program asks
+    ``compute_platform()`` where it runs and would take the dense path on
+    this CPU host: that one question is answered as the chip would."""
     from unittest import mock
 
     import numpy as np
 
     import paddle_tpu as fluid
-    from paddle_tpu import models
-    from paddle_tpu.contrib import mixed_precision as mp
     from paddle_tpu.core.compiler_engine import _stage_compiled_call
     from paddle_tpu.core.tensor import LoDTensor
 
     fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        feed = {name: LoDTensor(jnp.asarray(np.zeros(shape, dtype)))
+                for name, (shape, dtype) in feeds.items()}
+        fn, args, _ = _stage_compiled_call(
+            exe._core, jax.devices()[0], main, scope, feed, [loss])
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=topo_sharding), args)
+    with mock.patch.object(fa, "compute_platform", lambda: "tpu"):
+        return fn.lower(*shapes).compile()
+
+
+def bert_step(topo_sharding, layers=2, batch=4, seq=512):
+    """A BERT-base-wide training step of ``layers`` layers (bf16 AMP,
+    Adam) at T = ``seq``, compiled for the described chip. Returns the
+    optimized HLO."""
+    import paddle_tpu as fluid
+    from paddle_tpu import models
+    from paddle_tpu.contrib import mixed_precision as mp
+
     b, t, m, v = batch, seq, 8, 512
     main, startup = fluid.Program(), fluid.Program()
     with fluid.unique_name.guard(), fluid.program_guard(main, startup):
@@ -136,22 +176,44 @@ def bert_step(topo_sharding, layers=2, batch=4, seq=512):
             fluid.layers.reshape(logits, [b * m, v]),
             fluid.layers.reshape(labels, [b * m, 1])))
         mp.decorate(fluid.optimizer.AdamOptimizer(1e-4)).minimize(loss)
-    scope = fluid.Scope()
-    with fluid.scope_guard(scope):
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
-        feed = {name: LoDTensor(jnp.asarray(np.zeros(shape, "int64")))
-                for name, shape in (("src", (b, t)), ("pos", (b, t)),
-                                    ("mpos", (b, m)),
-                                    ("labels", (b, m, 1)))}
-        fn, args, _ = _stage_compiled_call(
-            exe._core, jax.devices()[0], main, scope, feed, [loss])
-    shapes = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                       sharding=topo_sharding), args)
-    with mock.patch.object(fa, "compute_platform", lambda: "tpu"):
-        lowered = fn.lower(*shapes)
-    return lowered.compile().as_text()
+    feeds = {"src": ((b, t), "int64"), "pos": ((b, t), "int64"),
+             "mpos": ((b, m), "int64"), "labels": ((b, m, 1), "int64")}
+    return compile_step(main, startup, loss, feeds, topo_sharding).as_text()
+
+
+def hybrid_step_temporaries(topo_sharding, recompute, seq=2048):
+    """``temp_size_in_bytes`` of a six-layer hybrid state-space / MoE
+    training step (bf16 AMP, Adam) compiled for the described chip, with
+    or without ``RecomputeOptimizer`` over the layers' inputs."""
+    import paddle_tpu as fluid
+    from paddle_tpu import models
+    from paddle_tpu.contrib import mixed_precision as mp
+
+    t, v = seq, 1024
+    main, startup = fluid.Program(), fluid.Program()
+    checkpoints = []
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        src = fluid.data(name="src", shape=[1, t], dtype="int64")
+        labels = fluid.data(name="labels", shape=[t, 1], dtype="int64")
+        logits = models.hybrid_ssm_moe(
+            src, "MEM*EM", v, 512, mamba_heads=16, mamba_head_dim=64,
+            n_groups=2, state_size=64, num_experts=16, top_k=2,
+            expert_dim=256, shared_dim=512, held=[0, 4], num_heads=8,
+            num_kv_heads=2, head_dim=128, checkpoints=checkpoints)
+        loss = fluid.layers.mean(fluid.layers.softmax_with_cross_entropy(
+            fluid.layers.reshape(logits, [t, v]), labels))
+        optimizer = fluid.optimizer.AdamOptimizer(1e-4)
+        if recompute:
+            optimizer = fluid.optimizer.RecomputeOptimizer(optimizer)
+            optimizer._set_checkpoints(checkpoints)
+        mp.decorate(optimizer).minimize(loss)
+    compiled = compile_step(
+        main, startup, loss,
+        {"src": ((1, t), "int64"), "labels": ((t, 1), "int64")},
+        topo_sharding)
+    hlo = compiled.as_text()
+    return (compiled.memory_analysis().temp_size_in_bytes,
+            hlo.count("tpu_custom_call"), hlo.count("ragged-dot"))
 
 
 def bert_step_report(hlo, seq=512) -> str:
@@ -204,6 +266,16 @@ def compile_all_for_v5e() -> int:
         failed += 1
         print("FAIL bert_step %s: %s" % (type(e).__name__,
                                          str(e)[:800].replace("\n", " | ")))
+    try:
+        plain = hybrid_step_temporaries(sharding, False)
+        saved = hybrid_step_temporaries(sharding, True)
+        print("HYBRID_STEP temporaries plain=%d checkpoints=%d "
+              "mosaic_calls=%d ragged_dots=%d"
+              % (plain[0], saved[0], plain[1], plain[2]))
+    except Exception as e:  # noqa: BLE001 — reported like a case
+        failed += 1
+        print("FAIL hybrid_step %s: %s" % (type(e).__name__,
+                                           str(e)[:800].replace("\n", " | ")))
     return 1 if failed else 0
 
 
