@@ -63,6 +63,7 @@ FULL = {
     "flash": dict(b=1, h=4, s=4096, d=64),
     "masked": dict(b=64, h=8, s=256, d=64),
     "short": dict(b=32, h=12, s=512, d=64),
+    "short_dp": dict(b=128, h=12, s=128, d=64),
     "opt_elems": 2 * 1024 * 1024,
     "conv": dict(batch=8, hw=28, cin=128, cout_1x1=512, cout_3x3=128),
     "decode": dict(streams=5, max_tokens=12),
@@ -75,7 +76,8 @@ REHEARSAL = {
                 seq=256, steps=2),
     "flash": dict(b=1, h=2, s=256, d=16),
     "masked": dict(b=2, h=2, s=128, d=16),
-    "short": dict(b=2, h=2, s=128, d=16),
+    "short": dict(b=2, h=2, s=128, d=64),
+    "short_dp": dict(b=4, h=4, s=128, d=32),
     "opt_elems": 4096,
     "conv": dict(batch=1, hw=8, cin=128, cout_1x1=128, cout_3x3=128),
     "decode": dict(streams=3, max_tokens=6),
@@ -488,14 +490,19 @@ def phase_kernels(sizes, dev_rec, platform, xla):
 
     rng = np.random.RandomState(7)
 
-    def flash_case(name, c, causal, lengths, tol, path, block=None):
+    def flash_case(name, c, causal, lengths, tol, path, block=None,
+                   tokens=False):
         """``path``: the kernels the shapes must select ("stream": fwd,
         dQ, dK+dV; "short": fwd and one backward kernel). ``block``
         smaller than the sequence keeps a short sequence on the
-        streaming kernels."""
+        streaming kernels. ``tokens``: operands [b, s, h * d] as the
+        projections leave them, for the token-major short kernels."""
         shape = (c["b"], c["h"], c["s"], c["d"])
         blocks = {} if block is None else {"block_q": block,
                                            "block_k": block}
+        if tokens:
+            shape = (c["b"], c["s"], c["h"] * c["d"])
+            blocks["num_heads"] = c["h"]
         q, k, v, w = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
                       for _ in range(4))
         scale = float(c["d"]) ** -0.5
@@ -514,7 +521,11 @@ def phase_kernels(sizes, dev_rec, platform, xla):
 
         def reference(q, k, v):
             def f(q, k, v):
+                if tokens:
+                    q, k, v = (fa.split_heads(x, c["h"]) for x in (q, k, v))
                 o = fa._dense_attention(q, k, v, causal, scale, lens)
+                if tokens:
+                    o = fa.merge_heads(o)
                 return jnp.sum(o.astype(jnp.float32)
                                * w.astype(jnp.float32)), o
             with jax.default_matmul_precision("highest"):
@@ -524,6 +535,9 @@ def phase_kernels(sizes, dev_rec, platform, xla):
 
         took = fa.attention_path(q, k, force_pallas=True, **blocks)
         assert took == path, (name, took)
+        if tokens:   # the token-major kernels' blocks, not a split and merge
+            planned = fa._plan(q, k, 512, 1024, c["h"])[0]
+            assert isinstance(planned, tuple), (name, planned)
         n = check_mosaic(name, kernel, (q, k, v),
                          3 if path == "stream" else 2)
         got = jax.jit(kernel)(q, k, v)
@@ -549,6 +563,16 @@ def phase_kernels(sizes, dev_rec, platform, xla):
     # and transformer_wmt's (causal + lengths) as the model routes it
     flash_case("flash_short", sizes["short"], False, None, 2e-2, "short")
     flash_case("flash_short_masked", m, True, lengths, 2e-2, "short")
+    # the same, token-major, as the models route them since PR 29: both
+    # BERT cells' shapes (T = 512; a dp4 replica's T = 128) and the
+    # encoder-decoder's with both masks
+    flash_case("flash_tokens", sizes["short"], False, None, 2e-2, "short",
+               tokens=True)
+    flash_case("flash_tokens_dp", sizes["short_dp"], False, None, 2e-2,
+               "short", tokens=True)
+    if m["h"] * m["d"] % 128 == 0:   # the rehearsal's toy heads are 32 wide
+        flash_case("flash_tokens_masked", m, True, lengths, 2e-2, "short",
+                   tokens=True)
 
     # -- fused optimizer over a flat buffer vs _update_math ----------------
     n_el = sizes["opt_elems"]

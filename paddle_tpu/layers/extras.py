@@ -957,10 +957,14 @@ __all__ += ["sequence_slice", "sequence_unpad", "im2sequence",
             "tensor_array_to_tensor", "adaptive_pool3d"]
 
 
-def flash_attention(q, k, v, causal=False, scale=0.0, lengths=None):
+def flash_attention(q, k, v, causal=False, scale=0.0, lengths=None,
+                    num_heads=0):
     """Fused attention over q [B, H, S, D] and k, v [B, H_kv, S, D], H_kv
     dividing H (the multihead hot path; fewer K/V heads are shared —
-    reference fused/multihead_matmul_op.cu). Lowers to the Pallas flash
+    reference fused/multihead_matmul_op.cu), or over token-major q, k, v
+    [B, T, H*hd] with ``num_heads``, as the projections leave them: the
+    layout is the operands' rank, and the context comes back in it (no
+    head split or merge in the program). Lowers to the Pallas flash
     kernels on TPU (which ones is the op's choice, from the shapes);
     ``apply_sequence_parallel`` rewrites it to ring attention over an
     'sp' mesh axis for long-context training. ``lengths`` ([B] int)
@@ -979,7 +983,8 @@ def flash_attention(q, k, v, causal=False, scale=0.0, lengths=None):
     helper.append_op(
         "flash_attention", inputs=ins,
         outputs={"Out": [out], "LSE": [lse]},
-        attrs={"causal": bool(causal), "scale": float(scale)},
+        attrs={"causal": bool(causal), "scale": float(scale),
+               "num_heads": int(num_heads)},
         infer_shape=False)
     if not framework.in_dygraph_mode():
         out.shape = tuple(q.shape)

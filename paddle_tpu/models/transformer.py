@@ -54,7 +54,12 @@ def multi_head_attention(q_in, num_heads, d_model, dropout=0.0,
     kernels up to T = 1024, streaming ones beyond, dense math off the
     TPU; ops/pallas/flash_attention.py), so no T x T matrix reaches HBM
     in either direction. What each path measures on the v5e: PERF.md
-    section 6, "PR 25". True/False force / forbid."""
+    section 6, "PR 25" and "PR 29". True/False force / forbid.
+
+    On that branch q, k, v go to the op as the projections leave them,
+    [B, T, H*hd], and the context comes back so: the program holds no
+    head split or merge (the op's kernels take a head's lanes inside).
+    The dense branch splits and merges heads as before."""
     B, T, D = q_in.shape
     kv = q_in if kv_in is None else kv_in
     T_kv = kv.shape[1]
@@ -67,8 +72,6 @@ def multi_head_attention(q_in, num_heads, d_model, dropout=0.0,
         x = layers.reshape(x, [B, t, num_heads, head])
         return layers.transpose(x, [0, 2, 1, 3])  # [B, H, t, head]
 
-    q = split_heads(q, T)
-    k, v = split_heads(k, T_kv), split_heads(v, T_kv)
     if use_flash is None:
         # self-attention only: the kernel grid assumes T_q == T_kv
         use_flash = attn_bias is None and kv_in is None and (
@@ -94,26 +97,29 @@ def multi_head_attention(q_in, num_heads, d_model, dropout=0.0,
         # padding mask.
         ctx = layers.flash_attention(q, k, v, causal=causal,
                                      scale=float(head) ** -0.5,
-                                     lengths=kv_lengths)
-    else:
-        q = layers.scale(q, scale=float(head) ** -0.5)
-        scores = layers.matmul(q, k, transpose_y=True)  # [B, H, T, T]
-        if attn_bias is not None:
-            scores = layers.elementwise_add(scores, attn_bias)
-        if kv_lengths is not None:
-            # dense fallback of the kernel-side padding mask
-            scores = layers.elementwise_add(
-                scores, _padding_bias(kv_lengths, T_kv, B,
-                                      scores.dtype))
-        if causal:
-            scores = layers.elementwise_add(
-                scores, _causal_bias(T, dtype=scores.dtype))
-        weights = layers.softmax(scores)
-        if dropout:
-            weights = layers.dropout(
-                weights, dropout_prob=dropout, is_test=is_test,
-                dropout_implementation="upscale_in_train")
-        ctx = layers.matmul(weights, v)  # [B, H, T, head]
+                                     lengths=kv_lengths,
+                                     num_heads=num_heads)
+        return _dense(ctx, d_model)
+    q = split_heads(q, T)
+    k, v = split_heads(k, T_kv), split_heads(v, T_kv)
+    q = layers.scale(q, scale=float(head) ** -0.5)
+    scores = layers.matmul(q, k, transpose_y=True)  # [B, H, T, T]
+    if attn_bias is not None:
+        scores = layers.elementwise_add(scores, attn_bias)
+    if kv_lengths is not None:
+        # dense fallback of the kernel-side padding mask
+        scores = layers.elementwise_add(
+            scores, _padding_bias(kv_lengths, T_kv, B,
+                                  scores.dtype))
+    if causal:
+        scores = layers.elementwise_add(
+            scores, _causal_bias(T, dtype=scores.dtype))
+    weights = layers.softmax(scores)
+    if dropout:
+        weights = layers.dropout(
+            weights, dropout_prob=dropout, is_test=is_test,
+            dropout_implementation="upscale_in_train")
+    ctx = layers.matmul(weights, v)  # [B, H, T, head]
     ctx = layers.transpose(ctx, [0, 2, 1, 3])
     ctx = layers.reshape(ctx, [B, T, d_model])
     return _dense(ctx, d_model)

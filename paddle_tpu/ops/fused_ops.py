@@ -203,15 +203,18 @@ def _flash_attention_grad(ins, attrs):
     q, k, v = ins["Q"], ins["K"], ins["V"]
     lengths = ins.get("Lengths")
     causal = bool(attrs.get("causal"))
-    scale = attrs.get("scale", 0.0) or float(q.shape[-1]) ** -0.5
+    num_heads = int(attrs.get("num_heads", 0))
+    scale = attrs.get("scale", 0.0) or None
     g = ins["Out@GRAD"].astype(q.dtype)
     if ins.get("LSE") is not None:
         dq, dk, dv = flash_attention_bwd(
-            q, k, v, lengths, ins["Out"], ins["LSE"], g, causal, scale)
+            q, k, v, lengths, ins["Out"], ins["LSE"], g, causal, scale,
+            num_heads=num_heads)
     else:
         _, vjp = jax.vjp(
             lambda q, k, v: flash_attention(
-                q, k, v, causal=causal, scale=scale, lengths=lengths),
+                q, k, v, causal=causal, scale=scale, lengths=lengths,
+                num_heads=num_heads),
             q, k, v)
         dq, dk, dv = vjp(g)
     return {"Q@GRAD": dq, "K@GRAD": dk, "V@GRAD": dv}
@@ -227,7 +230,7 @@ register_op(
     outputs=[Out("Q@GRAD", dispensable=True),
              Out("K@GRAD", dispensable=True),
              Out("V@GRAD", dispensable=True)],
-    attrs={"causal": False, "scale": 0.0},
+    attrs={"causal": False, "scale": 0.0, "num_heads": 0},
     grad=None,
 )(_flash_attention_grad)
 
@@ -237,27 +240,38 @@ register_op(
     inputs=[In("Q"), In("K"), In("V"),
             In("Lengths", dispensable=True, no_grad=True)],
     outputs=[Out("Out"), Out("LSE", dispensable=True, no_grad=True)],
-    attrs={"causal": False, "scale": 0.0},
+    attrs={"causal": False, "scale": 0.0, "num_heads": 0},
 )
 def _flash_attention(ins, attrs):
-    """Attention over Q [B, H, S, D] and K, V [B, H_kv, S, D] (H_kv
-    dividing H: shared K/V heads) with no S x S matrix in HBM: Pallas
+    """Attention with no S x S matrix in HBM. The layout is the
+    operands': rank 4 is Q [B, H, S, D] and K, V [B, H_kv, S, D] (H_kv
+    dividing H: shared K/V heads); rank 3 is token-major Q, K, V
+    [B, T, H*hd] with the attribute ``num_heads``, as the projections
+    leave them, and ``Out`` comes back in the operands' layout. Pallas
     kernels where the computation runs on a TPU (which ones is decided
-    from the shapes, see ops/pallas/flash_attention.py), the same dense
-    math elsewhere. ``Lengths`` [B] int: per-row valid-KV count — the
-    kernel-side padding mask (reference's additive src_slf_attn_bias).
-    ``LSE`` is the log-sum-exp residual ``flash_attention_grad`` reads
-    (None where the dense math ran). Each trace of the op counts the
-    path it took: ``kernels.flash_attention{path=short|stream|dense}``."""
+    from the shapes, see ops/pallas/flash_attention.py: token-major
+    operands the short kernels do not take are split into heads, and
+    the context merged, inside the op), the same dense math elsewhere.
+    ``Lengths`` [B] int: per-row valid-KV count — the kernel-side
+    padding mask (reference's additive src_slf_attn_bias). ``LSE`` is
+    the log-sum-exp residual ``flash_attention_grad`` reads (None where
+    the dense math ran). Each trace of the op counts the path it took
+    and the layout it was given:
+    ``kernels.flash_attention{path=short|stream|dense}``,
+    ``kernels.flash_attention_layout{layout=tokens|heads}``."""
     from .. import observability as _obs
     from .pallas.flash_attention import (attention_path,
                                          flash_attention_with_lse)
 
     q, k, v = ins["Q"], ins["K"], ins["V"]
+    num_heads = int(attrs.get("num_heads", 0))
     if _obs.enabled():
-        _obs.inc("kernels.flash_attention", path=attention_path(q, k))
+        _obs.inc("kernels.flash_attention",
+                 path=attention_path(q, k, num_heads=num_heads))
+        _obs.inc("kernels.flash_attention_layout",
+                 layout="tokens" if q.ndim == 3 else "heads")
     scale = attrs.get("scale", 0.0) or None
     out, lse = flash_attention_with_lse(
         q, k, v, causal=bool(attrs.get("causal")), scale=scale,
-        lengths=ins.get("Lengths"))
+        lengths=ins.get("Lengths"), num_heads=num_heads)
     return {"Out": out, "LSE": lse}

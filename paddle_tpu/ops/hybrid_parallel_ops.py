@@ -107,17 +107,26 @@ def _sharded_lookup_grad_exact(w, ids, axis):
     inputs=[In("Q"), In("K"), In("V"),
             In("Lengths", dispensable=True, no_grad=True)],
     outputs=[Out("Out")],
-    attrs={"shard_axis": "sp", "causal": False, "scale": 0.0},
+    attrs={"shard_axis": "sp", "causal": False, "scale": 0.0,
+           "num_heads": 0},
 )
 def _c_ring_attention(ins, attrs):
-    """Sequence-parallel attention over [B, H, S_local, D] (rewrite
-    target of flash_attention, apply_sequence_parallel): K/V shards
+    """Sequence-parallel attention over [B, H, S_local, D], or over
+    token-major [B, S_local, H*hd] with ``num_heads`` (split into heads
+    here, the context merged back), as the ``flash_attention`` op it
+    replaces took them (apply_sequence_parallel): K/V shards
     rotate around the ``shard_axis`` ring via ppermute with an exact
     streaming-softmax accumulator (parallel/ring_attention.py).
     ``Lengths`` [B] carries the GLOBAL per-example padding mask
     (replicated across the ring). Dense fallback is exact
     full-sequence attention."""
+    from .pallas.flash_attention import merge_heads, split_heads
+
     q, k, v = ins["Q"], ins["K"], ins["V"]
+    tokens = q.ndim == 3
+    if tokens:
+        q, k, v = (split_heads(x, int(attrs["num_heads"]))
+                   for x in (q, k, v))
     lengths = ins.get("Lengths")
     causal = bool(attrs.get("causal"))
     scale = attrs.get("scale", 0.0) or None
@@ -132,7 +141,7 @@ def _c_ring_attention(ins, attrs):
 
         out = reference_attention(q, k, v, causal=causal, scale=scale,
                                   lengths=lengths)
-    return {"Out": out}
+    return {"Out": merge_heads(out) if tokens else out}
 
 
 @register_op(

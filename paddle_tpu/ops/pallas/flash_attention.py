@@ -33,6 +33,17 @@ from one S/P/dP (five matmuls a head instead of seven). Both kernels
 work on the transposed tile [Tk, Tq], which keeps LSE and delta
 lane-dense rows and transposes only [S, D]-sized operands.
 
+**Token-major** (the short path for operands as the projections leave
+them, ``[B, T, H*hd]``): the same per-head math on blocks of ``rows``
+batch rows x T x ``lanes`` of the ``H*hd`` axis (``lanes`` a multiple of
+128 that holds whole heads; ``_tokens_blocks``), each head's ``hd`` lanes
+taken inside the kernel, the context written back in the same layout and
+``delta = rowsum(dO * O)`` computed inside the backward kernel: no head
+split or merge, and no layout copy, stands between the projections'
+matmuls and the kernels. Token-major operands the short path does not
+take are split into heads and merged again around the
+head-major kernels, inside ``flash_attention``.
+
 **Shared K/V heads** (grouped-query attention): K and V may have fewer
 heads than Q, ``H_kv`` dividing ``H``. The streaming kernels' index maps
 read the shared head (row ``b // group`` of the [B * H_kv] rows), nothing
@@ -212,6 +223,14 @@ def _flash_kernel(*refs, scale, causal, block_q, block_k, nk, has_len):
         l_safe = jnp.maximum(l_ref[:], 1e-30)
         o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
         lse_ref[0] = m_ref[:] + jnp.log(l_safe)          # [bq, 1]
+
+
+def _no_tangent(lengths):
+    """The cotangent of the int ``lengths`` argument (None or [B])."""
+    from jax.dtypes import float0
+
+    return (None if lengths is None
+            else np.zeros(lengths.shape, dtype=float0))
 
 
 def _len_bh(lengths, B, H):
@@ -532,34 +551,66 @@ def _short_heads(BH, T, D, itemsize):
     return 0
 
 
-def _short_mask(st, causal, len_val):
-    """Both masks on a whole-sequence TRANSPOSED score tile [Tk, Tq]."""
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
-    if causal:
-        q_pos = jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
-        st = jnp.where(q_pos >= k_pos, st, NEG_INF)
-    if len_val is not None:
-        st = jnp.where(k_pos < len_val, st, NEG_INF)
-    return st
-
-
-def _short_scores(q_ref, k_ref, len_ref, g, base, scale, causal):
+def _short_scores(q, k, len_val, scale, causal):
     """One head's masked scores, transposed: K (scale Q)^T, float32
-    [Tk, Tq], with (scale Q) and K. The softmax scale rides on the
-    [T, D] operand, in its dtype, not on the [T, T] tile (for bf16 and a
+    [Tk, Tq], with (scale Q). The softmax scale rides on the [T, D]
+    operand, in its dtype, not on the [T, T] tile (for bf16 and a
     power-of-two scale, head dim 64 among them, the product is exact;
-    the dense lowering scales q the same way). Both short kernels work
+    the dense lowering scales q the same way). All short kernels work
     in this orientation: the softmax statistics, the LSE and delta are
     lane-dense rows [1, Tq], reductions run down the sublanes, and only
     [T, D]-sized operands are ever transposed."""
-    q, k = q_ref[g], k_ref[g]
     q = (q.astype(jnp.float32) * scale).astype(q.dtype)
     st = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    len_val = None if len_ref is None else len_ref[base + g, 0]
     if causal or len_val is not None:
-        st = _short_mask(st, causal, len_val)
-    return st, q, k, len_val
+        k_pos = jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
+        if causal:
+            q_pos = jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
+            st = jnp.where(q_pos >= k_pos, st, NEG_INF)
+        if len_val is not None:
+            st = jnp.where(k_pos < len_val, st, NEG_INF)
+    return st, q
+
+
+def _short_fwd_math(q, k, v, len_val, scale, causal):
+    """One head's context, transposed, and its LSE from q, k, v [T, D]:
+    (O^T float32 [D, Tq], LSE [1, Tq])."""
+    st, _ = _short_scores(q, k, len_val, scale, causal)
+    m = jnp.max(st, axis=0, keepdims=True)                 # [1, Tq]
+    pt = jnp.exp(st - m)                                   # plain softmax
+    l = jnp.sum(pt, axis=0, keepdims=True)                 # >= 1
+    ot = jax.lax.dot_general(                              # V^T P^T
+        v, pt.astype(v.dtype), (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32) / l            # [D, Tq]
+    lse = m + jnp.log(l)
+    if len_val is not None:
+        # a row with no visible key puts out zeros and takes no
+        # gradient (P recomputed from this LSE is 0), as in the
+        # streaming kernels
+        ot = jnp.where(len_val > 0, ot, 0.0)
+        lse = jnp.where(len_val > 0, lse, -NEG_INF)
+    return ot, lse
+
+
+def _short_bwd_math(q, k, v, do, lse, delta, len_val, scale, causal):
+    """One head's gradients from ONE S/P/dP: five matmuls, where the
+    streaming dQ and dK+dV kernels make seven. Returns float32
+    (dQ^T / scale [D, Tq], dK, dV [Tk, D])."""
+    f32 = jnp.float32
+    st, q = _short_scores(q, k, len_val, scale, causal)
+    pt = jnp.exp(st - lse)                                 # [Tk, Tq]
+    dv = jax.lax.dot_general(                              # P^T dO
+        pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+        preferred_element_type=f32)
+    dpt = jax.lax.dot_general(                             # V dO^T
+        v, do, (((1,), (1,)), ((), ())), preferred_element_type=f32)
+    dst = (pt * (dpt - delta)).astype(q.dtype)
+    dk = jax.lax.dot_general(                              # dS^T (scale Q)
+        dst, q, (((1,), (0,)), ((), ())), preferred_element_type=f32)
+    dqt = jax.lax.dot_general(                             # K^T dS^T
+        k, dst, (((0,), (0,)), ((), ())), preferred_element_type=f32)
+    return dqt, dk, dv
 
 
 def _short_fwd_kernel(*refs, scale, causal, has_len, heads):
@@ -571,29 +622,15 @@ def _short_fwd_kernel(*refs, scale, causal, has_len, heads):
         (q_ref, k_ref, v_ref, o_ref, lse_ref), len_ref = refs, None
     base = pl.program_id(0) * heads
     for g in range(heads):
-        st, _, _, len_val = _short_scores(q_ref, k_ref, len_ref, g, base,
-                                          scale, causal)
-        v = v_ref[g]
-        m = jnp.max(st, axis=0, keepdims=True)             # [1, Tq]
-        pt = jnp.exp(st - m)                               # plain softmax
-        l = jnp.sum(pt, axis=0, keepdims=True)             # >= 1
-        ot = jax.lax.dot_general(                          # V^T P^T
-            v, pt.astype(v.dtype), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) / l        # [D, Tq]
-        lse = m + jnp.log(l)
-        if has_len:
-            # a row with no visible key puts out zeros and takes no
-            # gradient (P recomputed from this LSE is 0), as in the
-            # streaming kernels
-            ot = jnp.where(len_val > 0, ot, 0.0)
-            lse = jnp.where(len_val > 0, lse, -NEG_INF)
+        len_val = None if len_ref is None else len_ref[base + g, 0]
+        ot, lse = _short_fwd_math(q_ref[g], k_ref[g], v_ref[g], len_val,
+                                  scale, causal)
         o_ref[g] = jnp.transpose(ot).astype(o_ref.dtype)
         lse_ref[g] = lse
 
 
 def _short_bwd_kernel(*refs, scale, causal, has_len, heads):
-    """dQ, dK, dV of ``heads`` heads from ONE S/P/dP each: five matmuls
-    a head, where the streaming dQ and dK+dV kernels make seven."""
+    """dQ, dK, dV of ``heads`` heads, one S/P/dP each."""
     from jax.experimental import pallas as pl
 
     if has_len:
@@ -603,24 +640,13 @@ def _short_bwd_kernel(*refs, scale, causal, has_len, heads):
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dq_ref, dk_ref, dv_ref), len_ref = refs, None
     base = pl.program_id(0) * heads
-    f32 = jnp.float32
     for g in range(heads):
-        st, q, k, _ = _short_scores(q_ref, k_ref, len_ref, g, base, scale,
-                                    causal)
-        v, do = v_ref[g], do_ref[g]
-        pt = jnp.exp(st - lse_ref[g])                      # [Tk, Tq]
-        dv_ref[g] = jax.lax.dot_general(                   # P^T dO
-            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
-            preferred_element_type=f32).astype(dv_ref.dtype)
-        dpt = jax.lax.dot_general(                         # V dO^T
-            v, do, (((1,), (1,)), ((), ())), preferred_element_type=f32)
-        dst = (pt * (dpt - delta_ref[g])).astype(q.dtype)
-        dk_ref[g] = jax.lax.dot_general(                   # dS^T (scale Q)
-            dst, q, (((1,), (0,)), ((), ())),
-            preferred_element_type=f32).astype(dk_ref.dtype)
-        dqt = jax.lax.dot_general(                         # K^T dS^T
-            k, dst, (((0,), (0,)), ((), ())),
-            preferred_element_type=f32)                    # [D, Tq]
+        len_val = None if len_ref is None else len_ref[base + g, 0]
+        dqt, dk, dv = _short_bwd_math(
+            q_ref[g], k_ref[g], v_ref[g], do_ref[g], lse_ref[g],
+            delta_ref[g], len_val, scale, causal)
+        dv_ref[g] = dv.astype(dv_ref.dtype)
+        dk_ref[g] = dk.astype(dk_ref.dtype)
         dq_ref[g] = (scale * jnp.transpose(dqt)).astype(dq_ref.dtype)
 
 
@@ -695,6 +721,215 @@ def _short_backward(q, k, v, out, lse, g, causal, scale, heads,
 
 
 # ---------------------------------------------------------------------------
+# short sequences, token-major: q, k, v and the context as [B, T, H*hd]
+# ---------------------------------------------------------------------------
+
+# Elements of one [rows, T, lanes] block, and the heads a grid step
+# unrolls. Measured on the v5e for one layer's forward + backward
+# (PERF.md section 6, PR 29): the widest block of whole heads wins at
+# every length up to these (T = 512: 768 lanes; T = 1024: 384). More
+# batch rows a step gain a few hundredths of a millisecond a layer at
+# T <= 256 and nothing above, and every process traces and lowers the
+# unrolled per-head code again before a compilation cache can answer
+# (~2 s a head of the four-chip cell's first step), so a step stops at
+# 12 heads.
+_TOKENS_BLOCK = 512 * 768
+_TOKENS_MAX_HEADS = 12
+
+
+def _tokens_vmem_bytes(rows, T, lanes, itemsize):
+    """VMEM of the token-major backward kernel: eight [rows, T, lanes]
+    blocks, double-buffered, one head's float32 [T, T] temporaries and a
+    batch row's float32 results before they are stored."""
+    return (2 * 8 * rows * T * lanes * itemsize + 6 * T * T * 4
+            + 4 * T * lanes * 4)
+
+
+def _tokens_blocks(B, T, H, D, itemsize):
+    """``(rows, lanes)`` of the token-major short kernels for [B, T, H*D]
+    operands, or None where they do not apply (a head dim that neither
+    divides 128 nor is a multiple of it; blocks over the VMEM budget):
+    ``lanes`` is the widest divisor of ``H*D`` that is whole heads and a
+    multiple of 128 within ``_TOKENS_BLOCK``, ``rows`` the most batch rows
+    that still fit it (and ``_TOKENS_MAX_HEADS``)."""
+    if 128 % D and D % 128:
+        return None
+    E = H * D
+    for lanes in range(E, 0, -D):
+        if E % lanes or lanes % 128 or T * lanes > _TOKENS_BLOCK:
+            continue
+        most = min(_TOKENS_BLOCK // (T * lanes),
+                   max(1, _TOKENS_MAX_HEADS // (lanes // D)), B)
+        rows = next(r for r in range(most, 0, -1) if B % r == 0)
+        if _tokens_vmem_bytes(rows, T, lanes, itemsize) \
+                <= SHORT_VMEM_BUDGET:
+            return rows, lanes
+    return None
+
+
+def _heads_of(refs, i, hd):
+    """h -> head ``h``'s lanes of batch row ``i`` of each block in
+    ``refs``. Whole lane tiles are sliced off the references; a head
+    narrower than that is sliced off the row's loaded value (the faster
+    of the two on the v5e at every length)."""
+    if hd % 128 == 0:
+        return lambda h: [ref[i, :, h * hd:(h + 1) * hd] for ref in refs]
+    whole = [ref[i] for ref in refs]
+    return lambda h: [x[:, h * hd:(h + 1) * hd] for x in whole]
+
+
+def _store_row(ref, i, per_head, transposed):
+    """Batch row ``i`` of ``ref`` from its heads' float32 results
+    ([T, hd] each, or [hd, T] where ``transposed``): whole lane tiles are
+    stored one by one, narrower ones concatenated first (and transposed
+    once)."""
+    hd = per_head[0].shape[0 if transposed else 1]
+    if hd % 128:
+        per_head = [jnp.concatenate(per_head, axis=0 if transposed else 1)]
+        hd = ref.shape[2]
+    for h, x in enumerate(per_head):
+        ref[i, :, h * hd:(h + 1) * hd] = (
+            jnp.transpose(x) if transposed else x).astype(ref.dtype)
+
+
+def _tokens_fwd_kernel(*refs, scale, causal, has_len, rows, heads, hd):
+    from jax.experimental import pallas as pl
+
+    if has_len:
+        q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref = refs
+    else:
+        (q_ref, k_ref, v_ref, o_ref, lse_ref), len_ref = refs, None
+    base = pl.program_id(0) * rows
+    for i in range(rows):
+        len_val = None if len_ref is None else len_ref[base + i, 0]
+        take = _heads_of((q_ref, k_ref, v_ref), i, hd)
+        ots = []
+        for h in range(heads):
+            ot, lse_ref[i, h] = _short_fwd_math(*take(h), len_val, scale,
+                                                causal)
+            ots.append(ot)
+        _store_row(o_ref, i, ots, True)
+
+
+def _tokens_bwd_kernel(*refs, scale, causal, has_len, rows, heads, hd):
+    """dQ, dK, dV of ``rows`` x ``heads`` heads; delta = rowsum(dO * O)
+    is made here, as a lane-dense row: the [T, hd] product is transposed
+    and summed down the sublanes."""
+    from jax.experimental import pallas as pl
+
+    if has_len:
+        (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, len_ref,
+         dq_ref, dk_ref, dv_ref) = refs
+    else:
+        (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+         dq_ref, dk_ref, dv_ref), len_ref = refs, None
+    base = pl.program_id(0) * rows
+    for i in range(rows):
+        len_val = None if len_ref is None else len_ref[base + i, 0]
+        take = _heads_of((q_ref, k_ref, v_ref, do_ref, o_ref), i, hd)
+        dqts, dks, dvs = [], [], []
+        for h in range(heads):
+            q, k, v, do, o = take(h)
+            lse = lse_ref[i, h]
+            delta = jnp.sum(
+                jnp.transpose(do.astype(jnp.float32) * o.astype(jnp.float32)),
+                axis=0, keepdims=True)
+            dqt, dk, dv = _short_bwd_math(q, k, v, do, lse, delta, len_val,
+                                          scale, causal)
+            dqts.append(scale * dqt)
+            dks.append(dk)
+            dvs.append(dv)
+        _store_row(dv_ref, i, dvs, False)
+        _store_row(dk_ref, i, dks, False)
+        _store_row(dq_ref, i, dqts, True)
+
+
+def _tokens_call(kernel, name, blocks_in, lse, lengths, causal, scale,
+                 num_heads, blocks, interpret):
+    """One token-major kernel over the grid (B / rows, H*hd / lanes).
+    The forward (``lse`` None) writes the context and the LSE; the
+    backward reads the LSE and writes dQ, dK, dV."""
+    from jax.experimental import pallas as pl
+
+    q = blocks_in[0]
+    B, T, E = q.shape
+    rows, lanes = blocks
+    hd = E // num_heads
+    heads = lanes // hd
+    block = pl.BlockSpec((rows, T, lanes), lambda b, j: (b, 0, j))
+    row = pl.BlockSpec((rows, heads, 1, T), lambda b, j: (b, j, 0, 0))
+    like_q = jax.ShapeDtypeStruct((B, T, E), q.dtype)
+    in_specs, operands = [block] * len(blocks_in), list(blocks_in)
+    if lse is None:
+        out_specs, out_shape = [block, row], [like_q, jax.ShapeDtypeStruct(
+            (B, num_heads, 1, T), jnp.float32)]
+    else:
+        in_specs.append(row)
+        operands.append(lse)
+        out_specs, out_shape = [block] * 3, [like_q] * 3
+    has_len = lengths is not None
+    if has_len:   # the whole [B, 1] array in SMEM, a scalar a batch row
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        operands.append(lengths.astype(jnp.int32).reshape(B, 1))
+    return pl.pallas_call(
+        functools.partial(kernel, scale=scale, causal=causal,
+                          has_len=has_len, rows=rows, heads=heads, hd=hd),
+        grid=(B // rows, E // lanes),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_tokens_vmem_bytes(
+                rows, T, lanes, q.dtype.itemsize) + (8 << 20)),
+        interpret=interpret,
+        name=name,
+    )(*operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_tokens(q, k, v, lengths, causal, scale, num_heads, blocks,
+                  interpret):
+    """(out [B, T, H*hd], lse [B, H, 1, T] float32) of the token-major
+    short kernels; ``blocks`` as ``_tokens_blocks`` gives them."""
+    return tuple(_tokens_call(
+        _tokens_fwd_kernel, "flash_short_fwd", (q, k, v), None, lengths,
+        causal, scale, num_heads, blocks, interpret))
+
+
+def _flash_tokens_fwd(q, k, v, lengths, causal, scale, num_heads, blocks,
+                      interpret):
+    out, lse = _flash_tokens(q, k, v, lengths, causal, scale, num_heads,
+                             blocks, interpret)
+    return (out, lse), (q, k, v, lengths, out, lse)
+
+
+def _flash_tokens_bwd(causal, scale, num_heads, blocks, interpret, res,
+                      cts):
+    q, k, v, lengths, out, lse = res
+    grads = _tokens_call(
+        _tokens_bwd_kernel, "flash_short_bwd",
+        (q, k, v, cts[0].astype(q.dtype), out), lse, lengths, causal,
+        scale, num_heads, blocks, interpret)
+    return tuple(grads) + (_no_tangent(lengths),)
+
+
+_flash_tokens.defvjp(_flash_tokens_fwd, _flash_tokens_bwd)
+
+
+def split_heads(x, num_heads):
+    """[B, T, H*hd] -> [B, H, T, hd]."""
+    B, T, E = x.shape
+    return x.reshape(B, T, num_heads, E // num_heads).transpose(0, 2, 1, 3)
+
+
+def merge_heads(x):
+    """[B, H, T, hd] -> [B, T, H*hd]."""
+    B, H, T, D = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(B, T, H * D)
+
+
+# ---------------------------------------------------------------------------
 # custom VJP plumbing
 # ---------------------------------------------------------------------------
 
@@ -722,13 +957,9 @@ def _flash_fwd(q, k, v, lengths, causal, scale, block_q, block_k, heads,
 
 def _flash_bwd(causal, scale, block_q, block_k, heads, interpret, res,
                cts):
-    from jax.dtypes import float0
-
     q, k, v, lengths, out, lse = res
     g = cts[0]
-    # an int argument has no tangent
-    dlen = (None if lengths is None
-            else np.zeros(lengths.shape, dtype=float0))
+    dlen = _no_tangent(lengths)
     if heads:
         return _short_backward(q, k, v, out, lse, g, causal, scale,
                                heads, interpret, lengths) + (dlen,)
@@ -764,19 +995,31 @@ def _fit_block(S, block):
     return 0
 
 
-def _plan(q, k, block_q, block_k):
-    """Which kernels a call takes, from what it can see: sequence
-    lengths, head dim, dtype and the VMEM the short path would need.
-    Returns (heads, block_q, block_k); heads > 0 is the short path."""
-    B, H, S, D = q.shape
-    if S != k.shape[2]:
+def _plan(q, k, block_q, block_k, num_heads=0):
+    """Which kernels a call takes, from what it can see: the operands'
+    layout (rank 4 is [B, H, S, D]; rank 3 is token-major [B, T, H*hd]
+    with ``num_heads``), sequence lengths, head dim, dtype and the VMEM
+    the short path would need. Returns (short, block_q, block_k):
+    ``short`` is the token-major short kernels' blocks ``(rows,
+    lanes)``, or the heads a grid step of the head-major short kernels
+    takes, or 0 for the streaming kernels. Token-major operands whose
+    plan is no tuple are split into heads by the caller."""
+    tokens = q.ndim == 3
+    if tokens:
+        (B, S, E), H = q.shape, num_heads
+        D, H_kv, S_kv = E // H, H, k.shape[1]
+    else:
+        (B, H, S, D), H_kv, S_kv = q.shape, k.shape[1], k.shape[2]
+    if S != S_kv:
         return 0, block_q, block_k   # rectangular: the dense fallback
-    if S % 128 == 0 and S <= block_k and H == k.shape[1]:
+    if S % 128 == 0 and S <= block_k and H == H_kv:
         # the caller's K block holds the whole sequence (the short kernels
         # take one head count: shared K/V heads stream)
-        heads = _short_heads(B * H, S, D, q.dtype.itemsize)
-        if heads:
-            return heads, block_q, block_k
+        itemsize = q.dtype.itemsize
+        short = _tokens_blocks(B, S, H, D, itemsize) if tokens else None
+        short = short or _short_heads(B * H, S, D, itemsize)
+        if short:
+            return short, block_q, block_k
     # S not a multiple of the tuned blocks (e.g. 2560 % 1024): shrink to
     # the largest aligned divisor rather than silently dropping to the
     # dense O(S^2) path
@@ -790,52 +1033,95 @@ def _plan(q, k, block_q, block_k):
 
 
 def attention_path(q, k, block_q: int = 512, block_k: int = 1024,
-                   force_pallas: bool = False) -> str:
+                   force_pallas: bool = False, num_heads: int = 0) -> str:
     """"short" | "stream" | "dense": what ``flash_attention`` runs for
     these arguments where the computation is placed now."""
     if not (force_pallas or compute_platform() == "tpu"):
         return "dense"
-    return "short" if _plan(q, k, block_q, block_k)[0] else "stream"
+    return ("short" if _plan(q, k, block_q, block_k, num_heads)[0]
+            else "stream")
+
+
+def _check_layout(q, k, num_heads):
+    """Token-major operands name their head count, head-major ones carry
+    it; returns the head dim."""
+    if q.ndim == 3:
+        if num_heads <= 0 or q.shape[2] % num_heads \
+                or k.shape[2] != q.shape[2]:
+            raise ValueError(
+                "flash_attention: token-major q %s, k %s need num_heads "
+                "dividing their last axis (got %d)"
+                % (q.shape, k.shape, num_heads))
+        return q.shape[2] // num_heads
+    if num_heads and num_heads != q.shape[1]:
+        raise ValueError("flash_attention: num_heads %d for q %s"
+                         % (num_heads, q.shape))
+    return q.shape[3]
 
 
 def flash_attention_with_lse(q, k, v, causal: bool = False,
                              scale: Optional[float] = None,
                              block_q: int = 512, block_k: int = 1024,
-                             force_pallas: bool = False, lengths=None):
+                             force_pallas: bool = False, lengths=None,
+                             num_heads: int = 0):
     """``flash_attention`` and the residual its backward needs: (out,
     lse), differentiable (the LSE takes no cotangent). ``lse`` is None
     where the dense math ran (off the TPU)."""
+    head_dim = _check_layout(q, k, num_heads)
     if scale is None:
-        scale = float(q.shape[-1]) ** -0.5
+        scale = float(head_dim) ** -0.5
     on_tpu = compute_platform() == "tpu"
-    if not (on_tpu or force_pallas):
-        return _dense_attention(q, k, v, causal, scale, lengths), None
-    heads, block_q, block_k = _plan(q, k, block_q, block_k)
-    return _flash(q, k, v, lengths, causal, scale, block_q, block_k,
-                  heads, not on_tpu)
+    short = None   # no kernels: the dense math (0 is the streaming kernels)
+    if on_tpu or force_pallas:
+        short, block_q, block_k = _plan(q, k, block_q, block_k, num_heads)
+    if isinstance(short, tuple):
+        return _flash_tokens(q, k, v, lengths, causal, scale, num_heads,
+                             short, not on_tpu)
+    tokens = q.ndim == 3
+    if tokens:   # the other kernels, and the dense math, are head-major
+        q, k, v = (split_heads(x, num_heads) for x in (q, k, v))
+    if short is None:
+        out, lse = _dense_attention(q, k, v, causal, scale, lengths), None
+    else:
+        out, lse = _flash(q, k, v, lengths, causal, scale, block_q,
+                          block_k, short, not on_tpu)
+    return (merge_heads(out) if tokens else out), lse
 
 
 def flash_attention_bwd(q, k, v, lengths, out, lse, g, causal: bool,
-                        scale: float, block_q: int = 512,
-                        block_k: int = 1024):
+                        scale: Optional[float] = None, block_q: int = 512,
+                        block_k: int = 1024, num_heads: int = 0):
     """(dq, dk, dv) from the forward's own ``out`` and ``lse`` (as
     ``flash_attention_with_lse`` returned them for the same arguments):
     the backward kernels alone, no second forward."""
-    heads, block_q, block_k = _plan(q, k, block_q, block_k)
-    return _flash_bwd(causal, scale, block_q, block_k, heads,
-                      compute_platform() != "tpu",
-                      (q, k, v, lengths, out, lse), (g, None))[:3]
+    head_dim = _check_layout(q, k, num_heads)
+    if scale is None:
+        scale = float(head_dim) ** -0.5
+    short, block_q, block_k = _plan(q, k, block_q, block_k, num_heads)
+    interpret = compute_platform() != "tpu"
+    if isinstance(short, tuple):
+        return _flash_tokens_bwd(causal, scale, num_heads, short, interpret,
+                                 (q, k, v, lengths, out, lse), (g, None))[:3]
+    tokens = q.ndim == 3
+    if tokens:
+        q, k, v, out, g = (split_heads(x, num_heads)
+                           for x in (q, k, v, out, g))
+    grads = _flash_bwd(causal, scale, block_q, block_k, short, interpret,
+                       (q, k, v, lengths, out, lse), (g, None))[:3]
+    return tuple(merge_heads(x) for x in grads) if tokens else grads
 
 
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None, block_q: int = 512,
                     block_k: int = 1024, force_pallas: bool = False,
-                    lengths=None):
-    """Flash attention over ``[B, H, S, D]`` tensors — differentiable,
+                    lengths=None, num_heads: int = 0):
+    """Flash attention over ``[B, H, S, D]`` tensors, or token-major
+    ``[B, T, H*hd]`` ones with ``num_heads`` (the layout is read from the
+    operands' rank; the context comes back in it) — differentiable,
     and no S x S matrix in HBM in either direction: the backward
-    recomputes the probabilities from the saved logsumexp. ``k`` and
-    ``v`` may be ``[B, H_kv, S, D]`` with ``H_kv`` dividing ``H`` (shared
-    K/V heads; always the streaming kernels on the TPU).
+    recomputes the probabilities from the saved logsumexp. Head-major
+    ``k`` and ``v`` may be ``[B, H_kv, S, D]`` with ``H_kv`` dividing
+    ``H`` (shared K/V heads; always the streaming kernels on the TPU).
 
     Which kernels run is decided here, from the shapes (``_plan``):
 
@@ -844,11 +1130,15 @@ def flash_attention(q, k, v, causal: bool = False,
       ``SHORT_VMEM_BUDGET``: a head's whole score tile lives in VMEM,
       so the softmax is plain (no running max), several heads share a
       grid step, MXU operands stay in the input dtype, and ONE backward
-      kernel yields dQ, dK and dV from one S/P/dP.
+      kernel yields dQ, dK and dV from one S/P/dP. Token-major operands
+      whose ``H*hd`` axis splits into 128-lane blocks of whole heads are
+      read, and the context written, as they are: no head split or
+      merge in HBM (``_tokens_blocks``).
     - **stream** — longer S: K blocks stream past each Q block with a
       running softmax; the backward is a dQ and a dK+dV kernel.
       ``block_q`` x ``block_k`` = 512 x 1024 by default; blocks shrink
-      to an aligned divisor of S.
+      to an aligned divisor of S. Token-major operands are split into
+      heads, and the context merged, around these kernels.
     - **dense** — where the computation does not run on a TPU (and
       ``force_pallas``, interpret mode, was not asked for): the same
       math in plain XLA.
@@ -861,8 +1151,9 @@ def flash_attention(q, k, v, causal: bool = False,
     QUERY rows produce zeros/garbage exactly like the additive-mask
     formulation; mask the loss, as seq2seq training already does.
 
-    Timings on the v5e: PERF.md section 6, "PR 25"
+    Timings on the v5e: PERF.md section 6, "PR 25" and "PR 29"
     (``tools/attn_bench.py`` repeats them).
     """
     return flash_attention_with_lse(q, k, v, causal, scale, block_q,
-                                    block_k, force_pallas, lengths)[0]
+                                    block_k, force_pallas, lengths,
+                                    num_heads)[0]

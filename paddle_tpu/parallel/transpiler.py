@@ -228,7 +228,9 @@ def apply_sequence_parallel(program, axis: str = "sp", degree: int = 0,
     c_ring_attention over ``axis`` (K/V shards rotate the ring via
     ppermute — long-context training). ``feed_specs`` declares how data
     feeds are laid out over the mesh, e.g. {"x": ("dp", None, "sp")} for
-    [B, H, S, D] with batch over dp and sequence over sp. ``degree``
+    [B, H, S, D] with batch over dp and sequence over sp (token-major
+    ops, [B, T, H*hd] with ``num_heads``, keep their layout: ("dp",
+    "sp")). ``degree``
     (when given) validates the attention sequence length divides evenly
     — a clear error here beats a cryptic shard_map one at run time.
     Call BEFORE minimize()."""
@@ -253,17 +255,20 @@ def apply_sequence_parallel(program, axis: str = "sp", degree: int = 0,
             fs.setdefault(ln, ("dp",))
         if degree:
             q = block._find_var_recursive(op.input("Q")[0])
-            if (q is not None and q.shape is not None and len(q.shape) >= 3
-                    and q.shape[2] and q.shape[2] % degree):
-                raise ValueError(
-                    "sequence parallel: attention seq len %d not "
-                    "divisible by sp degree %d (Q=%r)"
-                    % (q.shape[2], degree, op.input("Q")[0]))
+            if q is not None and q.shape is not None and len(q.shape) >= 3:
+                # [B, T, H*hd] or [B, H, S, D]
+                seq = q.shape[1 if len(q.shape) == 3 else 2]
+                if seq and seq % degree:
+                    raise ValueError(
+                        "sequence parallel: attention seq len %d not "
+                        "divisible by sp degree %d (Q=%r)"
+                        % (seq, degree, op.input("Q")[0]))
         op.type = "c_ring_attention"
         op.outputs.pop("LSE", None)   # the flash kernels' residual
         op.attrs = {"shard_axis": axis,
                     "causal": bool(op.attrs.get("causal")),
-                    "scale": float(op.attrs.get("scale", 0.0))}
+                    "scale": float(op.attrs.get("scale", 0.0)),
+                    "num_heads": int(op.attrs.get("num_heads", 0))}
         n += 1
     if feed_specs:
         fs = getattr(program, "_feed_shard_specs", None)

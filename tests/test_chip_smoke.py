@@ -163,13 +163,18 @@ def test_kernels_compile_for_v5e(v5e_compile):
 
 
 def test_bert_step_for_v5e_holds_one_forward_kernel_a_layer(v5e_compile):
-    """A 2-layer BERT step at T = 512: the program's own forward kernel
-    once a layer (the grad op reads the forward op's Out and LSE; XLA
-    would not merge a re-run), one backward kernel a layer, no Mosaic
-    call the program did not write, no [*, *, 512, 512] buffer."""
-    (line,) = [x for x in v5e_compile.stdout.splitlines()
-               if x.startswith("BERT_STEP ")]
-    assert line == "BERT_STEP fwd=2 bwd=2 other_mosaic=0 tt_buffers=0"
+    """A 2-layer BERT step at T = 512 and a 1-layer one at T = 128 (the two
+    cells' lengths): the program's own forward kernel once a layer (the grad op
+    reads the forward op's Out and LSE; XLA would not merge a re-run), one
+    backward kernel a layer, no Mosaic call the program did not write, no
+    [*, *, T, T] buffer, and no head-major [*, heads, T, 64] buffer: the
+    kernels read q, k, v and write the context as the projections' matmuls
+    leave and take them."""
+    lines = [x for x in v5e_compile.stdout.splitlines()
+             if x.startswith("BERT_STEP ")]
+    assert lines == [
+        "BERT_STEP fwd=%d bwd=%d other_mosaic=0 tt_buffers=0 head_major=0"
+        % (n, n) for n in (2, 1)]
 
 
 def test_recomputation_lowers_the_compiled_steps_temporaries(v5e_compile):
@@ -197,4 +202,4 @@ def test_bert_step_report_counts_what_it_names():
   %fusion.2 = f32[4,12,512,512]{3,2,1,0} fusion(%fusion.1), kind=kLoop
 """
     assert tpu_kernel_cases.bert_step_report(hlo) == \
-        "BERT_STEP fwd=1 bwd=1 other_mosaic=1 tt_buffers=2"
+        "BERT_STEP fwd=1 bwd=1 other_mosaic=1 tt_buffers=2 head_major=2"

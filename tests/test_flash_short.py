@@ -180,7 +180,7 @@ def test_amp_puts_no_float32_cast_before_flash_attention():
         for name in op.input_arg_names:
             assert block.var(name).dtype == "bfloat16", name
             assert producers[name].type != "cast", (
-                "q, k, v come from bf16 transposes, not from a cast")
+                "q, k, v come from the bf16 projections, not from a cast")
         assert block.var(op.output("Out")[0]).dtype == "bfloat16"
     assert n == 12
     assert _count(main, "flash_attention_grad") == [12]
@@ -188,7 +188,9 @@ def test_amp_puts_no_float32_cast_before_flash_attention():
 
 @pytest.mark.parametrize("path", ["short", "stream", "dense"])
 def test_counter_names_the_path_a_trace_took(path):
-    """kernels.flash_attention{path=...}: one count per traced op."""
+    """kernels.flash_attention{path=...}: one count per traced op (and one
+    of the layout it was given: head-major here, token-major in
+    test_flash_tokens.py)."""
     from paddle_tpu import observability as obs
     from paddle_tpu.core.registry import OpInfoMap
 
@@ -216,7 +218,8 @@ def test_counter_names_the_path_a_trace_took(path):
     grown = {name: after[name] - before.get(name, 0) for name in after
              if name.startswith("kernels.flash_attention")
              and after[name] != before.get(name, 0)}
-    assert grown == {key: 1}
+    assert grown == {key: 1,
+                     "kernels.flash_attention_layout{layout=heads}": 1}
     assert (outs["LSE"] is None) == (path == "dense")
     ref = _dense_attention(q, k, v, False, float(D) ** -0.5)
     np.testing.assert_allclose(np.asarray(outs["Out"]), np.asarray(ref),

@@ -60,35 +60,44 @@ def test_program_path_sharded_embedding():
                                rtol=1e-5, atol=1e-6)
 
 
-def test_program_path_ring_attention():
+@pytest.mark.parametrize("layout", ["heads", "tokens"])
+def test_program_path_ring_attention(layout):
     """dp(2) x sp(4): flash_attention rewritten to ring attention over
-    sp; sequence-sharded feeds; loss + updated projection match dense."""
+    sp; sequence-sharded feeds; loss + updated projection match dense.
+    Head-major operands [B, H, S, D], or token-major [B, S, H*D] with
+    ``num_heads`` (the ring op splits them into heads and merges the
+    context)."""
     dp, sp = 2, 4
     B, H, S, D = 2 * dp, 2, 4 * sp, 8
+    tokens = layout == "tokens"
+    shape = [B, S, H * D] if tokens else [B, H, S, D]
+    spec = ("dp", "sp") if tokens else ("dp", None, "sp")
     main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        x = fluid.data(name="x", shape=[B, H, S, D], dtype="float32")
-        tgt = fluid.data(name="tgt", shape=[B, H, S, D], dtype="float32")
-        w = fluid.layers.create_parameter([D, D], "float32", name="w_q")
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        x = fluid.data(name="x", shape=shape, dtype="float32")
+        tgt = fluid.data(name="tgt", shape=shape, dtype="float32")
+        w = fluid.layers.create_parameter([shape[-1]] * 2, "float32",
+                                          name="w_q")
         q = fluid.layers.matmul(x, w)
-        o = fluid.layers.flash_attention(q, x, x, causal=True)
+        o = fluid.layers.flash_attention(q, x, x, causal=True,
+                                         num_heads=H if tokens else 0)
         loss = fluid.layers.reduce_mean(
             fluid.layers.square(fluid.layers.elementwise_sub(o, tgt)))
         strat = DistributedStrategy()
         strat.sequence_parallel = True
         strat.sp_degree = sp
-        strat.feed_shard_specs = {"x": ("dp", None, "sp"),
-                                  "tgt": ("dp", None, "sp")}
+        strat.feed_shard_specs = {"x": spec, "tgt": spec}
         CollectiveOptimizer(
             fluid.optimizer.SGDOptimizer(0.05), strat).minimize(loss)
 
-    assert any(op.type == "c_ring_attention"
-               for op in main.global_block().ops)
+    (ring,) = [op for op in main.global_block().ops
+               if op.type == "c_ring_attention"]
+    assert ring.attrs["num_heads"] == (H if tokens else 0)
     assert main._data_axes == ("dp", "sp")
 
     rng = np.random.RandomState(5)
-    feed = {"x": rng.randn(B, H, S, D).astype("float32"),
-            "tgt": rng.randn(B, H, S, D).astype("float32")}
+    feed = {"x": rng.randn(*shape).astype("float32"),
+            "tgt": rng.randn(*shape).astype("float32")}
     mesh = make_mesh([dp, sp], ["dp", "sp"])
     l_dense, l_mesh, p_dense, p_mesh = _run_dense_then_mesh(
         main, startup, loss, feed, mesh)

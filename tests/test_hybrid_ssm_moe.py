@@ -375,6 +375,52 @@ def test_amp_keeps_the_routers_and_the_decays_slots_float32():
         assert grad in types
 
 
+@pytest.mark.parametrize("recompute,ops,digest", [
+    (False, 182, "13df2a3b715357e21e7ae77021801a7874df048a"),
+    (True, 247, "d0152a133e08d6605a537067a6d3ffaeb179c92f")])
+def test_the_model_keeps_its_head_major_attention(recompute, ops, digest):
+    """``flash_attention`` reads the layout off its operands' rank, and this
+    model's are rank 4 with shared K/V heads: its program is op for op what
+    it was before the token-major layout existed (PR 27's list, by its
+    digest), and each traced attention op counts ``layout=heads``."""
+    import hashlib
+
+    from paddle_tpu import observability as obs
+
+    cfg, traffic, model, reference = tiny()
+    built = model.build_static(cfg, dict(traffic, recompute=recompute))
+    block = built["main"].global_block()
+    types = [o.type for o in block.ops]
+    assert (len(types), hashlib.sha1(
+        " ".join(types).encode()).hexdigest()) == (ops, digest)
+    attention = [o for o in block.ops if o.type == "flash_attention"]
+    assert len(attention) == 1 + recompute
+    for o in attention:
+        q, k = (block._find_var_recursive(o.input(slot)[0]).shape
+                for slot in "QK")
+        assert len(q) == 4 and k[1] < q[1] and o.attrs["num_heads"] == 0
+    feed = {k: np.asarray(v) for k, v in model.to_feed(
+        reference.make_batch(jax.random.key(1), cfg, traffic)).items()}
+    was_on = obs.enabled()
+    obs.enable()
+    try:
+        before = dict(obs.dump()["counters"])
+        scope, exe = fluid.Scope(), fluid.Executor(fluid.CPUPlace())
+        with fluid.scope_guard(scope):
+            exe.run(built["startup"])
+            exe.lower(built["main"], feed=feed, fetch_list=[built["loss"]])
+        after = obs.dump()["counters"]
+    finally:
+        if not was_on:
+            obs.disable()
+    grown = {name: after[name] - before.get(name, 0) for name in after
+             if name.startswith("kernels.flash_attention")
+             and after[name] != before.get(name, 0)}
+    assert grown == {"kernels.flash_attention{path=dense}": 1 + recompute,
+                     "kernels.flash_attention_layout{layout=heads}":
+                     1 + recompute}
+
+
 @pytest.mark.parametrize("recompute", [False, True])
 def test_tiny_model_follows_the_plain_reference_for_three_steps(recompute):
     """``models.hybrid_ssm_moe`` through ``fluid.Executor`` with bf16 AMP and

@@ -65,6 +65,27 @@ def cases():
     yield ("flash_short_masked_b64_s256", flash(True, 512, 1024),
            [qkv, qkv, qkv, ((64,), i32)])
 
+    # the token-major short kernels ([B, T, H*hd] operands, a head's lanes
+    # taken inside): BERT's cell, a replica of the dp4 cell, the longest the
+    # short path takes, and the encoder-decoder's shape with both masks
+    def tokens(causal, num_heads):
+        def both(q, k, v, *lengths):
+            blocks = fa._plan(q, k, 512, 1024, num_heads)[0]
+            out, vjp = jax.vjp(
+                lambda q, k, v: fa._flash_tokens(
+                    q, k, v, *(lengths or (None,)), causal, 0.125,
+                    num_heads, blocks, False)[0], q, k, v)
+            return (out,) + vjp(out)
+        return both
+
+    for name, (b, t) in (("b32_s512", (32, 512)), ("b128_s128", (128, 128)),
+                         ("b8_s1024", (8, 1024))):
+        qkv = ((b, t, 768), bf16)
+        yield "flash_tokens_" + name, tokens(False, 12), [qkv, qkv, qkv]
+    qkv = ((64, 256, 512), bf16)
+    yield ("flash_tokens_masked_b64_s256", tokens(True, 8),
+           [qkv, qkv, qkv, ((64,), i32)])
+
     # shared K/V heads (32 over 2) at head dim 128, causal, T = 8192: the
     # streaming kernels read the shared head through their index maps and
     # the dK/dV kernel sums over the group
@@ -217,10 +238,12 @@ def hybrid_step_temporaries(topo_sharding, recompute, seq=2048):
 
 
 def bert_step_report(hlo, seq=512) -> str:
-    """``BERT_STEP fwd=<n> bwd=<n> other_mosaic=<n> tt_buffers=<n>``:
-    the program's own forward and backward kernels by their names, the
-    Mosaic calls that are neither (XLA's own attention rewrite made
-    such), and the distinct [*, *, T, T] buffers of any dtype."""
+    """``BERT_STEP fwd=<n> bwd=<n> other_mosaic=<n> tt_buffers=<n>
+    head_major=<n>``: the program's own forward and backward kernels by
+    their names, the Mosaic calls that are neither (XLA's own attention
+    rewrite made such), the distinct [*, *, T, T] buffers of any dtype,
+    and the distinct [*, heads, T, 64] buffers: what a head split or
+    merge, or a layout copy around head-major kernels, would leave."""
     import re
 
     calls = [x for x in hlo.splitlines()
@@ -228,8 +251,10 @@ def bert_step_report(hlo, seq=512) -> str:
     fwd = sum("flash_short_fwd" in x.split("=")[0] for x in calls)
     bwd = sum("flash_short_bwd" in x.split("=")[0] for x in calls)
     tt = set(re.findall(r"\w+\[\d+,\d+,%d,%d\]" % (seq, seq), hlo))
-    return "BERT_STEP fwd=%d bwd=%d other_mosaic=%d tt_buffers=%d" % (
-        fwd, bwd, len(calls) - fwd - bwd, len(tt))
+    split = set(re.findall(r"\w+\[\d+,(?:\d+,)?%d,64\]" % seq, hlo))
+    return ("BERT_STEP fwd=%d bwd=%d other_mosaic=%d tt_buffers=%d "
+            "head_major=%d" % (fwd, bwd, len(calls) - fwd - bwd, len(tt),
+                               len(split)))
 
 
 def compile_all_for_v5e() -> int:
@@ -260,12 +285,15 @@ def compile_all_for_v5e() -> int:
             continue
         print("OK %s mosaic_calls=%d"
               % (name, compiled.as_text().count("tpu_custom_call")))
-    try:
-        print(bert_step_report(bert_step(sharding)))
-    except Exception as e:  # noqa: BLE001 — reported like a case
-        failed += 1
-        print("FAIL bert_step %s: %s" % (type(e).__name__,
-                                         str(e)[:800].replace("\n", " | ")))
+    # both BERT cells' lengths (a replica of the dp4 cell runs T = 128)
+    for layers, batch, seq in ((2, 4, 512), (1, 8, 128)):
+        try:
+            print(bert_step_report(bert_step(sharding, layers, batch, seq),
+                                   seq))
+        except Exception as e:  # noqa: BLE001 — reported like a case
+            failed += 1
+            print("FAIL bert_step T=%d %s: %s" % (
+                seq, type(e).__name__, str(e)[:800].replace("\n", " | ")))
     try:
         plain = hybrid_step_temporaries(sharding, False)
         saved = hybrid_step_temporaries(sharding, True)

@@ -1,14 +1,16 @@
 """Attention's core alone, forward + backward, on the chip: the short-path
-kernels of ``ops/pallas/flash_attention.py`` over the heads a grid step takes,
-against the streaming kernels and XLA's dense lowering on the same inputs.
+kernels of ``ops/pallas/flash_attention.py``, head-major over the heads a grid
+step takes and token-major over their blocks, against the streaming kernels and
+XLA's dense lowering on the same inputs.
 
     chiprun --chips 1 -- python3 tools/attn_bench.py            # times, on the chip
     JAX_PLATFORMS=cpu python3 tools/attn_bench.py --compile-only  # v5e compiler, no chip
 
-``short_h<n>`` takes n heads a grid step; the one ``_short_heads`` picks for
-the shape is starred. One line a variant: milliseconds of one forward + backward and of the forward
-alone, and the relative error of out, dq, dk, dv against the dense float32
-math.
+``short_h<n>`` takes n heads a grid step of [B, H, T, D] operands;
+``tokens_r<rows>c<lanes>`` takes that block of [B, T, H*D] operands; what
+``_short_heads`` and ``_tokens_blocks`` pick for the shape is starred. One line a
+variant: milliseconds of one forward + backward and of the forward alone, and the
+relative error of out, dq, dk, dv against the dense float32 math.
 """
 from __future__ import annotations
 
@@ -35,7 +37,27 @@ SHAPES = {   # name: (B, H, T, D, causal, with lengths)
 }
 
 
+def token_blocks(B, H, T, D):
+    """(rows, lanes) of the token-major kernels: the rule's own choice first,
+    then half and double its rows and half its lanes."""
+    chosen = fa._tokens_blocks(B, T, H, D, 2)
+    if chosen is None:
+        return []
+    rows, lanes = chosen
+    found = []
+    for r, c in (chosen, (max(1, rows // 2), lanes), (2 * rows, lanes),
+                 (rows, lanes // 2)):
+        if ((r, c) not in found and B % r == 0
+                and c % max(128, D) == 0 and (H * D) % c == 0
+                and fa._tokens_vmem_bytes(r, T, c, 2)
+                <= fa.SHORT_VMEM_BUDGET):
+            found.append((r, c))
+    return found
+
+
 def variants(B, H, T, D, causal, lengths):
+    """(name, forward + backward) of each variant; a name that starts with
+    ``tokens`` takes [B, T, H*D] operands, every other [B, H, T, D]."""
     scale = float(D) ** -0.5
 
     def grads(attn):
@@ -65,6 +87,10 @@ def variants(B, H, T, D, causal, lengths):
                    grads(lambda q, k, v, heads=heads: fa._flash(
                        q, k, v, lengths, causal, scale, 512, 1024, heads,
                        False)[0]))
+    for n, blocks in enumerate(token_blocks(B, H, T, D)):
+        yield ("tokens_r%dc%d%s" % (blocks + ("" if n else "*",)),
+               grads(lambda q, k, v, blocks=blocks: fa._flash_tokens(
+                   q, k, v, lengths, causal, scale, H, blocks, False)[0]))
 
 
 def timed(step, args, reps, inner=20):
@@ -94,6 +120,8 @@ def main():
     ap.add_argument("--compile-only", action="store_true")
     ap.add_argument("--shapes", default=",".join(SHAPES))
     ap.add_argument("--reps", type=int, default=5)
+    ap.add_argument("--only", default="",
+                    help="run the variants whose name contains this")
     args = ap.parse_args()
 
     sharding = None
@@ -113,10 +141,13 @@ def main():
         if args.compile_only:
             lengths = (jax.ShapeDtypeStruct((B,), jnp.int32, sharding=sharding)
                        if with_len else None)
-            spec = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
+            specs = {tokens: jax.ShapeDtypeStruct(
+                (B, T, H * D) if tokens else shape, jnp.bfloat16,
+                sharding=sharding) for tokens in (False, True)}
             for vname, fn in variants(B, H, T, D, causal, None):
-                if with_len:
+                if with_len or args.only not in vname:
                     continue   # lengths is closed over: needs an array
+                spec = specs[vname.startswith("tokens")]
                 t0 = time.perf_counter()
                 try:
                     c = jax.jit(fn).lower(spec, spec, spec, spec).compile()
@@ -137,12 +168,19 @@ def main():
                    if with_len else None)
         ref = None
         for vname, fn in variants(B, H, T, D, causal, lengths):
+            if args.only not in vname and vname != "dense":
+                continue   # dense is what the errors are taken against
             args_ = (q, k, v, ct)
+            tokens = vname.startswith("tokens")
             if vname == "dense":
                 args_ = tuple(x.astype(f32) for x in args_)
+            elif tokens:   # the same numbers, as the projections leave them
+                args_ = tuple(fa.merge_heads(x) for x in args_)
             try:
                 jitted = jax.jit(fn)
                 out = jax.block_until_ready(jitted(*args_))
+                if tokens:
+                    out = tuple(fa.split_heads(x, H) for x in out)
                 # (q, k, v, ct) <- (dq, dk, dv, out); forward: q <- out
                 ms = timed(lambda *a: (lambda r: r[1:] + r[:1])(fn(*a)),
                            args_, args.reps)
