@@ -81,9 +81,8 @@ def _measure_feed(feed, reps=5):
     """Per-step feed staging cost for this batch, both ways: SYNC =
     hard-synced H2D from host memory (what a naive per-step input
     pipeline pays on the critical path), ASYNC = the consumer-side
-    stall with the double-buffered AsyncDeviceFeeder staging ahead
-    (what remains under PADDLE_TPU_ASYNC_FEED). Returns
-    (feed_ms_async, feed_ms_sync)."""
+    stall with the double-buffered AsyncDeviceFeeder staging ahead.
+    Returns (feed_ms_async, feed_ms_sync)."""
     import jax
 
     from paddle_tpu.core.native_feed import AsyncDeviceFeeder
@@ -147,38 +146,10 @@ def _time_steps(exe, main, feed, loss, warmup=3, iters=20, windows=2,
                 "executor.compile_fallbacks"),
         }
 
-    from paddle_tpu.core.native_feed import async_feed_enabled
-
-    use_async = async_feed_enabled()
-    host_feed = None
-    if use_async:
-        from paddle_tpu.core.tensor import LoDTensor as _LT
-
-        # PADDLE_TPU_ASYNC_FEED: the timed loop feeds from HOST
-        # memory through the double-buffered feeder (the realistic
-        # input pipeline), not the pre-staged device dict — H2D of
-        # step N+1 overlaps compute of step N
-        host_feed = {k: np.asarray(v.array if isinstance(v, _LT)
-                                   else v) for k, v in feed.items()}
-
     def run_n(n):
         """n-1 device-resident steps + one numpy-fetch step: the final
         d2h is the window's hard sync."""
         t0 = time.time()
-        if use_async:
-            from paddle_tpu.core.native_feed import AsyncDeviceFeeder
-
-            with AsyncDeviceFeeder(
-                    (host_feed for _ in range(n))) as fdr:
-                o = None
-                for i, fb in enumerate(fdr):
-                    if i < n - 1:
-                        exe.run(main, feed=fb, fetch_list=[loss],
-                                return_numpy=False)
-                    else:
-                        (o,) = exe.run(main, feed=fb,
-                                       fetch_list=[loss])
-            return time.time() - t0, float(np.asarray(o).ravel()[0])
         for _ in range(n - 1):
             exe.run(main, feed=feed, fetch_list=[loss],
                     return_numpy=False)
@@ -214,13 +185,10 @@ def _time_steps(exe, main, feed, loss, warmup=3, iters=20, windows=2,
         "windows_s": [round(t, 3) for t in times],
         "warmup_s": round(t_compile, 1),
         # per-step feed staging: critical-path cost with the async
-        # double buffer (feed_ms — what the timed loop pays when
-        # PADDLE_TPU_ASYNC_FEED=1) vs the sync H2D a naive per-step
-        # pipeline would pay (feed_ms_sync); bench_diff watches
-        # feed_ms so the overlap win is gated, not hoped for
+        # double buffer (feed_ms, which bench_diff watches) vs the sync
+        # H2D a naive per-step pipeline would pay (feed_ms_sync)
         "feed_ms": feed_ms,
         "feed_ms_sync": feed_ms_sync,
-        "async_feed": use_async,
         "whole_compile": whole,
         # single-chip runs move zero collective bytes — recorded
         # explicitly so bench_diff.py can diff single- and multi-chip
@@ -1327,17 +1295,6 @@ def bench_multichip(out_path=None, configs=None, quant_config="bert_base"):
     return doc
 
 
-def _enable_fast_paths():
-    """Single-chip fast paths bench.py runs WITH (ISSUE 14): fused
-    optimizer update, fused epilogues, async host feed. Default-off in
-    the runtime; flipped on here because the bit-parity suite
-    (tests/test_single_chip_fusion.py) licenses it — an explicit
-    ``=0`` in the caller's environment still wins (setdefault)."""
-    for knob in ("PADDLE_TPU_FUSED_OPTIMIZER", "PADDLE_TPU_FUSED_EPILOGUE",
-                 "PADDLE_TPU_ASYNC_FEED"):
-        os.environ.setdefault(knob, "1")
-
-
 def _device_record():
     """The device this process ran on, as JAX reports it — stamped on
     every per-model record so a CPU run can never be read as a chip
@@ -1367,7 +1324,6 @@ def _run_one(name, use_bf16):
     from paddle_tpu.core.compile_cache import enable_compile_cache
 
     enable_compile_cache()
-    _enable_fast_paths()
     if name == "mnist_mlp":
         _emit(bench_mnist_mlp())
     elif name == "bert_base":

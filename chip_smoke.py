@@ -21,8 +21,7 @@ is fatal — nothing is caught and turned into a warning):
 
   device   the first device is a TPU; kind and count reported
   train    BERT-base pretraining, bf16 AMP, b32 x s128: startup + steps
-           on one fixed batch fed from host numpy; then the same
-           program with bench.py's three fast-path knobs
+           on one fixed batch fed from host numpy
   kernels  every kernel under ops/pallas/ compiled (Mosaic, not
            interpret) at the shapes its callers use, against its own
            reference under ``jax.default_matmul_precision("highest")``
@@ -50,8 +49,6 @@ import time
 import numpy as np
 
 SEED = 2024
-FAST_PATH_KNOBS = ("PADDLE_TPU_FUSED_OPTIMIZER", "PADDLE_TPU_FUSED_EPILOGUE",
-                   "PADDLE_TPU_ASYNC_FEED")
 
 # one model, two sizes: the repo's own BERT-base config on the chip, a
 # toy of the same shape for the CPU rehearsal
@@ -64,8 +61,6 @@ FULL = {
     "masked": dict(b=64, h=8, s=256, d=64),
     "short": dict(b=32, h=12, s=512, d=64),
     "short_dp": dict(b=128, h=12, s=128, d=64),
-    "opt_elems": 2 * 1024 * 1024,
-    "conv": dict(batch=8, hw=28, cin=128, cout_1x1=512, cout_3x3=128),
     "decode": dict(streams=5, max_tokens=12),
     "dp_steps": 4,
 }
@@ -78,8 +73,6 @@ REHEARSAL = {
     "masked": dict(b=2, h=2, s=128, d=16),
     "short": dict(b=2, h=2, s=128, d=64),
     "short_dp": dict(b=4, h=4, s=128, d=32),
-    "opt_elems": 4096,
-    "conv": dict(batch=1, hw=8, cin=128, cout_1x1=128, cout_3x3=128),
     "decode": dict(streams=3, max_tokens=6),
     "dp_steps": 2,
 }
@@ -244,18 +237,14 @@ def _counters(obs):
 def train_one_device(main, startup, loss, feed, steps, platform, xla,
                      param_probe=None):
     """startup + ``steps`` steps under Executor(TPUPlace(0)), feeding
-    host numpy every step (through the double-buffered feeder when
-    PADDLE_TPU_ASYNC_FEED is on, as bench.py does). Returns the loss
-    trajectory, set-up seconds (startup + compile + first step), the
-    steady step ms, what XLA built, the executor and the scope (kept
-    alive so the caller can lower the step), and asserts the path the
-    steps took."""
+    host numpy every step. Returns the loss trajectory, set-up seconds
+    (startup + compile + first step), the steady step ms, what XLA
+    built, the executor and the scope (kept alive so the caller can
+    lower the step), and asserts the path the steps took."""
     import jax
 
     import paddle_tpu as fluid
     from paddle_tpu import observability as obs
-    from paddle_tpu.core.native_feed import (AsyncDeviceFeeder,
-                                             async_feed_enabled)
 
     scope = fluid.Scope()
     exe = fluid.Executor(fluid.TPUPlace(0))
@@ -266,14 +255,10 @@ def train_one_device(main, startup, loss, feed, steps, platform, xla,
         t0 = time.perf_counter()
         exe.run(startup)
         c0 = _counters(obs)
-        if async_feed_enabled():
-            batches = AsyncDeviceFeeder(feed for _ in range(steps))
-        else:
-            batches = (feed for _ in range(steps))
         c1 = None
-        for batch in batches:
+        for _ in range(steps):
             t = time.perf_counter()
-            (out,) = exe.run(main, feed=batch, fetch_list=[loss],
+            (out,) = exe.run(main, feed=feed, fetch_list=[loss],
                              return_numpy=False)
             jax.block_until_ready(out.array)
             step_s.append(time.perf_counter() - t)
@@ -335,15 +320,12 @@ def phase_train(sizes, dev_rec, platform, xla):
 
     cfg = sizes["bert"]
     feed = bert_feed(cfg, cfg["batch"])
-    for k in FAST_PATH_KNOBS:
-        os.environ.pop(k, None)
-
     main, startup, loss = build_bert(cfg, cfg["batch"])
     probe = _first_param(main)
     base = train_one_device(main, startup, loss, feed, cfg["steps"],
                             platform, xla, param_probe=probe)
     peak = _peak_bytes(jax.devices()[0], platform)
-    _emit("train", dev_rec, program="bert_base", path="default",
+    _emit("train", dev_rec, program="bert_base",
           setup_s=round(base["setup_s"], 2),
           step_ms=round(base["step_ms"], 2),
           losses=[round(x, 5) for x in base["losses"]],
@@ -352,47 +334,9 @@ def phase_train(sizes, dev_rec, platform, xla):
     del base["exe"], base["scope"]
     gc.collect()
 
-    # the same program as bench.py runs it: fused optimizer (auto ->
-    # flat layout -> Pallas on TPU), fused epilogues, async host feed
-    for k in FAST_PATH_KNOBS:
-        os.environ[k] = "1"
-    try:
-        fmain, fstartup, floss = build_bert(cfg, cfg["batch"])
-        fast = train_one_device(fmain, fstartup, floss, feed,
-                                cfg["steps"], platform, xla)
-        ops = [op.type for op in fmain.global_block().ops]
-        assert "fused_optimizer" in ops, "fused optimizer pass did not run"
-        layout = ("flat" if getattr(fmain, "_sharded_flat_layout", None)
-                  else "chain")
-        with fluid.scope_guard(fast["scope"]):
-            n_mosaic = _mosaic_calls(
-                fast["exe"].lower(fmain, feed=feed, fetch_list=[floss]))
-    finally:
-        for k in FAST_PATH_KNOBS:
-            os.environ.pop(k, None)
-    if platform == "tpu":
-        # the compiled step holds the fused-optimizer kernel as a Mosaic
-        # call over the flat buffer — not its XLA twin
-        assert layout == "flat", layout
-        assert n_mosaic >= 1, "no Mosaic call in the fused BERT step"
-    # same bound tools/sc_smoke.py holds the fused path to on CPU: the
-    # fused ops evaluate the same expressions, so six steps of an
-    # iterated system may drift by rounding only
-    drift = max(abs(a - b) / abs(b)
-                for a, b in zip(fast["losses"], base["losses"]))
-    assert drift < 1e-3, (drift, fast["losses"], base["losses"])
-    _emit("train", dev_rec, program="bert_base", path="fast",
-          setup_s=round(fast["setup_s"], 2),
-          step_ms=round(fast["step_ms"], 2),
-          losses=[round(x, 5) for x in fast["losses"]],
-          loss_drift_vs_default=drift, optimizer_layout=layout,
-          mosaic_calls=n_mosaic, ops=len(ops), **fast["xla"])
-    del fast
-    gc.collect()
-
     # Executor(CPUPlace()) on the chip host: the kernels follow where
-    # the computation runs, so a flash_attention + fused-optimizer
-    # program must run as plain XLA on the host — or the place raises
+    # the computation runs, so a flash_attention program must run as
+    # plain XLA on the host — or the place raises
     # its typed error where this process has no CPU backend
     try:
         fluid.CPUPlace().jax_device()
@@ -406,40 +350,36 @@ def phase_train(sizes, dev_rec, platform, xla):
 
 def _cpu_place_program():
     """A tiny flash_attention + Adam program through
-    Executor(CPUPlace()) with the fused-optimizer knob on."""
+    Executor(CPUPlace())."""
     import paddle_tpu as fluid
     from paddle_tpu import layers
 
-    os.environ["PADDLE_TPU_FUSED_OPTIMIZER"] = "1"
-    try:
-        main, startup = fluid.Program(), fluid.Program()
-        startup.random_seed = SEED
-        with fluid.unique_name.guard(), fluid.program_guard(main, startup):
-            x = fluid.data(name="x", shape=[2, 2, 128, 16],
-                           dtype="float32")
-            q = layers.fc(x, 16, num_flatten_dims=3)
-            k = layers.fc(x, 16, num_flatten_dims=3)
-            o = layers.flash_attention(q, k, x, causal=True)
-            loss = layers.mean(layers.square(o))
-            fluid.optimizer.AdamOptimizer(1e-2).minimize(loss)
-        feed = {"x": np.random.RandomState(3).randn(
-            2, 2, 128, 16).astype("float32")}
-        scope = fluid.Scope()
-        with fluid.scope_guard(scope):
-            exe = fluid.Executor(fluid.CPUPlace())
-            exe.run(startup)
-            vals = []
-            for _ in range(2):
-                (out,) = exe.run(main, feed=feed, fetch_list=[loss],
-                                 return_numpy=False)
-                vals.append(float(np.asarray(out.array)))
-            assert all(np.isfinite(vals)), vals
-            assert {d.platform for d in out.array.devices()} == {"cpu"}
-            n = _mosaic_calls(exe.lower(main, feed=feed,
-                                        fetch_list=[loss]))
-            assert n == 0, "Mosaic call in a CPU-place computation"
-    finally:
-        os.environ.pop("PADDLE_TPU_FUSED_OPTIMIZER", None)
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = SEED
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.data(name="x", shape=[2, 2, 128, 16],
+                       dtype="float32")
+        q = layers.fc(x, 16, num_flatten_dims=3)
+        k = layers.fc(x, 16, num_flatten_dims=3)
+        o = layers.flash_attention(q, k, x, causal=True)
+        loss = layers.mean(layers.square(o))
+        fluid.optimizer.AdamOptimizer(1e-2).minimize(loss)
+    feed = {"x": np.random.RandomState(3).randn(
+        2, 2, 128, 16).astype("float32")}
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        vals = []
+        for _ in range(2):
+            (out,) = exe.run(main, feed=feed, fetch_list=[loss],
+                             return_numpy=False)
+            vals.append(float(np.asarray(out.array)))
+        assert all(np.isfinite(vals)), vals
+        assert {d.platform for d in out.array.devices()} == {"cpu"}
+        n = _mosaic_calls(exe.lower(main, feed=feed,
+                                    fetch_list=[loss]))
+        assert n == 0, "Mosaic call in a CPU-place computation"
     return "ran on cpu, xla path, loss %.5f -> %.5f" % tuple(vals)
 
 
@@ -452,9 +392,7 @@ def phase_kernels(sizes, dev_rec, platform, xla):
     import paddle_tpu as fluid
 
     fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
-    fo = importlib.import_module("paddle_tpu.ops.pallas.fused_optimizer")
     pa = importlib.import_module("paddle_tpu.ops.pallas.paged_attention")
-    cv = importlib.import_module("paddle_tpu.ops.pallas.conv")
     on_tpu = platform == "tpu"
     t_phase, x_phase = time.perf_counter(), xla.snapshot()
     report = {}
@@ -574,37 +512,6 @@ def phase_kernels(sizes, dev_rec, platform, xla):
         flash_case("flash_tokens_masked", m, True, lengths, 2e-2, "short",
                    tokens=True)
 
-    # -- fused optimizer over a flat buffer vs _update_math ----------------
-    n_el = sizes["opt_elems"]
-    p, g, sa = (jnp.asarray(rng.randn(n_el), jnp.float32) for _ in range(3))
-    sb = jnp.abs(jnp.asarray(rng.randn(n_el), jnp.float32))
-    lr, b1p, b2p = (jnp.float32(x) for x in (1e-3, 0.81, 0.98))
-    for op_type, attrs in (("adam", {"beta1": 0.9, "beta2": 0.999,
-                                     "epsilon": 1e-8}),
-                           ("momentum", {"mu": 0.9})):
-        adam = op_type == "adam"
-        args = (p, g, lr, sa, sb if adam else None,
-                b1p if adam else None, b2p if adam else None)
-
-        def kernel(*a, op_type=op_type, attrs=attrs):
-            return fo.fused_optimizer_update(op_type, attrs, *a,
-                                             force_pallas=True)
-
-        def reference(*a, op_type=op_type, attrs=attrs):
-            return fo._update_math(op_type, attrs, *a)
-
-        n = check_mosaic("fused_" + op_type, kernel, args, 1)
-        got = [x for x in jax.jit(kernel)(*args) if x is not None]
-        ref = [x for x in jax.jit(reference)(*args) if x is not None]
-        # the kernel and the XLA lowering evaluate the SAME f32
-        # expression sequence; they may differ in how sqrt and divide
-        # round on the VPU, i.e. by a few ULP of an O(1) value
-        tol = 1e-5
-        errs = [_rel_err(a, b) for a, b in zip(got, ref)]
-        assert max(errs) < tol, (op_type, errs)
-        report["fused_" + op_type] = {"mosaic_calls": n, "elems": n_el,
-                                      "rel_err": errs, "tol": tol}
-
     # -- paged attention at the decode engine's shapes ----------------------
     from paddle_tpu.serving.decode import DecodeConfig, DecodeEngine
 
@@ -673,38 +580,6 @@ def phase_kernels(sizes, dev_rec, platform, xla):
                                "serve_s": round(serve_s, 2),
                                "token_exact_vs_dense": True}
 
-    # -- conv kernel (default-off, C4 deletion candidate — but while it
-    # is in the tree it compiles) at one 1x1 and one 3x3 ResNet shape ------
-    c = sizes["conv"]
-    for name, ksz, cout, pad in (("conv_1x1", 1, c["cout_1x1"], 0),
-                                 ("conv_3x3", 3, c["cout_3x3"], 1)):
-        x = jnp.asarray(rng.randn(c["batch"], c["hw"], c["hw"], c["cin"]),
-                        jnp.bfloat16)
-        w = jnp.asarray(rng.randn(ksz, ksz, c["cin"], cout)
-                        / np.sqrt(ksz * ksz * c["cin"]), jnp.bfloat16)
-        scale = jnp.asarray(1.0 + 0.1 * rng.randn(cout), jnp.float32)
-        shift = jnp.asarray(0.1 * rng.randn(cout), jnp.float32)
-
-        def kernel(x, w, scale, shift, pad=pad):
-            return cv.conv2d_bn_act(x, w, scale, shift, stride=1,
-                                    padding=pad, relu=True)
-
-        def reference(x, w, scale, shift, pad=pad):
-            with jax.default_matmul_precision("highest"):
-                y = cv._xla_conv_nhwc(x.astype(jnp.float32),
-                                      w.astype(jnp.float32), 1, pad)
-            return jnp.maximum(y * scale + shift, 0.0)
-
-        n = check_mosaic(name, kernel, (x, w, scale, shift), 1)
-        got = jax.jit(kernel)(x, w, scale, shift)
-        ref = jax.jit(reference)(x, w, scale, shift)
-        # bf16 operands into an f32 accumulator (exact products), bf16
-        # output: one rounding of 2^-9 — 1e-2 of max is ~2.5x over it
-        err, tol = _rel_err(got, ref), 1e-2
-        assert err < tol, (name, err)
-        report[name] = {"mosaic_calls": n, "rel_err": err, "tol": tol,
-                        "x": list(x.shape), "w": list(w.shape)}
-
     _emit("kernels", dev_rec,
           setup_s=round(gpt_setup_s, 2), step_ms=round(gpt_step_ms, 2),
           phase_s=round(time.perf_counter() - t_phase, 2),
@@ -726,8 +601,6 @@ def phase_dp4(sizes, dev_rec, platform, xla):
     devices = jax.devices()[:n]
     gbatch = n * cfg["batch"]
     feed = bert_feed(cfg, gbatch)
-    for k in FAST_PATH_KNOBS:
-        os.environ.pop(k, None)
     x_phase = xla.snapshot()
 
     # the one-chip loss on the SAME global batch, from the same seed

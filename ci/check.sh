@@ -299,41 +299,6 @@ else
 fi
 cp "$MC_OUT" "$BASELINE"
 
-echo "== gate 7c: single-chip fusion smoke =="
-# ISSUE-14 acceptance: the fused-optimizer pass must STRICTLY cut the
-# per-step op count for an mlp + conv smoke with step-1 parity vs the
-# per-param path (bitwise where XLA's FMA contraction matches,
-# <=4 ULP otherwise) and full-run trajectory agreement; the async
-# feeder's critical-path cost must not exceed the sync H2D it hides.
-SC_OUT="$(mktemp)"
-trap 'rm -f "$FP_TMP" "$SRV_OUT" "$MC_OUT" "$SC_OUT"' EXIT
-python tools/sc_smoke.py --out "$SC_OUT"
-
-echo "== gate 7d: single-chip perf regression vs previous run =="
-# same run-over-run scheme as gates 5c/7b: timings gate loose (50%),
-# but sc.program_ops — the fused programs' op count — is DETERMINISTIC
-# and gates at 1%: growth means the fusion passes silently regressed.
-SC_BASELINE="ci/baseline/sc_smoke.json"
-mkdir -p ci/baseline
-if [[ -f "$SC_BASELINE" ]]; then
-    sc_rc=0
-    python tools/bench_diff.py "$SC_BASELINE" "$SC_OUT" \
-        --threshold 0.5 --counters-threshold 0.01 || sc_rc=$?
-    if [[ "$sc_rc" == "0" ]]; then
-        echo "single-chip perf gate: no regression vs previous run"
-    elif [[ "$sc_rc" == "2" ]]; then
-        echo "single-chip perf gate: baseline unreadable (rc=2) — reseeding $SC_BASELINE"
-    elif [[ "${PERF_BASELINE_ACCEPT:-0}" == "1" ]]; then
-        echo "single-chip perf gate: regression ACCEPTED (PERF_BASELINE_ACCEPT=1)"
-    else
-        echo "single-chip perf gate: regression vs $SC_BASELINE — intentional? re-run with PERF_BASELINE_ACCEPT=1" >&2
-        exit 1
-    fi
-else
-    echo "single-chip perf gate: no previous run on this machine — seeding $SC_BASELINE"
-fi
-cp "$SC_OUT" "$SC_BASELINE"
-
 echo "== gate 7e: placement-synthesis smoke =="
 # ISSUE-15 acceptance (~60s): the dp=8 mlp placement search must emit
 # a verifier-clean plan artifact — every enumerated candidate gated

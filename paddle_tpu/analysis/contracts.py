@@ -313,98 +313,6 @@ class _ShardedUpdateContract(RewriteContract):
                           % (p, p, g))
 
 
-class _FusedOptimizerContract(RewriteContract):
-    """core/fusion.py apply_fused_optimizer: every (param, grad) pair
-    the pass folds away must reappear in a ``fused_optimizer`` op at
-    matching slot positions — exactly once — and spared params keep
-    their per-param update op untouched."""
-
-    name = "fused_optimizer"
-
-    def pre(self, program):
-        from ..core.fusion import FUSED_OPTIMIZER_TYPES
-
-        block = program.global_block()
-        opts = []
-        for op in block.ops:
-            if op.type in FUSED_OPTIMIZER_TYPES and op.input("Param") \
-                    and op.input("Grad"):
-                opts.append((op._id, op.type, op.input("Param")[0],
-                             op.input("Grad")[0]))
-        return {"opts": opts}
-
-    def post(self, program, state) -> None:
-        block = program.global_block()
-        live_ids = {op._id for op in block.ops}
-        fused_pairs: Dict[str, str] = {}
-        seen_params: List[str] = []
-        for i, op in enumerate(block.ops):
-            if op.type != "fused_optimizer":
-                continue
-            params, grads = op.input("Param"), op.input("Grad")
-            if len(params) != len(grads):
-                _viol(self.name,
-                      "fused_optimizer op #%d binds %d params but %d "
-                      "grads — slot positions must pair"
-                      % (i, len(params), len(grads)))
-            if len(params) != len(op.output("ParamOut")):
-                _viol(self.name,
-                      "fused_optimizer op #%d updates %d params but "
-                      "rebinds %d ParamOut slots" %
-                      (i, len(params), len(op.output("ParamOut"))))
-            fused_pairs.update(zip(params, grads))
-            seen_params.extend(params)
-        dupes = {p for p in seen_params if seen_params.count(p) > 1}
-        if dupes:
-            _viol(self.name,
-                  "param(s) %s folded into more than one "
-                  "fused_optimizer op — double update"
-                  % sorted(dupes))
-        for opid, op_type, p, g in state["opts"]:
-            if opid in live_ids:
-                if p in fused_pairs:
-                    _viol(self.name,
-                          "param %r keeps its per-param %s op AND is "
-                          "folded into a fused_optimizer op — double "
-                          "update" % (p, op_type))
-            elif fused_pairs.get(p) != g:
-                _viol(self.name,
-                      "optimizer op for param %r was removed but no "
-                      "fused_optimizer carries (%r, %r) — the param "
-                      "would never be updated" % (p, p, g))
-
-
-class _FusedEpilogueContract(RewriteContract):
-    """core/fusion.py apply_fused_epilogues: the pass may merge ops
-    but must not LOSE a value — the set of written var names is
-    preserved (pre-built grad ops keep reading the intermediates) and
-    the op count never grows. Ordering/def-before-use is re-proven by
-    the post-rewrite ``verify_program`` run."""
-
-    name = "fused_epilogue"
-
-    def pre(self, program):
-        block = program.global_block()
-        writes = sorted({n for op in block.ops
-                         for n in op.output_arg_names if n})
-        return {"writes": writes, "n_ops": len(block.ops)}
-
-    def post(self, program, state) -> None:
-        block = program.global_block()
-        writes = sorted({n for op in block.ops
-                         for n in op.output_arg_names if n})
-        lost = sorted(set(state["writes"]) - set(writes))
-        if lost:
-            _viol(self.name,
-                  "fused epilogue dropped written var(s) %s — a "
-                  "reader (e.g. a pre-built grad op) would see a "
-                  "stale or missing value" % lost[:5])
-        if len(block.ops) > state["n_ops"]:
-            _viol(self.name,
-                  "epilogue fusion GREW the program (%d -> %d ops)"
-                  % (state["n_ops"], len(block.ops)))
-
-
 class _AsyncCollectiveContract(RewriteContract):
     """parallel/scheduling.py schedule_async_collectives: every grad a
     fused bucket reduced must still be reduced exactly once (now by the
@@ -648,8 +556,6 @@ class _BucketQuantContract(RewriteContract):
 register_contract(_InsertAllreduceContract())
 register_contract(_BucketAllreduceContract())
 register_contract(_ShardedUpdateContract())
-register_contract(_FusedOptimizerContract())
-register_contract(_FusedEpilogueContract())
 register_contract(_AsyncCollectiveContract())
 register_contract(_ReductionSwapContract())
 register_contract(_BucketQuantContract())

@@ -386,12 +386,8 @@ def _trace_ops(block, ops, env: Dict, step_seed) -> None:
 
                 ins[RNG_SEED_ATTR] = jnp.uint32(attrs["seed"])
             else:
-                # _fwd_op_id: a grad op reuses its forward op's
-                # stream; _rng_op_id: a fused FORWARD op (epilogue
-                # fusion) reuses the stream of the RNG op it absorbed
-                # without marking itself as backward
-                sid = attrs.get("_fwd_op_id",
-                                attrs.get("_rng_op_id", op._id or 0))
+                # _fwd_op_id: a grad op reuses its forward op's stream
+                sid = attrs.get("_fwd_op_id", op._id or 0)
                 ins[RNG_SEED_ATTR] = _op_seed(step_seed, sid)
         try:
             outs = info.fn(ins, attrs)

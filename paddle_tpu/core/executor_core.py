@@ -217,12 +217,8 @@ class CoreExecutor:
                 else:
                     # A grad op reuses its forward op's stream (attr set
                     # by backward.py) so e.g. dropout masks match
-                    # fwd/bwd; a fused forward op (epilogue fusion)
-                    # carries _rng_op_id for the same reuse without
-                    # the backward-marking attr.
-                    seed_id = attrs.get(
-                        "_fwd_op_id",
-                        attrs.get("_rng_op_id", op._id or 0))
+                    # fwd/bwd.
+                    seed_id = attrs.get("_fwd_op_id", op._id or 0)
                     seed_val = self.rng.next_seed(seed_id)
                 ins = dict(ins)
                 ins[RNG_SEED_ATTR] = jnp.asarray(seed_val, dtype=jnp.uint32)

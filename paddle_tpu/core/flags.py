@@ -31,10 +31,6 @@ _DEFS = {
     "FLAGS_selected_gpus": ("", "visible devices (use JAX platform env)"),
     "FLAGS_enable_parallel_graph": (False, "executor choice (no-op)"),
     "FLAGS_max_inplace_grad_add": (0, "grad-add inplace (no-op)"),
-    "FLAGS_use_pallas_conv": ("off", "route NHWC convs to the pallas "
-                              "implicit-GEMM kernel: off | auto (only "
-                              "the measured-win shape class: expansion "
-                              "1x1) | all (every viable shape)"),
     "FLAGS_dygraph_lazy": (False, "queue eager dygraph ops and flush "
                            "them as one compiled dispatch per step "
                            "(lazy-tensor mode, dygraph/lazy.py)"),

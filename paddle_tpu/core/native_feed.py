@@ -12,8 +12,7 @@ Two halves of "the input pipeline never serializes with the device":
   jax.Array feeds straight through (compiler_engine feed staging), so
   a feeder-supplied batch costs the step's critical path only the
   queue pop — ``feed.wait_ms`` measures exactly the stall that
-  remains, which is the number ``PADDLE_TPU_ASYNC_FEED`` exists to
-  drive to ~0.
+  remains.
 """
 from __future__ import annotations
 
@@ -25,32 +24,6 @@ import threading
 import time
 
 import numpy as np
-
-
-# fast path for the gate-4 disabled-path budget: probe os.environ's
-# backing dict directly (the _Environ mapping's encodekey + dispatch
-# costs ~1us under load — right at the budget). Same recipe, same
-# monkeypatch-safety argument, as analysis.verify_enabled.
-try:
-    _ENV_DATA = os.environ._data
-    _ENV_KEY = os.environ.encodekey("PADDLE_TPU_ASYNC_FEED")
-except Exception:  # non-CPython / exotic platform
-    _ENV_DATA = None
-    _ENV_KEY = None
-
-
-def async_feed_enabled() -> bool:
-    """``PADDLE_TPU_ASYNC_FEED``: opt-in double-buffered host feed
-    (default off — one dict probe, gate-4 disabled-path budget)."""
-    if _ENV_DATA is not None:
-        raw = _ENV_DATA.get(_ENV_KEY)
-    else:
-        raw = os.environ.get("PADDLE_TPU_ASYNC_FEED")
-    if not raw:
-        return False
-    if isinstance(raw, bytes):
-        raw = raw.decode("utf-8", "ignore")
-    return raw.strip().lower() in ("1", "true", "yes", "on")
 
 
 class AsyncDeviceFeeder:

@@ -96,7 +96,7 @@ class Executor:
 
             t_run = time.perf_counter() if _obs.enabled() else None
             with _obs.tracing.span("executor/prepare", cat="step"):
-                run_args = self._compiled_run_args(program, scope, feed,
+                run_args = self._compiled_run_args(program, feed,
                                                    fetch_list)
             if run_args is not None:
                 try:
@@ -144,18 +144,11 @@ class Executor:
         return self._core.run_program(program, scope, feed, fetch_list,
                                       return_numpy)
 
-    def _compiled_run_args(self, program, scope, feed, fetch_list):
+    def _compiled_run_args(self, program, feed, fetch_list):
         """(program, feed) to hand the whole-program compiler, or None
         when this program takes the interpreter."""
         from .core.compiler_engine import _program_version
 
-        # single-chip fusion rewrites (fused optimizer update /
-        # fused epilogues) — default-off knobs; the disabled path
-        # is two env reads (gate-4 budget), the enabled path is
-        # idempotent per program
-        from .core.fusion import maybe_rewrite_single_chip
-
-        maybe_rewrite_single_chip(program, scope, self.place)
         if _program_version(program) in self._compile_fallbacks:
             return None
         if self._can_whole_compile(program):
@@ -170,9 +163,8 @@ class Executor:
         """The ``jax.stages.Lowered`` of the whole-program step
         ``run`` would execute for this (program, feed, fetch_list) —
         ``.as_text()`` shows what the step contains, e.g. whether a
-        Pallas kernel is in it as a ``tpu_custom_call``. Call it after
-        a ``run`` of the same program (the single-chip rewrites happen
-        there); it executes nothing."""
+        Pallas kernel is in it as a ``tpu_custom_call``. It executes
+        nothing."""
         from .core.compiler_engine import lower_compiled_program
 
         scope = scope if scope is not None else global_scope()

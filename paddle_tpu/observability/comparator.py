@@ -173,10 +173,6 @@ COUNTER_WATCH_GROWS_BAD = ("parallel.collective_bytes",
                            # store regressed toward whole-table
                            # snapshots
                            "checkpoint.round_bytes",
-                           # fused single-chip program op count
-                           # (tools/sc_smoke.py): deterministic —
-                           # growth means the fusion passes regressed
-                           "sc.program_ops",
                            # the serving smokes must stay error-free:
                            # any growth (including 0 -> n) is a bug
                            # the functional assertions may have missed

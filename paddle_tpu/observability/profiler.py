@@ -88,14 +88,12 @@ __all__ = [
 
 # optimizer update op types (ops/optimizer_ops.py registrations) — the
 # boundary between the backward and optimizer phases.
-# "fused_optimizer" is the single-chip fused update (core/fusion.py):
-# one op carrying a whole optimizer instance, still optimizer phase.
 OPTIMIZER_OPS = frozenset({
     "sgd", "momentum", "lars_momentum", "adam", "adamw", "adamax",
     "adagrad", "decayed_adagrad", "adadelta", "rmsprop", "ftrl", "lamb",
     "dpsgd", "dgc", "dgc_momentum", "dgc_clip_by_norm", "proximal_gd",
     "proximal_adagrad", "lookahead_update", "ema_accumulate",
-    "ema_adaptive_decay", "model_average_accumulate", "fused_optimizer",
+    "ema_adaptive_decay", "model_average_accumulate",
 })
 
 # collectives that are safe to SKIP for the collective-free timing run:
@@ -947,10 +945,6 @@ _FLOPS_TABLE = {
     "flash_attention": ("attention", _fl_flash),
     "batch_norm": ("norm", _fl_first_input(8)),
     "layer_norm": ("norm", _fl_first_input(8)),
-    # fused epilogues (core/fusion.py): add + act (+ dropout) ~= 3
-    # elementwise passes; add + layer_norm = 1 + the norm's 8
-    "fused_bias_act": ("elementwise", _fl_first_input(3)),
-    "fused_residual_layer_norm": ("norm", _fl_first_input(9)),
     "softmax": ("elementwise", _fl_first_input(5)),
     "softmax_with_cross_entropy": ("loss", _fl_first_input(6)),
     "cross_entropy": ("loss", _fl_first_input(3)),
@@ -1014,13 +1008,9 @@ def op_flops(op, block, state=None) -> Tuple[int, str]:
     grad = t.endswith("_grad")
     base = t[:-5] if grad else t
     if base in OPTIMIZER_OPS:
-        # a handful of elementwise passes over every param element;
-        # fused_optimizer carries a whole instance's params in one
-        # duplicable slot — same per-element cost, summed across them
-        params = op.input("Param") or []
-        if base != "fused_optimizer":
-            params = params[:1]
-        tot = sum(_prod(shp(n)) or 0 for n in params)
+        # a handful of elementwise passes over every param element
+        tot = sum(_prod(shp(n)) or 0
+                  for n in (op.input("Param") or [])[:1])
         return 4 * tot, "optimizer"
     cat, fn = _FLOPS_TABLE.get(base, (None, None))
     if fn is None:

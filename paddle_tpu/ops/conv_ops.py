@@ -58,43 +58,6 @@ def _conv_nd(x, w, strides, paddings, dilations, groups, data_format="NCHW",
     )
 
 
-def _maybe_pallas_conv(ins, attrs, data_format):
-    """FLAGS_use_pallas_conv routing (off/auto/all): returns the pallas
-    implicit-GEMM result or None to take the lax path. Only NHWC
-    stride-1/2 square 1x1/3x3, groups=1, symmetric padding qualify; in
-    'auto' mode only the measured-win class routes (BASELINE.md r5)."""
-    from ..core.flags import flag
-    from ..core.place import compute_platform
-    from .pallas.conv import pallas_conv, route_pallas
-
-    mode = flag("use_pallas_conv")
-    if mode not in ("off", "auto", "all"):
-        import warnings
-
-        warnings.warn("FLAGS_use_pallas_conv=%r is not one of "
-                      "off/auto/all; treating as 'off'" % (mode,))
-        return None
-    if mode == "off" or compute_platform() != "tpu":
-        return None
-    x, w = ins["Input"], ins["Filter"]
-    strides = attrs.get("strides", [1, 1])
-    pads = _norm_pads(attrs.get("paddings", [0, 0]))
-    if attrs.get("padding_algorithm", "EXPLICIT") not in ("EXPLICIT",):
-        return None
-    if strides[0] != strides[1]:
-        return None
-    if not all(a == b == pads[0][0] for (a, b) in pads):
-        return None
-    w_hwio_shape = (w.shape[2], w.shape[3], w.shape[1], w.shape[0])
-    if not route_pallas(mode, x.shape, w_hwio_shape, strides[0],
-                        attrs.get("groups", 1),
-                        attrs.get("dilations", [1, 1]), data_format):
-        return None
-    # filter storage is OIHW; the kernel wants HWIO
-    w_hwio = jnp.transpose(w, (2, 3, 1, 0))
-    return pallas_conv(x, w_hwio, strides[0], pads[0][0])
-
-
 _CONV_ATTRS = {
     "strides": [1, 1],
     "paddings": [0, 0],
@@ -121,18 +84,16 @@ def _conv2d(ins, attrs):
     data_format = attrs.get("data_format", "NCHW")
     if data_format == "AnyLayout":
         data_format = "NCHW"
-    out = _maybe_pallas_conv(ins, attrs, data_format)
-    if out is None:
-        out = _conv_nd(
-            ins["Input"],
-            ins["Filter"],
-            attrs.get("strides", [1, 1]),
-            attrs.get("paddings", [0, 0]),
-            attrs.get("dilations", [1, 1]),
-            attrs.get("groups", 1),
-            data_format,
-            attrs.get("padding_algorithm", "EXPLICIT"),
-        )
+    out = _conv_nd(
+        ins["Input"],
+        ins["Filter"],
+        attrs.get("strides", [1, 1]),
+        attrs.get("paddings", [0, 0]),
+        attrs.get("dilations", [1, 1]),
+        attrs.get("groups", 1),
+        data_format,
+        attrs.get("padding_algorithm", "EXPLICIT"),
+    )
     if ins.get("Bias") is not None:
         bshape = ((1, -1, 1, 1) if data_format != "NHWC"
                   else (1, 1, 1, -1))

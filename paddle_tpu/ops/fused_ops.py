@@ -108,87 +108,6 @@ def _fc(ins, attrs):
     return {"Out": out.reshape(tuple(x.shape[:k]) + (w.shape[1],))}
 
 
-def _registry_fn(op_type):
-    from ..core.registry import OpInfoMap
-
-    return OpInfoMap.instance().get(op_type).fn
-
-
-@register_op(
-    "fused_bias_act",
-    inputs=[In("X"), In("Y")],
-    outputs=[Out("Out"), Out("AddOut", dispensable=True),
-             Out("ActOut", dispensable=True),
-             Out("Mask", dispensable=True, no_grad=True)],
-    attrs={"act": "relu", "axis": -1, "approximate": False,
-           "alpha": 0.02, "dropout_prob": -1.0, "is_test": False,
-           "fix_seed": False, "seed": 0,
-           "dropout_implementation": "downgrade_in_infer"},
-    grad=None,
-    needs_rng=True,
-)
-def _fused_bias_act(ins, attrs):
-    """bias/residual-add + activation (+ optional dropout) epilogue —
-    the chain the core/fusion.py epilogue rewrite collapses
-    (elementwise_add -> relu/gelu/... [-> dropout]). Each stage calls
-    the SAME registered kernel fn the standalone ops run, in the same
-    order, so the fused op is bit-for-bit with the chain it replaces —
-    including the dropout mask, which draws from the original dropout
-    op's RNG stream (the rewrite carries its ``_fwd_op_id`` so the
-    pre-built ``dropout_grad`` op sees matching masks). Intermediate
-    outputs (AddOut/ActOut/Mask) are emitted only when the program
-    still reads them (pre-built grad ops recompute through forward
-    INPUTS, so AddOut usually stays live); ``dropout_prob < 0`` means
-    no dropout stage. XLA fuses the whole epilogue into one loop —
-    the win is one traced/launched op instead of three."""
-    from ..core.registry import RNG_SEED_ATTR
-
-    inter = _registry_fn("elementwise_add")(
-        {"X": ins["X"], "Y": ins["Y"]},
-        {"axis": attrs.get("axis", -1)})["Out"]
-    act = attrs.get("act", "relu")
-    out = _registry_fn(act)({"X": inter}, dict(attrs))["Out"]
-    act_out = out
-    mask = None
-    if float(attrs.get("dropout_prob", -1.0)) >= 0.0:
-        d = _registry_fn("dropout")(
-            {"X": out, "Seed": None, RNG_SEED_ATTR: ins.get(RNG_SEED_ATTR)},
-            {"dropout_prob": attrs.get("dropout_prob"),
-             "is_test": attrs.get("is_test", False),
-             "dropout_implementation": attrs.get(
-                 "dropout_implementation", "downgrade_in_infer")})
-        out, mask = d["Out"], d.get("Mask")
-    return {"Out": out, "AddOut": inter, "ActOut": act_out,
-            "Mask": mask}
-
-
-@register_op(
-    "fused_residual_layer_norm",
-    inputs=[In("X"), In("Y"), In("Scale", dispensable=True),
-            In("Bias", dispensable=True)],
-    outputs=[Out("Out"), Out("AddOut", dispensable=True),
-             Out("Mean", dispensable=True, no_grad=True),
-             Out("Variance", dispensable=True, no_grad=True)],
-    attrs={"axis": -1, "epsilon": 1e-5, "begin_norm_axis": 1},
-    grad=None,
-)
-def _fused_residual_layer_norm(ins, attrs):
-    """residual-add + layer_norm epilogue (elementwise_add ->
-    layer_norm), fused by the core/fusion.py rewrite under the same
-    contract as fused_bias_act: identical registered kernels composed
-    in program order, intermediates re-emitted for the pre-built
-    backward."""
-    inter = _registry_fn("elementwise_add")(
-        {"X": ins["X"], "Y": ins["Y"]},
-        {"axis": attrs.get("axis", -1)})["Out"]
-    ln = _registry_fn("layer_norm")(
-        {"X": inter, "Scale": ins.get("Scale"), "Bias": ins.get("Bias")},
-        {"epsilon": attrs.get("epsilon", 1e-5),
-         "begin_norm_axis": attrs.get("begin_norm_axis", 1)})
-    return {"Out": ln["Y"], "AddOut": inter, "Mean": ln["Mean"],
-            "Variance": ln["Variance"]}
-
-
 def _flash_attention_grad(ins, attrs):
     """dQ, dK, dV from the forward op's own ``Out`` and ``LSE``: the
     backward kernels alone. XLA does not merge the kernel a ``jax.vjp``

@@ -338,126 +338,6 @@ _op(
 )
 
 
-@register_op(
-    "fused_optimizer",
-    inputs=[In("Param", duplicable=True), In("Grad", duplicable=True),
-            In("LearningRate"),
-            In("StateA", duplicable=True, dispensable=True),
-            In("StateB", duplicable=True, dispensable=True),
-            In("Beta1Pow", duplicable=True, dispensable=True),
-            In("Beta2Pow", duplicable=True, dispensable=True)],
-    outputs=[Out("ParamOut", duplicable=True, is_ref=True),
-             Out("StateAOut", duplicable=True, is_ref=True,
-                 dispensable=True),
-             Out("StateBOut", duplicable=True, is_ref=True,
-                 dispensable=True),
-             Out("Beta1PowOut", duplicable=True, is_ref=True,
-                 dispensable=True),
-             Out("Beta2PowOut", duplicable=True, is_ref=True,
-                 dispensable=True)],
-    attrs={"op_type": "sgd", "layout": "chain", "padded_size": 0,
-           "use_pallas": True},
-    grad=None,
-)
-def _fused_optimizer(ins, attrs):
-    """Single-chip fused optimizer update (core/fusion.py rewrite): ONE
-    traced op replaces an optimizer instance's whole per-param update
-    chain, in one of two layouts:
-
-    - ``layout="chain"`` (default off-TPU): StateA/StateB carry the
-      ORIGINAL per-param accumulators and the shared update math
-      (ops/pallas/fused_optimizer._update_math — expression-identical
-      to the per-param kernels) is applied pair by pair. Zero data
-      movement beyond the updates themselves — on backends where XLA
-      already fuses the elementwise chain, re-laying the state out
-      flat was MEASURED to cost ~40% step time in per-step concats,
-      so the chain layout keeps the op-count win without it.
-    - ``layout="flat"`` (the TPU/pallas layout): StateA/StateB are the
-      single flat re-laid-out state vars (the cross-replica sharded
-      update's mechanism, minus the mesh); params/grads flatten +
-      zero-pad to ``padded_size`` and ONE pallas streaming kernel
-      (ops/pallas/fused_optimizer.py) read-modify-writes the whole
-      buffer; updated params slice back out.
-
-    Elementwise math per element is identical either way, so both
-    layouts are bit-for-bit with the per-param chain (modulo the
-    cross-program FMA-contraction bound tools/sc_smoke.py documents).
-    """
-    import numpy as _np
-
-    from .pallas.fused_optimizer import (_update_math,
-                                         fused_optimizer_update)
-
-    op_type = attrs["op_type"]
-    params, grads = ins["Param"], ins["Grad"]
-    lr = ins["LearningRate"].reshape(())
-    b1pow = ins["Beta1Pow"][0] if ins.get("Beta1Pow") else None
-    b2pow = ins["Beta2Pow"][0] if ins.get("Beta2Pow") else None
-
-    result = {}
-    if attrs.get("layout", "chain") == "flat":
-        sizes = [int(p.size) for p in params]
-        total = sum(sizes)
-        padded = int(attrs.get("padded_size") or total)
-
-        def _flat_pad(xs):
-            flat = xs[0].reshape(-1) if len(xs) == 1 else \
-                jnp.concatenate([x.reshape(-1) for x in xs])
-            if padded > flat.size:
-                flat = jnp.concatenate(
-                    [flat,
-                     jnp.zeros((padded - flat.size,), flat.dtype)])
-            return flat
-
-        sa = ins["StateA"][0] if ins.get("StateA") else None
-        sb = ins["StateB"][0] if ins.get("StateB") else None
-        p_new, sa_out, sb_out = fused_optimizer_update(
-            op_type, attrs, _flat_pad(params), _flat_pad(grads), lr,
-            sa, sb,
-            b1pow.reshape(()) if b1pow is not None else None,
-            b2pow.reshape(()) if b2pow is not None else None,
-            force_pallas=(None if attrs.get("use_pallas", True)
-                          else False))
-        result["ParamOut"] = []
-        off = 0
-        for p, k in zip(params, sizes):
-            result["ParamOut"].append(
-                p_new[off:off + k].reshape(p.shape))
-            off += k
-        result["StateAOut"] = [sa_out] if sa_out is not None else None
-        result["StateBOut"] = [sb_out] if sb_out is not None else None
-    else:
-        sas = ins.get("StateA") or [None] * len(params)
-        sbs = ins.get("StateB") or [None] * len(params)
-        b1s = b1pow.reshape(()) if b1pow is not None else None
-        b2s = b2pow.reshape(()) if b2pow is not None else None
-        p_outs, sa_outs, sb_outs = [], [], []
-        for p, g, sa, sb in zip(params, grads, sas, sbs):
-            po, sao, sbo = _update_math(op_type, attrs, p,
-                                        g.astype(p.dtype), lr, sa, sb,
-                                        b1s, b2s)
-            p_outs.append(po)
-            sa_outs.append(sao)
-            sb_outs.append(sbo)
-        result["ParamOut"] = p_outs
-        result["StateAOut"] = sa_outs if sa_outs[0] is not None \
-            else None
-        result["StateBOut"] = sb_outs if sb_outs[0] is not None \
-            else None
-
-    if ins.get("Beta1Pow"):
-        b1 = attrs.get("beta1", 0.9)
-        result["Beta1PowOut"] = [
-            (b.reshape(()) * b1).reshape(_np.shape(b))
-            for b in ins["Beta1Pow"]]
-    if ins.get("Beta2Pow"):
-        b2 = attrs.get("beta2", 0.999)
-        result["Beta2PowOut"] = [
-            (b.reshape(()) * b2).reshape(_np.shape(b))
-            for b in ins["Beta2Pow"]]
-    return result
-
-
 def _dpsgd(ins, attrs):
     # Differentially-private SGD (operators/optimizers/dpsgd_op.cc):
     # clip-by-norm then noised update. Noise omitted in deterministic mode.

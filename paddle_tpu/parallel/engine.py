@@ -223,16 +223,6 @@ def run_data_parallel(core, program, scope: Scope, feed: Dict,
     # per program — fleet may have transpiled already). Loss/grad
     # scaling is over the DATA axes only: model-parallel axes see the
     # same batch and their sharded grads are already complete.
-    if nranks > 1 and getattr(program, "_fused_optimizer_groups", 0):
-        # the single-chip fused op is invisible to insert_allreduce_ops
-        # (its grads would dodge the reduction — silently divergent
-        # replicas); the mesh-side equivalent of this fusion is the
-        # cross-replica sharded update (PADDLE_TPU_SHARDED_UPDATE)
-        raise ValueError(
-            "program was rewritten by the single-chip fused-optimizer "
-            "pass; unset PADDLE_TPU_FUSED_OPTIMIZER before running it "
-            "on a multi-replica mesh (use PADDLE_TPU_SHARDED_UPDATE "
-            "there instead)")
     if nranks > 1:
         skip_axes = getattr(program, "_allreduce_skip_grads", None) or {}
         insert_allreduce_ops(

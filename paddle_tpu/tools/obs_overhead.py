@@ -143,35 +143,6 @@ def main():
     ok = ok and ver_cost < VERIFY_BUDGET_US \
         and hook_cost < VERIFY_BUDGET_US
 
-    # ISSUE 14: the single-chip fusion / async-feed knobs must default
-    # OFF, and the executor-side hook (two env reads + a branch, on
-    # every run call) gets the same tight per-run budget as the
-    # verifier hook
-    from paddle_tpu.core import fusion as _fusion
-    from paddle_tpu.core import native_feed as _nf
-
-    assert not _fusion.fused_optimizer_enabled(), \
-        "fused optimizer must default off (PADDLE_TPU_FUSED_OPTIMIZER)"
-    assert not _fusion.fused_epilogue_enabled(), \
-        "fused epilogues must default off (PADDLE_TPU_FUSED_EPILOGUE)"
-    assert not _nf.async_feed_enabled(), \
-        "async feed must default off (PADDLE_TPU_ASYNC_FEED)"
-    # steady-state hook cost: the knob is baked in at a program's
-    # first run (program._sc_fusion stamp), so per-step cost is one
-    # getattr + branch — bench exactly that shape
-    class _SeenProgram:
-        _sc_fusion = False
-
-    _seen = _SeenProgram()
-    fusion_cost = _bench_primitive(
-        lambda: _fusion.maybe_rewrite_single_chip(_seen, None))
-    feed_chk = _bench_primitive(_nf.async_feed_enabled)
-    print("fusion/feed disabled cost: maybe_rewrite_single_chip()="
-          "%.3fus async_feed_enabled()=%.3fus (budget %.1fus each)"
-          % (fusion_cost, feed_chk, VERIFY_BUDGET_US))
-    ok = ok and fusion_cost < VERIFY_BUDGET_US \
-        and feed_chk < VERIFY_BUDGET_US
-
     # ISSUE 16: sampled in-production capture must default OFF
     # (PADDLE_TPU_SAMPLE_EVERY unset), and the per-step hook the
     # executors call after EVERY successful step must degenerate to a

@@ -212,3 +212,72 @@ def test_max_nodes_valve_is_conservative():
     # valve fires many times mid-step (including mid-backward)
     assert np.allclose(run(3), ref, rtol=1e-5)
     assert np.allclose(run(7), ref, rtol=1e-5)
+
+
+# -- flush overhead -----------------------------------------------------------
+
+
+def test_lazy_recompiles_stay_flat():
+    """Steady-state lazy training: after warmup, further steps add
+    ZERO lazy.recompiles (the structure signature — including cached
+    ndarray attr digests — is stable across flushes)."""
+    from paddle_tpu import observability as obs
+
+    obs.enable()
+    with fluid.dygraph.guard(lazy=True):
+        l1 = Linear(16, 32, act="relu")
+        l2 = Linear(32, 10)
+        params = l1.parameters() + l2.parameters()
+        opt = fluid.optimizer.AdamOptimizer(1e-3, parameter_list=params)
+        rng = np.random.RandomState(0)
+        x = rng.rand(8, 16).astype("float32")
+        y = rng.randint(0, 10, (8, 1)).astype("int64")
+
+        def step():
+            logits = l2(l1(to_variable(x)))
+            loss = fluid.layers.mean(
+                fluid.layers.softmax_with_cross_entropy(
+                    logits, to_variable(y)))
+            loss.backward()
+            opt.minimize(loss, parameter_list=params)
+            for p in params:
+                p.clear_gradient()
+            return loss
+
+        for _ in range(3):
+            loss = step()
+        float(np.asarray(loss.numpy()).ravel()[0])
+        before = obs.counter_value("lazy.recompiles") or 0
+        for _ in range(3):
+            loss = step()
+        float(np.asarray(loss.numpy()).ravel()[0])
+        after = obs.counter_value("lazy.recompiles") or 0
+    assert after == before, (
+        "lazy steady state recompiled %d times" % (after - before))
+
+
+def test_ndarray_attr_digest_cached():
+    from paddle_tpu.dygraph import tracer as tr
+
+    arr = np.arange(64, dtype="f4").reshape(8, 8)
+    d1 = tr._canon_attr(arr)
+    assert id(arr) in tr._ndarray_digests
+    import hashlib
+
+    calls = []
+    real = hashlib.sha1
+
+    def counting(*a, **kw):
+        calls.append(1)
+        return real(*a, **kw)
+
+    hashlib.sha1 = counting
+    try:
+        d2 = tr._canon_attr(arr)
+    finally:
+        hashlib.sha1 = real
+    assert d1 == d2
+    assert not calls, "cached ndarray attr was re-hashed"
+    # a DIFFERENT array with identical content still hashes by content
+    arr2 = arr.copy()
+    assert tr._canon_attr(arr2) == d1

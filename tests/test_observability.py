@@ -552,18 +552,3 @@ def test_stop_profiler_without_start_keeps_metrics_spans(capsys):
     capsys.readouterr()
     names = [e[0] for e in obs.tracing.trace_events()]
     assert "precious_metrics_span" in names
-
-
-# -- conv stride guard (satellite ops/pallas/conv.py) ----------------------
-
-def test_conv2d_bn_act_rejects_unsupported_stride():
-    import jax.numpy as jnp
-
-    from paddle_tpu.ops.pallas.conv import conv2d_bn_act
-
-    x = jnp.zeros((1, 9, 9, 128), jnp.float32)
-    w = jnp.zeros((3, 3, 128, 128), jnp.float32)
-    with pytest.raises(ValueError, match="stride 1 or 2"):
-        conv2d_bn_act(x, w, stride=3)
-    with pytest.raises(ValueError, match="stride 1 or 2"):
-        conv2d_bn_act(x, w, stride=0)

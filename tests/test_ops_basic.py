@@ -2,6 +2,7 @@
 tests/unittests/test_elementwise_add_op.py, test_mul_op.py,
 test_softmax_op.py, test_conv2d_op.py, test_pool2d_op.py, ...)."""
 import numpy as np
+import pytest
 
 from op_test import OpTest
 
@@ -491,3 +492,67 @@ class TestSum(OpTest):
 
     def test_grad(self):
         self.check_grad(["a", "b"], "Out", max_relative_error=0.01)
+
+
+# ResNet's NHWC shapes (1x1 and 3x3, stride 1 and 2) through the conv2d
+# op's one lowering, lax.conv_general_dilated, in bf16 as AMP runs it:
+# (batch, size, c_in, c_out, kernel, stride, padding, dtype)
+NHWC_CONVS = [
+    (2, 8, 128, 128, 3, 1, 1, "bfloat16"),
+    (2, 8, 128, 256, 1, 1, 0, "bfloat16"),
+    (2, 16, 128, 128, 3, 2, 1, "bfloat16"),
+    (1, 8, 256, 128, 1, 2, 0, "bfloat16"),
+    (2, 8, 128, 128, 3, 1, 1, "float32"),
+]
+
+
+@pytest.mark.parametrize("case", NHWC_CONVS,
+                         ids=["b%d_s%d_%dto%d_k%d_st%d_p%d_%s" % c
+                              for c in NHWC_CONVS])
+def test_conv2d_nhwc_and_its_gradients_match_float32_reference(case):
+    """The registered conv2d and conv2d_grad ops on NHWC operands
+    against a float32 convolution at ``highest`` precision and its
+    ``jax.grad``."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from paddle_tpu.core.registry import OpInfoMap
+
+    b, size, c_in, c_out, k, stride, pad, dtype = case
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.randn(b, size, size, c_in), dtype)
+    w = jnp.asarray(rng.randn(c_out, c_in, k, k) * 0.1, dtype)   # OIHW
+    out_size = (size + 2 * pad - k) // stride + 1
+    ct = jnp.asarray(rng.randn(b, out_size, out_size, c_out), dtype)
+    attrs = {"strides": [stride, stride], "paddings": [pad, pad],
+             "dilations": [1, 1], "groups": 1, "data_format": "NHWC"}
+
+    ops = OpInfoMap.instance()
+    out = ops.get("conv2d").fn({"Input": x, "Filter": w}, attrs)["Output"]
+    grads = ops.get("conv2d_grad").fn(
+        {"Input": x, "Filter": w, "Output@GRAD": ct}, attrs)
+    assert out.dtype == x.dtype and out.shape == ct.shape
+
+    def reference(x, w):
+        return lax.conv_general_dilated(
+            x, w, (stride, stride), [(pad, pad), (pad, pad)],
+            dimension_numbers=("NHWC", "OIHW", "NHWC"),
+            precision="highest")
+
+    x32, w32, ct32 = (a.astype(jnp.float32) for a in (x, w, ct))
+    want = reference(x32, w32)
+    want_dx, want_dw = jax.grad(
+        lambda x, w: jnp.sum(reference(x, w) * ct32), argnums=(0, 1))(
+            x32, w32)
+
+    # each result is rounded once to the operands' type: 2^-9 of its
+    # size in bf16 (products exact, float32 accumulator)
+    tol = 1e-2 if dtype == "bfloat16" else 1e-5
+    for name, got, ref in (("out", out, want),
+                           ("dx", grads["Input@GRAD"], want_dx),
+                           ("dw", grads["Filter@GRAD"], want_dw)):
+        got = np.asarray(got.astype(jnp.float32))
+        assert got.shape == ref.shape, name
+        gap = np.max(np.abs(got - np.asarray(ref))) / np.max(np.abs(ref))
+        assert gap < tol, (name, gap)

@@ -27,9 +27,7 @@ bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
 
 def cases():
     fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
-    fo = importlib.import_module("paddle_tpu.ops.pallas.fused_optimizer")
     pa = importlib.import_module("paddle_tpu.ops.pallas.paged_attention")
-    cv = importlib.import_module("paddle_tpu.ops.pallas.conv")
 
     def flash(causal, block_q, block_k):
         """Forward + backward of the kernels ``flash_attention`` picks
@@ -106,26 +104,6 @@ def cases():
         yield ("moe_grouped_r6144_" + name, grouped,
                [((6144, k), bf16), ((8, k, n), bf16), ((8,), i32)])
 
-    # fused optimizer over a BERT-base-sized flat buffer: adam streams
-    # 4 inputs + 3 outputs of 2048x128 f32, double-buffered ~14 MiB of
-    # the 16 MiB scoped VMEM
-    n = (110_000_000 // fo.LANE_PAD) * fo.LANE_PAD
-    for op_type in ("adam", "momentum"):
-        n_state = fo._n_states(op_type)
-
-        def update(p, g, lr, sa, sb, b1, b2, op_type=op_type,
-                   n_state=n_state):
-            adam = n_state == 2
-            scalars = [lr.reshape(1)] + (
-                [b1.reshape(1), b2.reshape(1)] if adam else [])
-            return fo._pallas_update(
-                op_type, {}, p, g, scalars, sa, sb if adam else None,
-                n_state, adam, interpret=False)
-
-        flat, scalar = ((n,), f32), ((), f32)
-        yield ("fused_%s_110M" % op_type, update,
-               [flat, flat, scalar, flat, flat, scalar, scalar])
-
     # paged attention at the decode engine's geometry
     def paged(q, k_arena, v_arena, tables, lens):
         return pa._paged_pallas(q, k_arena, v_arena, tables, lens,
@@ -135,15 +113,6 @@ def cases():
     arena = ((128, 16, 2, 8), f32)
     yield "paged_b8_h2_d8", paged, [((8, 2, 8), f32), arena, arena,
                                     ((8, 5), i32), ((8,), i32)]
-
-    # conv: one 1x1 and one 3x3 ResNet stage-2 shape
-    for name, w_shape, pad in (("conv_1x1", (1, 1, 128, 512), 0),
-                               ("conv_3x3", (3, 3, 128, 128), 1)):
-        def conv(x, w, pad=pad):
-            return cv.conv2d_bn_act(x, w, stride=1, padding=pad,
-                                    relu=True, interpret=False)
-
-        yield name, conv, [((8, 28, 28, 128), bf16), (w_shape, bf16)]
 
 
 def compile_step(main, startup, loss, feeds, topo_sharding):

@@ -363,113 +363,6 @@ def _m_sharded_contract():
     return False, "dropped sharded param not flagged"
 
 
-def _build_single_chip(optimizer="adam"):
-    """Fresh SINGLE-CHIP training program (no collective transpile) —
-    the input of the ISSUE-14 fusion passes — with startup executed so
-    optimizer state exists for the flat-state splice."""
-    import paddle_tpu as fluid
-
-    scope = fluid.Scope()
-    with fluid.scope_guard(scope):
-        main, startup = fluid.Program(), fluid.Program()
-        with fluid.unique_name.guard(), fluid.program_guard(main,
-                                                            startup):
-            x = fluid.data(name="x", shape=[16, 8], dtype="float32")
-            lbl = fluid.data(name="lbl", shape=[16, 1], dtype="int64")
-            h = fluid.layers.fc(x, size=32, act="gelu")
-            pred = fluid.layers.fc(h, size=10, act="softmax")
-            loss = fluid.layers.mean(
-                fluid.layers.cross_entropy(pred, lbl))
-            if optimizer == "momentum":
-                fluid.optimizer.MomentumOptimizer(0.1, 0.9).minimize(
-                    loss)
-            else:
-                fluid.optimizer.AdamOptimizer(1e-3).minimize(loss)
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
-    return main, scope, loss
-
-
-def _m_fused_optimizer_contract():
-    from paddle_tpu.analysis import ContractViolation
-    from paddle_tpu.analysis.contracts import contract_for
-    from paddle_tpu.core.fusion import apply_fused_optimizer
-
-    main, scope, loss = _build_single_chip()
-    contract = contract_for("fused_optimizer")
-    state = contract.pre(main)
-    n = apply_fused_optimizer(main, scope)
-    assert n >= 1, "fused optimizer pass did not fire"
-    op = _op_of_type(main.global_block(), "fused_optimizer")
-    # sabotage: silently drop the LAST (param, grad) pair — that param
-    # would never be updated again
-    op.inputs["Param"] = op.input("Param")[:-1]
-    op.inputs["Grad"] = op.input("Grad")[:-1]
-    op.outputs["ParamOut"] = op.output("ParamOut")[:-1]
-    try:
-        contract.post(main, state)
-    except ContractViolation as e:
-        return "never be updated" in str(e), str(e)[:300]
-    return False, "dropped fused param not flagged"
-
-
-def _m_fused_optimizer_double_update():
-    from paddle_tpu.analysis import ContractViolation
-    from paddle_tpu.analysis.contracts import contract_for
-    from paddle_tpu.core.fusion import apply_fused_optimizer
-
-    main, scope, loss = _build_single_chip()
-    contract = contract_for("fused_optimizer")
-    state = contract.pre(main)
-    n = apply_fused_optimizer(main, scope)
-    assert n >= 1, "fused optimizer pass did not fire"
-    # sabotage: resurrect a per-param adam op for a param the fused op
-    # already carries — a double update the net must reject
-    import copy
-
-    block = main.global_block()
-    fop = _op_of_type(block, "fused_optimizer")
-    victim_p, victim_g = fop.input("Param")[0], fop.input("Grad")[0]
-    import paddle_tpu as fluid
-    dup = fluid.framework.Operator(
-        block, "adam",
-        {"Param": [victim_p], "Grad": [victim_g],
-         "LearningRate": fop.input("LearningRate"),
-         "Moment1": [victim_p], "Moment2": [victim_p],
-         "Beta1Pow": [victim_p], "Beta2Pow": [victim_p]},
-        {"ParamOut": [victim_p], "Moment1Out": [victim_p],
-         "Moment2Out": [victim_p], "Beta1PowOut": [victim_p],
-         "Beta2PowOut": [victim_p]}, {})
-    dup._id = state["opts"][0][0]  # pose as the original (live) op
-    block.ops.append(dup)
-    try:
-        contract.post(main, state)
-    except ContractViolation as e:
-        return "double update" in str(e), str(e)[:300]
-    return False, "double-updated fused param not flagged"
-
-
-def _m_fused_epilogue_contract():
-    from paddle_tpu.analysis import ContractViolation
-    from paddle_tpu.analysis.contracts import contract_for
-    from paddle_tpu.core.fusion import apply_fused_epilogues
-
-    main, scope, loss = _build_single_chip()
-    contract = contract_for("fused_epilogue")
-    state = contract.pre(main)
-    n = apply_fused_epilogues(main)
-    assert n >= 1, "fused epilogue pass did not fire"
-    # sabotage: drop the re-emitted intermediate (AddOut) binding —
-    # the pre-built gelu_grad op would read a never-written var
-    op = _op_of_type(main.global_block(), "fused_bias_act")
-    op.outputs.pop("AddOut")
-    try:
-        contract.post(main, state)
-    except ContractViolation as e:
-        return "dropped written var" in str(e), str(e)[:300]
-    return False, "dropped epilogue intermediate not flagged"
-
-
 def _build_async_input():
     """Per-grad buckets (tiny cap) so several have real slack before
     their first consumer — the shape the async split fires on."""
@@ -662,12 +555,6 @@ MUTATIONS = [
      _m_bucket_contract),
     ("sharded-contract-drop-param", "sharded update drops a param",
      _m_sharded_contract),
-    ("fused-optimizer-drop-pair", "fused optimizer drops a "
-     "(param, grad) pair", _m_fused_optimizer_contract),
-    ("fused-optimizer-double-update", "param updated per-param AND "
-     "fused", _m_fused_optimizer_double_update),
-    ("fused-epilogue-drop-intermediate", "epilogue fusion loses a "
-     "written var", _m_fused_epilogue_contract),
     ("async-drop-await", "async split loses an await (grads never "
      "written back)", _m_async_drop_await),
     ("async-reader-before-await", "consumer hoisted above its await",
