@@ -61,6 +61,7 @@ FULL = {
     "masked": dict(b=64, h=8, s=256, d=64),
     "short": dict(b=32, h=12, s=512, d=64),
     "short_dp": dict(b=128, h=12, s=128, d=64),
+    "scan": dict(b=1, t=2048, h=16, p=64, g=2, n=128),
     "decode": dict(streams=5, max_tokens=12),
     "dp_steps": 4,
 }
@@ -73,6 +74,7 @@ REHEARSAL = {
     "masked": dict(b=2, h=2, s=128, d=16),
     "short": dict(b=2, h=2, s=128, d=64),
     "short_dp": dict(b=4, h=4, s=128, d=32),
+    "scan": dict(b=1, t=256, h=4, p=64, g=2, n=128),
     "decode": dict(streams=3, max_tokens=6),
     "dp_steps": 2,
 }
@@ -511,6 +513,41 @@ def phase_kernels(sizes, dev_rec, platform, xla):
     if m["h"] * m["d"] % 128 == 0:   # the rehearsal's toy heads are 32 wide
         flash_case("flash_tokens_masked", m, True, lengths, 2e-2, "short",
                    tokens=True)
+
+    # -- the selective scan's kernels against its XLA form ------------------
+    from paddle_tpu.ops import ssm_ops
+    from paddle_tpu.ops.pallas import ssd_scan
+
+    c = sizes["scan"]
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    x, w = (jnp.asarray(rng.randn(c["b"], c["t"], c["h"], c["p"]), bf16)
+            for _ in range(2))
+    b_in, c_in = (jnp.asarray(rng.randn(c["b"], c["t"], c["g"], c["n"]), bf16)
+                  for _ in range(2))
+    dt, cs = ssm_ops._prologue(
+        jnp.asarray(rng.randn(c["b"], c["t"], c["h"]) - 2, f32),
+        jnp.asarray(-np.exp(rng.randn(c["h"])), f32), None, 128, 0)
+    scan_args = (x, dt, cs, b_in, c_in, jnp.asarray(rng.randn(c["h"]), f32))
+    assert ssd_scan.fits(x, b_in, 128), c
+
+    def both(scan):
+        def f(*a):
+            out, vjp = jax.vjp(scan, *a)
+            return (out,) + vjp(w)
+        return f
+
+    kernel = both(lambda *a: ssd_scan.scan(*a, 128, not on_tpu))
+    # forward, state pass, backward kernel
+    n = check_mosaic("ssd_scan", kernel, scan_args, 3)
+    got = jax.jit(kernel)(*scan_args)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(both(lambda *a: ssm_ops._xla_chunked(*a, 128)))(
+            *scan_args)
+    errs = {t: _rel_err(g, r) for t, g, r in zip(
+        ("out", "dx", "ddt", "dcs", "db", "dc", "dd"), got, ref)}
+    # bf16 operands on both sides, summed in another order: as the flash cases
+    assert max(errs.values()) < 2e-2, ("ssd_scan", errs)
+    report["ssd_scan"] = {"mosaic_calls": n, "rel_err": errs, "tol": 2e-2}
 
     # -- paged attention at the decode engine's shapes ----------------------
     from paddle_tpu.serving.decode import DecodeConfig, DecodeEngine

@@ -4,7 +4,10 @@ string, one letter a layer:
 
 - ``M``  Mamba-2: one input projection to gate, convolved ``x | B | C`` and
   step sizes; causal depthwise convolution + silu; the chunked selective
-  scan; gated RMS norm over groups; output projection;
+  scan (``layers.ssd_chunk_scan``: on a TPU, at widths that fill whole
+  tiles, the Pallas kernels of ``ops/pallas/ssd_scan.py``; the same
+  algorithm in XLA einsums everywhere else, ``ops.ssm_ops.scan_path``);
+  gated RMS norm over groups; output projection;
 - ``E``  routed experts, top-k of many without drops over the experts this
   program holds (``layers.moe_topk``), beside a shared expert that every
   token passes;

@@ -1,21 +1,34 @@
 """State-space ops: the causal depthwise convolution and the chunked
 selective scan of Mamba-2 (SSD, arXiv:2405.21060), with its gradient op.
 
-The scan is the chunked algorithm in plain XLA einsums, the first form a
-later kernel is measured against: inside a chunk the masked ``C B^T``
+The scan is the chunked algorithm: inside a chunk the masked ``C B^T``
 product applied to ``dt x``; the state at each chunk's end by one product;
 the recurrence only across the chunk ends; the carried state's part of the
 output by one more product. Decays (``dt A``, their cumulative sums and
 exponentials) and the state are float32 whatever type x, B and C arrive in
 (bf16 under AMP: they are the MXU's operands, accumulation is float32).
+
+It has two forms behind one float32 prologue (``_prologue``: the softplus,
+``dt A`` and its in-chunk cumulative sums) and ``scan_path``, which reads
+the choice from the operands. Where the computation runs on a TPU and the
+shapes fill whole tiles, the Pallas kernels of ``ops/pallas/ssd_scan.py``
+keep a chunk's tiles and the carried state in VMEM (forward; in the gradient
+op a state pass and a backward kernel). Everywhere else, the CPU and every
+shape the kernels' blocks cannot take, the same algorithm runs as plain XLA
+einsums (``_xla_chunked``): the kernels are measured and tested against it.
 """
 from __future__ import annotations
+
+import importlib
 
 import jax
 import jax.numpy as jnp
 
 from ..core.registry import In, Out, register_op
+from .pallas import ssd_scan as _kernels
 
+# the module: the package's attribute of that name is the function
+_fa = importlib.import_module(".pallas.flash_attention", __package__)
 _HI = jax.lax.Precision.HIGHEST
 
 
@@ -45,35 +58,51 @@ def _causal_conv1d(ins, attrs):
     return {"Out": out.astype(x.dtype)}
 
 
-def ssd_chunk_scan(x, dt, A, B, C, D=None, dt_bias=None, chunk=128):
-    """y [B, T, H, P] of the selective scan
-    ``S_t = exp(dt_t A_h) S_{t-1} + dt_t x_t B_t^T``,
-    ``y_t = S_t C_t + D_h x_t`` from a zero state, by chunks of ``chunk``
-    positions. x [B, T, H, P]; A, D, dt_bias [H]; B, C [B, T, G, N] with
-    head h in group ``h // (H / G)``; the step sizes arrive raw, dt [B, T, H],
-    and are ``softplus(dt + dt_bias)`` in float32 from here on. A length that is no
-    multiple of the chunk is padded with positions of dt = 0 (decay 1, no
-    input), which change nothing before them."""
+def _chunks(T, chunk):
+    """(Q, pad): the chunk's length and the positions that fill the last."""
+    Q = min(int(chunk), T)
+    return Q, -T % Q
+
+
+def scan_path(x, b, chunk=128):
+    """"pallas" | "xla_chunked": which form of the scan these operands
+    take. The kernels where the computation runs on a TPU (asked through
+    ``ops.pallas.flash_attention``, as ``benchmarks/aot_sizing.py`` answers
+    there) and the padded length, the chunk, the heads of a group and the
+    state fill the kernels' blocks (``ops.pallas.ssd_scan.fits``)."""
+    Q, pad = _chunks(x.shape[1], chunk)
+    padded = jax.ShapeDtypeStruct(
+        (x.shape[0], x.shape[1] + pad) + x.shape[2:], x.dtype)
+    on_tpu = _fa.compute_platform() == "tpu"
+    return "pallas" if on_tpu and _kernels.fits(padded, b, Q) \
+        else "xla_chunked"
+
+
+def _prologue(dt, A, dt_bias, Q, pad):
+    """Both forms' float32 head: (``softplus(dt + dt_bias)`` [B, T + pad,
+    H], the cumulative sums of ``dt A`` inside each chunk of Q positions,
+    same shape). The padding is positions of dt = 0: decay 1, no input."""
+    f32 = jnp.float32
+    dt = dt.astype(f32)
+    if dt_bias is not None:
+        dt = dt + dt_bias.astype(f32)
+    dt = jnp.pad(jax.nn.softplus(dt), ((0, 0), (0, pad), (0, 0)))
+    a = (dt * A.astype(f32)).reshape(dt.shape[0], -1, Q, dt.shape[2])
+    return dt, jnp.cumsum(a, axis=2).reshape(dt.shape)     # a <= 0
+
+
+def _xla_chunked(x, dt, cs, B, C, D, Q):
+    """The scan over whole chunks in XLA einsums: y [B, T, H, P]."""
     f32 = jnp.float32
     Bsz, T, H, P = x.shape
     G, N = B.shape[2], B.shape[3]
     mxu = x.dtype
-    dt = dt.astype(f32)
-    if dt_bias is not None:
-        dt = dt + dt_bias.astype(f32)
-    dt = jax.nn.softplus(dt)
-    Q = min(int(chunk), T)
-    pad = -T % Q
-    if pad:
-        x, dt, B, C = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-                       for a in (x, dt, B, C))
-    nc = (T + pad) // Q
+    nc = T // Q
     xc = x.reshape(Bsz, nc, Q, G, H // G, P)
     dtc = dt.reshape(Bsz, nc, Q, G, H // G)
+    cs = cs.reshape(Bsz, nc, Q, G, H // G)                 # [b,c,Q,g,r]
     Bc = B.reshape(Bsz, nc, Q, G, N).astype(mxu)
     Cc = C.reshape(Bsz, nc, Q, G, N).astype(mxu)
-    a = dtc * A.astype(f32).reshape(G, H // G)            # <= 0
-    cs = jnp.cumsum(a, axis=2)                             # [b,c,Q,g,r]
     xdt32 = xc.astype(f32) * dtc[..., None]
     xdt = xdt32.astype(mxu)
 
@@ -107,7 +136,36 @@ def ssd_chunk_scan(x, dt, A, B, C, D=None, dt_bias=None, chunk=128):
         * jnp.exp(cs)[..., None]
     if D is not None:
         y = y + xc.astype(f32) * D.astype(f32).reshape(G, H // G, 1)
-    return y.reshape(Bsz, nc * Q, H, P)[:, :T].astype(x.dtype)
+    return y.reshape(Bsz, T, H, P).astype(x.dtype)
+
+
+def _pad_time(a, pad):
+    return jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+
+
+def ssd_chunk_scan(x, dt, A, B, C, D=None, dt_bias=None, chunk=128):
+    """y [B, T, H, P] of the selective scan
+    ``S_t = exp(dt_t A_h) S_{t-1} + dt_t x_t B_t^T``,
+    ``y_t = S_t C_t + D_h x_t`` from a zero state, by chunks of ``chunk``
+    positions. x [B, T, H, P]; A, D, dt_bias [H]; B, C [B, T, G, N] with
+    head h in group ``h // (H / G)``; the step sizes arrive raw, dt [B, T, H],
+    and are ``softplus(dt + dt_bias)`` in float32 from here on. A length that is no
+    multiple of the chunk is padded with positions of dt = 0 (decay 1, no
+    input), which change nothing before them. Differentiable in either
+    form (``scan_path``); each trace counts
+    ``kernels.ssd_chunk_scan{path=pallas|xla_chunked}``."""
+    from .. import observability as _obs
+
+    T = x.shape[1]
+    Q, pad = _chunks(T, chunk)
+    path = scan_path(x, B, chunk)
+    if _obs.enabled():
+        _obs.inc("kernels.ssd_chunk_scan", path=path)
+    dt, cs = _prologue(dt, A, dt_bias, Q, pad)
+    if pad:
+        x, B, C = (_pad_time(a, pad) for a in (x, B, C))
+    form = _kernels.scan if path == "pallas" else _xla_chunked
+    return form(x, dt, cs, B, C, D, Q)[:, :T]
 
 
 def _scan(v, attrs):
@@ -118,14 +176,20 @@ def _scan(v, attrs):
 
 
 def _ssd_chunk_scan_grad(ins, attrs):
-    """The scan's gradients from its inputs alone: the forward is run again
-    inside (behind an optimization barrier, so that XLA cannot fold the
-    copy into the forward op's and keep its per-position intermediates
-    alive until here), and nothing but x, dt, A, B, C, D lives from the
-    forward to the backward."""
+    """The scan's gradients from its inputs alone: nothing but x, dt, A, B,
+    C, D, dt_bias lives from the forward to the backward. ``jax.vjp`` of the
+    function in either form. The kernels' form is then the state pass and
+    the backward kernel between the prologue and its gradient (its forward
+    kernel's result is not used, and XLA drops the call). The XLA form runs
+    the forward again, behind an optimization barrier, so that XLA cannot
+    fold the copy into the forward op's and keep its per-position
+    intermediates alive until here."""
     names = [n for n in ("X", "Dt", "A", "B", "C", "D", "DtBias")
              if ins.get(n) is not None]
-    vals = jax.lax.optimization_barrier(tuple(ins[n] for n in names))
+    vals = tuple(ins[n] for n in names)
+    if scan_path(ins["X"], ins["B"], int(attrs.get("chunk", 128))) \
+            == "xla_chunked":
+        vals = jax.lax.optimization_barrier(vals)
     out, vjp = jax.vjp(lambda *vals: _scan(dict(zip(names, vals)), attrs),
                        *vals)
     grads = vjp(ins["Out@GRAD"].astype(out.dtype))
@@ -156,10 +220,5 @@ def _ssd_chunk_scan(ins, attrs):
     """Mamba-2's selective scan over [B, T, H, P] (see ``ssd_chunk_scan``
     above for the equations and shapes). ``DtBias`` is added to ``Dt`` and
     the softplus applied inside, in float32, so that the step sizes and
-    decays never pass through the AMP type. Each trace of the op counts
-    ``kernels.ssd_chunk_scan{path=xla_chunked}``."""
-    from .. import observability as _obs
-
-    if _obs.enabled():
-        _obs.inc("kernels.ssd_chunk_scan", path="xla_chunked")
+    decays never pass through the AMP type."""
     return {"Out": _scan(ins, attrs)}

@@ -177,7 +177,10 @@ def test_bert_step_for_v5e_holds_one_forward_kernel_a_layer(v5e_compile):
         % (n, n) for n in (2, 1)]
 
 
-def test_recomputation_lowers_the_compiled_steps_temporaries(v5e_compile):
+@pytest.mark.parametrize("state_size, scan_calls, scans, recomputing", [
+    (128, 9, "3/3/3", "6/3/3"), (64, 0, "0/0/0", "0/0/0")])
+def test_recomputation_lowers_the_compiled_steps_temporaries(
+        v5e_compile, state_size, scan_calls, scans, recomputing):
     """A six-layer hybrid state-space / MoE step with
     ``RecomputeOptimizer`` over the layers' inputs holds fewer temporaries
     than without: the barrier on the checkpoint values keeps XLA from
@@ -185,12 +188,20 @@ def test_recomputation_lowers_the_compiled_steps_temporaries(v5e_compile):
     real configuration's step compiled to the same 7.898 GiB either way,
     PERF.md section 6, PR 27). The step also holds the streaming attention
     kernels for shared K/V heads and the grouped-product kernels of the
-    experts (megablox on the TPU, so no ``ragged-dot`` is left)."""
+    experts (megablox on the TPU, so no ``ragged-dot`` is left). With states
+    of 128 it holds the selective scan's kernels: for its three ``M`` layers
+    three forwards, state passes and backward kernels, and with
+    recomputation each forward once more (the gradient op runs the state
+    pass, not a third forward). With states of 64, which the kernels'
+    blocks cannot take, the scan is the XLA form on the TPU, its gradient
+    op's ``jax.vjp`` behind a barrier included."""
     (line,) = [x for x in v5e_compile.stdout.splitlines()
-               if x.startswith("HYBRID_STEP ")]
-    got = dict(kv.split("=") for kv in line.split()[2:])
+               if x.startswith("HYBRID_STEP state=%d " % state_size)]
+    got = dict(kv.split("=") for kv in line.split()[1:])
     assert int(got["checkpoints"]) < 0.8 * int(got["plain"]), line
-    assert int(got["mosaic_calls"]) >= 3 + 6 and int(got["ragged_dots"]) == 0
+    assert int(got["mosaic_calls"]) >= 3 + 6 + scan_calls, line
+    assert int(got["ragged_dots"]) == 0, line
+    assert (got["scans"], got["scans_recomputing"]) == (scans, recomputing)
 
 
 def test_bert_step_report_counts_what_it_names():

@@ -1,7 +1,8 @@
 """The ops of the hybrid state-space / mixture-of-experts model against
 plain definitions, at small sizes on the CPU: ``rms_norm``,
 ``causal_conv1d``, ``ssd_chunk_scan`` and its gradient op against the
-literal recurrence, ``moe_topk`` against a dense loop over all experts
+literal recurrence (the XLA form, and the Pallas kernels in interpret mode
+with what decides between them), ``moe_topk`` against a dense loop over all experts
 (shares add up, full skew, no held expert), grouped-query heads on the
 streaming ``flash_attention`` kernels in interpret mode, the AMP rewrite's
 float32 slots, and the tiny model through ``fluid.Executor`` with AMP and
@@ -19,6 +20,8 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu.core.registry import OpInfoMap
 from paddle_tpu.ops.moe_ops import buffer_rows, moe_topk
+from paddle_tpu.ops import ssm_ops
+from paddle_tpu.ops.pallas import ssd_scan
 from paddle_tpu.ops.ssm_ops import ssd_chunk_scan
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -162,6 +165,170 @@ def test_ssd_chunk_scan_op_adds_the_step_sizes_bias():
              "DtBias": bias}, {"chunk": 16})["Out"]
         want = recurrence(x, raw + bias, a, b, c, d)
     assert rel(got, want) < 1e-5
+
+
+# -- the same scan on the Pallas kernels (interpret mode) ---------------------
+
+SLOTS = ("X", "Dt", "A", "B", "C", "D", "DtBias")
+# (heads, head dim, groups): the hybrid model's head width, and one above and
+# one below it
+LAYOUTS = {"p64": (4, 64, 2), "p128": (2, 128, 2), "p32": (4, 32, 1)}
+
+
+def tile_inputs(t, seed=0, layout="p64", n=128):
+    """``scan_inputs`` at shapes that fill the kernels' blocks, and the step
+    sizes' bias."""
+    k = keys(7, seed)
+    bsz, (h, p, g) = 2, LAYOUTS[layout]
+    return (jax.random.normal(k[0], (bsz, t, h, p)),
+            jax.random.normal(k[1], (bsz, t, h)) - 2,
+            -jnp.exp(jax.random.normal(k[2], (h,))),
+            jax.random.normal(k[3], (bsz, t, g, n)),
+            jax.random.normal(k[4], (bsz, t, g, n)),
+            1 + 0.1 * jax.random.normal(k[5], (h,)),
+            0.5 * jax.random.normal(k[6], (h,)) - 0.5)
+
+
+@pytest.fixture
+def kernels_here(monkeypatch):
+    """The program's question answered as a TPU would, and the kernels it
+    then takes run in interpret mode, as the grouped kernels' test does."""
+    scan = ssd_scan.scan
+    monkeypatch.setattr(ssm_ops._fa, "compute_platform", lambda: "tpu")
+    monkeypatch.setattr(ssd_scan, "scan", lambda *a: scan(*a, True))
+
+
+def slots(args):
+    return dict(zip(SLOTS, args))
+
+
+# several chunks, one chunk, a length that is no multiple of the chunk; the
+# other two head widths
+@pytest.mark.parametrize("t,layout", [(384, "p64"), (128, "p64"),
+                                      (200, "p64"), (256, "p128"),
+                                      (256, "p32")])
+def test_the_scans_kernels_are_the_recurrence(kernels_here, t, layout):
+    args = tile_inputs(t, 1, layout)
+    x, dt, a, b, c, d, bias = args
+    assert ssm_ops.scan_path(x, b, 128) == "pallas"
+    with jax.default_matmul_precision("highest"):
+        got = ssd_chunk_scan(x, dt, a, b, c, d, dt_bias=bias)
+        want = recurrence(x, dt + bias, a, b, c, d)
+    assert rel(got, want) < 1e-5
+    low = ssd_chunk_scan(x.astype(jnp.bfloat16), dt, a,
+                         b.astype(jnp.bfloat16), c.astype(jnp.bfloat16), d,
+                         dt_bias=bias)
+    assert low.dtype == jnp.bfloat16 and rel(low, want) < 3e-2
+
+
+@pytest.mark.parametrize("t,layout", [(256, "p64"), (200, "p64"),
+                                      (256, "p128"), (256, "p32")])
+def test_the_kernels_gradients_are_the_recurrences(kernels_here, t, layout):
+    """Every gradient, from the gradient op and from ``jax.grad`` of the
+    function: the state pass and the backward kernel between the prologue
+    and its gradient, through the kernels' ``custom_vjp``."""
+    args = tile_inputs(t, 3, layout)
+    g = jax.random.normal(keys(1, 4)[0], args[0].shape)
+
+    def plain(x, dt, a, b, c, d, bias):
+        return jnp.sum(recurrence(x, dt + bias, a, b, c, d) * g)
+
+    def mine(x, dt, a, b, c, d, bias):
+        return jnp.sum(ssd_chunk_scan(x, dt, a, b, c, d, dt_bias=bias) * g)
+
+    with jax.default_matmul_precision("highest"):
+        got = op("ssd_chunk_scan_grad")(dict(slots(args), **{"Out@GRAD": g}),
+                                        {"chunk": 128})
+        also = jax.grad(mine, tuple(range(7)))(*args)
+        want = jax.grad(plain, tuple(range(7)))(*args)
+    for name, ref, fn in zip(SLOTS, want, also):
+        # A's and DtBias's are sums over every position, of both signs: at
+        # these lengths the chunked algorithm's float32 sums differ from the
+        # recurrence's by up to 1.3e-5 of the largest entry in either form
+        # (against a float64 recurrence: XLA form 1.1e-5, kernels 1.3e-5)
+        tol = 5e-5 if name in ("A", "DtBias") else 1e-5
+        assert rel(got[name + "@GRAD"], ref) < tol, name
+        assert rel(fn, ref) < tol, name
+
+
+def test_the_kernels_and_the_xla_form_agree_on_bf16_operands(monkeypatch,
+                                                             kernels_here):
+    """The same inputs through both forms, as the chip's check makes it:
+    bf16 x, B, C and cotangent, float32 decays. D and DtBias left out."""
+    x, dt, a, b, c, _, _ = tile_inputs(256, 5)
+    bf16 = jnp.bfloat16
+    ins = {"X": x.astype(bf16), "Dt": dt.astype(bf16), "A": a,
+           "B": b.astype(bf16), "C": c.astype(bf16),
+           "Out@GRAD": jax.random.normal(keys(1, 6)[0], x.shape, bf16)}
+    got = (op("ssd_chunk_scan")(ins, {"chunk": 128})["Out"],
+           op("ssd_chunk_scan_grad")(ins, {"chunk": 128}))
+    monkeypatch.setattr(ssm_ops._fa, "compute_platform", lambda: "cpu")
+    want = (op("ssd_chunk_scan")(ins, {"chunk": 128})["Out"],
+            op("ssd_chunk_scan_grad")(ins, {"chunk": 128}))
+    assert got[0].dtype == bf16 and rel(got[0], want[0]) < 2e-2
+    assert set(got[1]) == set(want[1]) == {
+        n + "@GRAD" for n in ("X", "Dt", "A", "B", "C")}
+    for name, ref in want[1].items():
+        assert got[1][name].dtype == ref.dtype, name
+        assert rel(got[1][name], ref.astype(jnp.float32)) < 2e-2, name
+
+
+def like(shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+@pytest.mark.parametrize("platform,x,b,chunk,want", [
+    # the hybrid cell's shape, where the computation runs on a TPU ...
+    ("tpu", (1, 8192, 64, 64), (1, 8192, 8, 128), 128, "pallas"),
+    # ... and where it does not
+    ("cpu", (1, 8192, 64, 64), (1, 8192, 8, 128), 128, "xla_chunked"),
+    # a length the existing padding makes whole chunks of
+    ("tpu", (2, 1000, 8, 64), (2, 1000, 2, 128), 128, "pallas"),
+    # ... and any number of heads a group: the kernels walk them one by one
+    ("tpu", (2, 512, 6, 64), (2, 512, 2, 128), 128, "pallas"),
+    # what the blocks cannot take: a short sequence (its chunk is T), a
+    # chunk of no whole lane tiles, a narrow state, heads of no whole
+    # 32-sublane slices
+    ("tpu", (2, 96, 8, 64), (2, 96, 2, 128), 128, "xla_chunked"),
+    ("tpu", (2, 512, 8, 64), (2, 512, 2, 128), 64, "xla_chunked"),
+    ("tpu", (2, 512, 8, 64), (2, 512, 2, 64), 128, "xla_chunked"),
+    ("tpu", (2, 512, 8, 48), (2, 512, 2, 128), 128, "xla_chunked"),
+])
+def test_scan_path_reads_the_platform_and_the_shapes(monkeypatch, platform,
+                                                     x, b, chunk, want):
+    monkeypatch.setattr(ssm_ops._fa, "compute_platform", lambda: platform)
+    assert ssm_ops.scan_path(like(x), like(b), chunk) == want
+    assert ssm_ops.scan_path(like(x, jnp.float32), like(b, jnp.float32),
+                             chunk) == want
+    assert ssm_ops.scan_path(like(x, jnp.float16), like(b, jnp.float16),
+                             chunk) == "xla_chunked"
+
+
+def test_each_traced_scan_op_counts_the_form_it_took(kernels_here,
+                                                     monkeypatch):
+    from paddle_tpu import observability as obs
+
+    args = tile_inputs(128, 7)
+    ins = dict(slots(args), **{"Out@GRAD": args[0]})
+
+    def counted():
+        got = obs.dump()["counters"]
+        return {k.split("path=")[1].rstrip("}"): v for k, v in got.items()
+                if k.startswith("kernels.ssd_chunk_scan{")}
+
+    obs.enable()
+    try:
+        before = counted()
+        op("ssd_chunk_scan")(ins, {"chunk": 128})
+        op("ssd_chunk_scan_grad")(ins, {"chunk": 128})
+        assert counted().get("pallas", 0) == before.get("pallas", 0) + 2
+        monkeypatch.setattr(ssm_ops._fa, "compute_platform", lambda: "cpu")
+        op("ssd_chunk_scan_grad")(ins, {"chunk": 128})
+        after = counted()
+    finally:
+        obs.disable()
+    assert after["pallas"] == before.get("pallas", 0) + 2
+    assert after["xla_chunked"] == before.get("xla_chunked", 0) + 1
 
 
 # -- top-k routing over the experts held here ---------------------------------

@@ -104,6 +104,17 @@ def cases():
         yield ("moe_grouped_r6144_" + name, grouped,
                [((6144, k), bf16), ((8, k, n), bf16), ((8,), i32)])
 
+    # the selective scan's kernels at the hybrid cell's shape (64 heads of
+    # 64 in 8 groups, states of 128, chunks of 128, T = 8192), as its two
+    # ops run them: the forward; the state pass and the backward kernel
+    ss = importlib.import_module("paddle_tpu.ops.pallas.ssd_scan")
+    x, dt = ((1, 8192, 64, 64), bf16), ((1, 8192, 64), f32)
+    bc, d = ((1, 8192, 8, 128), bf16), ((64,), f32)
+    yield ("ssd_scan_fwd_s8192",
+           lambda *a: ss.forward(*a, chunk=128), [x, dt, dt, bc, bc, d])
+    yield ("ssd_scan_state_bwd_s8192",
+           lambda *a: ss.backward(*a, chunk=128), [x, dt, dt, bc, bc, d, x])
+
     # paged attention at the decode engine's geometry
     def paged(q, k_arena, v_arena, tables, lens):
         return pa._paged_pallas(q, k_arena, v_arena, tables, lens,
@@ -171,10 +182,13 @@ def bert_step(topo_sharding, layers=2, batch=4, seq=512):
     return compile_step(main, startup, loss, feeds, topo_sharding).as_text()
 
 
-def hybrid_step_temporaries(topo_sharding, recompute, seq=2048):
+def hybrid_step_temporaries(topo_sharding, recompute, state_size, seq=2048):
     """``temp_size_in_bytes`` of a six-layer hybrid state-space / MoE
     training step (bf16 AMP, Adam) compiled for the described chip, with
-    or without ``RecomputeOptimizer`` over the layers' inputs."""
+    or without ``RecomputeOptimizer`` over the layers' inputs; its Mosaic
+    calls, ``ragged-dot`` instructions and the selective scan's kernels by
+    name. States of 128 fill the scan kernels' blocks; states of 64 do not,
+    and the scan is then the XLA form on the TPU too."""
     import paddle_tpu as fluid
     from paddle_tpu import models
     from paddle_tpu.contrib import mixed_precision as mp
@@ -187,7 +201,7 @@ def hybrid_step_temporaries(topo_sharding, recompute, seq=2048):
         labels = fluid.data(name="labels", shape=[t, 1], dtype="int64")
         logits = models.hybrid_ssm_moe(
             src, "MEM*EM", v, 512, mamba_heads=16, mamba_head_dim=64,
-            n_groups=2, state_size=64, num_experts=16, top_k=2,
+            n_groups=2, state_size=state_size, num_experts=16, top_k=2,
             expert_dim=256, shared_dim=512, held=[0, 4], num_heads=8,
             num_kv_heads=2, head_dim=128, checkpoints=checkpoints)
         loss = fluid.layers.mean(fluid.layers.softmax_with_cross_entropy(
@@ -202,8 +216,12 @@ def hybrid_step_temporaries(topo_sharding, recompute, seq=2048):
         {"src": ((1, t), "int64"), "labels": ((t, 1), "int64")},
         topo_sharding)
     hlo = compiled.as_text()
+    calls = [x.split("=")[0] for x in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in x]
+    scans = [sum(name in x for x in calls)
+             for name in ("ssd_scan_fwd", "ssd_scan_state", "ssd_scan_bwd")]
     return (compiled.memory_analysis().temp_size_in_bytes,
-            hlo.count("tpu_custom_call"), hlo.count("ragged-dot"))
+            hlo.count("tpu_custom_call"), hlo.count("ragged-dot"), scans)
 
 
 def bert_step_report(hlo, seq=512) -> str:
@@ -263,16 +281,23 @@ def compile_all_for_v5e() -> int:
             failed += 1
             print("FAIL bert_step T=%d %s: %s" % (
                 seq, type(e).__name__, str(e)[:800].replace("\n", " | ")))
-    try:
-        plain = hybrid_step_temporaries(sharding, False)
-        saved = hybrid_step_temporaries(sharding, True)
-        print("HYBRID_STEP temporaries plain=%d checkpoints=%d "
-              "mosaic_calls=%d ragged_dots=%d"
-              % (plain[0], saved[0], plain[1], plain[2]))
-    except Exception as e:  # noqa: BLE001 — reported like a case
-        failed += 1
-        print("FAIL hybrid_step %s: %s" % (type(e).__name__,
-                                           str(e)[:800].replace("\n", " | ")))
+    # both sides of ``ssm_ops.scan_path`` on the TPU: the kernels, and the
+    # XLA form with its ``jax.vjp`` behind a barrier in the gradient op
+    for state_size in (128, 64):
+        try:
+            plain = hybrid_step_temporaries(sharding, False, state_size)
+            saved = hybrid_step_temporaries(sharding, True, state_size)
+            print("HYBRID_STEP state=%d plain=%d checkpoints=%d "
+                  "mosaic_calls=%d ragged_dots=%d scans=%s "
+                  "scans_recomputing=%s"
+                  % (state_size, plain[0], saved[0], plain[1], plain[2],
+                     "/".join(map(str, plain[3])),
+                     "/".join(map(str, saved[3]))))
+        except Exception as e:  # noqa: BLE001 — reported like a case
+            failed += 1
+            print("FAIL hybrid_step state=%d %s: %s" % (
+                state_size, type(e).__name__,
+                str(e)[:800].replace("\n", " | ")))
     return 1 if failed else 0
 
 
