@@ -62,6 +62,7 @@ FULL = {
     "short": dict(b=32, h=12, s=512, d=64),
     "short_dp": dict(b=128, h=12, s=128, d=64),
     "scan": dict(b=1, t=2048, h=16, p=64, g=2, n=128),
+    "selected": dict(b=1, h=32, kv=4, s=2048, d=128, keep=512),
     "decode": dict(streams=5, max_tokens=12),
     "dp_steps": 4,
 }
@@ -75,6 +76,7 @@ REHEARSAL = {
     "short": dict(b=2, h=2, s=128, d=64),
     "short_dp": dict(b=4, h=4, s=128, d=32),
     "scan": dict(b=1, t=256, h=4, p=64, g=2, n=128),
+    "selected": dict(b=1, h=4, kv=2, s=256, d=16, keep=64),
     "decode": dict(streams=3, max_tokens=6),
     "dp_steps": 2,
 }
@@ -513,6 +515,50 @@ def phase_kernels(sizes, dev_rec, platform, xla):
     if m["h"] * m["d"] % 128 == 0:   # the rehearsal's toy heads are 32 wide
         flash_case("flash_tokens_masked", m, True, lengths, 2e-2, "short",
                    tokens=True)
+
+    # -- the streaming kernels with a per-query key selection ---------------
+    # shared K/V heads, causal, each query keeping ``keep`` of its causal
+    # keys (all of them where it has fewer): forward and backward against
+    # the dense masked form
+    c = sizes["selected"]
+    q, w = (jnp.asarray(rng.randn(c["b"], c["h"], c["s"], c["d"]),
+                        jnp.bfloat16) for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(c["b"], c["kv"], c["s"], c["d"]),
+                        jnp.bfloat16) for _ in range(2))
+    draw = rng.rand(c["b"], c["s"], c["s"]) + np.triu(
+        np.full((c["s"], c["s"]), 2.0), 1)           # keys after t: never
+    select = jnp.asarray((draw < 2.0) & (draw <= np.sort(draw, -1)[
+        ..., c["keep"] - 1:c["keep"]]), jnp.int8)
+    assert (int(select[0, -1].sum()), int(select[0, 0].sum())) == (
+        c["keep"], 1)
+    scale = float(c["d"]) ** -0.5
+    block = {} if c["s"] > 1024 else {"block_q": c["s"] // 2,
+                                      "block_k": c["s"] // 2}
+
+    def selected(attend):
+        def f(q, k, v):
+            def loss(q, k, v):
+                o = attend(q, k, v)
+                return jnp.sum(o.astype(jnp.float32)
+                               * w.astype(jnp.float32)), o
+            (_, o), g = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                           has_aux=True)(q, k, v)
+            return (o,) + g
+        return f
+
+    kernel = selected(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=True, scale=scale, force_pallas=True, select=select,
+        **block))
+    n = check_mosaic("flash_selected", kernel, (q, k, v), 3)
+    got = jax.jit(kernel)(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(selected(lambda q, k, v: fa._dense_attention(
+            q, k, v, True, scale, select=select)))(q, k, v)
+    errs = {t: _rel_err(g, r)
+            for t, g, r in zip(("out", "dq", "dk", "dv"), got, ref)}
+    assert max(errs.values()) < 2e-2, ("flash_selected", errs)
+    report["flash_selected"] = {"mosaic_calls": n, "rel_err": errs,
+                                "tol": 2e-2, "keys_a_query": c["keep"]}
 
     # -- the selective scan's kernels against its XLA form ------------------
     from paddle_tpu.ops import ssm_ops

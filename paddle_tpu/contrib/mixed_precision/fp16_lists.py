@@ -56,6 +56,11 @@ white_list = {
     "ssd_chunk_scan",
     # the held experts' grouped products; see fp32_slots for the router
     "moe_topk",
+    # the attention's own q, k as its kernels multiply them; see fp32_slots
+    # for the indexer's operands. ``attn_index_project`` and
+    # ``attn_index_select`` are on no list: every operand of an op the
+    # rewrite does not know stays float32, which is what a choice needs
+    "attn_index_loss",
 }
 
 # input slots of white-list ops that stay float32: what sets a decay or a
@@ -63,6 +68,7 @@ white_list = {
 fp32_slots = {
     "ssd_chunk_scan": frozenset(("A", "D", "DtBias")),
     "moe_topk": frozenset(("X", "RouterW", "Bias")),
+    "attn_index_loss": frozenset(("QI", "KI", "W", "LSE")),
 }
 
 # numerically sensitive reductions/losses/normalizations: keep f32

@@ -958,7 +958,7 @@ __all__ += ["sequence_slice", "sequence_unpad", "im2sequence",
 
 
 def flash_attention(q, k, v, causal=False, scale=0.0, lengths=None,
-                    num_heads=0):
+                    num_heads=0, select=None, return_lse=False):
     """Fused attention over q [B, H, S, D] and k, v [B, H_kv, S, D], H_kv
     dividing H (the multihead hot path; fewer K/V heads are shared —
     reference fused/multihead_matmul_op.cu), or over token-major q, k, v
@@ -968,9 +968,13 @@ def flash_attention(q, k, v, causal=False, scale=0.0, lengths=None,
     kernels on TPU (which ones is the op's choice, from the shapes);
     ``apply_sequence_parallel`` rewrites it to ring attention over an
     'sp' mesh axis for long-context training. ``lengths`` ([B] int)
-    masks padded keys inside the kernel. The op also binds ``LSE``, the
-    residual its grad op reads, so the backward runs no second
-    forward."""
+    masks padded keys inside the kernel. ``select`` ([B, S, S] int8, as
+    ``attn_index_select`` makes it) is a per-query key selection shared by
+    the heads, applied inside the streaming kernels beside the causal mask
+    (head-major operands). The op also binds ``LSE``, the residual its grad
+    op reads, so the backward runs no second forward; ``return_lse`` hands
+    it out too (with a selection: [B*H, S, 1] float32 wherever the op
+    runs)."""
     helper = LayerHelper("flash_attention", input=q)
     out = helper.create_variable_for_type_inference(q.dtype)
     lse = helper.create_variable_for_type_inference("float32",
@@ -978,6 +982,8 @@ def flash_attention(q, k, v, causal=False, scale=0.0, lengths=None,
     ins = {"Q": [q], "K": [k], "V": [v]}
     if lengths is not None:
         ins["Lengths"] = [lengths]
+    if select is not None:
+        ins["Select"] = [select]
     # no shape inference: it would trace the kernels at build time (and
     # count a path that no step runs); Out has Q's shape
     helper.append_op(
@@ -988,7 +994,7 @@ def flash_attention(q, k, v, causal=False, scale=0.0, lengths=None,
         infer_shape=False)
     if not framework.in_dygraph_mode():
         out.shape = tuple(q.shape)
-    return out
+    return (out, lse) if return_lse else out
 
 
 def switch_moe(input, num_experts, hidden_dim, capacity_factor=1.0,
@@ -1098,12 +1104,17 @@ def ssd_chunk_scan(x, dt, A, B, C, D=None, dt_bias=None, chunk=128):
 
 def moe_topk(input, num_experts, k, hidden_dim, held=None, scaling=1.0,
              norm_topk=True, correction_bias=None, return_load=False,
-             param_attr=None, name=None):
-    """Routed experts over [T, D] tokens, without drops: sigmoid scores
-    over all ``num_experts``, the ``k`` largest of score + correction
+             param_attr=None, name=None, scoring="sigmoid",
+             expert="relu2"):
+    """Routed experts over [T, D] tokens, without drops: scores over all
+    ``num_experts`` (``scoring``: ``sigmoid`` of each logit, or a
+    ``softmax`` over all of them), the ``k`` largest of score + correction
     bias chosen (the bias enters the choice only), weights ``scaling *
     s / sum of the chosen s`` (``norm_topk``), expert ``relu(u W1)^2 W2``
-    (not gated). ``held = [first, count]`` are the experts this program
+    (``expert`` ``relu2``, not gated) or ``(silu(u W1) * (u W3)) W2``
+    (``swiglu``: a third matrix ``W3``, created after the other two; the
+    op reads the form from whether it is bound).
+    ``held = [first, count]`` are the experts this program
     holds (all by default): their weights alone are created and their
     part of the sum alone is computed, which is what one rank of an
     expert-parallel deployment does (ops/moe_ops.py). ``correction_bias``
@@ -1139,21 +1150,134 @@ def moe_topk(input, num_experts, k, hidden_dim, held=None, scaling=1.0,
     out = helper.create_variable_for_type_inference(dtype)
     load = helper.create_variable_for_type_inference("int32",
                                                      stop_gradient=True)
+    inputs = {"X": [input], "RouterW": [router_w], "Bias": [bias],
+              "W1": [w1], "W2": [w2]}
+    if expert not in ("relu2", "swiglu"):
+        raise ValueError("moe_topk: no expert %r" % (expert,))
+    if expert == "swiglu":
+        inputs["W3"] = [helper.create_parameter(
+            attr=helper.param_attr, shape=[count, d, hidden_dim],
+            dtype=dtype)]
     # no shape inference: it would trace (and count) the op at build time
     helper.append_op(
-        "moe_topk",
-        inputs={"X": [input], "RouterW": [router_w], "Bias": [bias],
-                "W1": [w1], "W2": [w2]},
+        "moe_topk", inputs=inputs,
         outputs={"Out": [out], "Load": [load]},
         attrs={"k": int(k), "held": [int(first), int(count)],
-               "scaling": float(scaling), "norm_topk": bool(norm_topk)},
+               "scaling": float(scaling), "norm_topk": bool(norm_topk),
+               "scoring": scoring},
         infer_shape=False)
     if not framework.in_dygraph_mode():
         out.shape, load.shape = (t, d), (count + 1,)
     return (out, load) if return_load else out
 
 
-__all__ += ["rms_norm", "causal_conv1d", "ssd_chunk_scan", "moe_topk"]
+def rotary_embedding(input, positions, theta=10000.0, sections=None,
+                     rotary_dims=0):
+    """Rotary positions on [B, T, H, hd], rotate-half form: frequency pair
+    ``i`` of the ``rotary_dims / 2`` pairs (``rotary_dims`` 0: the whole
+    head) turns by ``pos * theta^(-i / pairs)``. ``positions`` [3, B, T]
+    int holds three components (temporal, height, width) and ``sections``
+    says how many pairs each takes, in order (None: all from the first);
+    text has the three equal (ops/sparse_attn_ops.py)."""
+    helper = LayerHelper("rotary_embedding", input=input)
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(
+        "rotary_embedding", inputs={"X": [input], "Pos": [positions]},
+        outputs={"Out": [out]},
+        attrs={"theta": float(theta), "sections": list(sections or []),
+               "rotary_dims": int(rotary_dims)})
+    return out
+
+
+def attn_index_project(input, positions, heads, dim, theta=10000.0,
+                       sections=None, rotary_dims=0, epsilon=1e-6,
+                       param_attr=None, name=None):
+    """The lightning indexer's operands from the normed hidden state
+    [B, T, D], which takes no gradient from here: queries QI
+    [B, T, heads, dim], one key head KI [B, T, dim] (LayerNorm after its
+    projection), rotary positions on the first ``rotary_dims`` dims of
+    both, and the heads' weights W [B, T, heads] (scaled by ``heads^-0.5
+    dim^-0.5``). Float32 at full precision, under AMP too. Parameters in
+    order: the queries', the key's and the weights' matrices, the
+    LayerNorm's scale and bias."""
+    from ..initializer import ConstantInitializer
+
+    helper = LayerHelper("attn_index_project", input=input,
+                         param_attr=param_attr, name=name)
+    dtype = helper.input_dtype()
+    b, t, d = (int(n) for n in input.shape)
+
+    def matrix(cols):
+        return helper.create_parameter(attr=helper.param_attr,
+                                       shape=[d, cols], dtype=dtype)
+
+    wq, wk, ww = matrix(heads * dim), matrix(dim), matrix(heads)
+    ln_scale = helper.create_parameter(
+        attr=helper.param_attr, shape=[dim], dtype=dtype,
+        default_initializer=ConstantInitializer(1.0))
+    ln_bias = helper.create_parameter(
+        attr=helper.param_attr, shape=[dim], dtype=dtype,
+        default_initializer=ConstantInitializer(0.0))
+    qi, ki, w = (helper.create_variable_for_type_inference(dtype)
+                 for _ in range(3))
+    helper.append_op(
+        "attn_index_project",
+        inputs={"X": [input], "WQ": [wq], "WK": [wk], "WW": [ww],
+                "LnScale": [ln_scale], "LnBias": [ln_bias],
+                "Pos": [positions]},
+        outputs={"QI": [qi], "KI": [ki], "W": [w]},
+        attrs={"heads": int(heads), "theta": float(theta),
+               "sections": list(sections or []),
+               "rotary_dims": int(rotary_dims), "epsilon": float(epsilon)},
+        infer_shape=False)
+    if not framework.in_dygraph_mode():
+        qi.shape, ki.shape, w.shape = ((b, t, heads, dim), (b, t, dim),
+                                       (b, t, heads))
+    return qi, ki, w
+
+
+def attn_index_select(qi, ki, w, topk):
+    """Select [B, T, T] int8 from ``attn_index_project``'s results: 1 where
+    query t attends key s, the ``min(t + 1, topk)`` causal keys with the
+    largest index score ``sum_h w[t, h] relu(qi[t, h] . ki[s])``, ties to
+    the lower s; exact, no gradient. ``flash_attention``'s ``select``."""
+    helper = LayerHelper("attn_index_select", input=qi)
+    select = helper.create_variable_for_type_inference("int8",
+                                                       stop_gradient=True)
+    # no shape inference: it would trace (and count) the op at build time
+    helper.append_op(
+        "attn_index_select", inputs={"QI": [qi], "KI": [ki], "W": [w]},
+        outputs={"Select": [select]}, attrs={"topk": int(topk)},
+        infer_shape=False)
+    if not framework.in_dygraph_mode():
+        b, t = int(qi.shape[0]), int(qi.shape[1])
+        select.shape = (b, t, t)
+    return select
+
+
+def attn_index_loss(qi, ki, w, select, q, k, lse, scale):
+    """The indexer's loss [1]: the mean over queries of ``KL(pbar || pi)``
+    over the selection, ``pi`` the softmax of the index scores there and
+    ``pbar`` the attention's head-averaged probabilities, made again from
+    its q [B, H, T, hd], k [B, Hkv, T, hd], ``scale`` and the ``lse`` its
+    forward returned (``flash_attention(..., return_lse=True)``) and held
+    constant. Only ``qi``, ``ki``, ``w`` take a gradient from it."""
+    helper = LayerHelper("attn_index_loss", input=qi)
+    loss = helper.create_variable_for_type_inference("float32")
+    helper.append_op(
+        "attn_index_loss",
+        inputs={"QI": [qi], "KI": [ki], "W": [w], "Select": [select],
+                "Q": [q], "K": [k], "LSE": [lse]},
+        outputs={"Loss": [loss]}, attrs={"scale": float(scale)},
+        infer_shape=False)
+    if not framework.in_dygraph_mode():
+        loss.shape = (1,)
+    return loss
+
+
+__all__ += ["rms_norm", "causal_conv1d", "ssd_chunk_scan", "moe_topk",
+            "rotary_embedding", "attn_index_project", "attn_index_select",
+            "attn_index_loss"]
 
 
 def chunk_eval(input, label, chunk_scheme, num_chunk_types,

@@ -1,6 +1,7 @@
 """Hybrid state-space / mixture-of-experts decoder (the ``nemotron_h``
 layout): pre-norm residual layers whose mixer is chosen by a pattern
-string, one letter a layer:
+string, one letter a layer (a transformer layer of attention then experts
+is two letters):
 
 - ``M``  Mamba-2: one input projection to gate, convolved ``x | B | C`` and
   step sizes; causal depthwise convolution + silu; the chunked selective
@@ -9,10 +10,19 @@ string, one letter a layer:
   algorithm in XLA einsums everywhere else, ``ops.ssm_ops.scan_path``);
   gated RMS norm over groups; output projection;
 - ``E``  routed experts, top-k of many without drops over the experts this
-  program holds (``layers.moe_topk``), beside a shared expert that every
-  token passes;
+  program holds (``layers.moe_topk``: sigmoid or softmax scores, a
+  ``relu(u W1)^2 W2`` or a gated ``(silu(u W1) * (u W3)) W2`` expert),
+  beside a shared expert that every token passes (none with
+  ``shared_dim = 0``);
 - ``*``  causal grouped-query attention on the ``flash_attention`` op, no
-  positional encoding (as ``NemotronHAttention`` has none).
+  positional encoding (as ``NemotronHAttention`` has none);
+- ``S``  causal grouped-query attention over the keys an indexer selects
+  for each query: per-head RMS norms on q and k, rotary positions from
+  three position components, a lightning indexer (``attn_index_project``,
+  ``attn_index_select``: the ``index_topk`` causal keys with the largest
+  index score, one set a query for all heads) whose selection the
+  ``flash_attention`` op applies inside its kernels, and the indexer's KL
+  loss (``attn_index_loss``), collected for the caller to add to the loss.
 
 Built from ``fluid.layers`` ops; nothing here knows a model's name, the
 sizes are arguments.
@@ -63,13 +73,14 @@ def mamba2_mixer(u, hidden, num_heads, head_dim, n_groups, state_size,
 
 
 def moe_mixer(u, hidden, num_experts, top_k, expert_dim, shared_dim,
-              held=None, scaling=1.0, correction_bias=None, loads=None):
+              held=None, scaling=1.0, correction_bias=None, loads=None,
+              scoring="sigmoid", expert="relu2"):
     """u [B, T, hidden] (normed) -> routed (held experts' part) + shared."""
     B, T, _ = u.shape
     routed, load = layers.moe_topk(
         layers.reshape(u, [B * T, hidden]), num_experts, top_k, expert_dim,
         held=held, scaling=scaling, correction_bias=correction_bias,
-        return_load=True)
+        return_load=True, scoring=scoring, expert=expert)
     if loads is not None:
         loads.append(load)
     out = layers.reshape(routed, [B, T, hidden])
@@ -97,13 +108,56 @@ def gqa_mixer(u, hidden, num_heads, num_kv_heads, head_dim):
     return _proj(ctx, hidden)
 
 
+def indexed_gqa_mixer(u, hidden, num_heads, num_kv_heads, head_dim, *,
+                      positions, index_heads, index_dim, index_topk,
+                      rope_theta=10000.0, rope_sections=None,
+                      index_rotary_dims=0, eps=1e-6, index_losses=None):
+    """u [B, T, hidden] (normed) -> causal grouped-query attention over the
+    ``index_topk`` keys the indexer selects for each query. ``positions``
+    [3, B, T] int. Parameters in order: q, k, v projections, the q and k
+    head norms, the indexer's (``layers.attn_index_project``), the output
+    projection. The indexer reads ``u`` cut from the gradient and learns
+    from its own loss alone, which goes to ``index_losses``."""
+    B, T, _ = u.shape
+    scale = float(head_dim) ** -0.5
+
+    def heads(x, n):
+        return layers.reshape(x, [B, T, n, head_dim])
+
+    def placed(x):
+        """Per-head RMS norm, rotary positions, head-major."""
+        x = layers.rotary_embedding(layers.rms_norm(x, epsilon=eps),
+                                    positions, theta=rope_theta,
+                                    sections=rope_sections)
+        return layers.transpose(x, [0, 2, 1, 3])
+
+    q = heads(_proj(u, num_heads * head_dim), num_heads)
+    k = heads(_proj(u, num_kv_heads * head_dim), num_kv_heads)
+    v = heads(_proj(u, num_kv_heads * head_dim), num_kv_heads)
+    q, k = placed(q), placed(k)
+    qi, ki, w = layers.attn_index_project(
+        u, positions, index_heads, index_dim, theta=rope_theta,
+        sections=rope_sections, rotary_dims=index_rotary_dims, epsilon=eps)
+    select = layers.attn_index_select(qi, ki, w, index_topk)
+    ctx, lse = layers.flash_attention(
+        q, k, layers.transpose(v, [0, 2, 1, 3]), causal=True, scale=scale,
+        select=select, return_lse=True)
+    if index_losses is not None:
+        index_losses.append(layers.attn_index_loss(
+            qi, ki, w, select, q, k, lse, scale))
+    ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
+                         [B, T, num_heads * head_dim])
+    return _proj(ctx, hidden)
+
+
 def hybrid_ssm_moe(ids, pattern, vocab_rows, hidden, *, mamba_heads=64,
                    mamba_head_dim=64, n_groups=8, state_size=128,
                    conv_kernel=4, chunk=128, num_experts=128, top_k=6,
                    expert_dim=1856, shared_dim=3712, held=None,
                    routed_scaling=2.5, correction_bias=None, num_heads=32,
                    num_kv_heads=2, head_dim=128, eps=1e-5, loads=None,
-                   checkpoints=None):
+                   checkpoints=None, scoring="sigmoid", expert="relu2",
+                   indexed=None):
     """Logits [B, T, vocab_rows] over int64 ids [B, T].
 
     ``pattern``: one letter a layer (see the module's docstring).
@@ -115,7 +169,13 @@ def hybrid_ssm_moe(ids, pattern, vocab_rows, hidden, *, mamba_heads=64,
     ``checkpoints``: a list that receives each layer's input and the last
     layer's output, which is what
     ``RecomputeOptimizer._set_checkpoints`` takes to recompute a layer's
-    activations from its input alone."""
+    activations from its input alone. ``scoring`` / ``expert``: the ``E``
+    layers' router scores and expert form (``layers.moe_topk``).
+    ``indexed``: the ``S`` layers' keyword arguments of
+    ``indexed_gqa_mixer`` as one dict: ``positions`` [3, B, T] int, the
+    ``rope_*`` and ``index_*`` sizes, and ``index_losses``, a list that
+    receives each ``S`` layer's indexer loss [1] for the caller to add to
+    the model's loss."""
     x = layers.embedding(ids, size=[vocab_rows, hidden])
     biases = iter(correction_bias or ())
     for kind in pattern:
@@ -128,9 +188,12 @@ def hybrid_ssm_moe(ids, pattern, vocab_rows, hidden, *, mamba_heads=64,
         elif kind == "E":
             y = moe_mixer(u, hidden, num_experts, top_k, expert_dim,
                           shared_dim, held, routed_scaling,
-                          next(biases, None), loads)
+                          next(biases, None), loads, scoring, expert)
         elif kind == "*":
             y = gqa_mixer(u, hidden, num_heads, num_kv_heads, head_dim)
+        elif kind == "S":
+            y = indexed_gqa_mixer(u, hidden, num_heads, num_kv_heads,
+                                  head_dim, eps=eps, **indexed)
         else:
             raise ValueError("hybrid_ssm_moe: no layer kind %r" % kind)
         x = layers.elementwise_add(x, y)

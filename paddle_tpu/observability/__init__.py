@@ -23,6 +23,9 @@ Metric families (see README "Runtime observability"):
 ``executor.ops{type=...}``             counter: interpreter per-op executions
 ``kernels.flash_attention{path=...}``  counter: traces of the flash_attention
                                        op, by kernels: short | stream | dense
+``kernels.flash_attention_select{form=...}``  counter: the same traces, by
+                                       the form of their key selection:
+                                       none | mask (int8 [B, S, S])
 ``executor.compiles``                  counter: whole-program (re)compiles
 ``executor.jit_traces``                counter: per-shape XLA (re)traces
 ``executor.compile_fallbacks``         counter: compiled -> interpreter drops

@@ -44,3 +44,4 @@ from . import text_match_ops  # noqa: F401
 from . import eval_ops  # noqa: F401
 from . import ssm_ops  # noqa: F401
 from . import moe_ops  # noqa: F401
+from . import sparse_attn_ops  # noqa: F401

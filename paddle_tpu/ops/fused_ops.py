@@ -116,24 +116,27 @@ def _flash_attention_grad(ins, attrs):
     forward twice a layer. A program whose forward op bound no ``LSE``
     (or ran the dense math, which has none) re-runs the forward under
     ``jax.vjp`` as every auto-VJP grad op does."""
-    from .pallas.flash_attention import (flash_attention,
+    from .pallas.flash_attention import (compute_platform, flash_attention,
                                          flash_attention_bwd)
 
     q, k, v = ins["Q"], ins["K"], ins["V"]
-    lengths = ins.get("Lengths")
+    lengths, select = ins.get("Lengths"), ins.get("Select")
     causal = bool(attrs.get("causal"))
     num_heads = int(attrs.get("num_heads", 0))
     scale = attrs.get("scale", 0.0) or None
     g = ins["Out@GRAD"].astype(q.dtype)
-    if ins.get("LSE") is not None:
+    # a selected call binds its LSE off the TPU too, where the dense math ran
+    kernels = ins.get("LSE") is not None and (
+        select is None or compute_platform() == "tpu")
+    if kernels:
         dq, dk, dv = flash_attention_bwd(
             q, k, v, lengths, ins["Out"], ins["LSE"], g, causal, scale,
-            num_heads=num_heads)
+            num_heads=num_heads, select=select)
     else:
         _, vjp = jax.vjp(
             lambda q, k, v: flash_attention(
                 q, k, v, causal=causal, scale=scale, lengths=lengths,
-                num_heads=num_heads),
+                num_heads=num_heads, select=select),
             q, k, v)
         dq, dk, dv = vjp(g)
     return {"Q@GRAD": dq, "K@GRAD": dk, "V@GRAD": dv}
@@ -144,6 +147,7 @@ register_op(
     "flash_attention_grad",
     inputs=[In("Q"), In("K"), In("V"),
             In("Lengths", dispensable=True, no_grad=True),
+            In("Select", dispensable=True, no_grad=True),
             In("Out", dispensable=True), In("LSE", dispensable=True),
             In("Out@GRAD")],
     outputs=[Out("Q@GRAD", dispensable=True),
@@ -157,7 +161,8 @@ register_op(
 @register_op(
     "flash_attention",
     inputs=[In("Q"), In("K"), In("V"),
-            In("Lengths", dispensable=True, no_grad=True)],
+            In("Lengths", dispensable=True, no_grad=True),
+            In("Select", dispensable=True, no_grad=True)],
     outputs=[Out("Out"), Out("LSE", dispensable=True, no_grad=True)],
     attrs={"causal": False, "scale": 0.0, "num_heads": 0},
 )
@@ -172,25 +177,34 @@ def _flash_attention(ins, attrs):
     operands the short kernels do not take are split into heads, and
     the context merged, inside the op), the same dense math elsewhere.
     ``Lengths`` [B] int: per-row valid-KV count — the kernel-side
-    padding mask (reference's additive src_slf_attn_bias). ``LSE`` is
-    the log-sum-exp residual ``flash_attention_grad`` reads (None where
-    the dense math ran). Each trace of the op counts the path it took
-    and the layout it was given:
+    padding mask (reference's additive src_slf_attn_bias). ``Select``
+    [B, S, S] int8: a per-query key selection (non-zero where row r sees
+    key c), one for all the heads of a batch row, applied inside the
+    streaming kernels beside the causal mask (head-major operands).
+    ``LSE`` is the log-sum-exp residual ``flash_attention_grad`` reads
+    (None where the dense math ran without a selection; [B*H, S, 1] with
+    one, wherever it ran). Each trace of the op counts the path it took,
+    the layout it was given and the form of its selection:
     ``kernels.flash_attention{path=short|stream|dense}``,
-    ``kernels.flash_attention_layout{layout=tokens|heads}``."""
+    ``kernels.flash_attention_layout{layout=tokens|heads}``,
+    ``kernels.flash_attention_select{form=none|mask}``."""
     from .. import observability as _obs
     from .pallas.flash_attention import (attention_path,
                                          flash_attention_with_lse)
 
     q, k, v = ins["Q"], ins["K"], ins["V"]
     num_heads = int(attrs.get("num_heads", 0))
+    select = ins.get("Select")
     if _obs.enabled():
         _obs.inc("kernels.flash_attention",
-                 path=attention_path(q, k, num_heads=num_heads))
+                 path=attention_path(q, k, num_heads=num_heads,
+                                     select=select))
         _obs.inc("kernels.flash_attention_layout",
                  layout="tokens" if q.ndim == 3 else "heads")
+        _obs.inc("kernels.flash_attention_select",
+                 form="none" if select is None else "mask")
     scale = attrs.get("scale", 0.0) or None
     out, lse = flash_attention_with_lse(
         q, k, v, causal=bool(attrs.get("causal")), scale=scale,
-        lengths=ins.get("Lengths"), num_heads=num_heads)
+        lengths=ins.get("Lengths"), num_heads=num_heads, select=select)
     return {"Out": out, "LSE": lse}

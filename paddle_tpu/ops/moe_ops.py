@@ -1,9 +1,14 @@
 """Top-k-of-many expert routing without drops, over the experts held here.
 
-``moe_topk`` routes every token over ALL the router's experts (sigmoid
-scores, a correction bias that enters the choice only, the k largest), and
-computes the part of the result that the ``held = [first, count]`` experts
-of this chip give: what expert parallelism asks of a rank. Nothing is
+``moe_topk`` routes every token over ALL the router's experts (``scoring``:
+``sigmoid`` scores or a ``softmax`` over all of them, float32; a correction
+bias that enters the choice only; the k largest; weights the chosen scores,
+over their sum with ``norm_topk``), and computes the part of the result that
+the ``held = [first, count]`` experts of this chip give: what expert
+parallelism asks of a rank. An expert is ``relu(u W1)^2 W2`` or, where a
+third matrix ``W3`` is bound, gated: ``(silu(u W1) * (u W3)) W2``; both go
+through the same sorted slots, row buffer, grouped
+products and overflow branch. Nothing is
 dropped: there is no capacity factor. Static shapes are met by a row buffer:
 the routed slots are sorted by expert, the first ``rows`` of them
 (``ROWS_MULTIPLE`` times the expected load ``T * k * count / E``) are
@@ -40,12 +45,26 @@ def _act(h):
     return jnp.square(jax.nn.relu(h))
 
 
-def route(x, router_w, bias, k, scaling, norm_topk):
+def _hidden(dot, w1, w3=None):
+    """An expert's hidden rows in float32: ``relu(u W1)^2``, or gated with a
+    third matrix, ``silu(u W1) * (u W3)``; ``dot(w)`` is ``u w``."""
+    if w3 is None:
+        return _act(dot(w1))
+    return jax.nn.silu(dot(w1)) * dot(w3)
+
+
+def route(x, router_w, bias, k, scaling, norm_topk, scoring="sigmoid"):
     """(idx [T, k] int32, weight [T, k] float32): the router's product,
-    scores and weights in float32 at full precision."""
+    scores (``sigmoid`` of each logit, or a ``softmax`` over all the
+    experts) and weights in float32 at full precision."""
     f32 = jnp.float32
-    s = jax.nn.sigmoid(jnp.dot(x.astype(f32), router_w.astype(f32),
-                               precision=_HI))
+    logits = jnp.dot(x.astype(f32), router_w.astype(f32), precision=_HI)
+    if scoring == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+    elif scoring == "softmax":
+        s = jax.nn.softmax(logits, -1)
+    else:
+        raise ValueError("moe_topk: no scoring %r" % scoring)
     choice = s if bias is None else s + jax.lax.stop_gradient(
         bias.astype(f32))
     _, idx = jax.lax.top_k(choice, k)
@@ -131,20 +150,23 @@ def _megablox_bwd(res, g):
 _megablox_dot.defvjp(_megablox_fwd, _megablox_bwd)
 
 
-def _grouped(x, w1, w2, tok, valid, weight, sizes):
+def _grouped(x, w1, w2, tok, valid, weight, sizes, w3=None):
     """The sorted slots' rows through their experts: gather, two grouped
-    products, weigh, scatter-add back to the tokens. Rows past the held
-    slots are cut off on both sides (``where``, not a product: what a
-    grouped product leaves in rows it does not own is not defined)."""
+    products (three for a gated expert), weigh, scatter-add back to the
+    tokens. Rows past the held slots are cut off on both sides (``where``,
+    not a product: what a grouped product leaves in rows it does not own is
+    not defined)."""
     f32 = jnp.float32
     xs = jnp.where(valid[:, None], x[tok].astype(w1.dtype), 0)
-    h = _act(_grouped_dot(xs, w1, sizes)).astype(w1.dtype)
-    y = _grouped_dot(h, w2, sizes)
+    h = _hidden(lambda w: _grouped_dot(xs, w, sizes), w1, w3)
+    if w3 is not None:   # silu of an undefined row times another: cut here too
+        h = jnp.where(valid[:, None], h, 0.0)
+    y = _grouped_dot(h.astype(w1.dtype), w2, sizes)
     y = jnp.where(valid[:, None], y * weight[:, None], 0.0)
     return jnp.zeros(x.shape, f32).at[tok].add(y)
 
 
-def _every_token(x, w1, w2, local, weight):
+def _every_token(x, w1, w2, local, weight, w3=None):
     """The exact branch for any skew: each held expert over all the tokens,
     weighed by what the router gave it there (0 where it was not chosen)."""
     f32 = jnp.float32
@@ -152,22 +174,25 @@ def _every_token(x, w1, w2, local, weight):
 
     @jax.checkpoint
     def one(out, ew):
-        e, a, b = ew
+        e, a, b, *gate = ew
         m = jnp.sum(jnp.where(local == e, weight, 0.0), -1)     # [T]
-        h = _act(jnp.dot(xc, a, preferred_element_type=f32))
+        h = _hidden(lambda w: jnp.dot(xc, w, preferred_element_type=f32),
+                    a, *gate)
         y = jnp.dot(h.astype(a.dtype), b, preferred_element_type=f32)
         return out + y * m[:, None], None
 
     count = w1.shape[0]
+    experts = (jnp.arange(count, dtype=jnp.int32), w1, w2)
     out, _ = jax.lax.scan(one, jnp.zeros(x.shape, f32),
-                          (jnp.arange(count, dtype=jnp.int32), w1, w2))
+                          experts + (() if w3 is None else (w3,)))
     return out
 
 
 def moe_topk(x, router_w, bias, w1, w2, k, held, scaling=1.0,
-             norm_topk=True):
+             norm_topk=True, scoring="sigmoid", w3=None):
     """(out [T, D] float32, load [count + 1] int32): the held experts'
-    part of the layer for tokens x [T, D], expert ``relu(u W1)^2 W2``;
+    part of the layer for tokens x [T, D], expert ``relu(u W1)^2 W2`` or,
+    with ``w3``, ``(silu(u W1) * (u W3)) W2``;
     ``load[:count]`` the slots each held expert received, ``load[count]``
     1 where the exact slower branch ran."""
     T, experts = x.shape[0], router_w.shape[1]
@@ -178,16 +203,19 @@ def moe_topk(x, router_w, bias, w1, w2, k, held, scaling=1.0,
         # part of its gradient alone sends every token their way (their load
         # tripled in 48 steps on the chip). A deployment sums the ranks'
         # parts before it applies them; alone, the router's weight takes a
-        # zero gradient. The tokens still take theirs through the scores.
+        # zero gradient, under either scoring. The tokens still take theirs
+        # through the scores.
         router_w = jax.lax.stop_gradient(router_w)
     # inner scopes, so that a trace tells the routing from the products
     with jax.named_scope("route"):
-        idx, weight = route(x, router_w, bias, k, scaling, norm_topk)
+        idx, weight = route(x, router_w, bias, k, scaling, norm_topk,
+                            scoring)
     with jax.named_scope("experts"):
-        return _held_part(x, w1, w2, idx, weight, k, experts, first, count)
+        return _held_part(x, w1, w2, idx, weight, k, experts, first, count,
+                          w3)
 
 
-def _held_part(x, w1, w2, idx, weight, k, experts, first, count):
+def _held_part(x, w1, w2, idx, weight, k, experts, first, count, w3=None):
     T = x.shape[0]
     local = idx - first
     is_held = (local >= 0) & (local < count)
@@ -198,20 +226,23 @@ def _held_part(x, w1, w2, idx, weight, k, experts, first, count):
     n_held = jnp.sum(sizes)
     rows = buffer_rows(T, k, experts, count)
 
-    def fast(x, w1, w2, weight):
+    # the gated expert's third matrix rides along as one more operand
+    more = () if w3 is None else (w3,)
+
+    def fast(x, w1, w2, weight, *w3):
         order = jnp.argsort(flat, stable=True)[:rows]
         valid = jnp.arange(rows) < n_held
         return _grouped(x, w1, w2, order // k, valid,
-                        weight.reshape(-1)[order], sizes)
+                        weight.reshape(-1)[order], sizes, *w3)
 
-    def slow(x, w1, w2, weight):
-        return _every_token(x, w1, w2, local, weight)
+    def slow(x, w1, w2, weight, *w3):
+        return _every_token(x, w1, w2, local, weight, *w3)
 
     fits = n_held <= rows
     if rows >= T * k:
-        out = fast(x, w1, w2, weight)
+        out = fast(x, w1, w2, weight, *more)
     else:
-        out = jax.lax.cond(fits, fast, slow, x, w1, w2, weight)
+        out = jax.lax.cond(fits, fast, slow, x, w1, w2, weight, *more)
     load = jnp.concatenate([sizes, (~fits).astype(jnp.int32)[None]])
     return out, load
 
@@ -220,14 +251,18 @@ def _held_part(x, w1, w2, idx, weight, k, experts, first, count):
     "moe_topk",
     inputs=[In("X"), In("RouterW"), In("Bias", dispensable=True,
                                        no_grad=True),
-            In("W1"), In("W2")],
+            In("W1"), In("W2"), In("W3", dispensable=True)],
     outputs=[Out("Out"), Out("Load", dispensable=True, no_grad=True)],
-    attrs={"k": 1, "held": [0, 1], "scaling": 1.0, "norm_topk": True},
+    attrs={"k": 1, "held": [0, 1], "scaling": 1.0, "norm_topk": True,
+           "scoring": "sigmoid"},
 )
 def _moe_topk(ins, attrs):
     """X [T, D] tokens; RouterW [D, E] over all E experts; Bias [E] the
     selection-only correction (a buffer: no gradient); W1 [count, D, F],
     W2 [count, F, D] the held experts ``held[0] .. held[0] + count - 1``.
+    ``scoring`` ``sigmoid`` or ``softmax`` (over all E experts). The expert
+    is ``relu(u W1)^2 W2``, or gated where W3 [count, D, F] is bound:
+    ``(silu(u W1) * (u W3)) W2``.
     RouterW takes a gradient only where every expert is held
     (``moe_topk`` above says why).
     ``Out`` is in W1's type (bf16 under AMP, where X, RouterW and Bias stay
@@ -245,5 +280,6 @@ def _moe_topk(ins, attrs):
         ins["X"], ins["RouterW"], ins.get("Bias"), ins["W1"], ins["W2"],
         k=int(attrs.get("k", 1)), held=attrs.get("held", [0, 1]),
         scaling=float(attrs.get("scaling", 1.0)),
-        norm_topk=bool(attrs.get("norm_topk", True)))
+        norm_topk=bool(attrs.get("norm_topk", True)),
+        scoring=attrs.get("scoring", "sigmoid"), w3=ins.get("W3"))
     return {"Out": out.astype(ins["W1"].dtype), "Load": load}

@@ -52,6 +52,12 @@ heads in its last grid axis so that their sums happen in its
 accumulators. Such a call takes the streaming kernels at any length (the
 short ones take one head count).
 
+**Selection** (learned sparse attention): the streaming kernels take an
+optional ``select`` operand, [B, S, S] int8, one tile a (Q block, K block)
+pair shared by all the heads of a batch row, and hide the keys it leaves
+out beside the causal mask, forward and backward. Without it the kernels,
+their operands and ``_plan`` are unchanged.
+
 Where the computation runs on a TPU (``core.place.compute_platform``)
 the public entry builds the kernels, and a kernel Mosaic refuses
 raises. Elsewhere it computes the identical dense math, so programs
@@ -108,6 +114,13 @@ def _kv_len_mask(s, ki, block_k, len_val):
     return jnp.where(k_pos < len_val, s, NEG_INF)
 
 
+def _select_mask(s, sel_ref):
+    """Selection mask: query row r sees key column c of this tile only where
+    the int8 tile of ``Select`` [B, S, S] is non-zero; one tile serves all
+    the heads of a batch row."""
+    return jnp.where(sel_ref[0].astype(jnp.int32) != 0, s, NEG_INF)
+
+
 def _group(q, k):
     """Query heads that share one K/V head: ``H / H_kv`` (1 = plain
     multi-head attention)."""
@@ -118,7 +131,11 @@ def _group(q, k):
     return H // H_kv
 
 
-def _dense_attention(q, k, v, causal, scale, lengths=None):
+def _dense_attention(q, k, v, causal, scale, lengths=None, select=None,
+                     with_lse=False):
+    """The dense math. ``with_lse``: (out, [B*H, S, 1] float32 log-sum-exp
+    of the visible scores, in the streaming kernels' shape): a selected call
+    hands its LSE on wherever it runs (the indexer's loss reads it)."""
     group = _group(q, k)
     if group > 1:   # the dense math repeats the shared heads; no kernel does
         k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
@@ -129,6 +146,8 @@ def _dense_attention(q, k, v, causal, scale, lengths=None):
         pos = jnp.arange(S)
         s = jnp.where((pos[:, None] >= pos[None, :])[None, None], s,
                       NEG_INF)
+    if select is not None:
+        s = jnp.where((select != 0)[:, None], s, NEG_INF)
     if lengths is not None:
         S_kv = k.shape[2]
         vis = jnp.arange(S_kv)[None, None, None, :] < \
@@ -141,7 +160,11 @@ def _dense_attention(q, k, v, causal, scale, lengths=None):
         out = jnp.where(
             (lengths.astype(jnp.int32) > 0)[:, None, None, None],
             out, 0.0)
-    return out.astype(q.dtype)
+    out = out.astype(q.dtype)
+    if not with_lse:
+        return out
+    B, H, S, _ = q.shape
+    return out, jax.scipy.special.logsumexp(s, axis=-1).reshape(B * H, S, 1)
 
 
 def _dense_lse(q, k, causal, scale, lengths_bh=None):
@@ -164,16 +187,14 @@ def _dense_lse(q, k, causal, scale, lengths_bh=None):
 # ---------------------------------------------------------------------------
 
 
-def _flash_kernel(*refs, scale, causal, block_q, block_k, nk, has_len):
+def _flash_kernel(*refs, scale, causal, block_q, block_k, nk, has_len,
+                  has_sel=False):
     from jax.experimental import pallas as pl
 
-    if has_len:
-        (q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref,
-         m_ref, l_ref, acc_ref) = refs
-    else:
-        (q_ref, k_ref, v_ref, o_ref, lse_ref,
-         m_ref, l_ref, acc_ref) = refs
-        len_ref = None
+    (q_ref, k_ref, v_ref), refs = refs[:3], refs[3:]
+    len_ref, refs = (refs[0], refs[1:]) if has_len else (None, refs)
+    sel_ref, refs = (refs[0], refs[1:]) if has_sel else (None, refs)
+    o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
     ki = pl.program_id(2)
     qi = pl.program_id(1)
 
@@ -194,6 +215,8 @@ def _flash_kernel(*refs, scale, causal, block_q, block_k, nk, has_len):
             s = _causal_mask(s, qi, ki, block_q, block_k)
         if has_len:
             s = _kv_len_mask(s, ki, block_k, len_ref[bi, 0])
+        if has_sel:
+            s = _select_mask(s, sel_ref)
 
         m_prev = m_ref[:]                             # [bq, 1]
         m_cur = jnp.max(s, axis=1, keepdims=True)
@@ -239,8 +262,9 @@ def _len_bh(lengths, B, H):
 
 
 def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
-                   lengths=None):
-    """Returns (out [B,H,S,D], lse [B*H, S] float32)."""
+                   lengths=None, select=None):
+    """Returns (out [B,H,S,D], lse [B*H, S, 1] float32). ``select``
+    [B, S, S] int8 (whole blocks only: ``_plan`` sees to that)."""
     from jax.experimental import pallas as pl
 
     B, H, S, D = q.shape
@@ -268,7 +292,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
     has_len = lengths is not None
     kernel = functools.partial(_flash_kernel, scale=scale, causal=causal,
                                block_q=bq, block_k=bk, nk=nk,
-                               has_len=has_len)
+                               has_len=has_len, has_sel=select is not None)
     in_specs = [
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk, D), lambda b, i, j: (b // group, j, 0)),
@@ -280,6 +304,10 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
         # (1,1) VMEM block would violate the TPU (8,128) tile rule)
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         args.append(_len_bh(lengths, B, H))
+    if select is not None:
+        in_specs.append(pl.BlockSpec((1, bq, bk),
+                                     lambda b, i, j: (b // H, i, j)))
+        args.append(select)
     out, lse = pl.pallas_call(
         kernel,
         grid=(B * H, nq, nk),
@@ -312,16 +340,14 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
 
 
 def _flash_bwd_dq_kernel(*refs, scale, causal, block_q, block_k, nk,
-                         has_len):
+                         has_len, has_sel=False):
     from jax.experimental import pallas as pl
 
-    if has_len:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, len_ref,
-         dq_ref, dq_acc) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dq_ref, dq_acc) = refs
-        len_ref = None
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), refs = (refs[:6],
+                                                               refs[6:])
+    len_ref, refs = (refs[0], refs[1:]) if has_len else (None, refs)
+    sel_ref, refs = (refs[0], refs[1:]) if has_sel else (None, refs)
+    dq_ref, dq_acc = refs
     ki = pl.program_id(2)
     qi = pl.program_id(1)
     bi = pl.program_id(0)
@@ -343,6 +369,8 @@ def _flash_bwd_dq_kernel(*refs, scale, causal, block_q, block_k, nk,
             s = _causal_mask(s, qi, ki, block_q, block_k)
         if has_len:
             s = _kv_len_mask(s, ki, block_k, len_ref[bi, 0])
+        if has_sel:
+            s = _select_mask(s, sel_ref)
         p = jnp.exp(s - lse)                           # [bq, bk]
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
         ds = p * (dp - delta)                          # [bq, bk]
@@ -366,19 +394,17 @@ def _flash_bwd_dq_kernel(*refs, scale, causal, block_q, block_k, nk,
 
 
 def _flash_bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, nq,
-                          has_len, group=1):
+                          has_len, group=1, has_sel=False):
     """dK and dV of one K/V head: the last grid axis runs over the
     ``group`` query heads that share it and, inside each, the Q blocks, so
     the sums over the group happen in the accumulators."""
     from jax.experimental import pallas as pl
 
-    if has_len:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, len_ref,
-         dk_ref, dv_ref, dk_acc, dv_acc) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dk_ref, dv_ref, dk_acc, dv_acc) = refs
-        len_ref = None
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), refs = (refs[:6],
+                                                               refs[6:])
+    len_ref, refs = (refs[0], refs[1:]) if has_len else (None, refs)
+    sel_ref, refs = (refs[0], refs[1:]) if has_sel else (None, refs)
+    dk_ref, dv_ref, dk_acc, dv_acc = refs
     step = pl.program_id(2)
     qi = step % nq if group > 1 else step
     ki = pl.program_id(1)
@@ -402,6 +428,8 @@ def _flash_bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, nq,
             s = _causal_mask(s, qi, ki, block_q, block_k)
         if has_len:
             s = _kv_len_mask(s, ki, block_k, len_ref[bi, 0])
+        if has_sel:
+            s = _select_mask(s, sel_ref)
         p = jnp.exp(s - lse)                           # [bq, bk]
         dv_acc[:] += jax.lax.dot_general(              # p^T @ do
             p, do, (((0,), (0,)), ((), ())))           # [bk, d]
@@ -429,7 +457,7 @@ def _flash_bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, nq,
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
-                    block_k, interpret, lengths=None):
+                    block_k, interpret, lengths=None, select=None):
     from jax.experimental import pallas as pl
 
     B, H, S, D = q.shape
@@ -455,10 +483,18 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
         dq_len, dkv_len = ([_len_bh(lengths, B, H)],
                            [_len_bh(lengths, B, H_kv)])
         len_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]
+    has_sel = select is not None
+    dq_sel, dkv_sel, sel = [], [], []
+    if has_sel:
+        # the selection's tile of a (Q block, K block) pair, for every head
+        sel = [select]
+        dq_sel = [pl.BlockSpec((1, bq, bk), lambda b, i, j: (b // H, i, j))]
+        dkv_sel = [pl.BlockSpec((1, bq, bk),
+                                lambda b, j, t: (b // H_kv, t % nq, j))]
 
     dq_kernel = functools.partial(
         _flash_bwd_dq_kernel, scale=scale, causal=causal, block_q=bq,
-        block_k=bk, nk=nk, has_len=has_len)
+        block_k=bk, nk=nk, has_len=has_len, has_sel=has_sel)
     dq = pl.pallas_call(
         dq_kernel,
         grid=(B * H, nq, nk),
@@ -469,18 +505,19 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-        ] + len_specs,
+        ] + len_specs + dq_sel,
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q3, k3, v3, do3, lse, delta, *dq_len)
+    )(q3, k3, v3, do3, lse, delta, *dq_len, *sel)
 
     dkv_kernel = functools.partial(
         _flash_bwd_dkv_kernel, scale=scale, causal=causal, block_q=bq,
-        block_k=bk, nq=nq, has_len=has_len, group=group)
+        block_k=bk, nq=nq, has_len=has_len, group=group,
+        has_sel=has_sel)
 
     def q_rows(b, j, t):
         # K/V head b, step t: query head t // nq of its group, Q block t % nq
@@ -496,7 +533,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
             pl.BlockSpec((1, bq, D), q_rows),
             pl.BlockSpec((1, bq, 1), q_rows),
             pl.BlockSpec((1, bq, 1), q_rows),
-        ] + len_specs,
+        ] + len_specs + dkv_sel,
         out_specs=[
             pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0)),
             pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0)),
@@ -512,7 +549,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q3, k3, v3, do3, lse, delta, *dkv_len)
+    )(q3, k3, v3, do3, lse, delta, *dkv_len, *sel)
 
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
@@ -981,6 +1018,34 @@ def _flash_bwd(causal, scale, block_q, block_k, heads, interpret, res,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_selected(q, k, v, select, causal, scale, block_q, block_k,
+                    interpret):
+    """(out, lse) of the streaming kernels with a per-query key selection
+    ``select`` [B, S, S] int8 applied beside the causal mask. Every causal
+    block is visited and masked; no block is skipped for the selection."""
+    return _flash_forward(q, k, v, causal, scale, block_q, block_k,
+                          interpret, select=select)
+
+
+def _flash_selected_fwd(q, k, v, select, causal, scale, block_q, block_k,
+                        interpret):
+    out, lse = _flash_selected(q, k, v, select, causal, scale, block_q,
+                               block_k, interpret)
+    return (out, lse), (q, k, v, select, out, lse)
+
+
+def _flash_selected_bwd(causal, scale, block_q, block_k, interpret, res,
+                        cts):
+    q, k, v, select, out, lse = res
+    return _flash_backward(q, k, v, out, lse, cts[0], causal, scale,
+                           block_q, block_k, interpret,
+                           select=select) + (_no_tangent(select),)
+
+
+_flash_selected.defvjp(_flash_selected_fwd, _flash_selected_bwd)
+
+
 def _fit_block(S, block):
     """Largest divisor of ``S`` that is <= ``block`` and lane-aligned
     (a multiple of 128, or ``S`` itself when S < block). Returns 0 when
@@ -993,6 +1058,19 @@ def _fit_block(S, block):
         if S % cand == 0:
             return cand
     return 0
+
+
+def _plan_selected(q, k, block_q, block_k):
+    """(block_q, block_k) of the streaming kernels for a call with a
+    selection: square head-major operands in whole aligned blocks (the
+    selection's tiles are cut the same way), or an error."""
+    S = q.shape[-2]
+    bq, bk = _fit_block(S, block_q), _fit_block(S, block_k)
+    if q.ndim != 4 or S != k.shape[2] or not (bq and bk):
+        raise ValueError(
+            "flash_attention: a selection needs head-major q %s, k %s of one "
+            "length that splits into aligned blocks" % (q.shape, k.shape))
+    return bq, bk
 
 
 def _plan(q, k, block_q, block_k, num_heads=0):
@@ -1033,11 +1111,15 @@ def _plan(q, k, block_q, block_k, num_heads=0):
 
 
 def attention_path(q, k, block_q: int = 512, block_k: int = 1024,
-                   force_pallas: bool = False, num_heads: int = 0) -> str:
+                   force_pallas: bool = False, num_heads: int = 0,
+                   select=None) -> str:
     """"short" | "stream" | "dense": what ``flash_attention`` runs for
-    these arguments where the computation is placed now."""
+    these arguments where the computation is placed now (a selection
+    always streams)."""
     if not (force_pallas or compute_platform() == "tpu"):
         return "dense"
+    if select is not None:
+        return "stream"
     return ("short" if _plan(q, k, block_q, block_k, num_heads)[0]
             else "stream")
 
@@ -1063,14 +1145,25 @@ def flash_attention_with_lse(q, k, v, causal: bool = False,
                              scale: Optional[float] = None,
                              block_q: int = 512, block_k: int = 1024,
                              force_pallas: bool = False, lengths=None,
-                             num_heads: int = 0):
+                             num_heads: int = 0, select=None):
     """``flash_attention`` and the residual its backward needs: (out,
     lse), differentiable (the LSE takes no cotangent). ``lse`` is None
-    where the dense math ran (off the TPU)."""
+    where the dense math ran (off the TPU) without a selection; with
+    ``select`` it is [B*H, S, 1] wherever the call runs."""
     head_dim = _check_layout(q, k, num_heads)
     if scale is None:
         scale = float(head_dim) ** -0.5
     on_tpu = compute_platform() == "tpu"
+    if select is not None:
+        if lengths is not None:
+            raise ValueError("flash_attention: select and lengths together")
+        if not (on_tpu or force_pallas):
+            out, lse = _dense_attention(q, k, v, causal, scale,
+                                        select=select, with_lse=True)
+            return out, jax.lax.stop_gradient(lse)
+        block_q, block_k = _plan_selected(q, k, block_q, block_k)
+        return _flash_selected(q, k, v, select, causal, scale, block_q,
+                               block_k, not on_tpu)
     short = None   # no kernels: the dense math (0 is the streaming kernels)
     if on_tpu or force_pallas:
         short, block_q, block_k = _plan(q, k, block_q, block_k, num_heads)
@@ -1090,13 +1183,19 @@ def flash_attention_with_lse(q, k, v, causal: bool = False,
 
 def flash_attention_bwd(q, k, v, lengths, out, lse, g, causal: bool,
                         scale: Optional[float] = None, block_q: int = 512,
-                        block_k: int = 1024, num_heads: int = 0):
+                        block_k: int = 1024, num_heads: int = 0,
+                        select=None):
     """(dq, dk, dv) from the forward's own ``out`` and ``lse`` (as
     ``flash_attention_with_lse`` returned them for the same arguments):
     the backward kernels alone, no second forward."""
     head_dim = _check_layout(q, k, num_heads)
     if scale is None:
         scale = float(head_dim) ** -0.5
+    if select is not None:
+        block_q, block_k = _plan_selected(q, k, block_q, block_k)
+        return _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
+                               block_k, compute_platform() != "tpu",
+                               select=select)
     short, block_q, block_k = _plan(q, k, block_q, block_k, num_heads)
     interpret = compute_platform() != "tpu"
     if isinstance(short, tuple):
@@ -1114,7 +1213,7 @@ def flash_attention_bwd(q, k, v, lengths, out, lse, g, causal: bool,
 def flash_attention(q, k, v, causal: bool = False,
                     scale: Optional[float] = None, block_q: int = 512,
                     block_k: int = 1024, force_pallas: bool = False,
-                    lengths=None, num_heads: int = 0):
+                    lengths=None, num_heads: int = 0, select=None):
     """Flash attention over ``[B, H, S, D]`` tensors, or token-major
     ``[B, T, H*hd]`` ones with ``num_heads`` (the layout is read from the
     operands' rank; the context comes back in it) — differentiable,
@@ -1151,9 +1250,17 @@ def flash_attention(q, k, v, causal: bool = False,
     QUERY rows produce zeros/garbage exactly like the additive-mask
     formulation; mask the loss, as seq2seq training already does.
 
+    ``select`` ([B, S, S] int8, non-zero where query row r may see key
+    column c; one selection for all the heads of a batch row) is a
+    per-query key selection applied inside the streaming kernels beside
+    the causal mask (head-major operands, shared K/V heads or not; the
+    dense math elsewhere). Every causal block is still visited: a
+    selection hides keys, it skips no block yet. A selection of every
+    causal key gives the unselected result bit for bit.
+
     Timings on the v5e: PERF.md section 6, "PR 25" and "PR 29"
     (``tools/attn_bench.py`` repeats them).
     """
     return flash_attention_with_lse(q, k, v, causal, scale, block_q,
                                     block_k, force_pallas, lengths,
-                                    num_heads)[0]
+                                    num_heads, select)[0]

@@ -219,7 +219,8 @@ def test_counter_names_the_path_a_trace_took(path):
              if name.startswith("kernels.flash_attention")
              and after[name] != before.get(name, 0)}
     assert grown == {key: 1,
-                     "kernels.flash_attention_layout{layout=heads}": 1}
+                     "kernels.flash_attention_layout{layout=heads}": 1,
+                     "kernels.flash_attention_select{form=none}": 1}
     assert (outs["LSE"] is None) == (path == "dense")
     ref = _dense_attention(q, k, v, False, float(D) ** -0.5)
     np.testing.assert_allclose(np.asarray(outs["Out"]), np.asarray(ref),

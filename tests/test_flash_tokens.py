@@ -247,7 +247,8 @@ def test_token_major_op_falls_back_inside_the_op(T, path):
         outs, grown = _counters_grown(lambda: _op("flash_attention")(
             {"Q": q, "K": k, "V": v, "Lengths": None}, attrs))
     assert grown == {"kernels.flash_attention{path=%s}" % path: 1,
-                     "kernels.flash_attention_layout{layout=tokens}": 1}
+                     "kernels.flash_attention_layout{layout=tokens}": 1,
+                     "kernels.flash_attention_select{form=none}": 1}
     assert outs["Out"].shape == q.shape
     assert (outs["LSE"] is None) == (path == "dense")
     ref = _dense_tokens(q, k, v, H, True, float(hd) ** -0.5, None)
@@ -426,7 +427,8 @@ def test_counter_names_the_layout_a_trace_was_given(layout):
         _, grown = _counters_grown(lambda: _op("flash_attention")(
             {"Q": q, "K": k, "V": v, "Lengths": None}, attrs))
     assert grown == {"kernels.flash_attention{path=short}": 1,
-                     "kernels.flash_attention_layout{layout=%s}" % layout: 1}
+                     "kernels.flash_attention_layout{layout=%s}" % layout: 1,
+                     "kernels.flash_attention_select{form=none}": 1}
 
 
 @pytest.mark.parametrize("layout", ["tokens", "heads"])

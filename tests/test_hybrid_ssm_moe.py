@@ -585,6 +585,8 @@ def test_the_model_keeps_its_head_major_attention(recompute, ops, digest):
              and after[name] != before.get(name, 0)}
     assert grown == {"kernels.flash_attention{path=dense}": 1 + recompute,
                      "kernels.flash_attention_layout{layout=heads}":
+                     1 + recompute,
+                     "kernels.flash_attention_select{form=none}":
                      1 + recompute}
 
 

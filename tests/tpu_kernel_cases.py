@@ -90,6 +90,19 @@ def cases():
     q, kv = ((1, 32, 8192, 128), bf16), ((1, 2, 8192, 128), bf16)
     yield "flash_gqa_causal_s8192_d128", flash(True, 512, 1024), [q, kv, kv]
 
+    # the same kernels with a per-query key selection (32 over 4 heads, head
+    # dim 128, T = 2048): the [B, S, S] int8 operand's tiles ride beside
+    # the K blocks, forward, dQ and dK/dV
+    def selected(q, k, v, select):
+        out, vjp = jax.vjp(
+            lambda q, k, v: fa._flash_selected(q, k, v, select, True, 0.125,
+                                               512, 1024, False)[0], q, k, v)
+        return (out,) + vjp(out)
+
+    q, kv = ((1, 32, 2048, 128), bf16), ((1, 4, 2048, 128), bf16)
+    yield ("flash_gqa_selected_s2048_d128", selected,
+           [q, kv, kv, ((1, 2048, 2048), jnp.int8)])
+
     # the held experts' grouped products on the megablox kernels, forward
     # and both backward kernels, at the hybrid cell's shapes: a buffer of
     # 6,144 sorted slots, 8 experts of 2688 x 1856 and back
