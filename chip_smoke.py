@@ -63,6 +63,7 @@ FULL = {
     "short_dp": dict(b=128, h=12, s=128, d=64),
     "scan": dict(b=1, t=2048, h=16, p=64, g=2, n=128),
     "selected": dict(b=1, h=32, kv=4, s=2048, d=128, keep=512),
+    "index": dict(r=512, s=2048, h=16, d=64),
     "decode": dict(streams=5, max_tokens=12),
     "dp_steps": 4,
 }
@@ -77,6 +78,7 @@ REHEARSAL = {
     "short_dp": dict(b=4, h=4, s=128, d=32),
     "scan": dict(b=1, t=256, h=4, p=64, g=2, n=128),
     "selected": dict(b=1, h=4, kv=2, s=256, d=16, keep=64),
+    "index": dict(r=32, s=64, h=2, d=8),
     "decode": dict(streams=3, max_tokens=6),
     "dp_steps": 2,
 }
@@ -594,6 +596,65 @@ def phase_kernels(sizes, dev_rec, platform, xla):
     # bf16 operands on both sides, summed in another order: as the flash cases
     assert max(errs.values()) < 2e-2, ("ssd_scan", errs)
     report["ssd_scan"] = {"mosaic_calls": n, "rel_err": errs, "tol": 2e-2}
+
+    # -- the indexer's float32 product from bfloat16 pieces ------------------
+    # the three pieces of a float32 value as the device makes them under
+    # jit (no rounding folded away: mid and lo hold bits, the sum is the
+    # value), and the packed product and its VJP against float64 on the host
+    from paddle_tpu.ops import sparse_attn_ops as sa
+
+    c = sizes["index"]
+
+    def spanning(*shape):
+        """Normal draws scaled over e^-4..e^4."""
+        return (rng.randn(*shape) * np.exp(rng.uniform(-4, 4, shape))).astype(
+            np.float32)
+
+    qi, ki, wi, gi = (spanning(c["r"], c["h"], c["d"]),
+                      spanning(c["s"], c["d"]), spanning(c["r"], c["h"]),
+                      spanning(c["r"], c["s"]))
+    pieces = [np.asarray(piece.astype(f32), np.float64)
+              for piece in jax.jit(sa.split3)(ki)]
+    assert np.array_equal(sum(pieces), ki.astype(np.float64)), "pieces' sum"
+    held = [float(np.mean(piece != 0)) for piece in pieces]
+    assert min(held) > 0.9, ("a piece is empty", held)
+
+    def scores_and_vjp(scores):
+        def f(q, k, w, g):
+            out, vjp = jax.vjp(scores, q, k, w)
+            return (out,) + vjp(g)
+        return jax.jit(f)
+
+    def highest_scores(q, k, w):
+        s = jnp.einsum("rhd,sd->hrs", q, k, precision=jax.lax.Precision.HIGHEST)
+        return jnp.sum(jax.nn.relu(s) * w.T[:, :, None], 0) + 0.0
+
+    q64, k64, w64 = (a.astype(np.float64) for a in (qi, ki, wi))
+    s64 = np.einsum("rhd,sd->hrs", q64, k64)
+    # no cotangent where a head's product is within rounding of the ReLU's
+    # kink: there float32 and float64 may take different sides of it
+    gi[(np.abs(s64) < 1e-5 * np.einsum("rhd,sd->hrs", np.abs(q64),
+                                       np.abs(k64))).any(0)] = 0.0
+    g64 = gi.astype(np.float64)
+    ds64 = (s64 > 0) * g64[None] * w64.T[:, :, None]
+    want = (np.sum(np.maximum(s64, 0) * w64.T[:, :, None], 0),
+            np.einsum("hrs,sd->rhd", ds64, k64),
+            np.einsum("hrs,rhd->sd", ds64, q64),
+            np.sum(np.maximum(s64, 0) * g64[None], -1).T)
+
+    def norm_errs(got):
+        return {t: float(np.linalg.norm(np.asarray(a, np.float64) - b)
+                         / np.linalg.norm(b))
+                for t, a, b in zip(("scores", "dq", "dk", "dw"), got, want)}
+
+    errs = norm_errs(scores_and_vjp(sa.index_scores)(qi, ki, wi, gi))
+    plain = norm_errs(scores_and_vjp(highest_scores)(qi, ki, wi, gi))
+    # float32-accurate: under 1e-6 of each result's norm, the scores no
+    # worse than twice what one float32 product at HIGHEST leaves here
+    assert max(errs.values()) < 1e-6 and errs["scores"] < 2 * plain[
+        "scores"], ("index_scores", errs, plain)
+    report["index_scores"] = {"pieces_nonzero": held, "norm_err": errs,
+                              "norm_err_highest": plain, "tol": 1e-6}
 
     # -- paged attention at the decode engine's shapes ----------------------
     from paddle_tpu.serving.decode import DecodeConfig, DecodeEngine

@@ -18,6 +18,14 @@ each query's keys (DeepSeek sparse attention's lightning indexer).
 
 Everything that sets the choice is float32 at full precision: a selection
 that differs from the exact one is a different function, not a rounding.
+The projections and the scores' transposed products are float32 products at
+``Precision.HIGHEST`` (on the TPU six bfloat16 partial products of the
+operands' three bfloat16 pieces, accumulated in float32). The index scores
+themselves, contraction d = 64 on a 128-deep array, are those same six
+partial products as ONE bfloat16 product over the pieces laid side by side
+along the contraction (``split3``, ``pack_keys``, ``packed_scores``):
+contraction 6 d, three full passes where ``HIGHEST`` makes six half-filled
+ones, nothing dropped and float32 accumulation; one path for every d.
 Nothing of [T, T] is made whole but the int8 selection: scores are made a
 block of query rows at a time, over the keys up to the block's causal group
 only, and the ``topk``-th largest of a row is found as an exact threshold
@@ -149,14 +157,77 @@ def _attn_index_project(ins, attrs):
     return {"QI": q, "KI": k, "W": w}
 
 
+def split3(x):
+    """float32 ``x`` as three bfloat16 pieces (hi, mid, lo) with
+    ``hi + mid + lo == x`` to float32's last bit: each piece is the bfloat16
+    nearest to what the ones before it leave, and the differences are exact
+    in float32. ``reduce_precision`` rounds in place: a convert to bfloat16
+    and back may be folded away (XLA keeps excess precision where it may),
+    which would leave ``mid`` and ``lo`` zero."""
+    def nearest(v):
+        return jax.lax.reduce_precision(v, exponent_bits=8, mantissa_bits=7)
+
+    hi = nearest(x)
+    mid = nearest(x - hi)
+    lo = nearest(x - hi - mid)
+    return tuple(p.astype(jnp.bfloat16) for p in (hi, mid, lo))
+
+
+def pack_keys(ki):
+    """bfloat16 [S, 6 d]: the keys' pieces ``[hi | mid | hi | mid | lo | hi]``
+    along the contraction, against the queries' ``[hi | hi | mid | mid | hi |
+    lo]``: the six partial products a float32 product at ``HIGHEST`` keeps
+    (hi.hi, hi.mid, mid.hi, mid.mid, hi.lo, lo.hi) in one bfloat16 product
+    of contraction 6 d, accumulated in float32 as the MXU accumulates any
+    contraction. At d = 64 that is three full passes of a 128-deep array
+    where ``HIGHEST`` makes six half-filled ones."""
+    hi, mid, lo = split3(ki)
+    return jnp.concatenate([hi, mid, hi, mid, lo, hi], -1)
+
+
+@jax.custom_vjp
+def packed_scores(qi, ki, w, ks):
+    """``index_scores(qi, ki, w)`` with ``ks = pack_keys(ki)`` made by the
+    caller, once for all its blocks of query rows. The gradient goes to qi,
+    ki and w; ks takes none."""
+    return _packed_scores_fwd(qi, ki, w, ks)[0]
+
+
+def _packed_scores_fwd(qi, ki, w, ks):
+    hi, mid, lo = split3(qi)
+    qs = jnp.concatenate([hi, hi, mid, mid, hi, lo], -1)
+    s = jnp.einsum("rhk,sk->hrs", qs, ks,
+                   preferred_element_type=jnp.float32)
+    out = jnp.sum(jax.nn.relu(s) * w.T[:, :, None], 0) + 0.0
+    return out, (s, qi, ki, w)
+
+
+def _packed_scores_bwd(res, g):
+    """dq = ds . k and dk = ds^T . q with ds[h, r, s] = g[r, s] w[r, h]
+    [s_hrs > 0], float32 products of qi and ki themselves: the packed
+    product's automatic transpose would multiply ds, rounded to bfloat16,
+    by the pieces. Their contraction (keys; heads x rows) fills the array
+    and their operand ds is made once a product, so ``HIGHEST`` stays: its
+    six terms packed two side by side (``[k_hi | k_mid]``, four passes) pay
+    for four makings of ds what they save in passes (``PERF.md``, PR 36)."""
+    s, qi, ki, w = res
+    ds = jnp.where(s > 0, g[None] * w.T[:, :, None], 0.0)
+    dq = jnp.einsum("hrs,sd->rhd", ds, ki, precision=_HI)
+    dk = jnp.einsum("hrs,rhd->sd", ds, qi, precision=_HI)
+    dw = jnp.sum(jax.nn.relu(s) * g[None], -1).T
+    return dq, dk, dw, None
+
+
+packed_scores.defvjp(_packed_scores_fwd, _packed_scores_bwd)
+
+
 def index_scores(qi, ki, w):
     """I [R, S] float32 = sum_h w[r, h] relu(qi[r, h] . ki[s]) for query
-    rows qi [R, H, d], w [R, H] and keys ki [S, d]. The sum over heads is
+    rows qi [R, H, d], w [R, H] and keys ki [S, d]. The products are float32
+    at full precision, as ``pack_keys`` makes them; the sum over heads is
     elementwise (no second matrix product to round it), and ``+ 0.0`` makes
     every zero a positive one: the selection orders bit patterns."""
-    s = jnp.einsum("rhd,sd->hrs", qi, ki, precision=_HI,
-                   preferred_element_type=jnp.float32)
-    return jnp.sum(jax.nn.relu(s) * w.T[:, :, None], 0) + 0.0
+    return packed_scores(qi, ki, w, pack_keys(ki))
 
 
 def _ordered(x):
@@ -228,12 +299,14 @@ def select_mask(qi, ki, w, topk):
     w [T, H]."""
     T = qi.shape[0]
     groups, block = _row_plan(T, SELECT_ROWS)
+    with jax.named_scope("score"):
+        ks = pack_keys(ki)
     parts = []
     for lo, hi, keys in groups:
         def one(args, keys=keys):
             q_b, w_b, row0 = args
             with jax.named_scope("score"):
-                scores = index_scores(q_b, ki[:keys], w_b)
+                scores = packed_scores(q_b, ki[:keys], w_b, ks[:keys])
             with jax.named_scope("select"):
                 return select_rows(scores, row0, topk)
 
@@ -319,12 +392,14 @@ def index_loss(ins, scale):
     total, count = jnp.zeros((), jnp.float32), 0
     for row in _loss_operands(ins):
         ki, k = row.ki, row.k
+        with jax.named_scope("score"):
+            ks = pack_keys(ki)
         count += row.qi.shape[0]
         for keys, xs in _loss_blocks(row):
             def one(args, keys=keys):
                 q_b, w_b, sel_b, qa_b, lse_b = args
                 with jax.named_scope("score"):
-                    scores = index_scores(q_b, ki[:keys], w_b)
+                    scores = packed_scores(q_b, ki[:keys], w_b, ks[:keys])
                 with jax.named_scope("loss"):
                     return _block_kl(scores, sel_b != 0, qa_b, k[:keys],
                                      jnp.moveaxis(lse_b, 0, -1), scale)[0]
@@ -341,18 +416,21 @@ def index_loss_grad(ins, scale, g):
     count = sum(row.qi.shape[0] for row in rows)
     for row in rows:
         qi, ki, w, k = row.qi, row.ki, row.w, row.k
+        with jax.named_scope("score"):
+            ks = pack_keys(ki)
         dk = jnp.zeros(ki.shape, jnp.float32)
         dq_parts, dw_parts = [], []
         for keys, xs in _loss_blocks(row):
             def one(dk_g, args, keys=keys):
                 q_b, w_b, sel_b, qa_b, lse_b = args
                 with jax.named_scope("score"):
-                    scores, vjp = jax.vjp(index_scores, q_b, ki[:keys], w_b)
+                    scores, vjp = jax.vjp(packed_scores, q_b, ki[:keys],
+                                          w_b, ks[:keys])
                 with jax.named_scope("loss"):
                     diff = _block_kl(scores, sel_b != 0, qa_b, k[:keys],
                                      jnp.moveaxis(lse_b, 0, -1), scale)[1]
                 with jax.named_scope("score"):
-                    dq_b, dk_b, dw_b = vjp(diff * (g / count))
+                    dq_b, dk_b, dw_b, _ = vjp(diff * (g / count))
                 return dk_g + dk_b, (dq_b, dw_b)
 
             dk_g, (dq_g, dw_g) = jax.lax.scan(

@@ -177,6 +177,20 @@ def test_bert_step_for_v5e_holds_one_forward_kernel_a_layer(v5e_compile):
         % (n, n) for n in (2, 1)]
 
 
+def test_index_scores_for_v5e_are_one_packed_product(v5e_compile):
+    """A block of 512 query rows over 16,384 keys, 16 heads of 64: ONE
+    product, of the bfloat16 pieces side by side (contraction 384), none at
+    ``HIGHEST``, and the ReLU, the heads' weights and the sum over heads
+    fused into its output (no [heads, rows, keys] array: under 64 MiB of
+    temporaries where one such array is 512)."""
+    (line,) = [x for x in v5e_compile.stdout.splitlines()
+               if x.startswith("INDEX_SCORES ")]
+    got = {k: int(v) for k, v in (kv.split("=") for kv in line.split()[1:])}
+    assert (got["products"], got["highest"], got["packed_keys"]) == (
+        1, 0, 1), line
+    assert got["temporaries_mib"] < 64, line
+
+
 @pytest.mark.parametrize("state_size, scan_calls, scans, recomputing", [
     (128, 9, "3/3/3", "6/3/3"), (64, 0, "0/0/0", "0/0/0")])
 def test_recomputation_lowers_the_compiled_steps_temporaries(

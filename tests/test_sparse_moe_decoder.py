@@ -176,6 +176,79 @@ def test_index_scores_are_the_references():
     assert rel(jnp.where(seen, got, 0.0), jnp.where(seen, want, 0.0)) < 1e-6
 
 
+def highest_scores(qi, ki, w):
+    """``index_scores`` as one float32 product at ``HIGHEST``."""
+    s = jnp.einsum("rhd,sd->hrs", qi, ki, precision=jax.lax.Precision.HIGHEST)
+    return jnp.sum(jax.nn.relu(s) * w.T[:, :, None], 0) + 0.0
+
+
+def spanning(rng, *shape):
+    """float32 normal draws with magnitudes over 1e-4..1e4."""
+    return (rng.randn(*shape) * 10 ** rng.uniform(-4, 4, shape)).astype(
+        np.float32)
+
+
+@pytest.mark.parametrize("d", [32, 64, 128])
+def test_packed_product_is_a_float32_product(d):
+    """Against float64: no further off than twice one float32 product at
+    ``HIGHEST`` on the same operands, and under 1e-6 of the result's norm."""
+    rng = np.random.RandomState(d)
+    qi, ki, w = spanning(rng, 64, 4, d), spanning(rng, 256, d), spanning(
+        rng, 64, 4)
+    s = np.einsum("rhd,sd->hrs", *(a.astype(np.float64) for a in (qi, ki)))
+    want = np.sum(np.maximum(s, 0) * w.astype(np.float64).T[:, :, None], 0)
+
+    def err(scores):
+        got = np.asarray(jax.jit(scores)(qi, ki, w), np.float64)
+        return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+    packed, plain = err(sa.index_scores), err(highest_scores)
+    assert packed < 1e-6 and packed <= 2 * plain, (packed, plain)
+
+
+def test_the_three_pieces_add_back_to_the_value_under_jit():
+    """hi + mid + lo is the float32 value, bit for bit, and the compiler has
+    folded no rounding away: mid and lo hold bits."""
+    x = spanning(np.random.RandomState(3), 256, 64)
+    pieces = jax.jit(sa.split3)(x)
+    assert all(p.dtype == jnp.bfloat16 for p in pieces)
+    hi, mid, lo = (np.asarray(p.astype(jnp.float32), np.float64)
+                   for p in pieces)
+    assert np.array_equal(hi + mid + lo, x.astype(np.float64))
+    assert np.mean(mid != 0) > 0.9 and np.mean(lo != 0) > 0.9
+
+
+@pytest.mark.parametrize("keys", [T, T // 2], ids=["full", "group_cut"])
+def test_index_scores_vjp_is_the_plain_products_gradient(keys):
+    """dq, dk, dw of the packed form's own VJP against ``jax.grad`` of the
+    float32 product at ``HIGHEST``, for a block over all the keys and one
+    over its causal group's keys only."""
+    ins = indexer_inputs(6)
+    with jax.default_matmul_precision("highest"):
+        qi, ki, w = (x[0] for x in plain_indexer(ins))
+    q_b, k_b, w_b = qi[keys - 16:keys], ki[:keys], w[keys - 16:keys]
+    g = jax.random.normal(jax.random.key(8), (16, keys))
+
+    def grads(scores):
+        return jax.jit(jax.grad(lambda *a: jnp.sum(scores(*a) * g),
+                                (0, 1, 2)))(q_b, k_b, w_b)
+
+    for name, got, want in zip(("dq", "dk", "dw"), grads(sa.index_scores),
+                               grads(highest_scores)):
+        assert got.shape == want.shape and rel(got, want) < 1e-5, name
+
+
+def test_duplicated_keys_give_bit_equal_scores():
+    """Equal keys score equal to the last bit in every row, so ties are cut
+    by position alone."""
+    rng = np.random.RandomState(9)
+    qi, ki, w = spanning(rng, 32, 4, 64), spanning(rng, 128, 64), spanning(
+        rng, 32, 4)
+    ki[1::2] = ki[0::2]
+    scores = np.asarray(jax.jit(sa.index_scores)(qi, ki, w)).view(np.uint32)
+    assert np.array_equal(scores[:, 0::2], scores[:, 1::2])
+
+
 @pytest.mark.parametrize("topk", [TOPK, 1, T])
 def test_selection_op_is_top_k_of_the_causal_scores(topk):
     ins = indexer_inputs(2)

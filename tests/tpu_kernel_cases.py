@@ -257,9 +257,32 @@ def bert_step_report(hlo, seq=512) -> str:
                                len(split)))
 
 
+def index_scores_report(sharding, rows=512, keys=16384, heads=16, d=64) -> str:
+    """``INDEX_SCORES products=<n> highest=<n> packed_keys=<n>
+    temporaries_mib=<n>`` of the indexer's scores for one block of query
+    rows at the sparse-attention cell's shape, compiled for the chip: the
+    matrix products the program holds, how many of them run at ``HIGHEST``,
+    whether the keys' bfloat16 pieces side by side ([keys, 6 d]) reach the
+    product, and the program's temporaries (the epilogue fused into the
+    product's output leaves no [heads, rows, keys] array: 512 MiB)."""
+    from paddle_tpu.ops import sparse_attn_ops as sa
+
+    args = [jax.ShapeDtypeStruct(shape, f32, sharding=sharding)
+            for shape in ((rows, heads, d), (keys, d), (rows, heads))]
+    compiled = jax.jit(sa.index_scores).lower(*args).compile()
+    hlo = compiled.as_text()
+    products = [x for x in hlo.splitlines() if " convolution(" in x]
+    return ("INDEX_SCORES products=%d highest=%d packed_keys=%d "
+            "temporaries_mib=%d" % (
+                len(products), sum("highest" in x for x in products),
+                "bf16[%d,%d" % (keys, 6 * d) in hlo,
+                compiled.memory_analysis().temp_size_in_bytes >> 20))
+
+
 def compile_all_for_v5e() -> int:
     """Compile every case and the BERT step for a compile-only v5e
-    topology; print one OK/FAIL line each and the BERT_STEP line. Exit
+    topology; print one OK/FAIL line each and the BERT_STEP, INDEX_SCORES
+    and HYBRID_STEP lines. Exit
     codes: 0 all compiled, 1 something was refused, 3 no TPU compiler
     could be set up on this host."""
     from jax.experimental import topologies
@@ -294,6 +317,12 @@ def compile_all_for_v5e() -> int:
             failed += 1
             print("FAIL bert_step T=%d %s: %s" % (
                 seq, type(e).__name__, str(e)[:800].replace("\n", " | ")))
+    try:
+        print(index_scores_report(sharding))
+    except Exception as e:  # noqa: BLE001 — reported like a case
+        failed += 1
+        print("FAIL index_scores %s: %s" % (
+            type(e).__name__, str(e)[:800].replace("\n", " | ")))
     # both sides of ``ssm_ops.scan_path`` on the TPU: the kernels, and the
     # XLA form with its ``jax.vjp`` behind a barrier in the gradient op
     for state_size in (128, 64):
