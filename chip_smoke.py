@@ -63,6 +63,7 @@ FULL = {
     "short_dp": dict(b=128, h=12, s=128, d=64),
     "scan": dict(b=1, t=2048, h=16, p=64, g=2, n=128),
     "selected": dict(b=1, h=32, kv=4, s=2048, d=128, keep=512),
+    "latent": dict(b=1, h=32, s=4096, d=192, dv=128),
     "index": dict(r=512, s=2048, h=16, d=64),
     "decode": dict(streams=5, max_tokens=12),
     "dp_steps": 4,
@@ -78,6 +79,7 @@ REHEARSAL = {
     "short_dp": dict(b=4, h=4, s=128, d=32),
     "scan": dict(b=1, t=256, h=4, p=64, g=2, n=128),
     "selected": dict(b=1, h=4, kv=2, s=256, d=16, keep=64),
+    "latent": dict(b=1, h=2, s=256, d=24, dv=16),
     "index": dict(r=32, s=64, h=2, d=8),
     "decode": dict(streams=3, max_tokens=6),
     "dp_steps": 2,
@@ -440,15 +442,17 @@ def phase_kernels(sizes, dev_rec, platform, xla):
         dQ, dK+dV; "short": fwd and one backward kernel). ``block``
         smaller than the sequence keeps a short sequence on the
         streaming kernels. ``tokens``: operands [b, s, h * d] as the
-        projections leave them, for the token-major short kernels."""
+        projections leave them, for the token-major short kernels.
+        ``c["dv"]``: a head dim of v's own (and the context's)."""
         shape = (c["b"], c["h"], c["s"], c["d"])
         blocks = {} if block is None else {"block_q": block,
                                            "block_k": block}
         if tokens:
             shape = (c["b"], c["s"], c["h"] * c["d"])
             blocks["num_heads"] = c["h"]
-        q, k, v, w = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
-                      for _ in range(4))
+        v_shape = shape[:-1] + (c.get("dv", shape[-1]),)
+        q, k, v, w = (jnp.asarray(rng.randn(*sh), jnp.bfloat16)
+                      for sh in (shape, shape, v_shape, v_shape))
         scale = float(c["d"]) ** -0.5
         lens = None if lengths is None else jnp.asarray(lengths, jnp.int32)
 
@@ -477,7 +481,7 @@ def phase_kernels(sizes, dev_rec, platform, xla):
                                                has_aux=True)(q, k, v)
             return (o,) + g
 
-        took = fa.attention_path(q, k, force_pallas=True, **blocks)
+        took = fa.attention_path(q, k, force_pallas=True, v=v, **blocks)
         assert took == path, (name, took)
         if tokens:   # the token-major kernels' blocks, not a split and merge
             planned = fa._plan(q, k, 512, 1024, c["h"])[0]
@@ -517,6 +521,38 @@ def phase_kernels(sizes, dev_rec, platform, xla):
     if m["h"] * m["d"] % 128 == 0:   # the rehearsal's toy heads are 32 wide
         flash_case("flash_tokens_masked", m, True, lengths, 2e-2, "short",
                    tokens=True)
+
+    # latent attention's shape: 32 heads, q and k at 192, v and the context
+    # at 128, T = 4096, causal, on the streaming kernels against the dense
+    # form, forward and the three gradients
+    la = sizes["latent"]
+    flash_case("flash_value_dim", la, True, None, 2e-2, "stream",
+               block=None if la["s"] > 1024 else la["s"] // 2)
+    # and at equal dims the entry that now reads v's head dim gives, bit for
+    # bit, what the kernels give when planned and called as before it did
+    eq = dict(la, d=la["dv"])
+    q, k, v = (jnp.asarray(rng.randn(eq["b"], eq["h"], eq["s"], eq["d"]),
+                           jnp.bfloat16) for _ in range(3))
+    blk = ({} if eq["s"] > 1024 else
+           {"block_q": eq["s"] // 2, "block_k": eq["s"] // 2})
+
+    def by_entry(q, k, v):
+        out, vjp = jax.vjp(lambda *a: fa.flash_attention(
+            *a, causal=True, force_pallas=True, **blk), q, k, v)
+        return (out,) + vjp(out)
+
+    def as_before(q, k, v):
+        short, bq, bk = fa._plan(q, k, blk.get("block_q", 512),
+                                 blk.get("block_k", 1024))
+        out, vjp = jax.vjp(lambda *a: fa._flash(
+            *a, None, True, float(eq["d"]) ** -0.5, bq, bk, short,
+            not on_tpu)[0], q, k, v)
+        return (out,) + vjp(out)
+
+    same = [bool(jnp.array_equal(a, b)) for a, b in zip(
+        jax.jit(by_entry)(q, k, v), jax.jit(as_before)(q, k, v))]
+    assert all(same), ("flash_equal_dims", same)
+    report["flash_equal_dims"] = {"bit_for_bit": same}
 
     # -- the streaming kernels with a per-query key selection ---------------
     # shared K/V heads, causal, each query keeping ``keep`` of its causal

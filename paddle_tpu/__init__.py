@@ -21,6 +21,7 @@ from .framework import (  # noqa: F401
     default_startup_program,
     in_dygraph_mode,
     program_guard,
+    name_scope,
 )
 from .core import (  # noqa: F401
     CPUPlace,

@@ -92,6 +92,10 @@ black_list = {
     "rms_norm",
     "instance_norm",
     "group_norm",
+    # the residual streams and the maps that mix them (flat norm, product,
+    # sigmoids, Sinkhorn rounds): float32 in and out
+    "mhc_pre",
+    "mhc_post",
 }
 
 # follow their inputs (reference gray_list)

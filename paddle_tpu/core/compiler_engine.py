@@ -416,9 +416,11 @@ def _trace_ops(block, ops, env: Dict, step_seed) -> None:
     # role = forward | backward | collective | optimizer, so a device
     # trace of any compiled step says which part of the step an
     # operation belongs to. named_scope adds NO equations, only
-    # name-stack metadata: trace-time cost, nothing per step.
+    # name-stack metadata: trace-time cost, nothing per step. An op built
+    # inside ``fluid.name_scope`` has that scope as a third component.
     for op, role in zip(ops, classify_ops(block, ops)):
-        with jax.named_scope("%s/%s" % (role, op.type)):
+        inner = (op.attrs.get("op_namescope") or "").strip("/")
+        with jax.named_scope("/".join(filter(None, (role, op.type, inner)))):
             trace_one(op)
 
 

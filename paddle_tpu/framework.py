@@ -232,6 +232,9 @@ class Block:
     # -- ops --------------------------------------------------------------
     def append_op(self, type, inputs=None, outputs=None, attrs=None,
                   infer_shape=True) -> Operator:
+        if _name_scopes and "op_namescope" not in (attrs or {}):
+            attrs = dict(attrs or {},
+                         op_namescope="/%s/" % "/".join(_name_scopes))
         op = Operator(self, type, inputs, outputs, attrs)
         op._id = self.program._next_op_id()
         self.ops.append(op)
@@ -511,6 +514,24 @@ def switch_startup_program(program: Program) -> Program:
     old = _startup_program_
     _startup_program_ = program
     return old
+
+
+_name_scopes: List[str] = []
+
+
+@contextlib.contextmanager
+def name_scope(prefix: str):
+    """Ops appended inside carry the attribute ``op_namescope``
+    (``"/outer/inner/"``, reference framework.py name_scope); their gradient
+    ops and recomputed copies inherit it with the other attributes. A compiled
+    step traces such an op inside ``jax.named_scope("<role>/<op_type>/
+    <outer/inner>")``, so a device trace tells one part of a model from
+    another that is built from the same op types."""
+    _name_scopes.append(str(prefix).strip("/"))
+    try:
+        yield
+    finally:
+        _name_scopes.pop()
 
 
 @contextlib.contextmanager

@@ -961,7 +961,8 @@ def flash_attention(q, k, v, causal=False, scale=0.0, lengths=None,
                     num_heads=0, select=None, return_lse=False):
     """Fused attention over q [B, H, S, D] and k, v [B, H_kv, S, D], H_kv
     dividing H (the multihead hot path; fewer K/V heads are shared —
-    reference fused/multihead_matmul_op.cu), or over token-major q, k, v
+    reference fused/multihead_matmul_op.cu; v's head dim may differ from
+    q's and k's, and is then the context's), or over token-major q, k, v
     [B, T, H*hd] with ``num_heads``, as the projections leave them: the
     layout is the operands' rank, and the context comes back in it (no
     head split or merge in the program). Lowers to the Pallas flash
@@ -985,7 +986,7 @@ def flash_attention(q, k, v, causal=False, scale=0.0, lengths=None,
     if select is not None:
         ins["Select"] = [select]
     # no shape inference: it would trace the kernels at build time (and
-    # count a path that no step runs); Out has Q's shape
+    # count a path that no step runs); Out has Q's shape with V's last axis
     helper.append_op(
         "flash_attention", inputs=ins,
         outputs={"Out": [out], "LSE": [lse]},
@@ -993,7 +994,7 @@ def flash_attention(q, k, v, causal=False, scale=0.0, lengths=None,
                "num_heads": int(num_heads)},
         infer_shape=False)
     if not framework.in_dygraph_mode():
-        out.shape = tuple(q.shape)
+        out.shape = tuple(q.shape[:-1]) + (v.shape[-1],)
     return (out, lse) if return_lse else out
 
 
@@ -1171,21 +1172,60 @@ def moe_topk(input, num_experts, k, hidden_dim, held=None, scaling=1.0,
     return (out, load) if return_load else out
 
 
-def rotary_embedding(input, positions, theta=10000.0, sections=None,
-                     rotary_dims=0):
+def yarn_inv_freq(dim, theta, factor, original_positions, beta_fast=32.0,
+                  beta_slow=1.0):
+    """YaRN's ``dim / 2`` frequencies for ``rotary_embedding(inv_freq=)``:
+    pair ``i`` blends the scaled and the unscaled one, ``(1 - m_i)
+    theta^(-2i/dim) / factor + m_i theta^(-2i/dim)`` with ``m_i = 1 -
+    clip((i - lo) / (hi - lo), 0, 1)``; ``lo`` and ``hi`` are the pairs that
+    make ``beta_fast`` and ``beta_slow`` turns over ``original_positions``
+    (floor and ceiling, within the head)."""
+    import math
+
+    def pair_of(turns):
+        return (dim * math.log(original_positions / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(pair_of(beta_fast)), 0)
+    hi = min(math.ceil(pair_of(beta_slow)), dim - 1)
+    if hi == lo:
+        hi += 0.001
+    i = np.arange(dim // 2, dtype=np.float64)
+    plain = theta ** (-2.0 * i / dim)
+    m = 1.0 - np.clip((i - lo) / (hi - lo), 0.0, 1.0)
+    return [float(f) for f in (1.0 - m) * plain / factor + m * plain]
+
+
+def yarn_mscale(factor, mscale=1.0):
+    """YaRN's attention factor ``0.1 mscale ln(factor) + 1`` (1 without
+    scaling): a model whose ``mscale`` equals its ``mscale_all_dim`` leaves
+    cos and sin alone and multiplies the scores' scale by its square."""
+    import math
+
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rotary_embedding(input, positions=None, theta=10000.0, sections=None,
+                     rotary_dims=0, inv_freq=None, offset=0):
     """Rotary positions on [B, T, H, hd], rotate-half form: frequency pair
-    ``i`` of the ``rotary_dims / 2`` pairs (``rotary_dims`` 0: the whole
-    head) turns by ``pos * theta^(-i / pairs)``. ``positions`` [3, B, T]
+    ``i`` of the ``rotary_dims / 2`` pairs (``rotary_dims`` 0: the head from
+    dim ``offset`` on; the rotated slice starts there) turns by ``pos *
+    theta^(-i / pairs)``, or by ``pos * inv_freq[i]`` where the frequencies
+    are given (``yarn_inv_freq``). ``positions`` [3, B, T]
     int holds three components (temporal, height, width) and ``sections``
     says how many pairs each takes, in order (None: all from the first);
-    text has the three equal (ops/sparse_attn_ops.py)."""
+    text has the three equal; without ``positions`` every row is one
+    document, ``0..T-1`` (ops/sparse_attn_ops.py)."""
     helper = LayerHelper("rotary_embedding", input=input)
     out = helper.create_variable_for_type_inference(input.dtype)
+    ins = {"X": [input]}
+    if positions is not None:
+        ins["Pos"] = [positions]
     helper.append_op(
-        "rotary_embedding", inputs={"X": [input], "Pos": [positions]},
-        outputs={"Out": [out]},
+        "rotary_embedding", inputs=ins, outputs={"Out": [out]},
         attrs={"theta": float(theta), "sections": list(sections or []),
-               "rotary_dims": int(rotary_dims)})
+               "rotary_dims": int(rotary_dims), "offset": int(offset),
+               "inv_freq": [float(f) for f in inv_freq or ()]})
     return out
 
 
@@ -1275,9 +1315,73 @@ def attn_index_loss(qi, ki, w, select, q, k, lse, scale):
     return loss
 
 
+def mhc_pre(x, sinkhorn_iters=20, epsilon=1e-6, clamp=(-30.0, 30.0),
+            name=None):
+    """The read side of manifold-constrained hyper-connections around one
+    sublayer, over the streams x [B, n, T, C], stream-major
+    (ops/hyper_connection_ops.py):
+    returns ``(h, h_post, h_res)``: the sublayer's input ``h`` [B, T, C] =
+    ``sum_i H_pre[i] x[i]``, and the maps ``mhc_post`` writes the sublayer's
+    output back with, ``H_post`` [B, n, T] and the Sinkhorn-normalised
+    ``H_res`` [B, n, n, T]. Parameters in order: ``Phi`` [n C, 2 n + n^2]
+    (N(0, 0.02)), ``alpha`` [3] (pre, post, res; 0.01), ``b_pre`` [n]
+    (logit(1 / n): ``h`` starts as the streams' mean), ``b_post`` [n] (0:
+    ``H_post`` starts at 1), ``b_res`` [n, n] (0 on the diagonal, -8 off it:
+    ``H_res`` starts near the identity).
+    Float32 under AMP (black list)."""
+    import math
+
+    from ..initializer import (ConstantInitializer, NormalInitializer,
+                               NumpyArrayInitializer)
+
+    helper = LayerHelper("mhc_pre", input=x, name=name)
+    B, n, T, C = (int(d) for d in x.shape)
+
+    def leaf(shape, init):
+        return helper.create_parameter(attr=helper.param_attr, shape=shape,
+                                       dtype="float32",
+                                       default_initializer=init)
+
+    b_res = np.full((n, n), -8.0, "float32")
+    np.fill_diagonal(b_res, 0.0)
+    ins = {"X": [x],
+           "Phi": [leaf([n * C, 2 * n + n * n],
+                        NormalInitializer(0.0, 0.02))],
+           "Alpha": [leaf([3], ConstantInitializer(0.01))],
+           "BPre": [leaf([n], ConstantInitializer(-math.log(n - 1.0)))],
+           "BPost": [leaf([n], ConstantInitializer(0.0))],
+           "BRes": [leaf([n, n], NumpyArrayInitializer(b_res))]}
+    h, h_post, h_res = (helper.create_variable_for_type_inference("float32")
+                        for _ in range(3))
+    # no shape inference: it would trace (and count) the op at build time
+    helper.append_op(
+        "mhc_pre", inputs=ins,
+        outputs={"H": [h], "HPost": [h_post], "HRes": [h_res]},
+        attrs={"sinkhorn_iters": int(sinkhorn_iters),
+               "epsilon": float(epsilon), "clamp_min": float(clamp[0]),
+               "clamp_max": float(clamp[1])},
+        infer_shape=False)
+    if not framework.in_dygraph_mode():
+        h.shape, h_post.shape, h_res.shape = (B, T, C), (B, n, T), (B, n, n, T)
+    return h, h_post, h_res
+
+
+def mhc_post(x, h_res, h_post, y):
+    """The write side: the streams after a sublayer whose output is y
+    [B, T, C], ``x'[i] = sum_j H_res[i, j] x[j] + H_post[i] y``."""
+    helper = LayerHelper("mhc_post", input=x)
+    out = helper.create_variable_for_type_inference("float32")
+    helper.append_op(
+        "mhc_post",
+        inputs={"X": [x], "HRes": [h_res], "HPost": [h_post], "Y": [y]},
+        outputs={"Out": [out]})
+    return out
+
+
 __all__ += ["rms_norm", "causal_conv1d", "ssd_chunk_scan", "moe_topk",
-            "rotary_embedding", "attn_index_project", "attn_index_select",
-            "attn_index_loss"]
+            "rotary_embedding", "yarn_inv_freq", "yarn_mscale",
+            "attn_index_project", "attn_index_select", "attn_index_loss",
+            "mhc_pre", "mhc_post"]
 
 
 def chunk_eval(input, label, chunk_scheme, num_chunk_types,

@@ -1,7 +1,9 @@
 """Hybrid state-space / mixture-of-experts decoder (the ``nemotron_h``
 layout): pre-norm residual layers whose mixer is chosen by a pattern
 string, one letter a layer (a transformer layer of attention then experts
-is two letters):
+is two letters), on a residual path that is an argument: a plain add, or
+``n`` streams mixed by manifold-constrained hyper-connections
+(``hyper=``, ``layers.mhc_pre`` / ``layers.mhc_post``):
 
 - ``M``  Mamba-2: one input projection to gate, convolved ``x | B | C`` and
   step sizes; causal depthwise convolution + silu; the chunked selective
@@ -12,8 +14,15 @@ is two letters):
 - ``E``  routed experts, top-k of many without drops over the experts this
   program holds (``layers.moe_topk``: sigmoid or softmax scores, a
   ``relu(u W1)^2 W2`` or a gated ``(silu(u W1) * (u W3)) W2`` expert),
-  beside a shared expert that every token passes (none with
-  ``shared_dim = 0``);
+  beside a shared expert of the same form that every token passes (none
+  with ``shared_dim = 0``);
+- ``D``  a dense gated feed-forward layer, ``(silu(u W1) * (u W3)) W2``;
+- ``L``  causal latent attention: queries through a low-rank latent with
+  its own RMS norm, keys and values through another, one rotary key head
+  shared by all query heads beside the per-head keys, rotary positions
+  (given frequencies: YaRN's) on the trailing ``rope_dim`` of a query's
+  ``nope_dim + rope_dim``, a value dim of its own, on the streaming
+  ``flash_attention`` kernels;
 - ``*``  causal grouped-query attention on the ``flash_attention`` op, no
   positional encoding (as ``NemotronHAttention`` has none);
 - ``S``  causal grouped-query attention over the keys an indexer selects
@@ -29,7 +38,7 @@ sizes are arguments.
 """
 from __future__ import annotations
 
-from .. import layers
+from .. import framework, layers
 
 
 def _proj(x, size):
@@ -39,6 +48,19 @@ def _proj(x, size):
 def _relu2(x):
     r = layers.relu(x)
     return layers.elementwise_mul(r, r)
+
+
+def _ffn(u, dim, hidden, form):
+    """One feed-forward expert over every token, in ``moe_topk``'s forms:
+    ``relu2`` ``relu(u W1)^2 W2``; ``swiglu`` ``(silu(u W1) * (u W3)) W2``
+    (parameters in order W1, W3, W2)."""
+    if form == "relu2":
+        return _proj(_relu2(_proj(u, dim)), hidden)
+    if form != "swiglu":
+        raise ValueError("hybrid_ssm_moe: no expert %r" % (form,))
+    gated = layers.elementwise_mul(layers.swish(_proj(u, dim)),
+                                   _proj(u, dim))
+    return _proj(gated, hidden)
 
 
 def mamba2_mixer(u, hidden, num_heads, head_dim, n_groups, state_size,
@@ -85,8 +107,7 @@ def moe_mixer(u, hidden, num_experts, top_k, expert_dim, shared_dim,
         loads.append(load)
     out = layers.reshape(routed, [B, T, hidden])
     if shared_dim:
-        out = layers.elementwise_add(
-            out, _proj(_relu2(_proj(u, shared_dim)), hidden))
+        out = layers.elementwise_add(out, _ffn(u, shared_dim, hidden, expert))
     return out
 
 
@@ -106,6 +127,54 @@ def gqa_mixer(u, hidden, num_heads, num_kv_heads, head_dim):
     ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
                          [B, T, num_heads * head_dim])
     return _proj(ctx, hidden)
+
+
+def latent_mixer(u, hidden, num_heads, *, q_rank, kv_rank, nope_dim,
+                 rope_dim, v_dim, inv_freq, scale=None, eps=1e-6):
+    """u [B, T, hidden] (normed) -> causal latent attention. Parameters in
+    order: the query's down-projection, its latent norm, its up-projection
+    (to ``num_heads x (nope_dim + rope_dim)``); the key/value
+    down-projection (to ``kv_rank + rope_dim``: the latent and ONE rotary key
+    head), the latent's norm, its up-projection (to ``num_heads x (nope_dim
+    + v_dim)``); the output projection. Rotary positions ``0..T-1`` turn the
+    trailing ``rope_dim`` of every query head and the shared key head
+    (``inv_freq``: the ``rope_dim / 2`` pairs' frequencies, as
+    ``layers.yarn_inv_freq`` gives them). The shared key head is repeated
+    beside each head's own keys in the program, so the kernels read plain
+    [B, H, T, nope_dim + rope_dim] keys; v and the context are [B, H, T,
+    v_dim]. ``scale``: the scores' (None: ``(nope_dim + rope_dim)^-0.5``).
+    Every op is built inside
+    ``name_scope("latent")``: a device trace tells the mixer's projections,
+    norms and rotary from the other layers' ``mul`` and ``rms_norm``."""
+    B, T, _ = u.shape
+    qk_dim = nope_dim + rope_dim
+
+    def cut(x, lo, hi, axis):
+        return layers.slice(x, axes=[axis], starts=[lo], ends=[hi])
+
+    def placed(x, offset):
+        return layers.rotary_embedding(x, offset=offset, inv_freq=inv_freq)
+
+    with framework.name_scope("latent"):
+        cq = layers.rms_norm(_proj(u, q_rank), epsilon=eps)
+        q = placed(layers.reshape(_proj(cq, num_heads * qk_dim),
+                                  [B, T, num_heads, qk_dim]), nope_dim)
+        kva = _proj(u, kv_rank + rope_dim)
+        ckv = layers.rms_norm(cut(kva, 0, kv_rank, 2), epsilon=eps)
+        k_rope = placed(layers.reshape(
+            cut(kva, kv_rank, kv_rank + rope_dim, 2), [B, T, 1, rope_dim]), 0)
+        kv = layers.reshape(_proj(ckv, num_heads * (nope_dim + v_dim)),
+                            [B, T, num_heads, nope_dim + v_dim])
+        k = layers.concat([cut(kv, 0, nope_dim, 3),
+                           layers.expand(k_rope, [1, 1, num_heads, 1])],
+                          axis=3)
+        v = cut(kv, nope_dim, nope_dim + v_dim, 3)
+        q, k, v = (layers.transpose(x, [0, 2, 1, 3]) for x in (q, k, v))
+        ctx = layers.flash_attention(q, k, v, causal=True,
+                                     scale=scale or float(qk_dim) ** -0.5)
+        ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
+                             [B, T, num_heads * v_dim])
+        return _proj(ctx, hidden)
 
 
 def indexed_gqa_mixer(u, hidden, num_heads, num_kv_heads, head_dim, *,
@@ -157,7 +226,7 @@ def hybrid_ssm_moe(ids, pattern, vocab_rows, hidden, *, mamba_heads=64,
                    routed_scaling=2.5, correction_bias=None, num_heads=32,
                    num_kv_heads=2, head_dim=128, eps=1e-5, loads=None,
                    checkpoints=None, scoring="sigmoid", expert="relu2",
-                   indexed=None):
+                   indexed=None, latent=None, dense_dim=0, hyper=None):
     """Logits [B, T, vocab_rows] over int64 ids [B, T].
 
     ``pattern``: one letter a layer (see the module's docstring).
@@ -175,13 +244,31 @@ def hybrid_ssm_moe(ids, pattern, vocab_rows, hidden, *, mamba_heads=64,
     ``indexed_gqa_mixer`` as one dict: ``positions`` [3, B, T] int, the
     ``rope_*`` and ``index_*`` sizes, and ``index_losses``, a list that
     receives each ``S`` layer's indexer loss [1] for the caller to add to
-    the model's loss."""
+    the model's loss. ``latent``: the ``L`` layers' keyword arguments of
+    ``latent_mixer`` as one dict (``num_heads`` is shared with the other
+    attention letters). ``dense_dim``: the ``D`` layers' width.
+    ``hyper``: the residual path. None: ``x = x + mixer(rms_norm(x))``.
+    A dict ``{"streams": n, ...}`` (the rest ``layers.mhc_pre``'s keyword
+    arguments): ``n`` residual streams [B, n, T, hidden], each a copy of the
+    embedding at the start, every sublayer reading ``h = H_pre . X`` and
+    writing ``X' = H_res X + H_post (x) mixer(rms_norm(h))`` through maps
+    made from the streams, the streams summed before the last norm; the
+    checkpoints are then the streams at each sublayer's input."""
     x = layers.embedding(ids, size=[vocab_rows, hidden])
+    streams = 0
+    if hyper is not None:
+        hyper = dict(hyper)
+        streams = hyper.pop("streams")
+        x = layers.stack([x] * streams, axis=1)
     biases = iter(correction_bias or ())
     for kind in pattern:
         if checkpoints is not None:
             checkpoints.append(x)
-        u = layers.rms_norm(x, epsilon=eps)
+        if streams:
+            h, h_post, h_res = layers.mhc_pre(x, **hyper)
+            u = layers.rms_norm(h, epsilon=eps)
+        else:
+            u = layers.rms_norm(x, epsilon=eps)
         if kind == "M":
             y = mamba2_mixer(u, hidden, mamba_heads, mamba_head_dim,
                              n_groups, state_size, conv_kernel, chunk, eps)
@@ -194,9 +281,18 @@ def hybrid_ssm_moe(ids, pattern, vocab_rows, hidden, *, mamba_heads=64,
         elif kind == "S":
             y = indexed_gqa_mixer(u, hidden, num_heads, num_kv_heads,
                                   head_dim, eps=eps, **indexed)
+        elif kind == "L":
+            y = latent_mixer(u, hidden, num_heads, eps=eps, **latent)
+        elif kind == "D":
+            y = _ffn(u, dense_dim, hidden, "swiglu")
         else:
             raise ValueError("hybrid_ssm_moe: no layer kind %r" % kind)
-        x = layers.elementwise_add(x, y)
+        if streams:
+            x = layers.mhc_post(x, h_res, h_post, y)
+        else:
+            x = layers.elementwise_add(x, y)
     if checkpoints is not None:
         checkpoints.append(x)
+    if streams:
+        x = layers.reduce_sum(x, dim=1)
     return _proj(layers.rms_norm(x, epsilon=eps), vocab_rows)

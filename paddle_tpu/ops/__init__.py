@@ -45,3 +45,4 @@ from . import eval_ops  # noqa: F401
 from . import ssm_ops  # noqa: F401
 from . import moe_ops  # noqa: F401
 from . import sparse_attn_ops  # noqa: F401
+from . import hyper_connection_ops  # noqa: F401

@@ -169,7 +169,8 @@ register_op(
 def _flash_attention(ins, attrs):
     """Attention with no S x S matrix in HBM. The layout is the
     operands': rank 4 is Q [B, H, S, D] and K, V [B, H_kv, S, D] (H_kv
-    dividing H: shared K/V heads); rank 3 is token-major Q, K, V
+    dividing H: shared K/V heads; V's head dim may be its own, and is
+    then ``Out``'s); rank 3 is token-major Q, K, V
     [B, T, H*hd] with the attribute ``num_heads``, as the projections
     leave them, and ``Out`` comes back in the operands' layout. Pallas
     kernels where the computation runs on a TPU (which ones is decided
@@ -187,7 +188,8 @@ def _flash_attention(ins, attrs):
     the layout it was given and the form of its selection:
     ``kernels.flash_attention{path=short|stream|dense}``,
     ``kernels.flash_attention_layout{layout=tokens|heads}``,
-    ``kernels.flash_attention_select{form=none|mask}``."""
+    ``kernels.flash_attention_select{form=none|mask}``, and, where V's head
+    dim is not Q's, ``kernels.flash_attention_value_dim{path=...}``."""
     from .. import observability as _obs
     from .pallas.flash_attention import (attention_path,
                                          flash_attention_with_lse)
@@ -196,13 +198,14 @@ def _flash_attention(ins, attrs):
     num_heads = int(attrs.get("num_heads", 0))
     select = ins.get("Select")
     if _obs.enabled():
-        _obs.inc("kernels.flash_attention",
-                 path=attention_path(q, k, num_heads=num_heads,
-                                     select=select))
+        path = attention_path(q, k, num_heads=num_heads, select=select, v=v)
+        _obs.inc("kernels.flash_attention", path=path)
         _obs.inc("kernels.flash_attention_layout",
                  layout="tokens" if q.ndim == 3 else "heads")
         _obs.inc("kernels.flash_attention_select",
                  form="none" if select is None else "mask")
+        if v.shape[-1] != q.shape[-1]:   # a value dim of its own
+            _obs.inc("kernels.flash_attention_value_dim", path=path)
     scale = attrs.get("scale", 0.0) or None
     out, lse = flash_attention_with_lse(
         q, k, v, causal=bool(attrs.get("causal")), scale=scale,

@@ -263,12 +263,13 @@ def _len_bh(lengths, B, H):
 
 def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
                    lengths=None, select=None):
-    """Returns (out [B,H,S,D], lse [B*H, S, 1] float32). ``select``
+    """Returns (out [B,H,S,Dv], lse [B*H, S, 1] float32); ``v``'s head dim
+    ``Dv`` may differ from ``D``, the one q and k share. ``select``
     [B, S, S] int8 (whole blocks only: ``_plan`` sees to that)."""
     from jax.experimental import pallas as pl
 
     B, H, S, D = q.shape
-    S_kv = k.shape[2]
+    S_kv, Dv = k.shape[2], v.shape[3]
     group = _group(q, k)
     bq = min(block_q, S)
     bk = min(block_k, S)
@@ -287,7 +288,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
     # b // group of the [B * H_kv] rows: the index map reads the shared
     # head, nothing is repeated in HBM
     k3 = k.reshape(B * H // group, S, D)
-    v3 = v.reshape(B * H // group, S, D)
+    v3 = v.reshape(B * H // group, S, Dv)
 
     has_len = lengths is not None
     kernel = functools.partial(_flash_kernel, scale=scale, causal=causal,
@@ -296,7 +297,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
     in_specs = [
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk, D), lambda b, i, j: (b // group, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b // group, j, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, i, j: (b // group, j, 0)),
         ]
     args = [q3, k3, v3]
     if has_len:
@@ -313,25 +314,25 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
         grid=(B * H, nq, nk),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
             # [BH, S, 1]: last block dim = full array dim (exempt from
             # the /128 lane rule), penultimate bq satisfies the /8 rule
             pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, S, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, S, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)
-    return out.reshape(B, H, S, D), lse
+    return out.reshape(B, H, S, Dv), lse
 
 
 # ---------------------------------------------------------------------------
@@ -461,6 +462,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
     from jax.experimental import pallas as pl
 
     B, H, S, D = q.shape
+    Dv = v.shape[3]      # the head dim of v, the context and its cotangent
     group = _group(q, k)
     H_kv = H // group
     bq = min(block_q, S)
@@ -468,9 +470,9 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
     nq, nk = S // bq, S // bk
     q3 = q.reshape(B * H, S, D)
     k3 = k.reshape(B * H_kv, S, D)
-    v3 = v.reshape(B * H_kv, S, D)
-    do3 = g.reshape(B * H, S, D)
-    o3 = out.reshape(B * H, S, D)
+    v3 = v.reshape(B * H_kv, S, Dv)
+    do3 = g.reshape(B * H, S, Dv)
+    o3 = out.reshape(B * H, S, Dv)
     # delta = rowsum(dO ∘ O): one fused elementwise pass, O(S·D)
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
                     axis=-1, keepdims=True)            # [BH, S, 1]
@@ -501,8 +503,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk, D), lambda b, i, j: (b // group, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b // group, j, 0)),
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, i, j: (b // group, j, 0)),
+            pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
         ] + len_specs + dq_sel,
@@ -529,22 +531,22 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
         in_specs=[
             pl.BlockSpec((1, bq, D), q_rows),
             pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0)),
-            pl.BlockSpec((1, bq, D), q_rows),
+            pl.BlockSpec((1, bk, Dv), lambda b, j, t: (b, j, 0)),
+            pl.BlockSpec((1, bq, Dv), q_rows),
             pl.BlockSpec((1, bq, 1), q_rows),
             pl.BlockSpec((1, bq, 1), q_rows),
         ] + len_specs + dkv_sel,
         out_specs=[
             pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, j, t: (b, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H_kv, S, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H_kv, S, D), v.dtype),
+            jax.ShapeDtypeStruct((B * H_kv, S, Dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, D), jnp.float32),
-            pltpu.VMEM((bk, D), jnp.float32),
+            pltpu.VMEM((bk, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -1073,10 +1075,17 @@ def _plan_selected(q, k, block_q, block_k):
     return bq, bk
 
 
-def _plan(q, k, block_q, block_k, num_heads=0):
+def _value_dim(v, num_heads=0):
+    """The head dim of ``v`` (and of the context), in either layout."""
+    return v.shape[2] // num_heads if v.ndim == 3 else v.shape[3]
+
+
+def _plan(q, k, block_q, block_k, num_heads=0, value_dim=0):
     """Which kernels a call takes, from what it can see: the operands'
     layout (rank 4 is [B, H, S, D]; rank 3 is token-major [B, T, H*hd]
-    with ``num_heads``), sequence lengths, head dim, dtype and the VMEM
+    with ``num_heads``), sequence lengths, head dim (``value_dim``: v's,
+    where it is not q's and k's; such a call streams, the short kernels
+    take one head dim), dtype and the VMEM
     the short path would need. Returns (short, block_q, block_k):
     ``short`` is the token-major short kernels' blocks ``(rows,
     lanes)``, or the heads a grid step of the head-major short kernels
@@ -1090,7 +1099,8 @@ def _plan(q, k, block_q, block_k, num_heads=0):
         (B, H, S, D), H_kv, S_kv = q.shape, k.shape[1], k.shape[2]
     if S != S_kv:
         return 0, block_q, block_k   # rectangular: the dense fallback
-    if S % 128 == 0 and S <= block_k and H == H_kv:
+    if S % 128 == 0 and S <= block_k and H == H_kv \
+            and value_dim in (0, D):
         # the caller's K block holds the whole sequence (the short kernels
         # take one head count: shared K/V heads stream)
         itemsize = q.dtype.itemsize
@@ -1112,16 +1122,17 @@ def _plan(q, k, block_q, block_k, num_heads=0):
 
 def attention_path(q, k, block_q: int = 512, block_k: int = 1024,
                    force_pallas: bool = False, num_heads: int = 0,
-                   select=None) -> str:
+                   select=None, v=None) -> str:
     """"short" | "stream" | "dense": what ``flash_attention`` runs for
     these arguments where the computation is placed now (a selection
-    always streams)."""
+    always streams, and so does a ``v`` whose head dim is not q's)."""
     if not (force_pallas or compute_platform() == "tpu"):
         return "dense"
     if select is not None:
         return "stream"
-    return ("short" if _plan(q, k, block_q, block_k, num_heads)[0]
-            else "stream")
+    value_dim = 0 if v is None else _value_dim(v, num_heads)
+    return ("short" if _plan(q, k, block_q, block_k, num_heads,
+                             value_dim)[0] else "stream")
 
 
 def _check_layout(q, k, num_heads):
@@ -1166,7 +1177,8 @@ def flash_attention_with_lse(q, k, v, causal: bool = False,
                                block_k, not on_tpu)
     short = None   # no kernels: the dense math (0 is the streaming kernels)
     if on_tpu or force_pallas:
-        short, block_q, block_k = _plan(q, k, block_q, block_k, num_heads)
+        short, block_q, block_k = _plan(q, k, block_q, block_k, num_heads,
+                                        _value_dim(v, num_heads))
     if isinstance(short, tuple):
         return _flash_tokens(q, k, v, lengths, causal, scale, num_heads,
                              short, not on_tpu)
@@ -1196,7 +1208,8 @@ def flash_attention_bwd(q, k, v, lengths, out, lse, g, causal: bool,
         return _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
                                block_k, compute_platform() != "tpu",
                                select=select)
-    short, block_q, block_k = _plan(q, k, block_q, block_k, num_heads)
+    short, block_q, block_k = _plan(q, k, block_q, block_k, num_heads,
+                                    _value_dim(v, num_heads))
     interpret = compute_platform() != "tpu"
     if isinstance(short, tuple):
         return _flash_tokens_bwd(causal, scale, num_heads, short, interpret,
@@ -1221,6 +1234,9 @@ def flash_attention(q, k, v, causal: bool = False,
     recomputes the probabilities from the saved logsumexp. Head-major
     ``k`` and ``v`` may be ``[B, H_kv, S, D]`` with ``H_kv`` dividing
     ``H`` (shared K/V heads; always the streaming kernels on the TPU).
+    ``v`` may have a head dim of its own, ``[B, H_kv, S, Dv]``: the context
+    is then ``[B, H, S, Dv]`` (latent attention's 192 beside 128; the
+    streaming kernels at any length, the short ones take one head dim).
 
     Which kernels run is decided here, from the shapes (``_plan``):
 

@@ -59,12 +59,16 @@ GROUPS = 8
 # ---------------------------------------------------------------------------
 
 
-def rotary_angles(pos, theta, sections, pairs):
+def rotary_angles(pos, theta, sections, pairs, inv_freq=None):
     """[B, T, pairs] float32 angles: pair ``i`` has frequency
-    ``theta ** (-i / pairs)`` and reads the position component whose
-    consecutive section holds ``i`` (the last one past the sections' end).
-    ``pos`` [3, B, T] int."""
-    inv = theta ** (-jnp.arange(pairs, dtype=jnp.float32) / pairs)
+    ``theta ** (-i / pairs)``, or ``inv_freq[i]`` where the frequencies are
+    given (scaled ones, YaRN's blend), and reads the position component
+    whose consecutive section holds ``i`` (the last one past the sections'
+    end). ``pos`` [3, B, T] int."""
+    if inv_freq is None:
+        inv = theta ** (-jnp.arange(pairs, dtype=jnp.float32) / pairs)
+    else:
+        inv = jnp.asarray(inv_freq, jnp.float32)
     ends = np.cumsum(np.asarray(sections, np.int64))
     comp = np.minimum(np.searchsorted(ends, np.arange(pairs), side="right"),
                       len(ends) - 1)
@@ -72,36 +76,51 @@ def rotary_angles(pos, theta, sections, pairs):
     return p * inv
 
 
-def rotary(x, pos, theta, sections, rotary_dims=0):
-    """x [B, T, H, hd] with its first ``rotary_dims`` dims (0: all) rotated,
-    rotate-half form: ``x * cos + rotate_half(x) * sin``; float32 inside."""
-    rd = int(rotary_dims) or x.shape[-1]
+def rotary(x, pos, theta, sections, rotary_dims=0, inv_freq=None, offset=0):
+    """x [B, T, H, hd] with ``rotary_dims`` of a head's dims (0: all that
+    follow) rotated from dim ``offset`` on, rotate-half form within that
+    slice: ``x * cos + rotate_half(x) * sin``; float32 inside."""
+    rd = int(rotary_dims) or x.shape[-1] - offset
     half = rd // 2
-    ang = rotary_angles(pos, theta, sections, half)[:, :, None, :]
+    ang = rotary_angles(pos, theta, sections, half, inv_freq)[:, :, None, :]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :half], xf[..., half:rd]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
-                           xf[..., rd:]], -1)
-    return out.astype(x.dtype)
+    x1, x2 = xf[..., offset:offset + half], xf[..., offset + half:offset + rd]
+    parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin, xf[..., offset + rd:]]
+    if offset:   # without one the trace is what it was
+        parts.insert(0, xf[..., :offset])
+    return jnp.concatenate(parts, -1).astype(x.dtype)
 
 
 @register_op(
     "rotary_embedding",
-    inputs=[In("X"), In("Pos", no_grad=True)],
+    inputs=[In("X"), In("Pos", dispensable=True, no_grad=True)],
     outputs=[Out("Out")],
-    attrs={"theta": 10000.0, "sections": [], "rotary_dims": 0},
+    attrs={"theta": 10000.0, "sections": [], "rotary_dims": 0,
+           "inv_freq": [], "offset": 0},
 )
 def _rotary_embedding(ins, attrs):
-    """X [B, T, H, hd]; Pos [3, B, T] int (temporal, height, width; a feed);
+    """X [B, T, H, hd]; Pos [3, B, T] int (temporal, height, width; a feed;
+    unbound: one document a row, every component ``0..T-1``);
     ``sections``: how many frequency pairs each component takes, in order
-    (empty: all from component 0); ``rotary_dims``: the leading dims of a
-    head that rotate (0: all)."""
+    (empty: all from component 0); ``rotary_dims``: how many dims of a head
+    rotate (0: all from ``offset`` on), starting at dim ``offset``;
+    ``inv_freq``: the pairs' frequencies, one each (empty: ``theta^(-i /
+    pairs)``)."""
     x = ins["X"]
-    rd = int(attrs.get("rotary_dims", 0)) or x.shape[-1]
+    offset = int(attrs.get("offset", 0))
+    rd = int(attrs.get("rotary_dims", 0)) or x.shape[-1] - offset
     sections = list(attrs.get("sections") or [rd // 2])
-    return {"Out": rotary(x, ins["Pos"], float(attrs.get("theta", 1e4)),
-                          sections, rd)}
+    inv_freq = list(attrs.get("inv_freq") or []) or None
+    if inv_freq is not None and len(inv_freq) != rd // 2:
+        raise ValueError("rotary_embedding: %d frequencies for %d pairs"
+                         % (len(inv_freq), rd // 2))
+    pos = ins.get("Pos")
+    if pos is None:
+        B, T = x.shape[:2]
+        pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (3, B, T))
+    return {"Out": rotary(x, pos, float(attrs.get("theta", 1e4)), sections,
+                          rd, inv_freq, offset)}
 
 
 # ---------------------------------------------------------------------------

@@ -590,6 +590,41 @@ def test_the_model_keeps_its_head_major_attention(recompute, ops, digest):
                      1 + recompute}
 
 
+@pytest.mark.parametrize("expert,act,matrices", [("relu2", "relu", 2),
+                                                 ("swiglu", "swish", 3)])
+def test_the_shared_expert_takes_the_experts_form(expert, act, matrices):
+    """``moe_mixer``'s shared expert is ``relu(u W1)^2 W2`` beside ``relu2``
+    experts, as the hybrid configuration has it (whose program the digests
+    above hold to PR 27's), and gated, ``(silu(u W1) * (u W3)) W2``, beside
+    ``swiglu`` ones: against the form written out, with no routed expert
+    held so that the layer is its shared expert alone."""
+    mixer = importlib.import_module("paddle_tpu.models.hybrid_ssm_moe")
+    d, f = 16, 24
+    k = keys(4, 31)
+    u = jax.random.normal(k[0], (2, 8, d))
+    w = [0.3 * jax.random.normal(kk, sh) for kk, sh in zip(
+        k[1:], [(d, f), (d, f), (f, d)])]
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = fluid.data(name="u", shape=[2, 8, d], dtype="float32")
+        out = mixer.moe_mixer(x, d, 8, 2, f, f, held=[0, 0], expert=expert)
+    types = [o.type for o in main.global_block().ops]
+    assert act in types and ("swish" in types) == (expert == "swiglu")
+    shared = [p.name for p in main.all_parameters()][-matrices:]
+    values = [w[0], w[2]] if expert == "relu2" else w
+    scope, exe = fluid.Scope(), fluid.Executor(fluid.CPUPlace())
+    with jax.default_matmul_precision("highest"), fluid.scope_guard(scope):
+        exe.run(startup)
+        for name, value in zip(shared, values):
+            scope.find_var(name).get_tensor().set(np.asarray(value))
+        (got,) = exe.run(main, feed={"u": np.asarray(u)}, fetch_list=[out])
+        if expert == "relu2":
+            want = jnp.square(jax.nn.relu(u @ w[0])) @ w[2]
+        else:
+            want = (jax.nn.silu(u @ w[0]) * (u @ w[1])) @ w[2]
+    assert rel(got, want) < 1e-5
+
+
 @pytest.mark.parametrize("recompute", [False, True])
 def test_tiny_model_follows_the_plain_reference_for_three_steps(recompute):
     """``models.hybrid_ssm_moe`` through ``fluid.Executor`` with bf16 AMP and

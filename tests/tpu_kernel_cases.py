@@ -33,7 +33,7 @@ def cases():
         """Forward + backward of the kernels ``flash_attention`` picks
         for the shapes (``_plan``), as the grad op runs them."""
         def both(q, k, v, *lengths):
-            heads, bq, bk = fa._plan(q, k, block_q, block_k)
+            heads, bq, bk = fa._plan(q, k, block_q, block_k, 0, v.shape[3])
             out, vjp = jax.vjp(
                 lambda q, k, v: fa._flash(q, k, v, *(lengths or (None,)),
                                           causal, 0.125, bq, bk, heads,
@@ -89,6 +89,14 @@ def cases():
     # the dK/dV kernel sums over the group
     q, kv = ((1, 32, 8192, 128), bf16), ((1, 2, 8192, 128), bf16)
     yield "flash_gqa_causal_s8192_d128", flash(True, 512, 1024), [q, kv, kv]
+
+    # latent attention's core: 32 heads, q and k at 192 (128 + the rotary
+    # 64), v and the context at 128, causal, T = 4096: the streaming
+    # kernels with a value dim of its own, forward, dQ and dK/dV; and the
+    # same call at equal dims, which plans and lowers as it did
+    qk, v = ((1, 32, 4096, 192), bf16), ((1, 32, 4096, 128), bf16)
+    yield "flash_causal_s4096_d192_v128", flash(True, 512, 1024), [qk, qk, v]
+    yield "flash_causal_s4096_d128_h32", flash(True, 512, 1024), [v, v, v]
 
     # the same kernels with a per-query key selection (32 over 4 heads, head
     # dim 128, T = 2048): the [B, S, S] int8 operand's tiles ride beside
