@@ -26,6 +26,11 @@ Metric families (see README "Runtime observability"):
 ``kernels.flash_attention_select{form=...}``  counter: the same traces, by
                                        the form of their key selection:
                                        none | mask (int8 [B, S, S])
+``kernels.flash_attention_grad{path=...}``  counter: traces of attention's
+                                       backward, counted at the branch that
+                                       ran: fused (the streaming path's one
+                                       kernel) | split (its dQ and dK+dV
+                                       pair) | short | dense
 ``executor.compiles``                  counter: whole-program (re)compiles
 ``executor.jit_traces``                counter: per-shape XLA (re)traces
 ``executor.compile_fallbacks``         counter: compiled -> interpreter drops

@@ -115,8 +115,13 @@ def _flash_attention_grad(ins, attrs):
     of different names), so the generic auto-VJP grad would run the
     forward twice a layer. A program whose forward op bound no ``LSE``
     (or ran the dense math, which has none) re-runs the forward under
-    ``jax.vjp`` as every auto-VJP grad op does."""
-    from .pallas.flash_attention import (compute_platform, flash_attention,
+    ``jax.vjp`` as every auto-VJP grad op does. The backward that runs
+    counts itself where its branch is taken,
+    ``kernels.flash_attention_grad{path=fused|split|short|dense}``
+    (``flash_attention.count_backward``); the dense math's automatic VJP
+    is counted here."""
+    from .pallas.flash_attention import (compute_platform, count_backward,
+                                         flash_attention,
                                          flash_attention_bwd)
 
     q, k, v = ins["Q"], ins["K"], ins["V"]
@@ -133,6 +138,8 @@ def _flash_attention_grad(ins, attrs):
             q, k, v, lengths, ins["Out"], ins["LSE"], g, causal, scale,
             num_heads=num_heads, select=select)
     else:
+        if compute_platform() != "tpu":
+            count_backward("dense")   # on the TPU the kernels' VJP counts
         _, vjp = jax.vjp(
             lambda q, k, v: flash_attention(
                 q, k, v, causal=causal, scale=scale, lengths=lengths,

@@ -18,8 +18,23 @@ score matrix never materializes in HBM. Two sets of kernels, chosen by
 - backward (FlashAttention-2 style): probabilities are RECOMPUTED
   blockwise from (Q, K, LSE) instead of stored, so training memory is
   O(S·D) instead of the O(S²) attention matrix a dense VJP carries.
-  Two kernels: dQ iterates K blocks per Q block; dK/dV iterates Q
-  blocks per K block; both consume the dense precomputed
+  ONE kernel (``_flash_bwd_fused_kernel``) makes s, the masks, p, dp and
+  ds of a (Q block, K block) tile once and accumulates dQ, dK and dV
+  from them, five matmuls a tile: grid (K/V head, Q block, query head
+  of the group x K block); dQ of a (query head, Q block) in a [bq, D]
+  float32 scratch over its K blocks; dK and dV of the whole K/V head,
+  [S, D] and [S, Dv] float32, resident in VMEM for all of it and cast
+  and written once, the sum over the group's query heads with them.
+  MXU operands stay in the dtype they arrive in (p and ds are cast to
+  it), accumulation, scores, exp, LSE and delta are float32. A causal
+  step above the diagonal computes nothing and its index maps name the
+  last K block it needed again, so nothing is copied for it. It runs
+  for calls with shared K/V heads whose accumulators fit
+  ``STREAM_VMEM_BUDGET`` (``_fused_bwd_fits``, from the shapes alone,
+  which also says why one K/V head a query head does not take it yet);
+  for every other call the older pair runs: dQ iterates K blocks per Q
+  block, dK/dV iterates Q blocks per K block, each making the tile
+  again (seven matmuls). All consume the dense precomputed
   delta = rowsum(dO ∘ O) (an elementwise pass XLA fuses).
 
 **Short** (S <= 1024 with the default blocks: a head's whole [S, S]
@@ -47,10 +62,10 @@ head-major kernels, inside ``flash_attention``.
 **Shared K/V heads** (grouped-query attention): K and V may have fewer
 heads than Q, ``H_kv`` dividing ``H``. The streaming kernels' index maps
 read the shared head (row ``b // group`` of the [B * H_kv] rows), nothing
-is repeated in HBM, and the dK/dV kernel runs over the group's query
-heads in its last grid axis so that their sums happen in its
-accumulators. Such a call takes the streaming kernels at any length (the
-short ones take one head count).
+is repeated in HBM, and the backward kernel (the dK/dV kernel of the
+pair) runs over the group's query heads in its last grid axis so that
+their sums happen in its accumulators. Such a call takes the streaming
+kernels at any length (the short ones take one head count).
 
 **Selection** (learned sparse attention): the streaming kernels take an
 optional ``select`` operand, [B, S, S] int8, one tile a (Q block, K block)
@@ -91,7 +106,7 @@ NEG_INF = -1e30
 
 def _causal_mask(s, qi, ki, block_q, block_k):
     """Mask the score tile with absolute positions (shared by the
-    forward and both backward kernels — one definition to extend for
+    forward and every backward kernel — one definition to extend for
     sliding-window/padding variants)."""
     q_pos = qi * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
@@ -246,6 +261,16 @@ def _flash_kernel(*refs, scale, causal, block_q, block_k, nk, has_len,
         l_safe = jnp.maximum(l_ref[:], 1e-30)
         o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
         lse_ref[0] = m_ref[:] + jnp.log(l_safe)          # [bq, 1]
+
+
+def count_backward(path):
+    """``kernels.flash_attention_grad{path=fused|split|short|dense}``: one
+    count a trace of attention's backward, made at the branch that was
+    taken (here, and in the grad op for the dense math's automatic VJP)."""
+    from ... import observability as obs
+
+    if obs.enabled():
+        obs.inc("kernels.flash_attention_grad", path=path)
 
 
 def _no_tangent(lengths):
@@ -457,8 +482,130 @@ def _flash_bwd_dkv_kernel(*refs, scale, causal, block_q, block_k, nq,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+# What the one-kernel streaming backward may ask of VMEM: a K/V head's dK and
+# dV stay there in float32 while its query heads and Q blocks pass, so the
+# need grows with S (16 MiB of accumulators at S = 16,384, D = 128). Beyond
+# it the dQ and dK+dV pair, whose scratch does not grow with S, runs instead.
+STREAM_VMEM_BUDGET = 96 << 20
+
+
+def _lanes(d):
+    """A minor dim as VMEM holds it: whole 128-lane tiles."""
+    return -(-d // 128) * 128
+
+
+def _fused_bwd_vmem_bytes(S, D, Dv, bq, bk, itemsize):
+    """VMEM of ``_flash_bwd_fused_kernel``: the float32 dK and dV of a whole
+    K/V head, their output blocks (double-buffered), the operands' blocks
+    (double-buffered: q, dO, dQ, k, v, a selection's tile) and the float32
+    [bq, bk] temporaries (s, p, dp, ds and their operand-dtype copies)."""
+    D, Dv = _lanes(D), _lanes(Dv)
+    return (S * (D + Dv) * (4 + 2 * itemsize)
+            + 2 * ((2 * bq + bk) * D + (bq + bk) * Dv) * itemsize
+            + 2 * bq * bk + bq * D * 4 + 6 * bq * bk * 4)
+
+
+def _fused_bwd_fits(S, D, Dv, bq, bk, itemsize, group):
+    """Whether a streaming backward call is the one kernel (each score tile
+    made once) or the dQ and dK+dV pair: from the shapes alone. The one
+    kernel must ask Mosaic for its VMEM (``vmem_limit_bytes``), which the
+    pair never does, and that request, not the kernel's body, is what hung
+    one kind of step on the v5e: the latent-attention cell's (one K/V head
+    a query head, beside that model's expert sublayers) never came back
+    from its first call with it, whatever the head dims; the pair hung the
+    same step when given a request, and the one kernel ran it when, at
+    smaller head dims, it needed none (PERF.md section 6, PR 38: what in
+    that step the request meets is not known). So the one kernel runs
+    where whole steps with its request have run on the chip: calls with
+    shared K/V heads (``group`` query heads a K/V head, > 1); every other
+    call keeps the pair."""
+    return group > 1 and _fused_bwd_vmem_bytes(
+        S, D, Dv, bq, bk, itemsize) <= STREAM_VMEM_BUDGET
+
+
+def _flash_bwd_fused_kernel(*refs, scale, causal, block_q, block_k, nq, nk,
+                            has_len, group, has_sel):
+    """dQ, dK and dV of one K/V head from ONE s, p, dp, ds a (Q block, K
+    block) tile: five matmuls where the dQ and dK+dV pair makes seven, and
+    every pass over the [bq, bk] float32 tile once. Grid (K/V head, Q block,
+    query head of the group x K block): dQ of a (query head, Q block)
+    accumulates over its K blocks in ``dq_acc``; dK and dV of the whole head,
+    [nk, bk, D] float32, stay in VMEM for all of it, the sum over the group
+    with them, and are cast and written at the head's last step. MXU
+    operands in the dtype they arrive in (p and ds cast to it), float32
+    accumulation; scores, exp, lse, delta float32."""
+    from jax.experimental import pallas as pl
+
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), refs = (refs[:6],
+                                                               refs[6:])
+    len_ref, refs = (refs[0], refs[1:]) if has_len else (None, refs)
+    sel_ref, refs = (refs[0], refs[1:]) if has_sel else (None, refs)
+    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs
+    step = pl.program_id(2)
+    ki = step % nk
+    qi = pl.program_id(1)
+    bi = pl.program_id(0)
+    f32 = jnp.float32
+
+    @pl.when(jnp.logical_and(qi == 0, step == 0))
+    def _init_head():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(ki == 0)
+    def _init_rows():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def _accumulate():
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        s = scale * jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=f32)
+        if causal:
+            s = _causal_mask(s, qi, ki, block_q, block_k)
+        if has_len:
+            s = _kv_len_mask(s, ki, block_k, len_ref[bi, 0])
+        if has_sel:
+            s = _select_mask(s, sel_ref)
+        p = jnp.exp(s - lse_ref[0])                        # [bq, bk]
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())), preferred_element_type=f32)
+        ds = (p * (dp - delta_ref[0])).astype(q.dtype)     # [bq, bk]
+        dv_acc[ki] += jax.lax.dot_general(                 # p^T dO
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=f32)                    # [bk, dv]
+        dk_acc[ki] += jax.lax.dot_general(                 # ds^T q
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=f32)                    # [bk, d]
+        dq_acc[...] += jax.lax.dot_general(                # ds k
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=f32)                    # [bq, d]
+
+    need = None
+    if causal:
+        need = _causal_block_needed(qi, ki, block_q, block_k)
+    if has_len:
+        in_len = ki * block_k < len_ref[bi, 0]
+        need = in_len if need is None else jnp.logical_and(need, in_len)
+    if need is not None:
+        pl.when(need)(_accumulate)
+    else:
+        _accumulate()
+
+    @pl.when(ki == nk - 1)
+    def _finish_rows():
+        dq_ref[0] = (scale * dq_acc[...]).astype(dq_ref.dtype)
+
+    @pl.when(jnp.logical_and(qi == nq - 1, step == group * nk - 1))
+    def _finish_head():
+        dk_ref[0] = (scale * dk_acc[...]).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
 def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
                     block_k, interpret, lengths=None, select=None):
+    """dQ, dK, dV of the streaming path: one kernel that makes each score
+    tile once for calls with shared K/V heads whose float32 dK and dV fit
+    VMEM (``_fused_bwd_fits``), the dQ and dK+dV pair for the rest."""
     from jax.experimental import pallas as pl
 
     B, H, S, D = q.shape
@@ -478,25 +625,74 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
                     axis=-1, keepdims=True)            # [BH, S, 1]
 
     has_len = lengths is not None
-    dq_len, dkv_len = [], []
-    len_specs = []
-    if has_len:
-        # one row a grid batch step: query heads for dQ, K/V heads for dK/dV
-        dq_len, dkv_len = ([_len_bh(lengths, B, H)],
-                           [_len_bh(lengths, B, H_kv)])
-        len_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)]
     has_sel = select is not None
-    dq_sel, dkv_sel, sel = [], [], []
-    if has_sel:
-        # the selection's tile of a (Q block, K block) pair, for every head
-        sel = [select]
-        dq_sel = [pl.BlockSpec((1, bq, bk), lambda b, i, j: (b // H, i, j))]
-        dkv_sel = [pl.BlockSpec((1, bq, bk),
-                                lambda b, j, t: (b // H_kv, t % nq, j))]
+    # lengths: the whole array in SMEM, one row a grid batch step (query
+    # heads for the dQ kernel, K/V heads for the others)
+    len_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] if has_len else []
+    kv_len = [_len_bh(lengths, B, H_kv)] if has_len else []
+    sel = [select] if has_sel else []
+    operands = (q3, k3, v3, do3, lse, delta)
+    flags = dict(scale=scale, causal=causal, block_q=bq, block_k=bk,
+                 has_len=has_len, has_sel=has_sel)
 
-    dq_kernel = functools.partial(
-        _flash_bwd_dq_kernel, scale=scale, causal=causal, block_q=bq,
-        block_k=bk, nk=nk, has_len=has_len, has_sel=has_sel)
+    fused = _fused_bwd_fits(S, D, Dv, bq, bk, q.dtype.itemsize, group)
+    count_backward("fused" if fused else "split")
+    if fused:
+        def q_rows(b, i, t):
+            # K/V head b, step t: query head t // nk of its group
+            return (b * group + t // nk, i, 0)
+
+        def k_block(i, t):
+            # the K block of step t; a causal step above the diagonal, which
+            # computes nothing, names the last block it needed: no new copy
+            j = t % nk
+            return jnp.minimum(j, (i * bq + bq - 1) // bk) if causal else j
+
+        def k_rows(b, i, t):
+            return (b, k_block(i, t), 0)
+
+        # dK, dV as [.., nk, bk, D]: the kernel indexes whole K blocks
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(_flash_bwd_fused_kernel, nq=nq, nk=nk,
+                              group=group, **flags),
+            grid=(B * H_kv, nq, group * nk),
+            in_specs=[
+                pl.BlockSpec((1, bq, D), q_rows),
+                pl.BlockSpec((1, bk, D), k_rows),
+                pl.BlockSpec((1, bk, Dv), k_rows),
+                pl.BlockSpec((1, bq, Dv), q_rows),
+                pl.BlockSpec((1, bq, 1), q_rows),
+                pl.BlockSpec((1, bq, 1), q_rows),
+            ] + len_specs + [
+                pl.BlockSpec((1, bq, bk),
+                             lambda b, i, t: (b // H_kv, i, k_block(i, t)))
+            ] * has_sel,
+            out_specs=[
+                pl.BlockSpec((1, bq, D), q_rows),
+                pl.BlockSpec((1, nk, bk, D), lambda b, i, t: (b, 0, 0, 0)),
+                pl.BlockSpec((1, nk, bk, Dv), lambda b, i, t: (b, 0, 0, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
+                jax.ShapeDtypeStruct((B * H_kv, nk, bk, D), k.dtype),
+                jax.ShapeDtypeStruct((B * H_kv, nk, bk, Dv), v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, D), jnp.float32),
+                pltpu.VMEM((nk, bk, D), jnp.float32),
+                pltpu.VMEM((nk, bk, Dv), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+                vmem_limit_bytes=_fused_bwd_vmem_bytes(
+                    S, D, Dv, bq, bk, q.dtype.itemsize) + (8 << 20)),
+            interpret=interpret,
+            name="flash_stream_bwd",
+        )(*operands, *kv_len, *sel)
+        return (dq.reshape(q.shape), dk.reshape(k.shape),
+                dv.reshape(v.shape))
+
+    dq_kernel = functools.partial(_flash_bwd_dq_kernel, nk=nk, **flags)
     dq = pl.pallas_call(
         dq_kernel,
         grid=(B * H, nq, nk),
@@ -507,19 +703,19 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
             pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-        ] + len_specs + dq_sel,
+        ] + len_specs + [
+            pl.BlockSpec((1, bq, bk), lambda b, i, j: (b // H, i, j))
+        ] * has_sel,
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q3, k3, v3, do3, lse, delta, *dq_len, *sel)
+    )(*operands, *([_len_bh(lengths, B, H)] if has_len else []), *sel)
 
-    dkv_kernel = functools.partial(
-        _flash_bwd_dkv_kernel, scale=scale, causal=causal, block_q=bq,
-        block_k=bk, nq=nq, has_len=has_len, group=group,
-        has_sel=has_sel)
+    dkv_kernel = functools.partial(_flash_bwd_dkv_kernel, nq=nq, group=group,
+                                   **flags)
 
     def q_rows(b, j, t):
         # K/V head b, step t: query head t // nq of its group, Q block t % nq
@@ -535,7 +731,10 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
             pl.BlockSpec((1, bq, Dv), q_rows),
             pl.BlockSpec((1, bq, 1), q_rows),
             pl.BlockSpec((1, bq, 1), q_rows),
-        ] + len_specs + dkv_sel,
+        ] + len_specs + [
+            pl.BlockSpec((1, bq, bk),
+                         lambda b, j, t: (b // H_kv, t % nq, j))
+        ] * has_sel,
         out_specs=[
             pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0)),
             pl.BlockSpec((1, bk, Dv), lambda b, j, t: (b, j, 0)),
@@ -551,7 +750,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q3, k3, v3, do3, lse, delta, *dkv_len, *sel)
+    )(*operands, *kv_len, *sel)
 
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
@@ -633,8 +832,8 @@ def _short_fwd_math(q, k, v, len_val, scale, causal):
 
 
 def _short_bwd_math(q, k, v, do, lse, delta, len_val, scale, causal):
-    """One head's gradients from ONE S/P/dP: five matmuls, where the
-    streaming dQ and dK+dV kernels make seven. Returns float32
+    """One head's gradients from ONE S/P/dP: five matmuls, as the
+    streaming path's one backward kernel makes a tile. Returns float32
     (dQ^T / scale [D, Tq], dK, dV [Tk, D])."""
     f32 = jnp.float32
     st, q = _short_scores(q, k, len_val, scale, causal)
@@ -730,6 +929,7 @@ def _short_backward(q, k, v, out, lse, g, causal, scale, heads,
                     interpret, lengths=None):
     from jax.experimental import pallas as pl
 
+    count_backward("short")
     B, H, T, D = q.shape
     BH = B * H
     # delta = rowsum(dO * O), one fused pass of XLA's, kept as rows
@@ -946,6 +1146,7 @@ def _flash_tokens_fwd(q, k, v, lengths, causal, scale, num_heads, blocks,
 def _flash_tokens_bwd(causal, scale, num_heads, blocks, interpret, res,
                       cts):
     q, k, v, lengths, out, lse = res
+    count_backward("short")
     grads = _tokens_call(
         _tokens_bwd_kernel, "flash_short_bwd",
         (q, k, v, cts[0].astype(q.dtype), out), lse, lengths, causal,
@@ -1008,6 +1209,7 @@ def _flash_bwd(causal, scale, block_q, block_k, heads, interpret, res,
     if S != k.shape[2] or S % bq or S % bk:
         # ragged tail / rectangular: dense VJP (matches the forward's
         # own fallback)
+        count_backward("dense")
         _, vjp = jax.vjp(
             lambda q, k, v: _dense_attention(q, k, v, causal, scale,
                                              lengths), q, k, v)
@@ -1250,7 +1452,10 @@ def flash_attention(q, k, v, causal: bool = False,
       read, and the context written, as they are: no head split or
       merge in HBM (``_tokens_blocks``).
     - **stream** — longer S: K blocks stream past each Q block with a
-      running softmax; the backward is a dQ and a dK+dV kernel.
+      running softmax; with shared K/V heads the backward is one kernel
+      that makes each score tile once for dQ, dK and dV (a K/V head's
+      float32 dK and dV stay in VMEM, up to ``STREAM_VMEM_BUDGET``);
+      otherwise a dQ and a dK+dV kernel.
       ``block_q`` x ``block_k`` = 512 x 1024 by default; blocks shrink
       to an aligned divisor of S. Token-major operands are split into
       heads, and the context merged, around these kernels.

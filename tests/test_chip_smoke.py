@@ -2,6 +2,7 @@
 chip_smoke.py's CPU rehearsal and its refusal to pass without a TPU,
 the compile-cache helper, TPUPlace strictness, and that the TPU
 compiler still accepts every Pallas kernel."""
+import importlib
 import json
 import os
 import shutil
@@ -11,6 +12,7 @@ import sys
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 import paddle_tpu as fluid
 from paddle_tpu.core import compile_cache
@@ -162,6 +164,73 @@ def test_kernels_compile_for_v5e(v5e_compile):
     assert len(ok) == len(list(tpu_kernel_cases.cases())), proc.stdout
 
 
+def test_streaming_gradients_for_v5e_are_one_mosaic_call(v5e_compile):
+    """The streaming ``flash_attention_grad`` at the sparse-attention and
+    hybrid cells' shapes (a selection over 32 / 4 heads at 16,384; 32 / 2
+    heads at 8,192) compiles to ONE Mosaic call, dQ, dK and dV from each
+    score tile made once (it was a dQ and a dK+dV call), and forward +
+    backward of such a case are two; with one K/V head a query head (the
+    latent-attention cell's 32 / 32 at 192 / 128, the gpt_long shape) the
+    rule keeps the pair (``_fused_bwd_fits``)."""
+    calls = {x.split()[1]: int(x.split("mosaic_calls=")[1])
+             for x in v5e_compile.stdout.splitlines() if x.startswith("OK ")}
+    assert {name: calls.get(name) for name in
+            tpu_kernel_cases.STREAM_GRADS} == {
+                name: 1 if h_kv < h else 2 for name, (h, h_kv, _, _, _, _)
+                in tpu_kernel_cases.STREAM_GRADS.items()}, v5e_compile.stdout
+    for name in ("flash_gqa_causal_s8192_d128",
+                 "flash_gqa_selected_s16384_d128"):
+        assert calls[name] == 2, (name, calls[name])
+    for name in ("flash_causal_s4096", "flash_masked_b64_s256",
+                 "flash_causal_s4096_d192_v128"):
+        assert calls[name] == 3, (name, calls[name])
+
+
+@pytest.mark.parametrize("path, shape, heads_kv, platform", [
+    ("fused", (1, 4, 2048, 128), 2, "tpu"),
+    ("split", (1, 4, 262144, 128), 2, "tpu"),
+    ("short", (2, 4, 256, 64), 4, "tpu"),
+    ("dense", (1, 4, 2048, 128), 2, "cpu")])
+def test_grad_counter_names_the_backward_a_trace_took(path, shape, heads_kv,
+                                                      platform):
+    """kernels.flash_attention_grad{path=...}: one count a traced gradient
+    op, by the backward its shapes took where the computation is placed
+    (traced only: nothing is lowered or run)."""
+    from unittest import mock
+
+    from paddle_tpu import observability as obs
+    from paddle_tpu.core.registry import OpInfoMap
+
+    fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+    B, H, S, D = shape
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((B, heads_kv, S, D), jnp.bfloat16)
+    # the streaming forward's LSE is a column, the short path's a row
+    lse = jax.ShapeDtypeStruct(
+        (B * H, 1, S) if path == "short" else (B * H, S, 1), jnp.float32)
+    op = OpInfoMap.instance().get("flash_attention_grad").fn
+    was_on = obs.enabled()
+    obs.enable()
+    try:
+        before = dict(obs.dump()["counters"])
+        with mock.patch.object(fa, "compute_platform", lambda: platform):
+            grads = jax.eval_shape(
+                lambda q, k, v, out, lse, g: op(
+                    {"Q": q, "K": k, "V": v, "Out": out,
+                     "LSE": None if path == "dense" else lse, "Out@GRAD": g},
+                    {"causal": True, "scale": 0.0}), q, kv, kv, q, lse, q)
+        after = obs.dump()["counters"]
+    finally:
+        if not was_on:
+            obs.disable()
+    grown = {name: after[name] - before.get(name, 0) for name in after
+             if name.startswith("kernels.flash_attention_grad")
+             and after[name] != before.get(name, 0)}
+    assert grown == {"kernels.flash_attention_grad{path=%s}" % path: 1}
+    assert grads["Q@GRAD"].shape == shape
+    assert grads["K@GRAD"].shape == grads["V@GRAD"].shape == kv.shape
+
+
 def test_bert_step_for_v5e_holds_one_forward_kernel_a_layer(v5e_compile):
     """A 2-layer BERT step at T = 512 and a 1-layer one at T = 128 (the two
     cells' lengths): the program's own forward kernel once a layer (the grad op
@@ -201,8 +270,9 @@ def test_recomputation_lowers_the_compiled_steps_temporaries(
     folding each re-emitted segment back onto its original (without it the
     real configuration's step compiled to the same 7.898 GiB either way,
     PERF.md section 6, PR 27). The step also holds the streaming attention
-    kernels for shared K/V heads and the grouped-product kernels of the
-    experts (megablox on the TPU, so no ``ragged-dot`` is left). With states
+    kernels for shared K/V heads (a forward and ONE backward kernel for its
+    ``*`` layer, under recomputation too) and the grouped-product kernels of
+    the experts (megablox on the TPU, so no ``ragged-dot`` is left). With states
     of 128 it holds the selective scan's kernels: for its three ``M`` layers
     three forwards, state passes and backward kernels, and with
     recomputation each forward once more (the gradient op runs the state
@@ -213,7 +283,8 @@ def test_recomputation_lowers_the_compiled_steps_temporaries(
                if x.startswith("HYBRID_STEP state=%d " % state_size)]
     got = dict(kv.split("=") for kv in line.split()[1:])
     assert int(got["checkpoints"]) < 0.8 * int(got["plain"]), line
-    assert int(got["mosaic_calls"]) >= 3 + 6 + scan_calls, line
+    assert int(got["mosaic_calls"]) >= 2 + 6 + scan_calls, line
+    assert int(got["flash_bwd"]) == 1, line
     assert int(got["ragged_dots"]) == 0, line
     assert (got["scans"], got["scans_recomputing"]) == (scans, recomputing)
 

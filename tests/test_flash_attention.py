@@ -4,6 +4,8 @@ The pallas kernel runs in interpret mode on CPU (force_pallas) so the
 exact streaming/log-sum-exp code path is exercised without TPU
 hardware; on-device it compiles to the real kernel.
 """
+import importlib
+
 import numpy as np
 import pytest
 
@@ -12,6 +14,8 @@ import jax.numpy as jnp
 
 from paddle_tpu.ops.pallas.flash_attention import (
     _dense_attention, flash_attention)
+
+fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 
 B, H, S, D = 2, 3, 32, 16
 
@@ -377,3 +381,205 @@ def test_dense_kv_lengths_mask_actually_masks():
     np.testing.assert_allclose(o1[0], o2[0], rtol=1e-5)
     np.testing.assert_allclose(o1[1, :4], o2[1, :4], rtol=1e-4,
                                atol=1e-4)
+
+
+# -- the streaming backward: one kernel for dQ, dK, dV ------------------------
+
+
+def _stream_case(causal, mask, group, dims, dtype, seed=5):
+    """Inputs of a streaming call (S = 32 in blocks of 16 x 8, B = 2, four
+    query heads over 4 / group K/V heads, head dims (D, Dv)), the flash and
+    the dense float32 loss over them, and the tolerance of the dtype."""
+    Bs, Hs, Ss = 2, 4, 32
+    D, Dv = dims
+    rng = np.random.RandomState(seed)
+    draw = lambda *shape: jnp.asarray(
+        rng.randn(*shape).astype("float32")).astype(dtype)
+    q, k, v = (draw(Bs, Hs, Ss, D), draw(Bs, Hs // group, Ss, D),
+               draw(Bs, Hs // group, Ss, Dv))
+    ct = np.asarray(rng.randn(Bs, Hs, Ss, Dv).astype("float32"))
+    lengths = select = None
+    if mask == "lengths":
+        lengths = jnp.asarray([Ss, 13], dtype=jnp.int32)
+        ct[1, :, 13:] = 0.0   # padded query rows are unspecified: no weight
+    if mask == "select":
+        keep = rng.rand(Bs, Ss, Ss) < 0.4
+        keep |= np.eye(Ss, dtype=bool)[None]   # every row sees its own key
+        select = jnp.asarray(keep.astype("int8"))
+    ct = jnp.asarray(ct)
+    scale = float(D) ** -0.5
+
+    def flash_loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=causal, block_q=16,
+                                 block_k=8, force_pallas=True,
+                                 lengths=lengths, select=select)
+        return jnp.sum(out.astype(jnp.float32) * ct)
+
+    def dense_loss(q, k, v):
+        out = fa._dense_attention(
+            q.astype(jnp.float32), k.astype(jnp.float32),
+            v.astype(jnp.float32), causal, scale, lengths, select)
+        return jnp.sum(out * ct)
+
+    tol = 2e-4 if dtype == jnp.float32 else 4e-2
+    return (q, k, v), flash_loss, dense_loss, tol, select
+
+
+def _backward_paths(loss, *args):
+    """{path: traces} that ``kernels.flash_attention_grad`` grew by over one
+    trace of ``loss``'s gradient: the counter is made at the branch the
+    backward takes (traced only: nothing is lowered or run)."""
+    from paddle_tpu import observability as obs
+
+    prefix = "kernels.flash_attention_grad{path="
+    was_on = obs.enabled()
+    obs.enable()
+    try:
+        before = dict(obs.dump()["counters"])
+        jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), *args)
+        after = obs.dump()["counters"]
+    finally:
+        if not was_on:
+            obs.disable()
+    return {name[len(prefix):-1]: count - before.get(name, 0)
+            for name, count in after.items()
+            if name.startswith(prefix) and count != before.get(name, 0)}
+
+
+def _assert_grads_match(flash_loss, dense_loss, args, tol, what):
+    got = jax.grad(flash_loss, argnums=(0, 1, 2))(*args)
+    want = jax.grad(dense_loss, argnums=(0, 1, 2))(*args)
+    for a, b, name in zip(got, want, "qkv"):
+        assert a.dtype == args[0].dtype and a.shape == b.shape
+        b = np.asarray(b, dtype="float32")
+        np.testing.assert_allclose(
+            np.asarray(a, dtype="float32"), b, rtol=tol,
+            atol=tol * max(1.0, float(np.abs(b).max())),
+            err_msg="d%s mismatch (%s)" % (name, what))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16"])
+@pytest.mark.parametrize("dims", [(16, 16), (24, 16)], ids=["d16", "d24v16"])
+@pytest.mark.parametrize("group", [1, 4])
+@pytest.mark.parametrize("mask", ["none", "lengths", "select"])
+@pytest.mark.parametrize("causal", [False, True])
+def test_fused_backward_matches_dense_vjp(causal, mask, group, dims, dtype):
+    """The streaming backward the rule picks (with shared K/V heads the one
+    kernel: each score tile made once, dK and dV of a K/V head summed over
+    its group in VMEM; the pair with one K/V head a query head) against the
+    dense VJP: every mask, a value dim of its own, both dtypes."""
+    args, flash_loss, dense_loss, tol, select = _stream_case(
+        causal, mask, group, dims, dtype)
+    assert _backward_paths(flash_loss, *args) == {
+        "fused" if group > 1 else "split": 1}
+    _assert_grads_match(flash_loss, dense_loss, args, tol,
+                        (causal, mask, group, dims))
+
+
+@pytest.mark.parametrize("dims", [(16, 16), (24, 16)], ids=["d16", "d24v16"])
+@pytest.mark.parametrize("mask", ["none", "lengths", "select"])
+@pytest.mark.parametrize("causal", [False, True])
+def test_fused_backward_with_one_kv_head_a_query_head(monkeypatch, causal,
+                                                      mask, dims):
+    """The one kernel's math holds without shared K/V heads too (the rule
+    keeps such a call on the pair for the chip's sake: ``_fused_bwd_fits``)."""
+    args, flash_loss, dense_loss, tol, select = _stream_case(
+        causal, mask, 1, dims, jnp.bfloat16)
+    monkeypatch.setattr(fa, "_fused_bwd_fits", lambda *shapes: True)
+    assert _backward_paths(flash_loss, *args) == {"fused": 1}
+    _assert_grads_match(flash_loss, dense_loss, args, tol,
+                        (causal, mask, dims))
+
+
+@pytest.mark.parametrize("group", [1, 4])
+@pytest.mark.parametrize("mask", ["none", "lengths", "select"])
+@pytest.mark.parametrize("causal", [False, True])
+def test_split_backward_over_the_budget_matches_dense_vjp(
+        monkeypatch, causal, mask, group):
+    """Past the VMEM budget the dQ and dK+dV pair is what runs, and it is
+    still the dense VJP (the budget is forced under a toy shape's need)."""
+    args, flash_loss, dense_loss, tol, select = _stream_case(
+        causal, mask, group, (16, 16), jnp.float32)
+    monkeypatch.setattr(fa, "STREAM_VMEM_BUDGET", 1 << 10)
+    assert _backward_paths(flash_loss, *args) == {"split": 1}
+    calls = jax.make_jaxpr(jax.grad(flash_loss, argnums=(0, 1, 2)))(
+        *args).pretty_print(use_color=False).count("pallas_call")
+    assert calls == 3   # forward, dQ, dK+dV
+    _assert_grads_match(flash_loss, dense_loss, args, tol,
+                        (causal, mask, group))
+
+
+def test_fused_backward_is_one_kernel_a_call():
+    args, flash_loss, _, _, _ = _stream_case(True, "none", 4, (16, 16),
+                                             jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(flash_loss, argnums=(0, 1, 2)))(*args)
+    assert jaxpr.pretty_print(use_color=False).count("pallas_call") == 2
+
+
+@pytest.mark.parametrize("group", [1, 4])
+def test_selecting_every_causal_key_gives_the_unselected_gradients(group):
+    """Bit for bit: the selection's mask changes no visible score."""
+    (q, k, v), _, _, _, _ = _stream_case(True, "none", group, (16, 16),
+                                         jnp.bfloat16)
+    every = jnp.asarray(np.tril(np.ones((q.shape[0], 32, 32), "int8")))
+
+    def grads(select):
+        return jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
+            q, k, v, causal=True, block_q=16, block_k=8, force_pallas=True,
+            select=select).astype(jnp.float32) ** 2), argnums=(0, 1, 2))(
+                q, k, v)
+
+    for a, b in zip(grads(every), grads(None)):
+        np.testing.assert_array_equal(np.asarray(a, dtype="float32"),
+                                      np.asarray(b, dtype="float32"))
+
+
+def test_fused_or_split_is_a_function_of_the_shapes():
+    """The one kernel for calls with shared K/V heads wherever a K/V head's
+    float32 dK and dV (and the tiles) fit the budget, the two decoder cells
+    with shared heads among them; the pair beyond, and with one K/V head a
+    query head (the latent-attention cell); nothing but S, D, Dv, the
+    blocks, the dtype and the heads' ratio decides."""
+    fits = lambda S, D, Dv, group, itemsize=2: fa._fused_bwd_fits(
+        S, D, Dv, 512, 1024, itemsize, group)
+    assert fits(16384, 128, 128, 8) and fits(8192, 128, 128, 16) \
+        and fits(4096, 192, 128, 4) and fits(4096, 64, 64, 2)
+    assert fits(16384, 128, 128, 8, 4)       # float32 operands too
+    assert not fits(262144, 128, 128, 8) and not fits(65536, 192, 192, 8)
+    # one K/V head a query head: the pair, whatever the head dims
+    assert not fits(4096, 192, 128, 1) and not fits(4096, 128, 128, 1) \
+        and not fits(2048, 64, 64, 1)
+    # monotone in each of S, D, Dv: one threshold, no island
+    sizes = [fa._fused_bwd_vmem_bytes(S, D, Dv, 512, 1024, 2)
+             for S in (4096, 8192, 16384) for D in (64, 128, 192)
+             for Dv in (64, 128)]
+    assert sizes == sorted(sizes)
+    assert fa._fused_bwd_vmem_bytes(16384, 128, 128, 512, 1024, 2) \
+        >= (16384 * 128 + 16384 * 128) * 4    # the accumulators are in it
+    # the backward that a trace takes follows the rule (shapes only: nothing
+    # is lowered or run), at the sparse-attention cell's shape and beyond
+    def paths(q, k, v, **kw):
+        return _backward_paths(lambda q, k, v: jnp.sum(fa.flash_attention(
+            q, k, v, causal=True, **kw).astype(jnp.float32)), q, k, v)
+
+    q = jax.ShapeDtypeStruct((1, 32, 16384, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 4, 16384, 128), jnp.bfloat16)
+    assert paths(q, kv, kv, force_pallas=True) == {"fused": 1}
+    long_q = jax.ShapeDtypeStruct((1, 8, 262144, 128), jnp.bfloat16)
+    long_kv = jax.ShapeDtypeStruct((1, 1, 262144, 128), jnp.bfloat16)
+    assert paths(long_q, long_kv, long_kv, force_pallas=True) == {"split": 1}
+    latent_qk = jax.ShapeDtypeStruct((1, 32, 4096, 192), jnp.bfloat16)
+    latent_v = jax.ShapeDtypeStruct((1, 32, 4096, 128), jnp.bfloat16)
+    assert paths(latent_qk, latent_qk, latent_v,
+                 force_pallas=True) == {"split": 1}
+    # off the TPU the dense math runs and JAX differentiates it: no kernel's
+    # branch is taken (the grad op counts that one, tests/test_chip_smoke.py)
+    small = jax.ShapeDtypeStruct((1, 4, 2048, 64), jnp.bfloat16)
+    assert paths(small, small, small) == {}
+    short = jax.ShapeDtypeStruct((2, 4, 256, 64), jnp.bfloat16)
+    assert paths(short, short, short, force_pallas=True) == {"short": 1}
+    ragged = jax.ShapeDtypeStruct((2, 4, 770, 64), jnp.bfloat16)
+    with pytest.warns(UserWarning):
+        assert paths(ragged, ragged, ragged,
+                     force_pallas=True) == {"dense": 1}

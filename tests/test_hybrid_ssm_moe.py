@@ -587,7 +587,8 @@ def test_the_model_keeps_its_head_major_attention(recompute, ops, digest):
                      "kernels.flash_attention_layout{layout=heads}":
                      1 + recompute,
                      "kernels.flash_attention_select{form=none}":
-                     1 + recompute}
+                     1 + recompute,
+                     "kernels.flash_attention_grad{path=dense}": 1}
 
 
 @pytest.mark.parametrize("expert,act,matrices", [("relu2", "relu", 2),

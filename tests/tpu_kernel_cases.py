@@ -25,6 +25,18 @@ import jax.numpy as jnp
 bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
 
 
+# the streaming gradient at the sparse-attention, latent-attention and hybrid
+# cells' shapes: name -> (H, H_kv, T, D, Dv, with a selection)
+STREAM_GRADS = {
+    "flash_grad_gqa_selected_s16384_d128": (32, 4, 16384, 128, 128, True),
+    "flash_grad_causal_s4096_d192_v128": (32, 32, 4096, 192, 128, False),
+    "flash_grad_gqa_causal_s8192_d128": (32, 2, 8192, 128, 128, False),
+    # the one kernel itself with a value dim of its own and lanes that pad
+    # (192): shared K/V heads, so the rule picks it
+    "flash_grad_gqa_causal_s4096_d192_v128": (32, 8, 4096, 192, 128, False),
+}
+
+
 def cases():
     fa = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
     pa = importlib.import_module("paddle_tpu.ops.pallas.paged_attention")
@@ -86,30 +98,46 @@ def cases():
 
     # shared K/V heads (32 over 2) at head dim 128, causal, T = 8192: the
     # streaming kernels read the shared head through their index maps and
-    # the dK/dV kernel sums over the group
+    # the backward kernel sums over the group in its dK, dV accumulators
     q, kv = ((1, 32, 8192, 128), bf16), ((1, 2, 8192, 128), bf16)
     yield "flash_gqa_causal_s8192_d128", flash(True, 512, 1024), [q, kv, kv]
 
     # latent attention's core: 32 heads, q and k at 192 (128 + the rotary
     # 64), v and the context at 128, causal, T = 4096: the streaming
     # kernels with a value dim of its own, forward, dQ and dK/dV; and the
-    # same call at equal dims, which plans and lowers as it did
+    # same call at equal dims
     qk, v = ((1, 32, 4096, 192), bf16), ((1, 32, 4096, 128), bf16)
     yield "flash_causal_s4096_d192_v128", flash(True, 512, 1024), [qk, qk, v]
     yield "flash_causal_s4096_d128_h32", flash(True, 512, 1024), [v, v, v]
 
     # the same kernels with a per-query key selection (32 over 4 heads, head
-    # dim 128, T = 2048): the [B, S, S] int8 operand's tiles ride beside
-    # the K blocks, forward, dQ and dK/dV
+    # dim 128, T = 2048, and T = 16,384 as the sparse-attention cell runs
+    # it: 16 MiB of float32 dK, dV a K/V head in VMEM): the [B, S, S] int8
+    # operand's tiles ride beside the K blocks, forward and backward
     def selected(q, k, v, select):
         out, vjp = jax.vjp(
             lambda q, k, v: fa._flash_selected(q, k, v, select, True, 0.125,
                                                512, 1024, False)[0], q, k, v)
         return (out,) + vjp(out)
 
-    q, kv = ((1, 32, 2048, 128), bf16), ((1, 4, 2048, 128), bf16)
-    yield ("flash_gqa_selected_s2048_d128", selected,
-           [q, kv, kv, ((1, 2048, 2048), jnp.int8)])
+    for t in (2048, 16384):
+        q, kv = ((1, 32, t, 128), bf16), ((1, 4, t, 128), bf16)
+        yield ("flash_gqa_selected_s%d_d128" % t, selected,
+               [q, kv, kv, ((1, t, t), jnp.int8)])
+
+    # the gradient op's kernels alone, from the forward's Out and LSE, at the
+    # three decoder cells' shapes: ONE Mosaic call with shared K/V heads, the
+    # dQ and dK+dV pair at latent attention's one K/V head a query head
+    def grad_alone(q, k, v, out, lse, g, *select):
+        return fa._flash_backward(q, k, v, out, lse, g, True, 0.125, 512,
+                                  1024, False, select=(select or (None,))[0])
+
+    for name, (h, h_kv, t, d, dv, sel) in STREAM_GRADS.items():
+        yield (name, grad_alone,
+               [((1, h, t, d), bf16), ((1, h_kv, t, d), bf16),
+                ((1, h_kv, t, dv), bf16), ((1, h, t, dv), bf16),
+                ((h, t, 1), f32), ((1, h, t, dv), bf16)]
+               + [((1, t, t), jnp.int8)] * sel)
 
     # the held experts' grouped products on the megablox kernels, forward
     # and both backward kernels, at the hybrid cell's shapes: a buffer of
@@ -207,9 +235,10 @@ def hybrid_step_temporaries(topo_sharding, recompute, state_size, seq=2048):
     """``temp_size_in_bytes`` of a six-layer hybrid state-space / MoE
     training step (bf16 AMP, Adam) compiled for the described chip, with
     or without ``RecomputeOptimizer`` over the layers' inputs; its Mosaic
-    calls, ``ragged-dot`` instructions and the selective scan's kernels by
-    name. States of 128 fill the scan kernels' blocks; states of 64 do not,
-    and the scan is then the XLA form on the TPU too."""
+    calls, ``ragged-dot`` instructions, the selective scan's kernels by
+    name and the streaming attention's backward kernels (``flash_stream_bwd``:
+    one a ``*`` layer). States of 128 fill the scan kernels' blocks; states
+    of 64 do not, and the scan is then the XLA form on the TPU too."""
     import paddle_tpu as fluid
     from paddle_tpu import models
     from paddle_tpu.contrib import mixed_precision as mp
@@ -242,7 +271,8 @@ def hybrid_step_temporaries(topo_sharding, recompute, state_size, seq=2048):
     scans = [sum(name in x for x in calls)
              for name in ("ssd_scan_fwd", "ssd_scan_state", "ssd_scan_bwd")]
     return (compiled.memory_analysis().temp_size_in_bytes,
-            hlo.count("tpu_custom_call"), hlo.count("ragged-dot"), scans)
+            hlo.count("tpu_custom_call"), hlo.count("ragged-dot"), scans,
+            sum("flash_stream_bwd" in x for x in calls))
 
 
 def bert_step_report(hlo, seq=512) -> str:
@@ -339,10 +369,10 @@ def compile_all_for_v5e() -> int:
             saved = hybrid_step_temporaries(sharding, True, state_size)
             print("HYBRID_STEP state=%d plain=%d checkpoints=%d "
                   "mosaic_calls=%d ragged_dots=%d scans=%s "
-                  "scans_recomputing=%s"
+                  "scans_recomputing=%s flash_bwd=%d"
                   % (state_size, plain[0], saved[0], plain[1], plain[2],
                      "/".join(map(str, plain[3])),
-                     "/".join(map(str, saved[3]))))
+                     "/".join(map(str, saved[3])), saved[4]))
         except Exception as e:  # noqa: BLE001 — reported like a case
             failed += 1
             print("FAIL hybrid_step state=%d %s: %s" % (

@@ -11,6 +11,15 @@ XLA's dense lowering on the same inputs.
 ``_short_heads`` and ``_tokens_blocks`` pick for the shape is starred. One line a
 variant: milliseconds of one forward + backward and of the forward alone, and the
 relative error of out, dq, dk, dv against the dense float32 math.
+
+The shapes that stream (the three decoder cells': shared K/V heads, a value dim
+of its own, a selection) run the streaming forward with each backward:
+``stream_bwd_fused`` is the one kernel that makes each score tile once,
+``stream_bwd_split`` the dQ and dK+dV pair (``_fused_bwd_fits`` answered for the
+trace: the rule itself picks the one kernel at the two shapes with shared K/V
+heads and the pair at the latent cell's 32 / 32). The dense math of such a
+shape runs a query head at a time (32 heads x 16,384^2 float32 scores do not
+fit the chip).
 """
 from __future__ import annotations
 
@@ -34,6 +43,11 @@ SHAPES = {   # name: (B, H, T, D, causal, with lengths)
     "bert_dp4_s128": (128, 12, 128, 64, False, False),
     "wmt_s256": (64, 8, 256, 64, True, True),
     "s1024": (8, 12, 1024, 64, False, False),
+}
+STREAM_SHAPES = {   # name: (H, H_kv, T, D, Dv, keys a query selects or 0)
+    "keye_s16384": (32, 4, 16384, 128, 128, 2048),
+    "xing4_s4096": (32, 32, 4096, 192, 128, 0),
+    "nemotron_s8192": (32, 2, 8192, 128, 128, 0),
 }
 
 
@@ -93,6 +107,133 @@ def variants(B, H, T, D, causal, lengths):
                    q, k, v, lengths, causal, scale, H, blocks, False)[0]))
 
 
+def stream_variants(D):
+    """(name, forward + backward) of the streaming kernels at a decoder cell's
+    shape, causal; each takes (q, k, v, ct) and, after them, the [1, T, T] int8
+    selection where the shape has one."""
+    from unittest import mock
+
+    scale = float(D) ** -0.5
+
+    def attn(q, k, v, *select):
+        if select:
+            return fa._flash_selected(q, k, v, select[0], True, scale, 512,
+                                      1024, False)[0]
+        return fa._flash(q, k, v, None, True, scale, 512, 1024, 0, False)[0]
+
+    def grads(patch):
+        def both(q, k, v, ct, *select):
+            with patch():   # the kernels are built while this traces
+                out, vjp = jax.vjp(lambda q, k, v: attn(q, k, v, *select),
+                                   q, k, v)
+                return (out,) + vjp(ct.astype(out.dtype))
+
+        def forward(q, k, v, ct, *select):
+            with patch():
+                return attn(q, k, v, *select)
+        both.forward = forward
+        return both
+
+    for name, fused in (("stream_bwd_split", False), ("stream_bwd_fused", True)):
+        yield name, grads(lambda fused=fused: mock.patch.object(
+            fa, "_fused_bwd_fits", lambda *shapes: fused))
+
+
+def dense_by_head(H, H_kv, D):
+    """(out, dq, dk, dv) of the dense float32 math, a query head at a time."""
+    scale, group = float(D) ** -0.5, H // H_kv
+
+    def run(q, k, v, ct, *select):
+        def one(h):
+            take = lambda x, i: jax.lax.dynamic_slice_in_dim(x, i, 1, axis=1)
+            out, vjp = jax.vjp(
+                lambda q, k, v: fa._dense_attention(
+                    q, k, v, True, scale, select=select[0] if select else None),
+                take(q, h), take(k, h // group), take(v, h // group))
+            return (out,) + vjp(take(ct, h))
+        out, dq, dk, dv = (jnp.moveaxis(x[:, :, 0], 0, 1) for x in
+                           jax.lax.map(one, jnp.arange(H)))
+        shared = lambda x: x.reshape(x.shape[0], H_kv, group,
+                                     *x.shape[2:]).sum(axis=2)
+        return out, dq, shared(dk), shared(dv)
+    return run
+
+
+def random_selection(key, T, topk):
+    """[1, T, T] int8: row r sees each of its r + 1 causal keys with
+    probability topk / (r + 1) (all of them up to row topk), scattered as an
+    untrained indexer's selection is."""
+    rows = jnp.arange(T)[:, None]
+    keep = jax.random.uniform(key, (T, T)) * (rows + 1) < topk
+    return (keep & (jnp.arange(T)[None, :] <= rows)).astype(jnp.int8)[None]
+
+
+def compile_report(name, vname, fn, specs):
+    """Compile one variant for the described chip and print what it took."""
+    t0 = time.perf_counter()
+    try:
+        c = jax.jit(fn).lower(*specs).compile()
+        print("%s %s OK %.1f s mosaic=%d temp=%.1f MiB" % (
+            name, vname, time.perf_counter() - t0,
+            c.as_text().count("tpu_custom_call"),
+            c.memory_analysis().temp_size_in_bytes / 2**20), flush=True)
+    except Exception as e:  # noqa: BLE001 — reported per variant
+        print("%s %s FAIL %s" % (name, vname,
+                                 str(e)[:600].replace("\n", " | ")),
+              flush=True)
+
+
+def report(name, vname, fn, args_, reps, inner, ref, tokens_heads=0):
+    """Run, time and print one variant of (q, k, v, ct, *rest) -> (out, dq,
+    dk, dv); returns those, or None where it failed."""
+    def forward(*a):   # q <- out (or, where out has a dim of its own, q + out)
+        out = fn.forward(*a).astype(a[0].dtype)
+        return (out if out.shape == a[0].shape else a[0] + out[..., :1],
+                ) + a[1:]
+    try:
+        out = jax.block_until_ready(jax.jit(fn)(*args_))
+        if tokens_heads:
+            out = tuple(fa.split_heads(x, tokens_heads) for x in out)
+        # (q, k, v, ct) <- (dq, dk, dv, out)
+        ms = timed(lambda *a: (lambda r: r[1:] + r[:1])(fn(*a)) + a[4:],
+                   args_, reps, inner)
+        fwd_ms = timed(forward, args_, reps, inner)
+    except Exception as e:  # noqa: BLE001 — reported per variant
+        print("%s %s FAIL %s" % (name, vname,
+                                 str(e)[:600].replace("\n", " | ")),
+              flush=True)
+        return None
+    print("%s %s %.3f ms (forward alone %.3f)  rel err out/dq/dk/dv %s"
+          % (name, vname, ms, fwd_ms,
+             " ".join("%.4f" % rel(a, b) for a, b in zip(out, ref or out))),
+          flush=True)
+    return out
+
+
+def run_stream(name, args, sharding):
+    H, H_kv, T, D, Dv, topk = STREAM_SHAPES[name]
+    shapes = [(1, H, T, D), (1, H_kv, T, D), (1, H_kv, T, Dv), (1, H, T, Dv)]
+    if args.compile_only:
+        specs = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=sharding)
+                 for s in shapes]
+        if topk:
+            specs.append(jax.ShapeDtypeStruct((1, T, T), jnp.int8,
+                                              sharding=sharding))
+        for vname, fn in stream_variants(D):
+            if args.only in vname:
+                compile_report(name, vname, fn, specs)
+        return
+    keys = jax.random.split(jax.random.key(T), 5)
+    q, k, v, ct = (jax.random.normal(kk, s, f32).astype(jnp.bfloat16)
+                   for kk, s in zip(keys, shapes))
+    select = (random_selection(keys[4], T, topk),) if topk else ()
+    ref = jax.block_until_ready(jax.jit(dense_by_head(H, H_kv, D))(
+        *(x.astype(f32) for x in (q, k, v, ct)), *select))
+    for vname, fn in stream_variants(D):
+        if args.only in vname:
+            report(name, vname, fn, (q, k, v, ct) + select, args.reps, 4, ref)
+
+
 def timed(step, args, reps, inner=20):
     """Median milliseconds of one ``step``, from ``reps`` calls of a jitted
     loop that runs it ``inner`` times on the device, each iteration on the
@@ -118,7 +259,8 @@ def rel(a, b):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--compile-only", action="store_true")
-    ap.add_argument("--shapes", default=",".join(SHAPES))
+    ap.add_argument("--shapes", default=",".join(list(SHAPES)
+                                                 + list(STREAM_SHAPES)))
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--only", default="",
                     help="run the variants whose name contains this")
@@ -136,6 +278,9 @@ def main():
         print("# device %s" % jax.devices()[0].device_kind, flush=True)
 
     for name in args.shapes.split(","):
+        if name in STREAM_SHAPES:
+            run_stream(name, args, sharding)
+            continue
         B, H, T, D, causal, with_len = SHAPES[name]
         shape = (B, H, T, D)
         if args.compile_only:
@@ -147,19 +292,8 @@ def main():
             for vname, fn in variants(B, H, T, D, causal, None):
                 if with_len or args.only not in vname:
                     continue   # lengths is closed over: needs an array
-                spec = specs[vname.startswith("tokens")]
-                t0 = time.perf_counter()
-                try:
-                    c = jax.jit(fn).lower(spec, spec, spec, spec).compile()
-                    m = c.memory_analysis()
-                    print("%s %s OK %.1f s mosaic=%d temp=%.1f MiB" % (
-                        name, vname, time.perf_counter() - t0,
-                        c.as_text().count("tpu_custom_call"),
-                        m.temp_size_in_bytes / 2**20), flush=True)
-                except Exception as e:  # noqa: BLE001 — reported per variant
-                    print("%s %s FAIL %s" % (
-                        name, vname, str(e)[:600].replace("\n", " | ")),
-                        flush=True)
+                compile_report(name, vname, fn,
+                               [specs[vname.startswith("tokens")]] * 4)
             continue
         keys = jax.random.split(jax.random.key(T), 5)
         q, k, v, ct = (jax.random.normal(kk, shape, f32).astype(jnp.bfloat16)
@@ -176,27 +310,10 @@ def main():
                 args_ = tuple(x.astype(f32) for x in args_)
             elif tokens:   # the same numbers, as the projections leave them
                 args_ = tuple(fa.merge_heads(x) for x in args_)
-            try:
-                jitted = jax.jit(fn)
-                out = jax.block_until_ready(jitted(*args_))
-                if tokens:
-                    out = tuple(fa.split_heads(x, H) for x in out)
-                # (q, k, v, ct) <- (dq, dk, dv, out); forward: q <- out
-                ms = timed(lambda *a: (lambda r: r[1:] + r[:1])(fn(*a)),
-                           args_, args.reps)
-                fwd_ms = timed(lambda *a: (fn.forward(*a),) + a[1:], args_,
-                               args.reps)
-            except Exception as e:  # noqa: BLE001 — reported per variant
-                print("%s %s FAIL %s" % (name, vname,
-                                         str(e)[:600].replace("\n", " | ")),
-                      flush=True)
-                continue
+            out = report(name, vname, fn, args_, args.reps, 20, ref,
+                         H if tokens else 0)
             if ref is None:
                 ref = out
-            print("%s %s %.3f ms (forward alone %.3f)  rel err out/dq/dk/dv %s"
-                  % (name, vname, ms, fwd_ms,
-                     " ".join("%.4f" % rel(a, b) for a, b in zip(out, ref))),
-                  flush=True)
 
 
 if __name__ == "__main__":
