@@ -111,6 +111,4 @@ def maybe_verify_program(program, where: str,
     from .. import observability as _obs
 
     _obs.inc("analysis.verify_runs", where=where)
-    for f in findings:
-        _obs.inc("analysis.findings", severity=f.severity)
     return findings

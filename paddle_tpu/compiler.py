@@ -109,9 +109,9 @@ class CompiledProgram:
     # called by Executor.run
     def _run(self, executor, feed, fetch_list, scope, return_numpy):
         if not self._is_data_parallel:
-            return executor.run(self._program, feed=feed,
-                                fetch_list=fetch_list, scope=scope,
-                                return_numpy=return_numpy)
+            # inside the caller's executor/run: one root, one run counted
+            return executor._run(self._program, scope, feed, fetch_list,
+                                 return_numpy)
         # a pipelined program (PipelineOptimizer metadata) over a mesh
         # with a 'pp' axis routes to the pipeline engine — composes
         # with dp replicas and model axes (dp x pp x mp in one program)

@@ -34,8 +34,7 @@ class AsyncDeviceFeeder:
     jax.device_put — async dispatch, so the transfer itself also
     overlaps the thread's next parse). Iterating yields dicts of
     jax.Arrays ready to feed ``Executor.run``; the consumer-side stall
-    is recorded as ``feed.wait_ms`` and the per-batch staging cost as
-    ``feed.stage_ms`` — the before/after pair for the async-feed win.
+    is recorded as ``feed.wait_ms``.
 
     ``close()`` (or exhaustion) joins the thread; the feeder is also a
     context manager. A ``depth`` of 2 is the classic double buffer:
@@ -58,15 +57,8 @@ class AsyncDeviceFeeder:
     def _stage(self, batch):
         import jax
 
-        t0 = time.perf_counter()
-        staged = {k: jax.device_put(v, self._device)
-                  for k, v in batch.items()}
-        from .. import observability as _obs
-
-        if _obs.enabled():
-            _obs.observe("feed.stage_ms",
-                         (time.perf_counter() - t0) * 1e3)
-        return staged
+        return {k: jax.device_put(v, self._device)
+                for k, v in batch.items()}
 
     def _put(self, item) -> bool:
         """Bounded put that re-checks the close flag: a close() racing

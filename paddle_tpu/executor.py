@@ -64,21 +64,21 @@ class Executor:
         if program is None:
             program = framework.default_main_program()
 
-        if isinstance(program, CompiledProgram):
-            return program._run(self, feed or {}, fetch_list or [],
-                                scope, return_numpy)
-
         feed = feed or {}
         fetch_list = list(fetch_list or [])
 
         from . import observability as _obs
 
-        # one span around the whole call; the spans opened inside it
-        # (by either path) inherit its step, the count of this
-        # executor's runs
+        # one span around the whole call, whichever engine it ends in;
+        # the spans opened inside it (the compiled step's, the
+        # interpreter's, the mesh engines') inherit its step, the count
+        # of this executor's runs
         self._runs += 1
         with _obs.tracing.span("executor/run", cat="step",
                                step=self._runs):
+            if isinstance(program, CompiledProgram):
+                return program._run(self, feed, fetch_list, scope,
+                                    return_numpy)
             return self._run(program, scope, feed, fetch_list,
                              return_numpy)
 

@@ -20,6 +20,10 @@ Metric families (see README "Runtime observability"):
 ``executor.feed_ms``                   histogram: host feeds staged in a step
 ``executor.trace_s``                   counter: seconds of Python tracing of
                                        compiled steps (span executor/trace)
+``executor.lower_s`` / ``parallel.lower_s``  counter: seconds of lowering
+                                       (jaxpr -> MLIR, Pallas bodies inside)
+                                       reported by jax.monitoring under a
+                                       span of that family (<family>/lower)
 ``executor.ops{type=...}``             counter: interpreter per-op executions
 ``kernels.flash_attention{path=...}``  counter: traces of the flash_attention
                                        op, by kernels: short | stream | dense
@@ -41,7 +45,10 @@ Metric families (see README "Runtime observability"):
 ``dygraph.ops{dispatch=...}``          counter: traced eager/lazy ops
 ``parallel.steps`` / ``.compiles``     counter: mesh-engine steps/compiles
 ``parallel.collective_bytes``          counter: bytes allreduced per step
-``parallel.step_ms``                   histogram: mesh step latency
+``parallel.step_ms``                   histogram: host step latency of the
+                                       whole run_data_parallel, fetch included
+``parallel.trace_s``                   counter: seconds of Python tracing of
+                                       mesh steps (span parallel/trace)
 ``pipeline.steps`` / ``.step_ms``      counter / histogram
 ``pipeline.bubble_fraction``           gauge: (S-1)/(M+S-1) GPipe bubble
 ``pipeline.boundary_bytes{boundary=}`` gauge: rotating-buffer payload
@@ -103,6 +110,7 @@ observability".
 from __future__ import annotations
 
 import os
+import sys
 from typing import Dict, Optional
 
 from . import flight  # noqa: F401
@@ -160,11 +168,43 @@ def _sync_flag(on: bool) -> None:
     via sys.modules so this never forces core.flags (and its package
     init) to load early — if flags isn't loaded yet, its own env init
     resolves to the same value."""
-    import sys
-
     fl = sys.modules.get(__package__.rsplit(".", 1)[0] + ".core.flags")
     if fl is not None:
         fl._values["FLAGS_tpu_metrics"] = bool(on)
+
+
+# what JAX reports once a jaxpr is lowered to an MLIR module, every
+# Pallas body's lowering to Mosaic inside it
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_listening = False
+
+
+def _count_lowering(event: str, seconds: float, **_) -> None:
+    """Lowering seconds of the program's own steps: counted only on a
+    thread that is inside a program span (``executor.lower_s`` under
+    ``executor/launch``, ``parallel.lower_s`` under ``parallel/step``),
+    with a span ``<family>/lower`` beside the counter. What a user's
+    own ``jax.jit`` lowers under no span counts nothing."""
+    if event != _LOWER_EVENT or not _enabled:
+        return
+    family = tracing.open_family()
+    if family is None:
+        return
+    _registry.counter(family + ".lower_s").inc(seconds)
+    tracing.record_ending_now(family + "/lower", seconds, cat="compile")
+
+
+def _listen_for_lowering() -> None:
+    """One ``jax.monitoring`` listener a process, registered by the
+    first ``enable()`` that finds JAX loaded (this module never loads
+    it)."""
+    global _listening
+    if _listening or "jax" not in sys.modules:
+        return
+    from jax import monitoring
+
+    monitoring.register_event_duration_secs_listener(_count_lowering)
+    _listening = True
 
 
 def enable() -> None:
@@ -172,6 +212,7 @@ def enable() -> None:
     _enabled = True
     tracing._set_metrics_on(True)
     _sync_flag(True)
+    _listen_for_lowering()
 
 
 def disable() -> None:

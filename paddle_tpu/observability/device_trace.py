@@ -550,21 +550,6 @@ def cross_check(host_phase_ms: Dict, device_phase_ms: Dict) -> Dict:
             "agreement": (num / den) if den else None}
 
 
-def _emit_device_profile(dev: Dict, agreement=None) -> None:
-    from .. import observability as _obs
-
-    if not _obs.enabled():
-        return
-    for phase, ms in dev["device_phase_ms"].items():
-        _obs.observe("profile.device_phase_ms", ms, phase=phase)
-    if dev["overlap_frac"] is not None:
-        _obs.set_gauge("profile.device_overlap_frac", dev["overlap_frac"])
-    _obs.set_gauge("profile.device_critical_path_ms",
-                   dev["critical_path_ms"])
-    if agreement is not None:
-        _obs.set_gauge("profile.host_device_agreement", agreement)
-
-
 # -- one-call device profile of a static program ----------------------------
 
 
@@ -598,7 +583,4 @@ def device_profile_step(program, scope, feed, mesh=None,
             jax.block_until_ready(fn(*args))
 
     space = capture_xspace(run, trace_dir)
-    dev = fold_device_phases(space, steps=steps)
-    if dev is not None:
-        _emit_device_profile(dev)
-    return dev
+    return fold_device_phases(space, steps=steps)

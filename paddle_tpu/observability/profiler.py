@@ -788,9 +788,6 @@ def _emit_profile(prof: Dict) -> None:
     if prof["critical_path_ms"] is not None:
         _obs.set_gauge("profile.critical_path_ms",
                        prof["critical_path_ms"])
-    if prof["exposed_collective_ms"] is not None:
-        _obs.set_gauge("profile.exposed_collective_ms",
-                       prof["exposed_collective_ms"])
     if prof.get("feed_ms") is not None:
         _obs.set_gauge("profile.feed_ms", prof["feed_ms"])
     if tracing.active():

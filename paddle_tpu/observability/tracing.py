@@ -40,8 +40,9 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
-__all__ = ["span", "active", "trace_events", "nest", "chrome_trace",
-           "write_chrome_trace", "clear", "ANNOTATION_PREFIX"]
+__all__ = ["span", "active", "open_family", "record_ending_now",
+           "trace_events", "nest", "chrome_trace", "write_chrome_trace",
+           "clear", "ANNOTATION_PREFIX"]
 
 # what a program span is called in a jax.profiler trace
 ANNOTATION_PREFIX = "pt:"
@@ -161,6 +162,35 @@ def span(name: str, cat: str = "op", **args):
     if not (_metrics_on or _profiler_on):
         return _NULL
     return Span(name, cat, args or None)
+
+
+def open_family() -> Optional[str]:
+    """The part before the ``/`` of this thread's innermost open span
+    that has one (``executor`` under ``executor/launch``; the
+    interpreter's per-op spans are named by the op type alone and stand
+    under ``executor/step``). None where no such span is open."""
+    sp = getattr(_open, "span", None)
+    while sp is not None:
+        family, slash, _ = sp.name.partition("/")
+        if slash:
+            return family
+        sp = sp._outer
+    return None
+
+
+def record_ending_now(name: str, dur_s: float, cat: str = "op") -> None:
+    """Record a span of ``dur_s`` seconds that ends now: a duration
+    something else measured (``jax.monitoring``), as a child of this
+    thread's innermost open span, whose ``step`` it takes. The buffer
+    only: an annotation cannot be opened in the past."""
+    if not active():
+        return
+    outer = getattr(_open, "span", None)
+    args = None
+    if outer is not None and outer.args and "step" in outer.args:
+        args = {"step": outer.args["step"]}
+    _record(name, (time.perf_counter() - dur_s) * 1e6, dur_s * 1e6,
+            cat, args)
 
 
 def _record(name, ts_us, dur_us, cat, args) -> None:

@@ -269,9 +269,6 @@ def maybe_rewrite_collectives(program, scope, nranks: int, data_axes,
         schedule_async_collectives(program, report=report, scope=scope)
     if pplan is not None:
         program._placement_plan = pplan.summary()
-        from .. import observability as _obs
-
-        _obs.inc("placement.plan_applied")
 
 
 # -- bucketed allreduce -----------------------------------------------------

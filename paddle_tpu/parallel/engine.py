@@ -171,204 +171,222 @@ def run_data_parallel(core, program, scope: Scope, feed: Dict,
     assembled into a global array via
     jax.make_array_from_process_local_data; fetches and updated state
     are read back from the locally-addressable replica."""
+    import time as _time
+
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    if mesh is None and isinstance(places, Mesh):
-        mesh = places  # CompiledProgram.with_data_parallel(places=mesh)
-        places = None
-    mesh = mesh or default_mesh(len(places) if places else None, axis_name)
-    nranks = int(np.prod(list(mesh.shape.values())))
-    multiproc = _mesh_spans_processes(mesh)
+    from .. import observability as _obs
+    from ..observability import distributed as _dtrace
 
-    # hybrid-parallel metadata recorded by the transpiler passes
-    shard_specs = dict(getattr(program, "_var_shard_specs", None) or {})
-    feed_specs = dict(getattr(program, "_feed_shard_specs", None) or {})
-    mesh_axes = set(mesh.axis_names)
-    data_axes = tuple(a for a in (getattr(program, "_data_axes", None)
-                                  or (axis_name,)) if a in mesh_axes)
-    # a pure model-parallel mesh (every mesh axis is a shard axis, no dp
-    # member) legitimately has NO data axis: the full batch is
-    # replicated, grads need no allreduce. Promoting a model axis to a
-    # data axis here would shard the feeds and skip the wrong allreduces
-    # — silently wrong gradients.
-    shard_axes_used = {a for spec in shard_specs.values()
-                       for a in spec if a}
-    if not data_axes and (mesh.axis_names[0] not in shard_axes_used):
-        data_axes = (mesh.axis_names[0],)
-    for n, spec in list(shard_specs.items()) + list(feed_specs.items()):
-        for a in spec:
-            if a is not None and a not in mesh_axes:
-                raise ValueError(
-                    "var %r sharded over axis %r absent from mesh axes %s"
-                    % (n, a, sorted(mesh_axes)))
-    if multiproc and (shard_specs or feed_specs):
-        raise NotImplementedError(
-            "hybrid shard specs over a multi-process mesh")
-    data_nranks = int(np.prod([mesh.shape[a] for a in data_axes]))
+    # the host's part of a step, one span for each stretch of it, all
+    # inside the executor/run that Executor.run opened: under a live
+    # jax.profiler trace they say what the host was in whenever the
+    # chips idled
+    span = _obs.tracing.span
+    t_call = _time.perf_counter() if _obs.enabled() else None
+    with span("parallel/prepare", cat="step"):
+        if mesh is None and isinstance(places, Mesh):
+            mesh = places  # CompiledProgram.with_data_parallel(places=mesh)
+            places = None
+        mesh = mesh or default_mesh(len(places) if places else None,
+                                    axis_name)
+        nranks = int(np.prod(list(mesh.shape.values())))
+        multiproc = _mesh_spans_processes(mesh)
 
-    sync_bn = bool(build_strategy is not None and getattr(
-        build_strategy, "sync_batch_norm", False))
-    if build_strategy is not None and hasattr(build_strategy,
-                                              "_warn_inert"):
-        build_strategy._warn_inert()
-    # GradientScaleStrategy: One and Customized both mean the USER owns
-    # the loss-grad scale (One = already averaged, Customized = their
-    # own scale op) — only the default CoeffNumDevice applies 1/n
-    # (build_strategy.h)
-    scale_loss = (build_strategy is None or getattr(
-        build_strategy, "gradient_scale_strategy", 0) == 0)
-    # collective rewrite (insert_allreduce_ops is itself idempotent
-    # per program — fleet may have transpiled already). Loss/grad
-    # scaling is over the DATA axes only: model-parallel axes see the
-    # same batch and their sharded grads are already complete.
-    if nranks > 1:
-        skip_axes = getattr(program, "_allreduce_skip_grads", None) or {}
-        insert_allreduce_ops(
-            program, data_nranks, scale_loss=scale_loss,
-            skip_grads={g for g, axes in skip_axes.items()
-                        if set(axes) & set(data_axes)})
-        from .transpiler import mark_sync_batch_norm
-
-        mark_sync_batch_norm(program, sync_bn)
-        # fast collective path (bucketed / quantized allreduce, sharded
-        # weight update) — rewrites per-grad collectives in place; may
-        # add flat optimizer-state vars sharded over the data axis, so
-        # the shard-spec snapshot is refreshed below
-        from .collectives import maybe_rewrite_collectives
-
-        maybe_rewrite_collectives(program, scope, data_nranks, data_axes,
-                                  build_strategy=build_strategy,
-                                  multiproc=multiproc)
+        # hybrid-parallel metadata recorded by the transpiler passes
         shard_specs = dict(getattr(program, "_var_shard_specs", None)
                            or {})
+        feed_specs = dict(getattr(program, "_feed_shard_specs", None)
+                          or {})
+        mesh_axes = set(mesh.axis_names)
+        data_axes = tuple(a for a in (getattr(program, "_data_axes", None)
+                                      or (axis_name,)) if a in mesh_axes)
+        # a pure model-parallel mesh (every mesh axis is a shard axis, no dp
+        # member) legitimately has NO data axis: the full batch is
+        # replicated, grads need no allreduce. Promoting a model axis to a
+        # data axis here would shard the feeds and skip the wrong allreduces
+        # — silently wrong gradients.
+        shard_axes_used = {a for spec in shard_specs.values()
+                           for a in spec if a}
+        if not data_axes and (mesh.axis_names[0] not in shard_axes_used):
+            data_axes = (mesh.axis_names[0],)
+        for n, spec in list(shard_specs.items()) + list(feed_specs.items()):
+            for a in spec:
+                if a is not None and a not in mesh_axes:
+                    raise ValueError(
+                        "var %r sharded over axis %r absent from mesh axes %s"
+                        % (n, a, sorted(mesh_axes)))
+        if multiproc and (shard_specs or feed_specs):
+            raise NotImplementedError(
+                "hybrid shard specs over a multi-process mesh")
+        data_nranks = int(np.prod([mesh.shape[a] for a in data_axes]))
 
-    if not data_axes:
-        ring_val = None  # collectives become identity (nranks_data = 1)
-        default_feed_spec = ()  # feeds replicated across the model mesh
-    else:
-        ring_val = data_axes if len(data_axes) > 1 else data_axes[0]
-        default_feed_spec = (data_axes[0],)
+        sync_bn = bool(build_strategy is not None and getattr(
+            build_strategy, "sync_batch_norm", False))
+        if build_strategy is not None and hasattr(build_strategy,
+                                                  "_warn_inert"):
+            build_strategy._warn_inert()
+        # GradientScaleStrategy: One and Customized both mean the USER owns
+        # the loss-grad scale (One = already averaged, Customized = their
+        # own scale op) — only the default CoeffNumDevice applies 1/n
+        # (build_strategy.h)
+        scale_loss = (build_strategy is None or getattr(
+            build_strategy, "gradient_scale_strategy", 0) == 0)
+        # collective rewrite (insert_allreduce_ops is itself idempotent
+        # per program — fleet may have transpiled already). Loss/grad
+        # scaling is over the DATA axes only: model-parallel axes see the
+        # same batch and their sharded grads are already complete.
+        if nranks > 1:
+            skip_axes = getattr(program, "_allreduce_skip_grads", None) or {}
+            insert_allreduce_ops(
+                program, data_nranks, scale_loss=scale_loss,
+                skip_grads={g for g, axes in skip_axes.items()
+                            if set(axes) & set(data_axes)})
+            from .transpiler import mark_sync_batch_norm
 
-    fetch_names = tuple(f if isinstance(f, str) else f.name
-                        for f in fetch_list)
-    feed_vals = {}
-    for name, value in (feed or {}).items():
-        arr = value.array if isinstance(value, LoDTensor) else value
-        if multiproc:
-            # local shard -> global array over the dp axis (straight
-            # from host memory: no intermediate device put)
-            if getattr(arr, "is_fully_addressable", True):
-                arr = jax.make_array_from_process_local_data(
-                    NamedSharding(mesh, P(axis_name)), np.asarray(arr))
+            mark_sync_batch_norm(program, sync_bn)
+            # fast collective path (bucketed / quantized allreduce, sharded
+            # weight update) — rewrites per-grad collectives in place; may
+            # add flat optimizer-state vars sharded over the data axis, so
+            # the shard-spec snapshot is refreshed below
+            from .collectives import maybe_rewrite_collectives
+
+            maybe_rewrite_collectives(program, scope, data_nranks, data_axes,
+                                      build_strategy=build_strategy,
+                                      multiproc=multiproc)
+            shard_specs = dict(getattr(program, "_var_shard_specs", None)
+                               or {})
+
+        if not data_axes:
+            ring_val = None  # collectives become identity (nranks_data = 1)
+            default_feed_spec = ()  # feeds replicated across the model mesh
         else:
-            arr = jnp.asarray(np.asarray(arr)) \
-                if not isinstance(value, LoDTensor) else arr
-        feed_vals[name] = arr
-    feed_names = tuple(sorted(feed_vals))
+            ring_val = data_axes if len(data_axes) > 1 else data_axes[0]
+            default_feed_spec = (data_axes[0],)
 
-    read_first, written, persist_written = _analyze(program)
-    state = {}
-    repl = NamedSharding(mesh, P()) if multiproc else None
-    for n in sorted(read_first - set(feed_names)):
-        var = scope.find_var(n)
-        if var is None or not var.is_initialized():
-            raise RuntimeError("var %r must be fed or initialized" % n)
-        arr = var.raw().array
-        if multiproc and getattr(arr, "is_fully_addressable", True):
-            # host value / local array -> replicated global array (an
-            # already-global array from the previous step passes through)
-            arr = jax.make_array_from_process_local_data(
-                repl, np.asarray(arr))
-        state[n] = arr
-    state_names = tuple(sorted(state))
-    block = program.global_block()
-    out_state_names = tuple(sorted(set(state_names) | persist_written))
+        fetch_names = tuple(f if isinstance(f, str) else f.name
+                            for f in fetch_list)
+    with span("parallel/stage", cat="step"):
+        feed_vals = {}
+        for name, value in (feed or {}).items():
+            arr = value.array if isinstance(value, LoDTensor) else value
+            if multiproc:
+                # local shard -> global array over the dp axis (straight
+                # from host memory: no intermediate device put)
+                if getattr(arr, "is_fully_addressable", True):
+                    arr = jax.make_array_from_process_local_data(
+                        NamedSharding(mesh, P(axis_name)), np.asarray(arr))
+            else:
+                arr = jnp.asarray(np.asarray(arr)) \
+                    if not isinstance(value, LoDTensor) else arr
+            feed_vals[name] = arr
+        feed_names = tuple(sorted(feed_vals))
 
-    from .. import observability as _obs
+        read_first, written, persist_written = _analyze(program)
+        state = {}
+        repl = NamedSharding(mesh, P()) if multiproc else None
+        for n in sorted(read_first - set(feed_names)):
+            var = scope.find_var(n)
+            if var is None or not var.is_initialized():
+                raise RuntimeError("var %r must be fed or initialized" % n)
+            arr = var.raw().array
+            if multiproc and getattr(arr, "is_fully_addressable", True):
+                # host value / local array -> replicated global array (an
+                # already-global array from the previous step passes through)
+                arr = jax.make_array_from_process_local_data(
+                    repl, np.asarray(arr))
+            state[n] = arr
+        state_names = tuple(sorted(state))
+        block = program.global_block()
+        out_state_names = tuple(sorted(set(state_names) | persist_written))
 
-    key = (_program_version(program), feed_names, fetch_names, state_names,
-           out_state_names, _mesh_key(mesh), data_axes, sync_bn,
-           tuple(sorted((k, v) for k, v in shard_specs.items())),
-           tuple(sorted((k, v) for k, v in feed_specs.items())))
-    hit = _dp_cache.get(key)
-    if hit is None:
-        # first run of this (program, mesh) pairing: statically verify
-        # the rewritten IR and its collective schedule BEFORE paying
-        # the compile — a malformed rewrite or a rank-divergent
-        # schedule fails here with the op named, not as a hang inside
-        # shard_map. Default off (PADDLE_TPU_VERIFY_IR); cache hits
-        # never reach this branch, so steady-state cost is zero.
-        from ..analysis import maybe_verify_program
+        key = (_program_version(program), feed_names, fetch_names,
+               state_names, out_state_names, _mesh_key(mesh), data_axes,
+               sync_bn,
+               tuple(sorted((k, v) for k, v in shard_specs.items())),
+               tuple(sorted((k, v) for k, v in feed_specs.items())))
+        hit = _dp_cache.get(key)
+        if hit is None:
+            # first run of this (program, mesh) pairing: statically verify
+            # the rewritten IR and its collective schedule BEFORE paying
+            # the compile — a malformed rewrite or a rank-divergent
+            # schedule fails here with the op named, not as a hang inside
+            # shard_map. Default off (PADDLE_TPU_VERIFY_IR); cache hits
+            # never reach this branch, so steady-state cost is zero.
+            from ..analysis import maybe_verify_program
 
-        maybe_verify_program(program, where="parallel.engine",
-                             fetch_names=fetch_names, nranks=nranks,
-                             scope=scope)
-        _obs.inc("parallel.compiles")
-        coll_est = _estimate_collective_bytes(program, state)
-        if not multiproc:
-            # lay the state out over the mesh NOW, as the step's own
-            # outputs will be from step 2 on: fed as the startup run
-            # left it (one device, uncommitted), step 1 compiles for
-            # that layout and step 2 compiles the whole program again
-            # for the sharded one
-            state = {n: jax.device_put(
-                a, NamedSharding(mesh, P(*shard_specs.get(n, ()))))
-                for n, a in state.items()}
-        def shard_step(state_d, feeds_d, seed):
-            with ring_axis_guard({0: ring_val, -1: ring_val}), \
-                    mesh_axes_guard(mesh_axes):
-                env = dict(state_d)
-                env.update(feeds_d)
-                _trace_block(block, env, seed)
-                fetches = [
-                    jax.lax.all_gather(env[n], data_axes) if data_axes
-                    else env[n]
-                    for n in fetch_names
-                ]
-                new_state = {n: env[n] for n in out_state_names if n in env}
-                return fetches, new_state
+            maybe_verify_program(program, where="parallel.engine",
+                                 fetch_names=fetch_names, nranks=nranks,
+                                 scope=scope)
+            _obs.inc("parallel.compiles")
+            coll_est = _estimate_collective_bytes(program, state)
+            if not multiproc:
+                # lay the state out over the mesh NOW, as the step's own
+                # outputs will be from step 2 on: fed as the startup run
+                # left it (one device, uncommitted), step 1 compiles for
+                # that layout and step 2 compiles the whole program again
+                # for the sharded one
+                state = {n: jax.device_put(
+                    a, NamedSharding(mesh, P(*shard_specs.get(n, ()))))
+                    for n, a in state.items()}
+            def shard_step(state_d, feeds_d, seed):
+                with ring_axis_guard({0: ring_val, -1: ring_val}), \
+                        mesh_axes_guard(mesh_axes):
+                    env = dict(state_d)
+                    env.update(feeds_d)
+                    # the Python trace of the block, once a compiled
+                    # step: every process pays it before XLA's
+                    # persistent cache can answer
+                    t_trace = _time.perf_counter()
+                    with span("parallel/trace", cat="step"):
+                        _trace_block(block, env, seed)
+                    _obs.inc("parallel.trace_s",
+                             _time.perf_counter() - t_trace)
+                    fetches = [
+                        jax.lax.all_gather(env[n], data_axes) if data_axes
+                        else env[n]
+                        for n in fetch_names
+                    ]
+                    new_state = {n: env[n] for n in out_state_names
+                                 if n in env}
+                    return fetches, new_state
 
-        mapped = jax.shard_map(
-            shard_step, mesh=mesh,
-            in_specs=({n: P(*shard_specs.get(n, ()))
-                       for n in state_names},
-                      {n: P(*feed_specs.get(n, default_feed_spec))
-                       for n in feed_names}, P()),
-            out_specs=([P() for _ in fetch_names],
-                       {n: P(*shard_specs.get(n, ()))
-                        for n in out_state_names}),
-            check_vma=False)
-        fn = jax.jit(mapped, donate_argnums=(0,))
-        hit = (fn, coll_est)
-        _dp_cache[key] = hit
-    fn, coll_est = hit
-
-    import time as _time
-
-    from ..observability import distributed as _dtrace
+            mapped = jax.shard_map(
+                shard_step, mesh=mesh,
+                in_specs=({n: P(*shard_specs.get(n, ()))
+                           for n in state_names},
+                          {n: P(*feed_specs.get(n, default_feed_spec))
+                           for n in feed_names}, P()),
+                out_specs=([P() for _ in fetch_names],
+                           {n: P(*shard_specs.get(n, ()))
+                            for n in out_state_names}),
+                check_vma=False)
+            fn = jax.jit(mapped, donate_argnums=(0,))
+            hit = (fn, coll_est)
+            _dp_cache[key] = hit
+        fn, coll_est = hit
 
     global _sync_round
     round_no = _sync_round
     _sync_round += 1
-    t_step = _time.perf_counter() if _obs.enabled() else None
     # the step span joins the job trace (launcher-minted
     # PADDLE_TPU_TRACE_ID) under a round id every rank derives
     # identically — a dp sync round is ONE cross-process timeline, the
-    # same propagation contract ps_rpc and serving already keep
-    with _obs.tracing.span("parallel/step", cat="step", ranks=nranks,
-                           round=round_no,
-                           **_dtrace.fleet_round_args(round_no)):
+    # same propagation contract ps_rpc and serving already keep.
+    # It returns once the step is enqueued (or, for a new key, traced,
+    # lowered and compiled): this path's executor/launch
+    with span("parallel/step", cat="step", ranks=nranks, round=round_no,
+              **_dtrace.fleet_round_args(round_no)):
         fetches, new_state = fn(
             state, feed_vals,
             jnp.uint32(core.rng.next_seed(0) ^
                        ((core.rng.step * 2654435761) & 0xFFFFFFFF)))
     core.rng.advance()
-    if t_step is not None:
+    if t_call is not None:
+        # while the chips work
         _obs.inc("parallel.steps")
-        _obs.observe("parallel.step_ms",
-                     (_time.perf_counter() - t_step) * 1e3)
         _obs.inc("parallel.collective_ops", coll_est["ops_total"])
         _obs.inc("parallel.collective_bytes", coll_est["bytes_total"])
         for k, n in coll_est["ops"].items():
@@ -387,10 +405,11 @@ def run_data_parallel(core, program, scope: Scope, feed: Dict,
             return v.addressable_shards[0].data
         return v
 
-    for n, v in new_state.items():
-        # keep the global (replicated) array in scope: the next step
-        # feeds it straight back without a host round-trip
-        scope.var(n).get_tensor()._array = v
+    with span("parallel/writeback", cat="step"):
+        for n, v in new_state.items():
+            # keep the global (replicated) array in scope: the next step
+            # feeds it straight back without a host round-trip
+            scope.var(n).get_tensor()._array = v
     # sampled in-production capture (PADDLE_TPU_SAMPLE_EVERY): every
     # Nth mesh step re-profiles the live (program, scope, feed) into a
     # rolling report for the steering daemon — default off, one branch.
@@ -400,8 +419,19 @@ def run_data_parallel(core, program, scope: Scope, feed: Dict,
 
     _capture.maybe_sample_step("parallel", program, scope, feed,
                                mesh=mesh, axis_name=axis_name)
-    results = []
-    for name, v in zip(fetch_names, fetches):
-        results.append(np.asarray(_local(v)) if return_numpy
-                       else _local(v))
+    # the wait for the chips and the copy to the host
+    with span("parallel/fetch", cat="step"):
+        results = [np.asarray(_local(v)) if return_numpy else _local(v)
+                   for v in fetches]
+    # the call deleted the donated arguments' buffers; the ~800 array
+    # objects that named them (a shard a chip each) die with `state`,
+    # here, after the fetch, with the chips idle: written out, because
+    # a span cannot hold what the frame's teardown would do
+    with span("parallel/release", cat="step"):
+        del state
+    if t_call is not None:
+        # host step latency: the whole call, fetch included, as
+        # executor.step_ms{path=compiled} is
+        _obs.observe("parallel.step_ms",
+                     (_time.perf_counter() - t_call) * 1e3)
     return results

@@ -327,9 +327,4 @@ def schedule_async_collectives(program, report=None, scope=None) -> int:
         block.ops = new_ops
         _bump_version(program)
     program._async_schedule = {"split": split, "kept": kept}
-    from .. import observability as _obs
-
-    _obs.inc("parallel.async_buckets", split, state="split")
-    if kept:
-        _obs.inc("parallel.async_buckets", kept, state="kept")
     return split

@@ -466,11 +466,6 @@ def search_placement(builder: Callable, n_devices: int,
         "unsupported": unsupported,
         "candidates": [c.audit_row() for c in all_rows],
     }
-    from .. import observability as _obs
-
-    _obs.inc("placement.candidates", len(all_rows))
-    _obs.inc("placement.candidates_verified", audit["verified"])
-
     if not ranked_b:
         return None, audit
     best = ranked_b[0][1]

@@ -7,9 +7,12 @@ Builds the cell as ``benchmarks/run.py`` does (same driver, pool and loop, the
 program's spans armed, no profiler trace), then measures ``--windows`` windows
 in this one process. After each it takes the window's spans from
 ``observability.tracing.trace_events()`` and prints, for the slowest step and
-for the median one, the duration of ``executor/run``, of each span inside it,
-and of the time between the previous ``executor/run`` and this one (the
-caller's loop). Host clock: enough for a step that is tens of ms long.
+for the median one, the duration of ``executor/run``, of each span inside it
+(``executor/prepare|stage|launch|writeback|fetch`` on one chip,
+``parallel/prepare|stage|step|writeback|fetch`` in a mesh cell such as
+``bert_base.dp4_s128``: ``Executor.run`` opens the root on both paths), its
+self time, and the time between the previous ``executor/run`` and this one
+(the caller's loop). Host clock: enough for a step that is tens of ms long.
 """
 import argparse
 import gc
