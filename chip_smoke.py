@@ -64,6 +64,7 @@ FULL = {
     "scan": dict(b=1, t=2048, h=16, p=64, g=2, n=128),
     "selected": dict(b=1, h=32, kv=4, s=2048, d=128, keep=512),
     "latent": dict(b=1, h=32, s=4096, d=192, dv=128),
+    "mhc": dict(b=1, n=4, t=4096, c=3584),
     "index": dict(r=512, s=2048, h=16, d=64),
     "decode": dict(streams=5, max_tokens=12),
     "dp_steps": 4,
@@ -80,6 +81,7 @@ REHEARSAL = {
     "scan": dict(b=1, t=256, h=4, p=64, g=2, n=128),
     "selected": dict(b=1, h=4, kv=2, s=256, d=16, keep=64),
     "latent": dict(b=1, h=2, s=256, d=24, dv=16),
+    "mhc": dict(b=1, n=4, t=128, c=128),
     "index": dict(r=32, s=64, h=2, d=8),
     "decode": dict(streams=3, max_tokens=6),
     "dp_steps": 2,
@@ -634,6 +636,67 @@ def phase_kernels(sizes, dev_rec, platform, xla):
     # bf16 operands on both sides, summed in another order: as the flash cases
     assert max(errs.values()) < 2e-2, ("ssd_scan", errs)
     report["ssd_scan"] = {"mosaic_calls": n, "rel_err": errs, "tol": 2e-2}
+
+    # -- the hyper-connections' passes on their kernels against the XLA form --
+    # the four ops over the latent-attention cell's streams, each form traced
+    # with the program's question answered for it: every output of the two
+    # forward ops and every gradient of the two gradient ops
+    import contextlib
+    import functools
+    from unittest import mock
+
+    from paddle_tpu.core.registry import OpInfoMap
+    from paddle_tpu.ops import hyper_connection_ops as hc
+    from paddle_tpu.ops.pallas import hyper_connection as hk
+
+    c = sizes["mhc"]
+    n_s, k_s = c["n"], 2 * c["n"] + c["n"] ** 2
+    streams = (c["b"], n_s, c["t"], c["c"])
+    ins = {"X": rng.randn(*streams), "Phi": 0.02 * rng.randn(n_s * c["c"], k_s),
+           "Alpha": rng.randn(3), "BPre": rng.randn(n_s),
+           "BPost": rng.randn(n_s), "BRes": rng.randn(n_s, n_s),
+           "Y": rng.randn(c["b"], c["t"], c["c"]),
+           "H@GRAD": rng.randn(c["b"], c["t"], c["c"]),
+           "HPost@GRAD": rng.randn(c["b"], n_s, c["t"]),
+           "HRes@GRAD": rng.randn(c["b"], n_s, n_s, c["t"]),
+           "Out@GRAD": rng.randn(*streams)}
+    ins = {k: jnp.asarray(v, f32) for k, v in ins.items()}
+    assert hk.fits(ins["X"]), c
+
+    def mhc_passes(ins):
+        ops = OpInfoMap.instance()
+        pre = ops.get("mhc_pre").fn(ins, {})
+        post_in = dict(ins, HRes=pre["HRes"], HPost=pre["HPost"])
+        return dict(
+            pre, Out=ops.get("mhc_post").fn(post_in, {})["Out"],
+            **{"pre." + k: v for k, v in
+               ops.get("mhc_pre_grad").fn(ins, {}).items()},
+            **{"post." + k: v for k, v in
+               ops.get("mhc_post_grad").fn(post_in, {}).items()})
+
+    def in_form(asked):
+        """``mhc_passes`` traced with ``compute_platform()`` answering
+        ``asked``; off the chip the kernels it then takes are interpreted."""
+        def traced(ins):
+            with contextlib.ExitStack() as stack:
+                stack.enter_context(mock.patch.object(
+                    hc._fa, "compute_platform", lambda: asked))
+                for entry in () if on_tpu else hk.ENTRIES:
+                    stack.enter_context(mock.patch.object(
+                        hk, entry, functools.partial(getattr(hk, entry),
+                                                     interpret=True)))
+                return mhc_passes(ins)
+        return traced
+
+    # one kernel a forward op, one for the mix's gradient, two for the maps'
+    n = check_mosaic("mhc_passes", in_form("tpu"), (ins,), 5)
+    got = jax.jit(in_form("tpu"))(ins)
+    ref = jax.jit(in_form("cpu"))(ins)
+    errs = {t: _rel_err(got[t], r) for t, r in ref.items()}
+    # float32 on both sides, products at full precision, sums in another
+    # order: rows of 3,584 and, in dPhi, columns of 4,096
+    assert max(errs.values()) < 1e-4, ("mhc_passes", errs)
+    report["mhc_passes"] = {"mosaic_calls": n, "rel_err": errs, "tol": 1e-4}
 
     # -- the indexer's float32 product from bfloat16 pieces ------------------
     # the three pieces of a float32 value as the device makes them under

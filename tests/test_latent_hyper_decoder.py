@@ -415,8 +415,8 @@ def test_a_near_miss_of_the_equations_fails_at_the_stirred_point(miss):
 
 @pytest.mark.parametrize("stirred", [False, True], ids=["start", "stirred"])
 def test_the_hyper_connection_gradient_ops_are_the_references(stirred):
-    """``mhc_pre_grad`` and the automatic ``mhc_post_grad``, through the
-    twenty rounds, against the gradient of the reference's sublayer."""
+    """``mhc_pre_grad`` and ``mhc_post_grad``, through the twenty rounds,
+    against the gradient of the reference's sublayer."""
     from benchmarks.configs.xing4_29b_a4b_ep8 import reference
 
     cfg, x, y, p = hyper_point(stirred)
@@ -512,7 +512,12 @@ def test_tiny_model_follows_the_plain_reference(monkeypatch, amp, recompute):
     # ten hyper-connected sublayers would be ten of each; the toy has four
     assert types.count("mhc_pre") == 4 * (1 + recompute) \
         and types.count("mhc_pre_grad") == 4 \
+        and types.count("mhc_post") == 4 \
+        and types.count("mhc_post_grad") == 4 \
         and types.count("flash_attention") == 2 * (1 + recompute)
+    # both gradient ops are the registered ones, not automatic VJPs
+    from paddle_tpu.core import registry
+    assert not {"mhc_pre_grad", "mhc_post_grad"} & registry._AUTO_VJP_TYPES
     key = jax.random.key(3)
     start = reference.init_params(key, cfg)
     kept = {k: np.asarray(v) for k, v in start.items()}

@@ -164,6 +164,21 @@ def cases():
     yield ("ssd_scan_state_bwd_s8192",
            lambda *a: ss.backward(*a, chunk=128), [x, dt, dt, bc, bc, d, x])
 
+    # the hyper-connections' passes at the latent-attention cell's shape
+    # (four float32 streams of 4,096 x 3,584, 24 products a token), an entry
+    # of ``ops/pallas/hyper_connection.py`` each
+    hk = importlib.import_module("paddle_tpu.ops.pallas.hyper_connection")
+    xs, phi = ((1, 4, 4096, 3584), f32), ((4, 24, 3584), f32)
+    y, coef = ((1, 4096, 3584), f32), ((1, 4096, 20), f32)
+    yield ("mhc_pre_fwd_s4096", lambda *a: hk.pre_forward(*a, eps=1e-6),
+           [xs, phi, ((2, 24), f32)])
+    yield ("mhc_pre_reads_s4096",
+           lambda *a: hk.pre_grad_reads(*a, eps=1e-6), [xs, phi, y])
+    yield ("mhc_pre_writes_s4096", hk.pre_grad_writes,
+           [xs, phi, y, ((1, 4096, 24), f32), ((1, 4096, 5), f32)])
+    yield "mhc_post_fwd_s4096", hk.post_forward, [xs, coef, y]
+    yield "mhc_post_bwd_s4096", hk.post_backward, [xs, coef, y, xs]
+
     # paged attention at the decode engine's geometry
     def paged(q, k_arena, v_arena, tables, lens):
         return pa._paged_pallas(q, k_arena, v_arena, tables, lens,
