@@ -62,6 +62,7 @@ FULL = {
     "short": dict(b=32, h=12, s=512, d=64),
     "short_dp": dict(b=128, h=12, s=128, d=64),
     "scan": dict(b=1, t=2048, h=16, p=64, g=2, n=128),
+    "delta": dict(b=1, t=2048, h=8, d=128),
     "selected": dict(b=1, h=32, kv=4, s=2048, d=128, keep=512),
     "latent": dict(b=1, h=32, s=4096, d=192, dv=128),
     "mhc": dict(b=1, n=4, t=4096, c=3584),
@@ -79,6 +80,7 @@ REHEARSAL = {
     "short": dict(b=2, h=2, s=128, d=64),
     "short_dp": dict(b=4, h=4, s=128, d=32),
     "scan": dict(b=1, t=256, h=4, p=64, g=2, n=128),
+    "delta": dict(b=1, t=128, h=4, d=128),
     "selected": dict(b=1, h=4, kv=2, s=256, d=16, keep=64),
     "latent": dict(b=1, h=2, s=256, d=24, dv=16),
     "mhc": dict(b=1, n=4, t=128, c=128),
@@ -636,6 +638,50 @@ def phase_kernels(sizes, dev_rec, platform, xla):
     # bf16 operands on both sides, summed in another order: as the flash cases
     assert max(errs.values()) < 2e-2, ("ssd_scan", errs)
     report["ssd_scan"] = {"mosaic_calls": n, "rel_err": errs, "tol": 2e-2}
+
+    # -- the delta rule's kernels against its XLA form -----------------------
+    # q, k, v in bf16 on the kernels, the einsums in float32 at full
+    # precision: the output and the five gradients the kernels give
+    from unittest import mock
+
+    from paddle_tpu.ops import kda_ops
+    from paddle_tpu.ops.pallas import kda
+
+    c = sizes["delta"]
+    shape = (c["b"], c["t"], c["h"], c["d"])
+    q, k, v, w = (jnp.asarray(rng.randn(*shape), bf16) for _ in range(4))
+    leaves = (jnp.asarray(np.log(rng.uniform(1, 16, c["h"])), f32),
+              jnp.asarray(0.1 * rng.randn(c["h"] * c["d"]), f32))
+    g, beta = kda_ops.gates(jnp.asarray(rng.randn(*shape) - 2, f32),
+                            jnp.asarray(rng.randn(*shape[:3]), f32), *leaves)
+    rule_args = (q, k, v, g, beta)
+    assert kda.fits(q, v, 64), c
+
+    def einsums(q, k, v, g, beta):
+        # the XLA form takes the raw gates: hand it the made ones through
+        # ``gates``' place
+        with mock.patch.object(kda_ops, "gates", lambda g, b, *_: (g, b)):
+            return kda_ops._xla_chunked(q, k, v, g, beta, *leaves, 64)
+
+    def with_gradients(rule):
+        def f(*a):
+            out, vjp = jax.vjp(rule, *a)
+            return (out,) + vjp(w.astype(out.dtype))
+        return f
+
+    kernel = with_gradients(lambda *a: kda.delta_rule(*a, 64, not on_tpu))
+    # forward; forward with the entering states, backward kernel
+    n = check_mosaic("kda", kernel, rule_args, 3)
+    got = jax.jit(kernel)(*rule_args)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(with_gradients(einsums))(
+            *(a.astype(f32) for a in rule_args))
+    errs = {t: _rel_err(a, r) for t, a, r in zip(
+        ("out", "dq", "dk", "dv", "dg", "dbeta"), got, ref)}
+    # bf16 operands and cotangents against float32: dg is a sum back over a
+    # chunk's positions of terms that cancel
+    assert max(errs.values()) < 5e-2, ("kda", errs)
+    report["kda"] = {"mosaic_calls": n, "rel_err": errs, "tol": 5e-2}
 
     # -- the hyper-connections' passes on their kernels against the XLA form --
     # the four ops over the latent-attention cell's streams, each form traced

@@ -54,6 +54,10 @@ white_list = {
     "flash_attention",
     # x, B, C are the scan's MXU operands; see fp32_slots for the rest
     "ssd_chunk_scan",
+    # q, k, v and the raw gate projections as the products before them make
+    # them; the gates' activations, the decays and the L2 norms are float32
+    # inside the op; see fp32_slots for the decay's leaves
+    "kda_chunk",
     # the held experts' grouped products; see fp32_slots for the router
     "moe_topk",
     # the attention's own q, k as its kernels multiply them; see fp32_slots
@@ -67,6 +71,7 @@ white_list = {
 # choice must not pass through the low type
 fp32_slots = {
     "ssd_chunk_scan": frozenset(("A", "D", "DtBias")),
+    "kda_chunk": frozenset(("ALog", "DtBias")),
     "moe_topk": frozenset(("X", "RouterW", "Bias")),
     "attn_index_loss": frozenset(("QI", "KI", "W", "LSE")),
 }

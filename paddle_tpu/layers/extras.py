@@ -1033,12 +1033,14 @@ __all__ += ["flash_attention", "switch_moe"]
 
 
 def rms_norm(input, scale=True, epsilon=1e-5, groups=1, gate=None,
-             param_attr=None, name=None):
+             param_attr=None, name=None, gating="silu_before"):
     """Root-mean-square norm over the last axis: ``x * rsqrt(mean(x^2) +
     epsilon) * Scale`` (no mean, no bias). ``groups`` > 1 norms each of
     that many equal groups of the last axis; with ``gate`` the normed value
-    is ``x * silu(gate)`` (Mamba-2's gated norm). Float32 statistics under
-    AMP (black list)."""
+    is ``x * silu(gate)`` (``gating`` ``silu_before``: Mamba-2's gated
+    norm), or the result is multiplied by ``sigmoid(gate)``
+    (``sigmoid_after``: the delta-rule mixer's output gate). Float32
+    statistics under AMP (black list)."""
     from ..initializer import ConstantInitializer
 
     helper = LayerHelper("rms_norm", input=input, param_attr=param_attr,
@@ -1052,9 +1054,11 @@ def rms_norm(input, scale=True, epsilon=1e-5, groups=1, gate=None,
     if gate is not None:
         inputs["Gate"] = [gate]
     out = helper.create_variable_for_type_inference(input.dtype)
+    attrs = {"epsilon": float(epsilon), "groups": int(groups)}
+    if gating != "silu_before":
+        attrs["gating"] = gating
     helper.append_op("rms_norm", inputs=inputs, outputs={"Y": [out]},
-                     attrs={"epsilon": float(epsilon),
-                            "groups": int(groups)})
+                     attrs=attrs)
     return out
 
 
@@ -1100,6 +1104,30 @@ def ssd_chunk_scan(x, dt, A, B, C, D=None, dt_bias=None, chunk=128):
                      attrs={"chunk": int(chunk)}, infer_shape=False)
     if not framework.in_dygraph_mode():
         out.shape = tuple(x.shape)
+    return out
+
+
+def kda_chunk(q, k, v, g, beta, a_log, dt_bias, chunk=64):
+    """Kimi Delta Attention's core by chunks (ops/kda_ops.py): q, k [B, T,
+    H, K] and v [B, T, H, V] after their convolutions, the raw gate
+    projections g [B, T, H, K] and beta [B, T, H], the decay's leaves a_log
+    [H] and dt_bias [H K]; from a zero state ``S_t = (I - beta_t k_t k_t^T)
+    Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T``, ``o_t = S_t^T q_t`` with q
+    and k L2-normed over K (q then times ``K^-0.5``), ``g_t =
+    -exp(a_log) softplus(g + dt_bias)`` and ``beta_t = sigmoid(beta)``, all
+    taken inside the op in float32. The gradient op keeps nothing but these
+    inputs and recomputes inside."""
+    helper = LayerHelper("kda_chunk", input=v)
+    out = helper.create_variable_for_type_inference(v.dtype)
+    # no shape inference: it would trace (and count) the op at build time
+    helper.append_op(
+        "kda_chunk",
+        inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta],
+                "ALog": [a_log], "DtBias": [dt_bias]},
+        outputs={"Out": [out]},
+        attrs={"chunk": int(chunk)}, infer_shape=False)
+    if not framework.in_dygraph_mode():
+        out.shape = tuple(v.shape)
     return out
 
 
@@ -1378,7 +1406,8 @@ def mhc_post(x, h_res, h_post, y):
     return out
 
 
-__all__ += ["rms_norm", "causal_conv1d", "ssd_chunk_scan", "moe_topk",
+__all__ += ["rms_norm", "causal_conv1d", "ssd_chunk_scan", "kda_chunk",
+            "moe_topk",
             "rotary_embedding", "yarn_inv_freq", "yarn_mscale",
             "attn_index_project", "attn_index_select", "attn_index_loss",
             "mhc_pre", "mhc_post"]

@@ -11,6 +11,16 @@ is two letters), on a residual path that is an argument: a plain add, or
   tiles, the Pallas kernels of ``ops/pallas/ssd_scan.py``; the same
   algorithm in XLA einsums everywhere else, ``ops.ssm_ops.scan_path``);
   gated RMS norm over groups; output projection;
+- ``K``  Kimi Delta Attention: q, k, v each through a projection, a causal
+  depthwise convolution and silu; a log-decay for every channel of a head
+  through a low-rank pair of projections, a write strength a head; the
+  gated delta rule by chunks (``layers.kda_chunk``, which takes the L2
+  norms of q and k and the gates' activations inside, in float32; on a
+  TPU, where the heads fill whole lane tiles, the Pallas kernels of
+  ``ops/pallas/kda.py``, XLA einsums everywhere else,
+  ``ops.kda_ops.kda_path``); an RMS
+  norm a head under a sigmoid output gate made by another low-rank pair;
+  output projection;
 - ``E``  routed experts, top-k of many without drops over the experts this
   program holds (``layers.moe_topk``: sigmoid or softmax scores, a
   ``relu(u W1)^2 W2`` or a gated ``(silu(u W1) * (u W3)) W2`` expert),
@@ -18,11 +28,12 @@ is two letters), on a residual path that is an argument: a plain add, or
   with ``shared_dim = 0``);
 - ``D``  a dense gated feed-forward layer, ``(silu(u W1) * (u W3)) W2``;
 - ``L``  causal latent attention: queries through a low-rank latent with
-  its own RMS norm, keys and values through another, one rotary key head
-  shared by all query heads beside the per-head keys, rotary positions
-  (given frequencies: YaRN's) on the trailing ``rope_dim`` of a query's
-  ``nope_dim + rope_dim``, a value dim of its own, on the streaming
-  ``flash_attention`` kernels;
+  its own RMS norm (or straight from the input, ``q_rank`` None), keys and
+  values through another, one key head shared by all query heads beside
+  the per-head keys, rotary positions (given frequencies: YaRN's) on it
+  and on the trailing ``rope_dim`` of a query's ``nope_dim + rope_dim``
+  (or no positions at all, ``inv_freq`` None), a value dim of its own, on
+  the streaming ``flash_attention`` kernels;
 - ``*``  causal grouped-query attention on the ``flash_attention`` op, no
   positional encoding (as ``NemotronHAttention`` has none);
 - ``S``  causal grouped-query attention over the keys an indexer selects
@@ -94,6 +105,48 @@ def mamba2_mixer(u, hidden, num_heads, head_dim, n_groups, state_size,
     return _proj(y, hidden)
 
 
+def kda_mixer(u, hidden, num_heads, head_dim, *, conv_kernel=4, chunk=64,
+              eps=1e-5, checkpoints=None):
+    """u [B, T, hidden] (normed) -> Kimi Delta Attention. Parameters in
+    order: for each of q, k, v its projection (to ``num_heads x head_dim``)
+    and its convolution's taps; the decay's low-rank pair (``head_dim``
+    wide between the two), the write strength's projection (to
+    ``num_heads``), ``A_log`` [num_heads] and ``dt_bias`` [num_heads x
+    head_dim]; the output gate's low-rank pair with the second's bias; the
+    head norm's weight [head_dim]; the output projection. No other bias.
+    ``checkpoints`` receives the delta rule's output: kept beside the
+    sublayer's input, the op is not run again when the sublayer is
+    recomputed (its gradient op reads its inputs alone, and what follows
+    it reads its output). Every op is built inside ``name_scope("kda")``:
+    a device trace tells the
+    mixer's projections, convolutions, gate and norm from the other layers'
+    ``mul`` and ``rms_norm``."""
+    B, T, _ = u.shape
+    inner = num_heads * head_dim
+
+    def heads(x):
+        return layers.reshape(x, [B, T, num_heads, head_dim])
+
+    def convolved():
+        return heads(layers.causal_conv1d(
+            _proj(u, inner), kernel_size=conv_kernel, bias=False,
+            act="silu"))
+
+    with framework.name_scope("kda"):
+        q, k, v = convolved(), convolved(), convolved()
+        g = heads(_proj(_proj(u, head_dim), inner))
+        beta = _proj(u, num_heads)
+        a_log = layers.create_parameter([num_heads], "float32")
+        dt_bias = layers.create_parameter([inner], "float32")
+        o = layers.kda_chunk(q, k, v, g, beta, a_log, dt_bias, chunk=chunk)
+        if checkpoints is not None:
+            checkpoints.append(o)
+        gate = layers.fc(_proj(u, head_dim), size=inner, num_flatten_dims=2)
+        o = layers.rms_norm(o, gate=heads(gate), gating="sigmoid_after",
+                            epsilon=eps)
+        return _proj(layers.reshape(o, [B, T, inner]), hidden)
+
+
 def moe_mixer(u, hidden, num_experts, top_k, expert_dim, shared_dim,
               held=None, scaling=1.0, correction_bias=None, loads=None,
               scoring="sigmoid", expert="relu2"):
@@ -133,13 +186,15 @@ def latent_mixer(u, hidden, num_heads, *, q_rank, kv_rank, nope_dim,
                  rope_dim, v_dim, inv_freq, scale=None, eps=1e-6):
     """u [B, T, hidden] (normed) -> causal latent attention. Parameters in
     order: the query's down-projection, its latent norm, its up-projection
-    (to ``num_heads x (nope_dim + rope_dim)``); the key/value
+    (to ``num_heads x (nope_dim + rope_dim)``; with ``q_rank`` None one
+    projection straight from ``u`` and no latent); the key/value
     down-projection (to ``kv_rank + rope_dim``: the latent and ONE rotary key
     head), the latent's norm, its up-projection (to ``num_heads x (nope_dim
     + v_dim)``); the output projection. Rotary positions ``0..T-1`` turn the
     trailing ``rope_dim`` of every query head and the shared key head
     (``inv_freq``: the ``rope_dim / 2`` pairs' frequencies, as
-    ``layers.yarn_inv_freq`` gives them). The shared key head is repeated
+    ``layers.yarn_inv_freq`` gives them; None: no positions anywhere, the
+    ``rope_dim`` slices are plain dims). The shared key head is repeated
     beside each head's own keys in the program, so the kernels read plain
     [B, H, T, nope_dim + rope_dim] keys; v and the context are [B, H, T,
     v_dim]. ``scale``: the scores' (None: ``(nope_dim + rope_dim)^-0.5``).
@@ -153,10 +208,13 @@ def latent_mixer(u, hidden, num_heads, *, q_rank, kv_rank, nope_dim,
         return layers.slice(x, axes=[axis], starts=[lo], ends=[hi])
 
     def placed(x, offset):
+        if inv_freq is None:
+            return x
         return layers.rotary_embedding(x, offset=offset, inv_freq=inv_freq)
 
     with framework.name_scope("latent"):
-        cq = layers.rms_norm(_proj(u, q_rank), epsilon=eps)
+        cq = u if q_rank is None else layers.rms_norm(_proj(u, q_rank),
+                                                      epsilon=eps)
         q = placed(layers.reshape(_proj(cq, num_heads * qk_dim),
                                   [B, T, num_heads, qk_dim]), nope_dim)
         kva = _proj(u, kv_rank + rope_dim)
@@ -226,7 +284,8 @@ def hybrid_ssm_moe(ids, pattern, vocab_rows, hidden, *, mamba_heads=64,
                    routed_scaling=2.5, correction_bias=None, num_heads=32,
                    num_kv_heads=2, head_dim=128, eps=1e-5, loads=None,
                    checkpoints=None, scoring="sigmoid", expert="relu2",
-                   indexed=None, latent=None, dense_dim=0, hyper=None):
+                   indexed=None, latent=None, dense_dim=0, hyper=None,
+                   kda=None):
     """Logits [B, T, vocab_rows] over int64 ids [B, T].
 
     ``pattern``: one letter a layer (see the module's docstring).
@@ -235,8 +294,8 @@ def hybrid_ssm_moe(ids, pattern, vocab_rows, hidden, *, mamba_heads=64,
     ``E`` layer held here. ``correction_bias``: None, or one array
     [num_experts] for each ``E`` layer in order. ``loads``: a list that
     receives each ``E`` layer's ``Load`` variable, for a fetch.
-    ``checkpoints``: a list that receives each layer's input and the last
-    layer's output, which is what
+    ``checkpoints``: a list that receives each layer's input (and a ``K``
+    layer's delta-rule output) and the last layer's output, which is what
     ``RecomputeOptimizer._set_checkpoints`` takes to recompute a layer's
     activations from its input alone. ``scoring`` / ``expert``: the ``E``
     layers' router scores and expert form (``layers.moe_topk``).
@@ -246,7 +305,9 @@ def hybrid_ssm_moe(ids, pattern, vocab_rows, hidden, *, mamba_heads=64,
     receives each ``S`` layer's indexer loss [1] for the caller to add to
     the model's loss. ``latent``: the ``L`` layers' keyword arguments of
     ``latent_mixer`` as one dict (``num_heads`` is shared with the other
-    attention letters). ``dense_dim``: the ``D`` layers' width.
+    attention letters). ``kda``: the ``K`` layers' arguments of
+    ``kda_mixer`` as one dict, ``num_heads`` and ``head_dim`` among them.
+    ``dense_dim``: the ``D`` layers' width.
     ``hyper``: the residual path. None: ``x = x + mixer(rms_norm(x))``.
     A dict ``{"streams": n, ...}`` (the rest ``layers.mhc_pre``'s keyword
     arguments): ``n`` residual streams [B, n, T, hidden], each a copy of the
@@ -283,6 +344,9 @@ def hybrid_ssm_moe(ids, pattern, vocab_rows, hidden, *, mamba_heads=64,
                                   head_dim, eps=eps, **indexed)
         elif kind == "L":
             y = latent_mixer(u, hidden, num_heads, eps=eps, **latent)
+        elif kind == "K":
+            y = kda_mixer(u, hidden, eps=eps, checkpoints=checkpoints,
+                          **kda)
         elif kind == "D":
             y = _ffn(u, dense_dim, hidden, "swiglu")
         else:

@@ -43,6 +43,7 @@ from . import tail_ops3  # noqa: F401
 from . import text_match_ops  # noqa: F401
 from . import eval_ops  # noqa: F401
 from . import ssm_ops  # noqa: F401
+from . import kda_ops  # noqa: F401
 from . import moe_ops  # noqa: F401
 from . import sparse_attn_ops  # noqa: F401
 from . import hyper_connection_ops  # noqa: F401

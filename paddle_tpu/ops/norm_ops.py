@@ -246,19 +246,25 @@ def _l2_normalize(ins, attrs):
     inputs=[In("X"), In("Scale", dispensable=True),
             In("Gate", dispensable=True)],
     outputs=[Out("Y")],
-    attrs={"epsilon": 1e-5, "groups": 1},
+    attrs={"epsilon": 1e-5, "groups": 1, "gating": "silu_before"},
 )
 def _rms_norm(ins, attrs):
     """Root-mean-square norm over the last axis (no mean, no bias):
-    ``x * rsqrt(mean(x^2) + eps) * Scale``. With ``Gate`` the normed value
-    is ``x * silu(Gate)`` (Mamba-2's gated norm); with ``groups`` > 1 the
-    mean runs over each of that many equal groups of the last axis. The
-    statistics are float32 whatever the input's type (AMP black list)."""
+    ``x * rsqrt(mean(x^2) + eps) * Scale``. With ``Gate`` and ``gating``
+    ``silu_before`` the normed value is ``x * silu(Gate)`` (Mamba-2's gated
+    norm); with ``sigmoid_after`` the result is multiplied by
+    ``sigmoid(Gate)`` (the delta-rule mixer's output gate); with ``groups``
+    > 1 the mean runs over each of that many equal groups of the last axis.
+    The statistics are float32 whatever the input's type (AMP black list)."""
     x = ins["X"]
     dtype = x.dtype
     x = x.astype(jnp.float32)
-    if ins.get("Gate") is not None:
-        x = x * jax.nn.silu(ins["Gate"].astype(jnp.float32))
+    gate = ins.get("Gate")
+    gating = attrs.get("gating", "silu_before")
+    if gate is not None and gating not in ("silu_before", "sigmoid_after"):
+        raise ValueError("rms_norm: no gating %r" % (gating,))
+    if gate is not None and gating == "silu_before":
+        x = x * jax.nn.silu(gate.astype(jnp.float32))
     groups = int(attrs.get("groups", 1) or 1)
     shape = x.shape
     g = x.reshape(shape[:-1] + (groups, shape[-1] // groups))
@@ -267,4 +273,6 @@ def _rms_norm(ins, attrs):
     y = g.reshape(shape)
     if ins.get("Scale") is not None:
         y = y * ins["Scale"].astype(jnp.float32)
+    if gate is not None and gating == "sigmoid_after":
+        y = y * jax.nn.sigmoid(gate.astype(jnp.float32))
     return {"Y": y.astype(dtype)}

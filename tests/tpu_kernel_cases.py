@@ -179,6 +179,16 @@ def cases():
     yield "mhc_post_fwd_s4096", hk.post_forward, [xs, coef, y]
     yield "mhc_post_bwd_s4096", hk.post_backward, [xs, coef, y, xs]
 
+    # the delta rule's kernels at the delta-rule cell's shape (32 heads of
+    # 128, chunks of 64, T = 8192), as its two ops run them: the forward;
+    # the forward with the entering states and the backward kernel
+    kd = importlib.import_module("paddle_tpu.ops.pallas.kda")
+    qk, gk = ((1, 8192, 32, 128), bf16), ((1, 8192, 32, 128), f32)
+    yield ("kda_fwd_s8192", lambda *a: kd.forward(*a, chunk=64),
+           [qk, qk, qk, gk, ((1, 8192, 32), f32)])
+    yield ("kda_state_bwd_s8192", lambda *a: kd.backward(*a, chunk=64),
+           [qk, qk, qk, gk, ((1, 8192, 32), f32), qk])
+
     # paged attention at the decode engine's geometry
     def paged(q, k_arena, v_arena, tables, lens):
         return pa._paged_pallas(q, k_arena, v_arena, tables, lens,

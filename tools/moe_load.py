@@ -48,8 +48,13 @@ def main(argv):
     loads = []
     built = model.build_static(cfg, traffic, loads)
     tokens = traffic["batch"] * traffic["seq_len"]
-    rows = buffer_rows(tokens, cfg["num_experts_per_tok"],
-                       cfg["n_routed_experts"], cfg["n_routed_experts_held"])
+    # the sizes as the program's own op holds them: the configurations
+    # name these keys differently
+    block = built["main"].global_block()
+    routed = next(o for o in block.ops if o.type == "moe_topk")
+    experts = block._find_var_recursive(routed.input("RouterW")[0]).shape[1]
+    rows = buffer_rows(tokens, routed.attrs["k"], experts,
+                       routed.attrs["held"][1])
     exe, lines = fluid.Executor(fluid.TPUPlace(0)), []
     for seed in args.seeds:
         scope = fluid.Scope()
@@ -87,7 +92,9 @@ def main(argv):
         lines.append({
             "seed": seed, "steps": int(seen.shape[0]),
             "step_ms_median": 1e3 * statistics.median(times[8:] or times),
-            "slots_by_step": held.sum(-1).sum(-1).tolist()})
+            "step_ms_by_step": [round(1e3 * t, 1) for t in times],
+            "slots_by_step": held.sum(-1).sum(-1).tolist(),
+            "largest_layer_by_step": held.sum(-1).max(-1).tolist()})
         print(json.dumps(lines[-1]), flush=True)
     if args.out:
         os.makedirs(os.path.dirname(args.out), exist_ok=True)
