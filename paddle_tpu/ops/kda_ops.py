@@ -23,7 +23,8 @@ matrix, and one step of a recurrence over the chunks.
 Two forms of it, chosen by ``kda_path`` from what the op sees: on a TPU,
 where the heads fill whole lane tiles, the Pallas kernels of
 ``ops/pallas/kda.py``, which keep a chunk's tiles and the states in VMEM
-(their module has how); everywhere else the XLA einsums below, which the
+and, in the gradient op, make a chunk's inverse once, in a state pass whose
+tiles the backward kernel reads (their module has how); everywhere else the XLA einsums below, which the
 CPU's tests run and the kernels are held to. Both take the gates here
 (``gates``) and both keep what follows.
 
@@ -373,8 +374,9 @@ def _kda_chunk_grad(ins, attrs):
     """The gradients from the op's inputs alone: nothing but q, k, v, the
     raw gates and the decay's leaves lives from the forward to the backward.
     ``jax.vjp`` of the function in either form. The kernels' form is then
-    the forward kernel once more, for the state that enters each chunk, and
-    the backward kernel between the gates and their gradient. The XLA form
+    the state pass, which keeps each chunk's entering state, inverse and the
+    inverse's products, and the backward kernel that reads them, between
+    the gates and their gradient. The XLA form
     runs again behind an optimization barrier, so that XLA cannot fold the
     copy into the forward op's and keep its per-chunk intermediates alive
     until here."""

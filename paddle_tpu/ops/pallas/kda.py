@@ -9,19 +9,25 @@ equations and the XLA form of the same algorithm; it calls here where
 norms of q and k are taken here), the log-decays g ``[B, T, H, K]`` <= 0 and
 the write strengths beta ``[B, T, H]`` float32, T a whole number of chunks.
 
-Two kernels over the grid ``(batch, chunk, heads / HEADS)`` with the chunk
+Three kernels over the grid ``(batch, chunk, heads / HEADS)`` with the chunk
 axis sequential and the states of ALL heads in one float32 scratch, so that
 a step's beta block ``[C, H]`` and its gradient's are one block for all the
 heads of a chunk. A head is a 128-lane slice of the token-major operands
 ``[B, T, H*K]``: nothing is transposed on either side of a call.
 
-- ``kda_fwd``: for each head of the step the in-chunk cumulative decays
-  ``G`` (a product with a triangle of ones), the two decayed Gram matrices,
-  ``(I + Diag(beta) A)^-1``, the rows written ``U = T (beta v) - T (beta k
-  e^G) S``, the outputs and ``S <- e^{G_C} S + (k e^{G_C - G})^T U``; with
-  ``save`` also the state that enters each chunk, which the backward needs.
+- ``kda_fwd``, the forward op: for each head of the step the in-chunk
+  cumulative decays ``G`` (a product with a triangle of ones), the two
+  decayed Gram matrices, ``T = (I + Diag(beta) A)^-1``, the rows written ``U
+  = T (beta v) - T (beta k e^G) S``, the outputs and ``S <- e^{G_C} S + (k
+  e^{G_C - G})^T U``.
+- ``kda_states``, the gradient op's pass over the chunks in order: the same
+  without what only the outputs need (no q, no ``P``, no ``o``). It keeps, of
+  every (chunk, head), the state that enters it and ``T``, ``A``, ``W = T
+  (beta k e^G)`` and ``U0 = T (beta v)``: 144 KiB at a chunk of 64, alive
+  inside the gradient op only.
 - ``kda_bwd``: the chunks in reverse with ``dS`` carried in the scratch;
-  makes a chunk's tiles again from the operands and the entering state and
+  reads what the state pass kept, makes from the operands what is
+  elementwise in them and ``P`` (the state pass has no q), no inverse, and
   gives dq, dk, dv, dg and dbeta.
 
 **No exponent is ever positive**, as in the XLA form, by other means. A
@@ -141,55 +147,94 @@ def _pick(rows, cols, d, M):
 
 
 class _Chunk:
-    """What a head's chunk is made of, from its operands alone: both
-    kernels build it, the backward reads more of it."""
+    """What a head's chunk is made of. From its operands alone and
+    elementwise (but for one product with the triangle): the norms, the
+    decays and the operands they scale, without the q side where q is None.
+    The Gram matrices (``grams``) and the inverse with its two products
+    (``solve``) are made where a kernel asks for them: the backward is
+    handed what the state pass made of them instead (``kept``)."""
 
     def __init__(self, q, k, v, g, beta, tri, rows, cols):
         C, K = g.shape
-        self.mxu = mxu = v.dtype
-        q, k, self.v = q.astype(_F32), k.astype(_F32), v.astype(_F32)
-        self.scale = float(K) ** -0.5
-        self.rq = jax.lax.rsqrt(_rowsum(q * q) + L2_EPS)
+        self.mxu = v.dtype
+        self.rows, self.cols = rows, cols
+        k, self.v = k.astype(_F32), v.astype(_F32)
         self.rk = jax.lax.rsqrt(_rowsum(k * k) + L2_EPS)
-        self.nq = q * self.rq
-        self.qn, self.kn = self.nq * self.scale, k * self.rk
+        self.kn = k * self.rk
+        if q is not None:
+            q = q.astype(_F32)
+            self.scale = float(K) ** -0.5
+            self.rq = jax.lax.rsqrt(_rowsum(q * q) + L2_EPS)
+            self.nq = q * self.rq
+            self.qn = self.nq * self.scale
         self.beta = beta
-        G = _dot32(tri, g)                          # in-chunk cumulative sums
+        self.G = G = _dot32(tri, g)                 # in-chunk cumulative sums
         self.E = jnp.exp(G)
         self.last = G[C - 1:]                       # [1, K]
         self.Eend = jnp.exp(self.last - G)
-        self.Kd, self.Qd = self.kn * self.E, self.qn * self.E
+        self.Kd = self.kn * self.E
+        if q is not None:
+            self.Qd = self.qn * self.E
         self.Ke = self.kn * self.Eend
-        # the Gram matrices: A strict, P with the diagonal
-        A = jnp.zeros((C, C), _F32)
-        P = _place(rows, cols, 0, _rowsum(self.qn * self.kn))
+
+    def grams(self, of_q, of_k):
+        """(P, A) [C, C] float32, None where not asked for: the decayed
+        Gram matrices of q against k with the diagonal and of k against k
+        strictly under it. A level is ONE product for those asked for.
+        The decays of the levels and of the bands stay (``levels``,
+        ``bands``): the Gram matrices' gradient is taken against them."""
+        rows, cols, mxu = self.rows, self.cols, self.mxu
+        C = self.kn.shape[0]
+        A = jnp.zeros((C, C), _F32) if of_k else None
+        P = _place(rows, cols, 0, _rowsum(self.qn * self.kn)) if of_q else None
         self.levels = []
         for lg in _levels(C):
-            El = _level_decay(G, lg)
-            X, Y = (self.kn * El).astype(mxu), (self.qn * El).astype(mxu)
-            YX = jnp.concatenate([Y, X], axis=0)
-            both = _dot(YX, X, _NT)                 # [2C, C]
+            El = _level_decay(self.G, lg)
+            X = (self.kn * El).astype(mxu)
+            YX = jnp.concatenate([(self.qn * El).astype(mxu), X], axis=0) \
+                if of_q else X
+            left = YX if of_k else YX[:C]
+            both = _dot(left, X, _NT)               # [2C, C] for both
             mask = _level_mask(rows, cols, lg)
-            P = P + jnp.where(mask, both[:C], 0.0)
-            A = A + jnp.where(mask, both[C:], 0.0)
+            if of_q:
+                P = P + jnp.where(mask, both[:C], 0.0)
+            if of_k:
+                A = A + jnp.where(mask, both[-C:], 0.0)
             self.levels.append((El, X, YX, mask))
         self.bands = []
         for d in range(1, BASE):
-            F = _band_decay(G, d)
+            F = _band_decay(self.G, d)
             ks = pltpu.roll(self.kn, d, 0) * F      # k_{t-d} e^{G_t-G_{t-d}}
-            A = A + _place(rows, cols, d, _rowsum(self.kn * ks))
-            P = P + _place(rows, cols, d, _rowsum(self.qn * ks))
+            if of_k:
+                A = A + _place(rows, cols, d, _rowsum(self.kn * ks))
+            if of_q:
+                P = P + _place(rows, cols, d, _rowsum(self.qn * ks))
             self.bands.append((F, ks))
-        self.A, self.P = A, P
-        self.T = _inverse(beta * A, rows, cols)
-        self.bKd, self.bv = beta * self.Kd, beta * self.v
-        self.W = _dot32(self.T, self.bKd)           # [C, K]
-        self.U0 = _dot32(self.T, self.bv)           # [C, V]
+        return P, A
+
+    def solve(self, A):
+        """``T = (I + Diag(beta) A)^-1`` and its products with the operands,
+        ``W = T (beta Kd)`` [C, K] and ``U0 = T (beta v)`` [C, V]."""
+        T = _inverse(self.beta * A, self.rows, self.cols)
+        self.kept(T, A, _dot32(T, self.beta * self.Kd),
+                  _dot32(T, self.beta * self.v))
+
+    def kept(self, T, A, W, U0):
+        """W in the MXU's type, which is all any product reads of it."""
+        self.T, self.A, self.U0 = T, A, U0
+        self.W = W.astype(self.mxu)
 
     def written(self, state):
         """The rows the chunk writes, ``U0 - W S``, for the state [V, K]
         that enters it (in the MXU's type)."""
-        return self.U0 - _dot(self.W.astype(self.mxu), state, _NT)
+        return self.U0 - _dot(self.W, state, _NT)
+
+    def leaving(self, state, u):
+        """The state [V, K] float32 that leaves the chunk, ``e^{G_C} S +
+        u^T Ke``, for the one that entered and the rows written (in the
+        MXU's type)."""
+        return state * jnp.exp(self.last) \
+            + _dot(u, self.Ke.astype(self.mxu), _TN)
 
 
 def _inverse(M, rows, cols):
@@ -217,9 +262,17 @@ def _head_beta(tile, head):
     return _rowsum(jnp.where(lanes == head, tile, 0.0))
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
-                K, V, save):
-    st_ref, s_ref = rest if save else (None,) + rest
+def _head(refs, h, K, V, betas, head, tri, rows, cols):
+    """The chunk of head ``h`` of a step from the operands' blocks (q, k, v,
+    g), q None in the state pass."""
+    kl, vl = slice(h * K, (h + 1) * K), slice(h * V, (h + 1) * V)
+    q_ref, k_ref, v_ref, g_ref = refs
+    return _Chunk(None if q_ref is None else q_ref[0, :, kl], k_ref[0, :, kl],
+                  v_ref[0, :, vl], g_ref[0, :, kl], _head_beta(betas, head),
+                  tri, rows, cols)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, s_ref, *, K, V):
     C = q_ref.shape[1]
     j = pl.program_id(2)
 
@@ -230,24 +283,44 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
     tri, rows, cols = _tri(C)
     betas = beta_ref[0]
     for h in range(HEADS):
-        kl, vl = slice(h * K, (h + 1) * K), slice(h * V, (h + 1) * V)
-        c = _Chunk(q_ref[0, :, kl], k_ref[0, :, kl], v_ref[0, :, vl],
-                   g_ref[0, :, kl], _head_beta(betas, j * HEADS + h),
-                   tri, rows, cols)
+        c = _head((q_ref, k_ref, v_ref, g_ref), h, K, V, betas,
+                  j * HEADS + h, tri, rows, cols)
+        P, A = c.grams(True, True)
+        c.solve(A)
         st = s_ref[j, h]                                    # [V, K]
-        if save:
-            st_ref[0, 0, h] = st
         sm = st.astype(c.mxu)
         u = c.written(sm).astype(c.mxu)
-        o = _dot(c.Qd.astype(c.mxu), sm, _NT) \
-            + _dot(c.P.astype(c.mxu), u, _NN)
-        o_ref[0, :, vl] = o.astype(o_ref.dtype)
-        s_ref[j, h] = st * jnp.exp(c.last) \
-            + _dot(u, c.Ke.astype(c.mxu), _TN)
+        o = _dot(c.Qd.astype(c.mxu), sm, _NT) + _dot(P.astype(c.mxu), u, _NN)
+        o_ref[0, :, h * V:(h + 1) * V] = o.astype(o_ref.dtype)
+        s_ref[j, h] = c.leaving(st, u)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref, st_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, ds_ref, *, K, V):
+def _states_kernel(k_ref, v_ref, g_ref, beta_ref, st_ref, ta_ref, w_ref,
+                   u0_ref, s_ref, *, K, V):
+    C = k_ref.shape[1]
+    j = pl.program_id(2)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        s_ref[j] = jnp.zeros(s_ref.shape[1:], _F32)
+
+    tri, rows, cols = _tri(C)
+    betas = beta_ref[0]
+    for h in range(HEADS):
+        c = _head((None, k_ref, v_ref, g_ref), h, K, V, betas,
+                  j * HEADS + h, tri, rows, cols)
+        c.solve(c.grams(False, True)[1])
+        st = s_ref[j, h]                                    # [V, K]
+        st_ref[0, 0, h] = st
+        ta_ref[0, 0, h] = jnp.concatenate([c.T, c.A], axis=1)
+        w_ref[0, 0, h] = c.W
+        u0_ref[0, 0, h] = c.U0
+        s_ref[j, h] = c.leaving(st, c.written(st.astype(c.mxu)).astype(c.mxu))
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref, st_ref, ta_ref,
+                w_ref, u0_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
+                ds_ref, *, K, V):
     C = q_ref.shape[1]
     j = pl.program_id(2)
 
@@ -265,15 +338,17 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref, st_ref,
     for h in range(HEADS):
         kl, vl = slice(h * K, (h + 1) * K), slice(h * V, (h + 1) * V)
         head = j * HEADS + h
-        c = _Chunk(q_ref[0, :, kl], k_ref[0, :, kl], v_ref[0, :, vl],
-                   g_ref[0, :, kl], _head_beta(betas, head),
-                   tri, rows, cols)
+        c = _head((q_ref, k_ref, v_ref, g_ref), h, K, V, betas, head,
+                  tri, rows, cols)
+        P, _ = c.grams(True, False)
+        ta = ta_ref[0, 0, h]
+        c.kept(ta[:, :C], ta[:, C:], w_ref[0, 0, h], u0_ref[0, 0, h])
         mxu = c.mxu
         st, dst = st_ref[0, 0, h], ds_ref[j, h]             # [V, K]
         sm, dsm = st.astype(mxu), dst.astype(mxu)
         u = c.written(sm).astype(mxu)
         do = do_ref[0, :, vl].astype(mxu)
-        Pm, Kem = c.P.astype(mxu), c.Ke.astype(mxu)
+        Pm, Kem = P.astype(mxu), c.Ke.astype(mxu)
         # o = Qd S + P u;  S' = e^last S + Ke^T u;  u = U0 - W S
         du = _dot(Pm, do, _TN) + _dot(Kem, dsm, _NT)        # [C, V]
         dP = jnp.where(cols <= rows, _dot(do, u, _NT), 0.0)
@@ -284,12 +359,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, do_ref, st_ref,
         decay = jnp.exp(c.last)
         ds_ref[j, h] = dst * decay \
             + _dot(do, c.Qd.astype(mxu), _TN) \
-            - _dot(dum, c.W.astype(mxu), _TN)
+            - _dot(dum, c.W, _TN)
         dlast = jnp.sum(dst * st, axis=0, keepdims=True) * decay \
             + jnp.sum(dKe * c.Ke, axis=0, keepdims=True)    # [1, K]
         # U0 = T (beta v), W = T (beta Kd), T = (I + beta A)^-1
         dbv, dbKd = _dot32(c.T, du, _TN), _dot32(c.T, dW, _TN)
-        dT = _dot32(du, c.bv, _NT) + _dot32(dW, c.bKd, _NT)
+        dT = _dot32(du, c.beta * c.v, _NT) + _dot32(dW, c.beta * c.Kd, _NT)
         dM = -jnp.where(cols < rows,
                         _dot32(_dot32(c.T, dT, _TN), c.T, _NT), 0.0)
         dbeta = _rowsum(dM * c.A) + _rowsum(dbv * c.v) \
@@ -350,54 +425,84 @@ def _specs(dims, chunk, reverse=False):
         return pl.BlockSpec((1, chunk, HEADS * width),
                             lambda b, n, j: (b, at(n), j))
 
+    def tiles(rows, width):
+        """A [rows, width] tile a (chunk, head) of [B, N, H, rows, width]."""
+        return pl.BlockSpec((1, 1, HEADS, rows, width),
+                            lambda b, n, j: (b, at(n), j, 0, 0))
+
     betas = pl.BlockSpec((1, chunk, H), lambda b, n, j: (b, at(n), 0))
-    states = pl.BlockSpec((1, 1, HEADS, V, K),
-                          lambda b, n, j: (b, at(n), j, 0, 0))
-    return tokens, betas, states
+    return tokens, betas, tiles
+
+
+def _kept(dims, chunk, mxu, reverse=False):
+    """(shapes, specs) of what the state pass keeps of every (chunk, head)
+    for the backward: the state that enters the chunk [V, K], ``T | A``
+    side by side [C, 2C] and ``U0`` [C, V], float32, and ``W`` [C, K] in
+    the MXU's type."""
+    B, T, H, K, V = dims
+    tiles = _specs(dims, chunk, reverse)[2]
+    sizes = ((V, K, _F32), (chunk, 2 * chunk, _F32), (chunk, K, mxu),
+             (chunk, V, _F32))
+    return ([jax.ShapeDtypeStruct((B, T // chunk, H, r, w), t)
+             for r, w, t in sizes], [tiles(r, w) for r, w, _ in sizes])
 
 
 _PARAMS = dict(dimension_semantics=("parallel", "arbitrary", "arbitrary"))
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "save", "interpret"))
-def forward(q, k, v, g, beta, *, chunk, save=False, interpret=False):
-    """o [B, T, H, V]; with ``save`` also the state that enters each chunk,
-    [B, T / chunk, H, V, K] float32."""
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def forward(q, k, v, g, beta, *, chunk, interpret=False):
+    """o [B, T, H, V]."""
     B, T, H, K = q.shape
     V = v.shape[-1]
-    tokens, betas, states = _specs((B, T, H, K, V), chunk)
-    out_shape = [jax.ShapeDtypeStruct((B, T, H * V), v.dtype)]
-    out_specs = [tokens(V)]
-    if save:
-        out_shape.append(jax.ShapeDtypeStruct((B, T // chunk, H, V, K), _F32))
-        out_specs.append(states)
-    out = pl.pallas_call(
-        functools.partial(_fwd_kernel, K=K, V=V, save=save),
+    tokens, betas, _ = _specs((B, T, H, K, V), chunk)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, K=K, V=V),
         grid=(B, T // chunk, H // HEADS),
         in_specs=[tokens(K), tokens(K), tokens(V), tokens(K), betas],
-        out_specs=out_specs, out_shape=out_shape,
+        out_specs=tokens(V),
+        out_shape=jax.ShapeDtypeStruct((B, T, H * V), v.dtype),
         scratch_shapes=[pltpu.VMEM((H // HEADS, HEADS, V, K), _F32)],
         compiler_params=pltpu.CompilerParams(**_PARAMS),
         interpret=interpret, name="kda_fwd",
     )(_flat(q), _flat(k), _flat(v), _flat(g.astype(_F32)),
-      beta.astype(_F32))
-    o = out[0].reshape(B, T, H, V)
-    return (o, out[1]) if save else o
+      beta.astype(_F32)).reshape(B, T, H, V)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def states(k, v, g, beta, *, chunk, interpret=False):
+    """The gradient op's pass over the chunks in order, which makes no
+    output: what ``_kept`` lists, [B, T / chunk, H, ...] each."""
+    B, T, H, K = k.shape
+    V = v.shape[-1]
+    dims = (B, T, H, K, V)
+    tokens, betas, _ = _specs(dims, chunk)
+    out_shape, out_specs = _kept(dims, chunk, v.dtype)
+    return pl.pallas_call(
+        functools.partial(_states_kernel, K=K, V=V),
+        grid=(B, T // chunk, H // HEADS),
+        in_specs=[tokens(K), tokens(V), tokens(K), betas],
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((H // HEADS, HEADS, V, K), _F32)],
+        compiler_params=pltpu.CompilerParams(**_PARAMS),
+        interpret=interpret, name="kda_states",
+    )(_flat(k), _flat(v), _flat(g.astype(_F32)), beta.astype(_F32))
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def backward(q, k, v, g, beta, do, *, chunk, interpret=False):
-    """(dq, dk, dv, dg, dbeta) of ``forward``'s o for its cotangent do."""
+    """(dq, dk, dv, dg, dbeta) of ``forward``'s o for its cotangent do:
+    ``states``, then the chunks in reverse."""
     B, T, H, K = q.shape
     V = v.shape[-1]
-    _, entering = forward(q, k, v, g, beta, chunk=chunk, save=True,
-                          interpret=interpret)
-    tokens, betas, states = _specs((B, T, H, K, V), chunk, reverse=True)
+    dims = (B, T, H, K, V)
+    kept = states(k, v, g, beta, chunk=chunk, interpret=interpret)
+    tokens, betas, _ = _specs(dims, chunk, reverse=True)
     dq, dk, dv, dg, dbeta = pl.pallas_call(
         functools.partial(_bwd_kernel, K=K, V=V),
         grid=(B, T // chunk, H // HEADS),
         in_specs=[tokens(K), tokens(K), tokens(V), tokens(K), betas,
-                  tokens(V), states],
+                  tokens(V)] + _kept(dims, chunk, v.dtype, reverse=True)[1],
         out_specs=[tokens(K), tokens(K), tokens(V), tokens(K), betas],
         out_shape=[jax.ShapeDtypeStruct((B, T, H * K), q.dtype),
                    jax.ShapeDtypeStruct((B, T, H * K), k.dtype),
@@ -408,7 +513,7 @@ def backward(q, k, v, g, beta, do, *, chunk, interpret=False):
         compiler_params=pltpu.CompilerParams(**_PARAMS),
         interpret=interpret, name="kda_bwd",
     )(_flat(q), _flat(k), _flat(v), _flat(g.astype(_F32)),
-      beta.astype(_F32), _flat(do.astype(v.dtype)), entering)
+      beta.astype(_F32), _flat(do.astype(v.dtype)), *kept)
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
             dg.reshape(g.shape).astype(g.dtype), dbeta.astype(beta.dtype))
 

@@ -181,7 +181,8 @@ def cases():
 
     # the delta rule's kernels at the delta-rule cell's shape (32 heads of
     # 128, chunks of 64, T = 8192), as its two ops run them: the forward;
-    # the forward with the entering states and the backward kernel
+    # the state pass with the tiles it keeps and the backward kernel that
+    # reads them
     kd = importlib.import_module("paddle_tpu.ops.pallas.kda")
     qk, gk = ((1, 8192, 32, 128), bf16), ((1, 8192, 32, 128), f32)
     yield ("kda_fwd_s8192", lambda *a: kd.forward(*a, chunk=64),

@@ -13,8 +13,16 @@ step, ``kda.HEADS``, and the positions whose pairs are summed channel by
 channel, ``kda.BASE``: this tool sets them, the kernels read them):
 milliseconds on the host's clock (median of ``--iters``) and the device's
 own time a call with its five longest operations (a profiler trace, as
-``tools/ssm_bench.py`` reads one). Whether the kernels compile for the v5e
-is ``tests/tpu_kernel_cases.py``'s to say, here, without a chip.
+``tools/ssm_bench.py`` reads one). The gradient op is two kernels: the
+state pass ``kda_states``, which goes over the chunks in order without q
+and without an output and keeps, of every chunk and head, the state that
+enters it, the inverse ``T``, the Gram matrix ``A`` and the inverse's
+products ``W`` and ``U0``; and ``kda_bwd``, which reads them in reverse. Its
+line names each by the device's own time where it is among the five
+longest (``state_pass_ms``, ``kda_bwd_ms``: a third Mosaic call, the
+``vjp``'s dead forward, would stand beside them) and gives the bytes kept a
+layer (``kept_bytes``). Whether the kernels compile for the v5e is
+``tests/tpu_kernel_cases.py``'s to say, here, without a chip.
 
 The operands are 4-D parameters of the jitted call, whose layout XLA pins
 head-minor, so each call pays ~1 ms of ``reshape`` and ``copy`` that the
@@ -85,6 +93,27 @@ def ops(chunk, path=None):
             on_path(lambda ins: bwd(ins, attrs)))
 
 
+def gradient_kernels(device, ins, chunk):
+    """The gradient op's two kernels by the device's own time, from
+    ``device_ms``'s five longest operations, and the bytes the state pass
+    keeps for the backward."""
+    def ms(kernel):
+        return next((v for k, v in device["ops"].items()
+                     if k.startswith(kernel)), None)
+
+    B, T, H, K = ins["K"].shape
+    T += -T % chunk
+    f32 = jnp.float32
+    kept = jax.eval_shape(
+        lambda *a: kda.states(*a, chunk=chunk),
+        *(jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in (
+            ((B, T, H, K), ins["K"].dtype),
+            ((B, T, H, ins["V"].shape[-1]), ins["V"].dtype),
+            ((B, T, H, K), f32), ((B, T, H), f32))))
+    return {"state_pass_ms": ms("kda_states"), "kda_bwd_ms": ms("kda_bwd"),
+            "kept_bytes": sum(a.size * a.dtype.itemsize for a in kept)}
+
+
 def rel(a, b):
     a, b = a.astype(jnp.float32), b.astype(jnp.float32)
     return float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-30))
@@ -144,9 +173,13 @@ def main(argv):
         kda.HEADS, kda.BASE = heads, base
         jax.clear_caches()
         for name, fn in zip(("kda_chunk", "kda_chunk_grad"), ops(args.chunk)):
-            say({"op": name, "heads": heads, "base": base,
-                 "host_ms": ms_of(fn, (ins,), args.iters),
-                 "device": device_ms(fn, (ins,), args.iters)})
+            line = {"op": name, "heads": heads, "base": base,
+                    "host_ms": ms_of(fn, (ins,), args.iters),
+                    "device": device_ms(fn, (ins,), args.iters)}
+            if name == "kda_chunk_grad":
+                line.update(gradient_kernels(line["device"], ins,
+                                             args.chunk))
+            say(line)
     if args.out:
         os.makedirs(os.path.dirname(os.path.abspath(args.out)),
                     exist_ok=True)
