@@ -67,13 +67,15 @@ white_list = {
     "attn_index_loss",
 }
 
-# input slots of white-list ops that stay float32: what sets a decay or a
-# choice must not pass through the low type
+# input slots of white- and gray-list ops that stay float32: what sets a
+# decay or a choice must not pass through the low type
 fp32_slots = {
     "ssd_chunk_scan": frozenset(("A", "D", "DtBias")),
     "kda_chunk": frozenset(("ALog", "DtBias")),
     "moe_topk": frozenset(("X", "RouterW", "Bias")),
     "attn_index_loss": frozenset(("QI", "KI", "W", "LSE")),
+    # the taps weigh in float32, as the op sums them
+    "short_conv_gate": frozenset(("W",)),
 }
 
 # numerically sensitive reductions/losses/normalizations: keep f32
@@ -142,4 +144,8 @@ gray_list = {
     "sign",
     "cast",
     "causal_conv1d",
+    # the projection's three streams as the product before them makes them;
+    # the two gates and the taps' sum are float32 inside the op, and the
+    # taps stay float32 (fp32_slots)
+    "short_conv_gate",
 }

@@ -1085,6 +1085,30 @@ def causal_conv1d(input, kernel_size=4, bias=True, act=None,
     return out
 
 
+def short_conv_gate(input, kernel_size=3, param_attr=None, name=None):
+    """The doubly gated short convolution over one projection's result
+    [B, T, 3C], its three streams ``B | C | z`` read by offset: ``C *
+    causal_conv1d(B * z)`` with taps [C, kernel_size] (the last weighs the
+    current position), no bias, no activation; [B, T, C]
+    (ops/ssm_ops.py). The gradient op keeps nothing but these inputs."""
+    helper = LayerHelper("short_conv_gate", input=input,
+                         param_attr=param_attr, name=name)
+    c = int(input.shape[-1]) // 3
+    if 3 * c != int(input.shape[-1]):
+        raise ValueError("short_conv_gate: %d channels are no three streams"
+                         % int(input.shape[-1]))
+    w = helper.create_parameter(attr=helper.param_attr,
+                                shape=[c, int(kernel_size)],
+                                dtype=input.dtype)
+    out = helper.create_variable_for_type_inference(input.dtype)
+    # no shape inference: it would trace (and count) the op at build time
+    helper.append_op("short_conv_gate", inputs={"X": [input], "W": [w]},
+                     outputs={"Out": [out]}, infer_shape=False)
+    if not framework.in_dygraph_mode():
+        out.shape = tuple(input.shape[:-1]) + (c,)
+    return out
+
+
 def ssd_chunk_scan(x, dt, A, B, C, D=None, dt_bias=None, chunk=128):
     """Mamba-2's selective scan by chunks (ops/ssm_ops.py): x [B, T, H, P],
     raw step sizes dt [B, T, H], A / D / dt_bias [H] (A negative), B and C
@@ -1134,12 +1158,13 @@ def kda_chunk(q, k, v, g, beta, a_log, dt_bias, chunk=64):
 def moe_topk(input, num_experts, k, hidden_dim, held=None, scaling=1.0,
              norm_topk=True, correction_bias=None, return_load=False,
              param_attr=None, name=None, scoring="sigmoid",
-             expert="relu2"):
+             expert="relu2", route_eps=None):
     """Routed experts over [T, D] tokens, without drops: scores over all
     ``num_experts`` (``scoring``: ``sigmoid`` of each logit, or a
     ``softmax`` over all of them), the ``k`` largest of score + correction
     bias chosen (the bias enters the choice only), weights ``scaling *
-    s / sum of the chosen s`` (``norm_topk``), expert ``relu(u W1)^2 W2``
+    s / (sum of the chosen s + route_eps)`` (``norm_topk``; ``route_eps``
+    None: the op's own 1e-20), expert ``relu(u W1)^2 W2``
     (``expert`` ``relu2``, not gated) or ``(silu(u W1) * (u W3)) W2``
     (``swiglu``: a third matrix ``W3``, created after the other two; the
     op reads the form from whether it is bound).
@@ -1187,13 +1212,15 @@ def moe_topk(input, num_experts, k, hidden_dim, held=None, scaling=1.0,
         inputs["W3"] = [helper.create_parameter(
             attr=helper.param_attr, shape=[count, d, hidden_dim],
             dtype=dtype)]
+    attrs = {"k": int(k), "held": [int(first), int(count)],
+             "scaling": float(scaling), "norm_topk": bool(norm_topk),
+             "scoring": scoring}
+    if route_eps is not None:
+        attrs["route_eps"] = float(route_eps)
     # no shape inference: it would trace (and count) the op at build time
     helper.append_op(
         "moe_topk", inputs=inputs,
-        outputs={"Out": [out], "Load": [load]},
-        attrs={"k": int(k), "held": [int(first), int(count)],
-               "scaling": float(scaling), "norm_topk": bool(norm_topk),
-               "scoring": scoring},
+        outputs={"Out": [out], "Load": [load]}, attrs=attrs,
         infer_shape=False)
     if not framework.in_dygraph_mode():
         out.shape, load.shape = (t, d), (count + 1,)
@@ -1406,7 +1433,8 @@ def mhc_post(x, h_res, h_post, y):
     return out
 
 
-__all__ += ["rms_norm", "causal_conv1d", "ssd_chunk_scan", "kda_chunk",
+__all__ += ["rms_norm", "causal_conv1d", "short_conv_gate", "ssd_chunk_scan",
+            "kda_chunk",
             "moe_topk",
             "rotary_embedding", "yarn_inv_freq", "yarn_mscale",
             "attn_index_project", "attn_index_select", "attn_index_loss",

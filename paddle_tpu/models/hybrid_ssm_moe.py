@@ -21,6 +21,11 @@ is two letters), on a residual path that is an argument: a plain add, or
   ``ops.kda_ops.kda_path``); an RMS
   norm a head under a sigmoid output gate made by another low-rank pair;
   output projection;
+- ``C``  a doubly gated short convolution: one projection to three streams
+  ``B | C | z``, ``B * z`` through a causal depthwise convolution of a few
+  taps, the result times ``C`` (``layers.short_conv_gate``, which reads the
+  three streams out of the projection's result in place), an output
+  projection; no activation, no state beyond the taps;
 - ``E``  routed experts, top-k of many without drops over the experts this
   program holds (``layers.moe_topk``: sigmoid or softmax scores, a
   ``relu(u W1)^2 W2`` or a gated ``(silu(u W1) * (u W3)) W2`` expert),
@@ -35,7 +40,8 @@ is two letters), on a residual path that is an argument: a plain add, or
   (or no positions at all, ``inv_freq`` None), a value dim of its own, on
   the streaming ``flash_attention`` kernels;
 - ``*``  causal grouped-query attention on the ``flash_attention`` op, no
-  positional encoding (as ``NemotronHAttention`` has none);
+  positional encoding (as ``NemotronHAttention`` has none), or, by
+  argument, with an RMS norm on every q and k head and rotary positions;
 - ``S``  causal grouped-query attention over the keys an indexer selects
   for each query: per-head RMS norms on q and k, rotary positions from
   three position components, a lightning indexer (``attn_index_project``,
@@ -44,12 +50,17 @@ is two letters), on a residual path that is an argument: a plain add, or
   ``flash_attention`` op applies inside its kernels, and the indexer's KL
   loss (``attn_index_loss``), collected for the caller to add to the loss.
 
+The head is a matrix of its own, or the embedding table again
+(``tied_head``).
+
 Built from ``fluid.layers`` ops; nothing here knows a model's name, the
 sizes are arguments.
 """
 from __future__ import annotations
 
+
 from .. import framework, layers
+from ..param_attr import ParamAttr
 
 
 def _proj(x, size):
@@ -147,15 +158,26 @@ def kda_mixer(u, hidden, num_heads, head_dim, *, conv_kernel=4, chunk=64,
         return _proj(layers.reshape(o, [B, T, inner]), hidden)
 
 
+def short_conv_mixer(u, hidden, conv_kernel=3):
+    """u [B, T, hidden] (normed) -> the doubly gated short convolution.
+    Parameters in order: the input projection (to ``3 x hidden``, the
+    streams ``B | C | z``), the taps [hidden, conv_kernel], the output
+    projection. Every op is built inside ``name_scope("shortconv")``: a
+    device trace tells the mixer's two ``mul``s from the other layers'."""
+    with framework.name_scope("shortconv"):
+        return _proj(layers.short_conv_gate(_proj(u, 3 * hidden),
+                                            kernel_size=conv_kernel), hidden)
+
+
 def moe_mixer(u, hidden, num_experts, top_k, expert_dim, shared_dim,
               held=None, scaling=1.0, correction_bias=None, loads=None,
-              scoring="sigmoid", expert="relu2"):
+              scoring="sigmoid", expert="relu2", route_eps=None):
     """u [B, T, hidden] (normed) -> routed (held experts' part) + shared."""
     B, T, _ = u.shape
     routed, load = layers.moe_topk(
         layers.reshape(u, [B * T, hidden]), num_experts, top_k, expert_dim,
         held=held, scaling=scaling, correction_bias=correction_bias,
-        return_load=True, scoring=scoring, expert=expert)
+        return_load=True, scoring=scoring, expert=expert, route_eps=route_eps)
     if loads is not None:
         loads.append(load)
     out = layers.reshape(routed, [B, T, hidden])
@@ -164,17 +186,32 @@ def moe_mixer(u, hidden, num_experts, top_k, expert_dim, shared_dim,
     return out
 
 
-def gqa_mixer(u, hidden, num_heads, num_kv_heads, head_dim):
-    """u [B, T, hidden] (normed) -> causal grouped-query attention."""
+def gqa_mixer(u, hidden, num_heads, num_kv_heads, head_dim, *,
+              qk_norm_eps=None, rope_theta=None):
+    """u [B, T, hidden] (normed) -> causal grouped-query attention.
+    ``qk_norm_eps``: an RMS norm with a weight [head_dim] on every q and k
+    head (None: none); ``rope_theta``: rotary positions ``0..T-1`` on the
+    whole head after it, rotate-half form (None: no positions). Parameters
+    in order: the q projection (and its head norm), the k projection (and
+    its head norm), the v and the output projections."""
     B, T, _ = u.shape
 
     def heads(x, n):
-        return layers.transpose(layers.reshape(x, [B, T, n, head_dim]),
-                                [0, 2, 1, 3])
+        return layers.reshape(x, [B, T, n, head_dim])
 
-    q = heads(_proj(u, num_heads * head_dim), num_heads)
-    k = heads(_proj(u, num_kv_heads * head_dim), num_kv_heads)
-    v = heads(_proj(u, num_kv_heads * head_dim), num_kv_heads)
+    def placed(x):
+        if qk_norm_eps is not None:
+            x = layers.rms_norm(x, epsilon=qk_norm_eps)
+        if rope_theta is not None:
+            x = layers.rotary_embedding(x, theta=rope_theta)
+        return x
+
+    def major(x):
+        return layers.transpose(x, [0, 2, 1, 3])
+
+    q = major(placed(heads(_proj(u, num_heads * head_dim), num_heads)))
+    k = major(placed(heads(_proj(u, num_kv_heads * head_dim), num_kv_heads)))
+    v = major(heads(_proj(u, num_kv_heads * head_dim), num_kv_heads))
     ctx = layers.flash_attention(q, k, v, causal=True,
                                  scale=float(head_dim) ** -0.5)
     ctx = layers.reshape(layers.transpose(ctx, [0, 2, 1, 3]),
@@ -285,7 +322,8 @@ def hybrid_ssm_moe(ids, pattern, vocab_rows, hidden, *, mamba_heads=64,
                    num_kv_heads=2, head_dim=128, eps=1e-5, loads=None,
                    checkpoints=None, scoring="sigmoid", expert="relu2",
                    indexed=None, latent=None, dense_dim=0, hyper=None,
-                   kda=None):
+                   kda=None, gqa=None, short_conv_kernel=3, route_eps=None,
+                   tied_head=False):
     """Logits [B, T, vocab_rows] over int64 ids [B, T].
 
     ``pattern``: one letter a layer (see the module's docstring).
@@ -307,7 +345,13 @@ def hybrid_ssm_moe(ids, pattern, vocab_rows, hidden, *, mamba_heads=64,
     ``latent_mixer`` as one dict (``num_heads`` is shared with the other
     attention letters). ``kda``: the ``K`` layers' arguments of
     ``kda_mixer`` as one dict, ``num_heads`` and ``head_dim`` among them.
-    ``dense_dim``: the ``D`` layers' width.
+    ``dense_dim``: the ``D`` layers' width. ``gqa``: the ``*`` layers'
+    keyword arguments of ``gqa_mixer`` as one dict (``qk_norm_eps``,
+    ``rope_theta``). ``short_conv_kernel``: the ``C`` layers' taps.
+    ``route_eps``: what stands beside the chosen scores' sum in the ``E``
+    layers' weights (None: ``moe_topk``'s own). ``tied_head``: the head is
+    the embedding table (one parameter, used twice; its gradient is the sum
+    of both uses) instead of a matrix of its own.
     ``hyper``: the residual path. None: ``x = x + mixer(rms_norm(x))``.
     A dict ``{"streams": n, ...}`` (the rest ``layers.mhc_pre``'s keyword
     arguments): ``n`` residual streams [B, n, T, hidden], each a copy of the
@@ -315,7 +359,10 @@ def hybrid_ssm_moe(ids, pattern, vocab_rows, hidden, *, mamba_heads=64,
     writing ``X' = H_res X + H_post (x) mixer(rms_norm(h))`` through maps
     made from the streams, the streams summed before the last norm; the
     checkpoints are then the streams at each sublayer's input."""
-    x = layers.embedding(ids, size=[vocab_rows, hidden])
+    table_name = (framework.unique_name.generate("tied_embedding")
+                  if tied_head else None)
+    x = layers.embedding(ids, size=[vocab_rows, hidden],
+                         param_attr=ParamAttr(name=table_name))
     streams = 0
     if hyper is not None:
         hyper = dict(hyper)
@@ -336,9 +383,13 @@ def hybrid_ssm_moe(ids, pattern, vocab_rows, hidden, *, mamba_heads=64,
         elif kind == "E":
             y = moe_mixer(u, hidden, num_experts, top_k, expert_dim,
                           shared_dim, held, routed_scaling,
-                          next(biases, None), loads, scoring, expert)
+                          next(biases, None), loads, scoring, expert,
+                          route_eps)
         elif kind == "*":
-            y = gqa_mixer(u, hidden, num_heads, num_kv_heads, head_dim)
+            y = gqa_mixer(u, hidden, num_heads, num_kv_heads, head_dim,
+                          **(gqa or {}))
+        elif kind == "C":
+            y = short_conv_mixer(u, hidden, short_conv_kernel)
         elif kind == "S":
             y = indexed_gqa_mixer(u, hidden, num_heads, num_kv_heads,
                                   head_dim, eps=eps, **indexed)
@@ -359,4 +410,10 @@ def hybrid_ssm_moe(ids, pattern, vocab_rows, hidden, *, mamba_heads=64,
         checkpoints.append(x)
     if streams:
         x = layers.reduce_sum(x, dim=1)
-    return _proj(layers.rms_norm(x, epsilon=eps), vocab_rows)
+    x = layers.rms_norm(x, epsilon=eps)
+    if not tied_head:
+        return _proj(x, vocab_rows)
+    table = framework.default_main_program().global_block().var(table_name)
+    # `transpose2` + `mul`, not `matmul`: that op type reads as attention's
+    # in a device trace (benchmarks/layer_metrics/attention.py)
+    return layers.mul(x, layers.transpose(table, [1, 0]), x_num_col_dims=2)

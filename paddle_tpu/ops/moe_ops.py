@@ -53,10 +53,15 @@ def _hidden(dot, w1, w3=None):
     return jax.nn.silu(dot(w1)) * dot(w3)
 
 
-def route(x, router_w, bias, k, scaling, norm_topk, scoring="sigmoid"):
+ROUTE_EPS = 1e-20     # beside the chosen scores' sum, unless the op says
+
+
+def route(x, router_w, bias, k, scaling, norm_topk, scoring="sigmoid",
+          route_eps=ROUTE_EPS):
     """(idx [T, k] int32, weight [T, k] float32): the router's product,
     scores (``sigmoid`` of each logit, or a ``softmax`` over all the
-    experts) and weights in float32 at full precision."""
+    experts) and weights (over ``sum of the chosen + route_eps`` with
+    ``norm_topk``) in float32 at full precision."""
     f32 = jnp.float32
     logits = jnp.dot(x.astype(f32), router_w.astype(f32), precision=_HI)
     if scoring == "sigmoid":
@@ -70,7 +75,7 @@ def route(x, router_w, bias, k, scaling, norm_topk, scoring="sigmoid"):
     _, idx = jax.lax.top_k(choice, k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if norm_topk:
-        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, -1, keepdims=True) + route_eps)
     return idx.astype(jnp.int32), scaling * w
 
 
@@ -189,7 +194,7 @@ def _every_token(x, w1, w2, local, weight, w3=None):
 
 
 def moe_topk(x, router_w, bias, w1, w2, k, held, scaling=1.0,
-             norm_topk=True, scoring="sigmoid", w3=None):
+             norm_topk=True, scoring="sigmoid", w3=None, route_eps=ROUTE_EPS):
     """(out [T, D] float32, load [count + 1] int32): the held experts'
     part of the layer for tokens x [T, D], expert ``relu(u W1)^2 W2`` or,
     with ``w3``, ``(silu(u W1) * (u W3)) W2``;
@@ -209,7 +214,7 @@ def moe_topk(x, router_w, bias, w1, w2, k, held, scaling=1.0,
     # inner scopes, so that a trace tells the routing from the products
     with jax.named_scope("route"):
         idx, weight = route(x, router_w, bias, k, scaling, norm_topk,
-                            scoring)
+                            scoring, route_eps)
     with jax.named_scope("experts"):
         return _held_part(x, w1, w2, idx, weight, k, experts, first, count,
                           w3)
@@ -254,13 +259,14 @@ def _held_part(x, w1, w2, idx, weight, k, experts, first, count, w3=None):
             In("W1"), In("W2"), In("W3", dispensable=True)],
     outputs=[Out("Out"), Out("Load", dispensable=True, no_grad=True)],
     attrs={"k": 1, "held": [0, 1], "scaling": 1.0, "norm_topk": True,
-           "scoring": "sigmoid"},
+           "scoring": "sigmoid", "route_eps": ROUTE_EPS},
 )
 def _moe_topk(ins, attrs):
     """X [T, D] tokens; RouterW [D, E] over all E experts; Bias [E] the
     selection-only correction (a buffer: no gradient); W1 [count, D, F],
     W2 [count, F, D] the held experts ``held[0] .. held[0] + count - 1``.
-    ``scoring`` ``sigmoid`` or ``softmax`` (over all E experts). The expert
+    ``scoring`` ``sigmoid`` or ``softmax`` (over all E experts);
+    ``route_eps`` stands beside the chosen scores' sum. The expert
     is ``relu(u W1)^2 W2``, or gated where W3 [count, D, F] is bound:
     ``(silu(u W1) * (u W3)) W2``.
     RouterW takes a gradient only where every expert is held
@@ -281,5 +287,6 @@ def _moe_topk(ins, attrs):
         k=int(attrs.get("k", 1)), held=attrs.get("held", [0, 1]),
         scaling=float(attrs.get("scaling", 1.0)),
         norm_topk=bool(attrs.get("norm_topk", True)),
-        scoring=attrs.get("scoring", "sigmoid"), w3=ins.get("W3"))
+        scoring=attrs.get("scoring", "sigmoid"), w3=ins.get("W3"),
+        route_eps=float(attrs.get("route_eps", ROUTE_EPS)))
     return {"Out": out.astype(ins["W1"].dtype), "Load": load}

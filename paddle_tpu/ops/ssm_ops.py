@@ -1,4 +1,5 @@
-"""State-space ops: the causal depthwise convolution and the chunked
+"""State-space ops: the causal depthwise convolution, the doubly gated
+short convolution (``short_conv_gate``, with its gradient op) and the chunked
 selective scan of Mamba-2 (SSD, arXiv:2405.21060), with its gradient op.
 
 The scan is the chunked algorithm: inside a chunk the masked ``C B^T``
@@ -56,6 +57,80 @@ def _causal_conv1d(ins, attrs):
     elif act:
         raise NotImplementedError("causal_conv1d activation %r" % act)
     return {"Out": out.astype(x.dtype)}
+
+
+def _taps_sum(xp, w, T):
+    """``sum_j w[:, j] xp[:, j : j + T]`` over the padded ``xp``."""
+    return sum(xp[:, j:j + T, :] * w[:, j] for j in range(w.shape[1]))
+
+
+def _gated(x, w):
+    """(B, C, z, padded B * z, convolved B * z) in float32: the three
+    streams of x [B, T, 3C] read by offset out of the projection's result,
+    ``B * z`` with the K - 1 zeros before the sequence, and the taps' sum
+    over it; w [C, K] float32."""
+    C, K = w.shape
+    b, c, z = (x[..., i * C:(i + 1) * C].astype(jnp.float32)
+               for i in range(3))
+    bz = jnp.pad(b * z, ((0, 0), (K - 1, 0), (0, 0)))
+    return b, c, z, bz, _taps_sum(bz, w, x.shape[1])
+
+
+def short_conv_gate(x, w):
+    """[B, T, C] from the projection's result x [B, T, 3C] = ``B | C | z``
+    and the taps w [C, K] (``w[:, K-1]`` weighs the current position):
+    ``C * conv(B * z)``, the convolution causal and depthwise, positions
+    before the sequence read as zero, no bias, no activation. The two gates
+    and the K taps' sum are float32; the result has x's type. Each trace
+    counts ``kernels.short_conv_gate``."""
+    from .. import observability as _obs
+
+    if _obs.enabled():
+        _obs.inc("kernels.short_conv_gate")
+    _, c, _, _, y = _gated(x, w.astype(jnp.float32))
+    return (c * y).astype(x.dtype)
+
+
+def _short_conv_gate_grad(ins, attrs):
+    """The gradients from the op's inputs alone (nothing else lives from the
+    forward to the backward): the convolved stream is made again, the
+    cotangent runs back through the taps (an anti-causal convolution), and
+    the three streams' gradients go out side by side as X's."""
+    x, w = ins["X"], ins["W"].astype(jnp.float32)
+    g = ins["Out@GRAD"].astype(jnp.float32)
+    T, K = x.shape[1], w.shape[1]
+    b, c, z, bz, y = _gated(x, w)
+    dy = jnp.pad(g * c, ((0, 0), (0, K - 1), (0, 0)))
+    # y[t] reads (B z)[t - (K-1) + j] through tap j: (B z)[s] is read by
+    # y[s + (K-1) - j]
+    dbz = _taps_sum(dy, w[:, ::-1], T)
+    dw = jnp.stack([jnp.sum(dy[:, :T] * bz[:, j:j + T], axis=(0, 1))
+                    for j in range(K)], axis=-1)
+    dx = jnp.concatenate([dbz * z, g * y, dbz * b], -1)
+    return {"X@GRAD": dx.astype(x.dtype),
+            "W@GRAD": dw.astype(ins["W"].dtype)}
+
+
+# registered before its forward op, so that no auto-VJP grad op is made
+register_op(
+    "short_conv_gate_grad",
+    inputs=[In("X"), In("W"), In("Out@GRAD")],
+    outputs=[Out("X@GRAD", dispensable=True),
+             Out("W@GRAD", dispensable=True)],
+    grad=None,
+)(_short_conv_gate_grad)
+
+
+@register_op(
+    "short_conv_gate",
+    inputs=[In("X"), In("W")],
+    outputs=[Out("Out")],
+)
+def _short_conv_gate(ins, attrs):
+    """X [B, T, 3C] the three streams ``B | C | z`` of one projection, W
+    [C, K] the taps; Out [B, T, C] = ``C * causal_conv(B * z)``
+    (``short_conv_gate`` above)."""
+    return {"Out": short_conv_gate(ins["X"], ins["W"])}
 
 
 def _chunks(T, chunk):

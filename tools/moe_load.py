@@ -64,7 +64,7 @@ def main(argv):
             for leaf, name in built["leaves"].items():
                 scope.find_var(name).get_tensor().set(params[leaf])
             del params
-            seen, times = [], []
+            seen, times, losses = [], [], []
             pool = harness.make_pool(reference, cfg, traffic, seed,
                                      traffic["pool"])
             for i in range(args.steps or len(pool)):
@@ -73,6 +73,7 @@ def main(argv):
                 t0 = time.perf_counter()
                 out = exe.run(built["main"], feed=feed,
                               fetch_list=[built["loss"]] + loads)
+                losses.append(float(np.mean(out[0])))
                 seen.append(np.stack([np.asarray(x) for x in out[1:]]))
                 times.append(time.perf_counter() - t0)
         seen = np.stack(seen)          # [steps, layers, held + 1]
@@ -93,6 +94,7 @@ def main(argv):
             "seed": seed, "steps": int(seen.shape[0]),
             "step_ms_median": 1e3 * statistics.median(times[8:] or times),
             "step_ms_by_step": [round(1e3 * t, 1) for t in times],
+            "loss_by_step": [round(x, 5) for x in losses],
             "slots_by_step": held.sum(-1).sum(-1).tolist(),
             "largest_layer_by_step": held.sum(-1).max(-1).tolist()})
         print(json.dumps(lines[-1]), flush=True)
