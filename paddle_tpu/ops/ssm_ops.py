@@ -1,6 +1,8 @@
-"""State-space ops: the causal depthwise convolution, the doubly gated
-short convolution (``short_conv_gate``, with its gradient op) and the chunked
-selective scan of Mamba-2 (SSD, arXiv:2405.21060), with its gradient op.
+"""State-space ops: the causal depthwise convolution (``causal_conv1d``, with
+its gradient op), the doubly gated short convolution (``short_conv_gate``,
+with its gradient op) and the chunked selective scan of Mamba-2 (SSD,
+arXiv:2405.21060), with its gradient op. Each gradient op reads its
+forward's inputs and the cotangent, nothing else of the forward.
 
 The scan is the chunked algorithm: inside a chunk the masked ``C B^T``
 product applied to ``dt x``; the state at each chunk's end by one product;
@@ -33,30 +35,28 @@ _fa = importlib.import_module(".pallas.flash_attention", __package__)
 _HI = jax.lax.Precision.HIGHEST
 
 
-@register_op(
-    "causal_conv1d",
-    inputs=[In("X"), In("W"), In("Bias", dispensable=True)],
-    outputs=[Out("Out")],
-    attrs={"activation": ""},
-)
-def _causal_conv1d(ins, attrs):
-    """Depthwise causal convolution along time: X [B, T, C], W [C, K]
-    (``W[:, K-1]`` weighs the current position), Bias [C];
-    ``out[t] = sum_k W[:, k] x[t - (K-1) + k] + Bias``, positions before
-    the sequence read as zero. ``activation`` "" or "silu". The K taps
-    accumulate in float32; Out has X's type."""
-    x, w = ins["X"], ins["W"].astype(jnp.float32)
-    T, K = x.shape[1], w.shape[1]
-    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
-    out = sum(xp[:, k:k + T, :] * w[:, k] for k in range(K))
-    if ins.get("Bias") is not None:
-        out = out + ins["Bias"].astype(jnp.float32)
-    act = attrs.get("activation", "")
-    if act == "silu":
-        out = jax.nn.silu(out)
-    elif act:
-        raise NotImplementedError("causal_conv1d activation %r" % act)
-    return {"Out": out.astype(x.dtype)}
+def _tap(x, s):
+    """x [B, T, C] read ``s`` positions earlier (later where s < 0), zero
+    outside the sequence: ``out[:, t] = x[:, t - s]``, in x's type. One
+    ``pad`` that XLA fuses into its reader: no padded copy is written."""
+    return jax.lax.pad(x, jnp.zeros((), x.dtype),
+                       ((0, 0, 0), (s, -s, 0), (0, 0, 0)))
+
+
+def _conv_taps(x, w, bias):
+    """float32 ``sum_k w[:, k] x[t - (K-1) + k] + bias``: the convolution
+    before its activation; w [C, K] float32, bias [C] or None."""
+    K = w.shape[1]
+    out = sum(_tap(x, K - 1 - k).astype(jnp.float32) * w[:, k]
+              for k in range(K))
+    return out if bias is None else out + bias.astype(jnp.float32)
+
+
+# ``causal_conv1d`` and its gradient op (registered first, so that no
+# automatic one is made) are at the end of the file: a Mosaic payload holds
+# its call stack's source lines, so lines added above the scan's call sites
+# would change its kernels' payloads and miss the compilation cache in
+# every cell that runs them.
 
 
 def _taps_sum(xp, w, T):
@@ -297,3 +297,73 @@ def _ssd_chunk_scan(ins, attrs):
     the softplus applied inside, in float32, so that the step sizes and
     decays never pass through the AMP type."""
     return {"Out": _scan(ins, attrs)}
+
+
+def _causal_conv1d(ins, attrs):
+    """Depthwise causal convolution along time: X [B, T, C], W [C, K]
+    (``W[:, K-1]`` weighs the current position), Bias [C];
+    ``out[t] = sum_k W[:, k] x[t - (K-1) + k] + Bias``, positions before
+    the sequence read as zero. ``activation`` "" or "silu". The K taps
+    accumulate in float32; Out has X's type."""
+    x = ins["X"]
+    out = _conv_taps(x, ins["W"].astype(jnp.float32), ins.get("Bias"))
+    act = attrs.get("activation", "")
+    if act == "silu":
+        out = jax.nn.silu(out)
+    elif act:
+        raise NotImplementedError("causal_conv1d activation %r" % act)
+    return {"Out": out.astype(x.dtype)}
+
+
+def _causal_conv1d_grad(ins, attrs):
+    """The gradients from the op's inputs alone: under ``silu`` the
+    pre-activation is made again and the cotangent taken through it; then
+    it runs back through the taps (the anti-causal convolution with the
+    taps reversed, positions past the end read as zero), and each tap's
+    gradient is the cotangent against the stream that tap read. All in
+    float32; each gradient goes out in its operand's type. Each trace
+    counts ``kernels.causal_conv1d_grad``."""
+    from .. import observability as _obs
+
+    if _obs.enabled():
+        _obs.inc("kernels.causal_conv1d_grad")
+    x, w = ins["X"], ins["W"].astype(jnp.float32)
+    bias = ins.get("Bias")
+    K = w.shape[1]
+    dy = ins["Out@GRAD"].astype(jnp.float32)
+    act = attrs.get("activation", "")
+    if act == "silu":
+        pre = _conv_taps(x, w, bias)
+        sig = jax.nn.sigmoid(pre)
+        dy = dy * (sig * (1 + pre * (1 - sig)))
+    elif act:
+        raise NotImplementedError("causal_conv1d activation %r" % act)
+    # out[t] reads x[t - (K-1) + k] through tap k: x[s] is read by
+    # out[s + (K-1) - k]
+    dx = sum(_tap(dy, k - (K - 1)) * w[:, k] for k in range(K))
+    dw = jnp.stack([jnp.sum(dy * _tap(x, K - 1 - k).astype(jnp.float32),
+                            axis=(0, 1)) for k in range(K)], axis=-1)
+    grads = {"X@GRAD": dx.astype(x.dtype),
+             "W@GRAD": dw.astype(ins["W"].dtype)}
+    if bias is not None:
+        grads["Bias@GRAD"] = jnp.sum(dy, axis=(0, 1)).astype(bias.dtype)
+    return grads
+
+
+# registered before its forward op, so that no auto-VJP grad op is made
+register_op(
+    "causal_conv1d_grad",
+    inputs=[In("X"), In("W"), In("Bias", dispensable=True),
+            In("Out@GRAD")],
+    outputs=[Out(n + "@GRAD", dispensable=True)
+             for n in ("X", "W", "Bias")],
+    attrs={"activation": ""},
+    grad=None,
+)(_causal_conv1d_grad)
+
+register_op(
+    "causal_conv1d",
+    inputs=[In("X"), In("W"), In("Bias", dispensable=True)],
+    outputs=[Out("Out")],
+    attrs={"activation": ""},
+)(_causal_conv1d)

@@ -63,17 +63,33 @@ def test_rms_norm(groups, gated):
     assert low.dtype == jnp.bfloat16 and rel(low, want) < 2e-2
 
 
-@pytest.mark.parametrize("act", ["", "silu"])
-def test_causal_conv1d_and_its_gradient(act):
-    k = keys(3, 1)
-    x = jax.random.normal(k[0], (2, 9, 6))
-    w = jax.random.normal(k[1], (6, 4))
-    b = jax.random.normal(k[2], (6,))
+@pytest.mark.parametrize("T", [9, 2])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("bias", [True, False], ids=["bias", "no_bias"])
+@pytest.mark.parametrize("act", ["", "silu"], ids=["linear", "silu"])
+def test_causal_conv1d_and_its_gradient(act, bias, dtype, T):
+    """The op and its gradient op (registered, not an automatic VJP; it
+    reads the forward's inputs and the cotangent) against the definition
+    position by position and ``jax.grad`` of it, in float32 on the
+    operands as they arrive; T = 2 is shorter than the K = 4 taps."""
+    from paddle_tpu import observability as obs
+    from paddle_tpu.core import registry
 
-    def plain(x, w, b):
+    assert "causal_conv1d_grad" not in registry._AUTO_VJP_TYPES
+    assert [s.name for s in OpInfoMap.instance().get(
+        "causal_conv1d_grad").inputs] == ["X", "W", "Bias", "Out@GRAD"]
+    dtype = jnp.dtype(dtype)
+    k = keys(4, 1)
+    x = jax.random.normal(k[0], (2, T, 6)).astype(dtype)
+    w = jax.random.normal(k[1], (6, 4)).astype(dtype)
+    b = jax.random.normal(k[2], (6,)).astype(dtype) if bias else None
+    g = jax.random.normal(k[3], x.shape).astype(dtype)
+    operands = (x, w) + ((b,) if bias else ())
+
+    def plain(x, w, b=None):
         rows = []
-        for t in range(x.shape[1]):
-            acc = b
+        for t in range(T):
+            acc = jnp.zeros((2, 6)) if b is None else b
             for j in range(4):
                 if t - 3 + j >= 0:
                     acc = acc + w[:, j] * x[:, t - 3 + j]
@@ -81,15 +97,124 @@ def test_causal_conv1d_and_its_gradient(act):
         y = jnp.stack(rows, 1)
         return jax.nn.silu(y) if act else y
 
-    fn = op("causal_conv1d")
-    got = fn({"X": x, "W": w, "Bias": b}, {"activation": act})["Out"]
-    np.testing.assert_allclose(got, plain(x, w, b), rtol=1e-5, atol=1e-6)
-    g = jax.random.normal(keys(1, 2)[0], got.shape)
-    grads = op("causal_conv1d_grad")(
-        {"X": x, "W": w, "Bias": b, "Out@GRAD": g}, {"activation": act})
-    want = jax.grad(lambda *a: jnp.sum(plain(*a) * g), (0, 1, 2))(x, w, b)
+    # the forward at float32 to the limits it has always had; the
+    # gradients, sums over positions, to ten times those
+    fwd_tol, tol = (dict(rtol=1e-5, atol=1e-6), dict(rtol=1e-4, atol=1e-5)) \
+        if dtype == jnp.float32 else (dict(rtol=2e-2, atol=2e-2),) * 2
+    f32 = [a.astype(jnp.float32) for a in operands]
+    ins = dict(zip(("X", "W", "Bias"), operands))
+    attrs = {"activation": act}
+    got = op("causal_conv1d")(ins, attrs)["Out"]
+    assert got.dtype == dtype
+    np.testing.assert_allclose(got.astype(jnp.float32), plain(*f32),
+                               **fwd_tol)
+    want = jax.grad(lambda *a: jnp.sum(plain(*a) * g.astype(jnp.float32)),
+                    tuple(range(len(f32))))(*f32)
+    was_on = obs.enabled()
+    obs.enable()
+    try:
+        before = obs.counter_value("kernels.causal_conv1d_grad") or 0
+        grads = op("causal_conv1d_grad")(dict(ins, **{"Out@GRAD": g}), attrs)
+        assert obs.counter_value("kernels.causal_conv1d_grad") == before + 1
+    finally:
+        if not was_on:
+            obs.disable()
+    assert sorted(grads) == sorted(n + "@GRAD" for n in ins)
     for name, ref in zip(("X@GRAD", "W@GRAD", "Bias@GRAD"), want):
-        np.testing.assert_allclose(grads[name], ref, rtol=1e-4, atol=1e-5)
+        assert grads[name].dtype == dtype
+        np.testing.assert_allclose(grads[name].astype(jnp.float32), ref,
+                                   **tol)
+
+
+@pytest.mark.parametrize("jit", [False, True], ids=["eager", "jit"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("bias", [True, False], ids=["bias", "no_bias"])
+@pytest.mark.parametrize("act", ["", "silu"], ids=["linear", "silu"])
+def test_causal_conv1d_is_the_padded_copy_and_slices_to_the_bit(
+        act, bias, dtype, jit):
+    """The forward reads its taps through ``_tap`` (a ``pad`` of X in X's
+    own type that XLA fuses into the reader), where until PR 47 it made a
+    float32 padded copy and sliced it. Same products, summed in the same
+    order: op by op the results are equal bit for bit, T = 2 < K = 4
+    included. Under ``jit`` XLA's CPU backend contracts the multiply-adds
+    of a fusion, and the two forms fuse differently, so float32 results
+    may differ in the last bit there (the old form's jitted result differs
+    from its own eager one just so): held to the forward's limits."""
+    dtype = jnp.dtype(dtype)
+    attrs = {"activation": act}
+
+    def padded_and_sliced(x, w, b=None):
+        w = w.astype(jnp.float32)
+        T, K = x.shape[1], w.shape[1]
+        xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
+        out = sum(xp[:, k:k + T, :] * w[:, k] for k in range(K))
+        if b is not None:
+            out = out + b.astype(jnp.float32)
+        return (jax.nn.silu(out) if act else out).astype(x.dtype)
+
+    def the_op(x, w, b=None):
+        return op("causal_conv1d")({"X": x, "W": w, "Bias": b}, attrs)["Out"]
+
+    for T in (9, 2):
+        k = keys(3, 10 + T)
+        x = jax.random.normal(k[0], (2, T, 6)).astype(dtype)
+        w = jax.random.normal(k[1], (6, 4)).astype(dtype)
+        b = (jax.random.normal(k[2], (6,)).astype(dtype),) if bias else ()
+        fns = [jax.jit(f) if jit else f for f in (the_op, padded_and_sliced)]
+        got, want = (f(x, w, *b) for f in fns)
+        assert got.dtype == want.dtype == dtype
+        got, want = (np.asarray(a.astype(jnp.float32)) for a in (got, want))
+        if jit and dtype == jnp.float32:
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+        else:
+            np.testing.assert_array_equal(got, want)
+
+
+def test_causal_conv1d_grad_takes_silu_and_nothing_else():
+    """Both ops refuse an activation that is not theirs: a gradient op built
+    by hand does not take silu's derivative for another function's."""
+    x, w = jnp.ones((1, 5, 3)), jnp.ones((3, 4))
+    for name, more in (("causal_conv1d", {}),
+                       ("causal_conv1d_grad", {"Out@GRAD": x})):
+        with pytest.raises(NotImplementedError, match="gelu"):
+            op(name)(dict({"X": x, "W": w}, **more), {"activation": "gelu"})
+
+
+def test_the_benchmarks_reader_of_the_convolutions(monkeypatch, capsys):
+    """``conv1d.taps_ms``: the union a step of the operations scoped
+    ``causal_conv1d`` (forward, and emitted again inside the backward) or
+    ``causal_conv1d_grad``, whatever mixer's name scope they carry; a
+    program without the op reads nothing."""
+    from benchmarks.layer_metrics import _scoped as S
+    from benchmarks.layer_metrics import conv1d
+
+    ms, jit = 1_000_000, "jit(step_s1)/jit(main)/"
+    names = {"fwd": jit + "forward/causal_conv1d/kda/mul",
+             "again": jit + "backward/causal_conv1d/add",
+             "grad": jit + "backward/causal_conv1d_grad/kda/reduce_sum",
+             "gate": jit + "backward/short_conv_gate_grad/shortconv/mul",
+             "fc": jit + "forward/mul/kda/dot_general"}
+    for name, mine in (("fwd", True), ("again", True), ("grad", True),
+                       ("gate", False), ("fc", False)):
+        assert conv1d.is_taps("%f", names[name]) == mine, name
+    assert not conv1d.is_taps("%f", "")
+    steps = [(0, 100 * ms), (100 * ms, 200 * ms)]
+    events = [("fwd", 0, 2 * ms), ("fc", 2 * ms, 30 * ms),
+              ("again", 40 * ms, 42 * ms), ("grad", 41 * ms, 46 * ms),
+              ("gate", 46 * ms, 50 * ms),
+              ("fwd", 100 * ms, 103 * ms), ("again", 150 * ms, 152 * ms),
+              ("grad", 152 * ms, 157 * ms)]
+    monkeypatch.setattr(S, "load", lambda: ("/x", steps, events, names))
+    assert conv1d.read({"suffix": "tokens"}) == {
+        "conv1d.taps_ms.tokens": pytest.approx(9.0)}
+    line = capsys.readouterr().out
+    assert "backward/causal_conv1d 2.0000" in line \
+        and "backward/causal_conv1d_grad 5.0000" in line
+    others = [e for e in events if e[0] in ("gate", "fc")]
+    monkeypatch.setattr(S, "load", lambda: ("/x", steps, others, names))
+    assert conv1d.read({"suffix": "tokens"}) == {}
+    monkeypatch.setattr(S, "load", lambda: None)
+    assert conv1d.read({"suffix": "tokens"}) == {}
 
 
 # -- the selective scan -------------------------------------------------------
